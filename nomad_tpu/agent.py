@@ -40,7 +40,8 @@ class Agent:
                  slo: Optional[Dict[str, float]] = None,
                  profile_hz: Optional[float] = None,
                  worker_mode: str = "thread",
-                 follow: str = "") -> None:
+                 follow: str = "",
+                 mesh=None) -> None:
         # producer-side log gate (agent_config log_level): records below
         # this level never reach the ring or its subscribers.  Only set
         # when explicitly configured — the process-wide ring default
@@ -66,6 +67,9 @@ class Agent:
                 "this process already has a cluster encrypt key installed; "
                 "in-process agents must share it (pass the same encrypt "
                 "value, or reset deliberately with wire.set_key(None))")
+        # `mesh` is Server's keyword (None = shard the node axis over
+        # every visible device, False = single device): an embedding
+        # argument for A/B drivers, deliberately not an agent_config key
         if not server_enabled:
             raise NotImplementedError(
                 "client-only agents need a remote RPC transport; "
@@ -130,7 +134,8 @@ class Agent:
                 acl_enabled=acl_enabled,
                 transport=self.transport, clock=self.clock,
                 device_executor=device_executor, slo=slo,
-                profile_hz=profile_hz, worker_mode=worker_mode)
+                profile_hz=profile_hz, worker_mode=worker_mode,
+                mesh=mesh)
         else:
             self.transport = resolve_transport(transport, node_name="agent",
                                                clock=self.clock)
@@ -139,7 +144,7 @@ class Agent:
                                  acl_enabled=acl_enabled, clock=self.clock,
                                  device_executor=device_executor,
                                  slo=slo, profile_hz=profile_hz,
-                                 worker_mode=worker_mode)
+                                 worker_mode=worker_mode, mesh=mesh)
         self.clients: List[Client] = []
         if client_enabled:
             if cluster_mode:

@@ -205,7 +205,8 @@ class FlightRecorder:
 # threshold set negative disables its rule.
 DEFAULT_SLO = {
     # rolling-window p99 of plan enqueue->apply-start wait (the north
-    # star's latency metric; BENCH_r05 measured 0.99ms at full scale)
+    # star's latency metric; an earlier installation's chip run measured
+    # 0.99 ms at full scale, today's chip: not measured)
     "p99_plan_queue_ms": 500.0,
     # refuted plans / committed plans over the check interval (measured
     # 0.0 with partitioned workers; sustained refutes mean the fence or

@@ -106,8 +106,11 @@ BUCKETS = ("device-wait", "lock-wait", "gil-wait", "queue-wait",
 
 # stack-frame classification tables (checked against the co_name and
 # filename of sampled frames, innermost first)
+# `_fetch` is PlacementEngine._fetch: jax.Array converts to numpy inside
+# C++ (no jax Python frame exists to match), so the innermost Python
+# frame of a blocking device->host fetch is its caller
 _DEVICE_WAIT_FUNCS = frozenset((
-    "block_until_ready", "_single_device_array_to_np_array", "fetch",
+    "block_until_ready", "_fetch", "fetch",
 ))
 _LOCK_WAIT_FUNCS = frozenset((
     "acquire", "_wait_for_tstate_lock", "__enter__",

@@ -129,7 +129,7 @@ class Worker:
             try:
                 self.run_once(timeout=0.1)
             except Exception as exc:  # noqa: BLE001 - keep the loop alive
-                log("worker", "warn", "worker iteration failed",
+                log("worker", "error", "worker iteration failed",
                     worker=self.id, error=repr(exc))
 
     # ------------------------------------------------------------- steps
@@ -291,8 +291,11 @@ class Worker:
                     and isinstance(sched, GenericScheduler)):
                 try:
                     prep = sched.prepare_batch(ev)
-                except Exception:  # noqa: BLE001 - fall back to solo
-                    prep = None
+                except Exception as e:  # noqa: BLE001 - nack this eval:
+                    # "not batchable" is prepare_batch returning None;
+                    # a raise is a failure, not a reason to go solo
+                    work.append((ev, token, None, e))
+                    continue
             work.append((ev, token, sched, prep))
 
         # phase 2: ONE device dispatch for all eligible placement blocks
@@ -327,20 +330,21 @@ class Worker:
             # wave pipeline's output bit-identical to serial processing
             seeds = [(zlib.crc32(w[0].id.encode()) & 0xFFFFFFFF) or 1
                      for _, w in prepared]
-            try:
-                pending = self.pipeline.dispatch(
-                    snapshot, items, seed=seeds, used0_dev=used_dev)
-                prepared_idx = [i for i, _ in prepared]
-                # the batch now heads into a device wait that may include
-                # a first-time compile: restart the delivery deadlines so
-                # the broker doesn't redeliver mid-launch
-                self.server.eval_broker.extend_outstanding(
-                    [(ev.id, token) for ev, token in batch],
-                    now=self.server.clock.time())
-            except Exception as e:  # noqa: BLE001 - solo fallback
-                log("worker", "warn", "batch launch failed; going solo",
-                    worker=self.id, error=str(e))
-                pending = None
+            # no solo fallback for a FAILED launch (a compile the device
+            # refuses, an HBM limit, a lowering bug): the solo path is
+            # for evals that are not batchable, and quietly finishing
+            # the batch there would hide that the device path is down.
+            # The raise reaches run_batch (or the prefetch site), which
+            # logs it at error and nacks the batch.
+            pending = self.pipeline.dispatch(
+                snapshot, items, seed=seeds, used0_dev=used_dev)
+            prepared_idx = [i for i, _ in prepared]
+            # the batch now heads into a device wait that may include
+            # a first-time compile: restart the delivery deadlines so
+            # the broker doesn't redeliver mid-launch
+            self.server.eval_broker.extend_outstanding(
+                [(ev.id, token) for ev, token in batch],
+                now=self.server.clock.time())
         elif chain is not None:
             # a prefetch-handed chain this batch cannot ride (fewer than
             # two coupled evals): park it back for a later coupled batch
@@ -400,7 +404,7 @@ class Worker:
                     self._prefetch = self._start_batch(
                         nxt, t, chain=(batch_id, batch_seq0, chain_used))
                 except Exception as e:  # noqa: BLE001 - hand them back
-                    log("worker", "warn", "prefetch dispatch failed",
+                    log("worker", "error", "prefetch dispatch failed",
                         worker=self.id, error=repr(e))
                     for ev, token in nxt:
                         self.server.eval_broker.nack(ev.id, token, now=t)
@@ -490,7 +494,7 @@ class Worker:
         for i in [i for i in range(len(work)) if i not in bds]:
             ev, token, sched, prep = work[i]
             if sched is None:
-                self._settle(ev, token, prep, t)      # factory error
+                self._settle(ev, token, prep, t)      # factory/prepare error
                 settled.add(ev.id)
                 continue
             try:

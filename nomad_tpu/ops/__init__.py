@@ -1,5 +1,9 @@
 """Device kernels and the host↔device placement engine."""
 
+from .compile_cache import place_compile_cache
+
+place_compile_cache()      # before any kernel below can compile
+
 from .engine import PlacementDecision, PlacementEngine, PlacementRequest  # noqa: F401
 from .executor import (  # noqa: F401
     DeviceExecutor,

@@ -451,13 +451,12 @@ class BridgeExecutor(DeviceExecutor):
                 "device_executor 'bridge' drives a single PJRT device; "
                 "this engine shards over a mesh — use 'jax'")
         from nomad_tpu.native import bridge as nb
-        plugin = plugin or nb.DEFAULT_PLUGIN
         if not nb.bridge_available(plugin):
             raise ExecutorUnavailable(
                 "device_executor 'bridge' requires the native bridge "
-                f"build and a PJRT plugin at {plugin} (build with "
-                "`make -C native`); falling back is not automatic — "
-                "configure device_executor = \"jax\" instead")
+                "build (`make -C native`) and an explicit PJRT plugin "
+                f"path (got {plugin!r}); falling back is not automatic "
+                "— configure device_executor = \"jax\" instead")
         super().__init__(engine, chain_enabled=chain_enabled)
         self._bridge = nb.PjrtBridge(plugin)
         # the engine's collect path materializes bridge result buffers

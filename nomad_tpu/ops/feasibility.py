@@ -29,32 +29,37 @@ from nomad_tpu.pack.packer import (
 def constraint_mask(attrs: jnp.ndarray,      # [N, A] int32
                     con: jnp.ndarray,        # [G, C, 3] int32 (col, op, arg)
                     luts: jnp.ndarray,       # [L, V] bool
+                    xp=jnp,
                     ) -> jnp.ndarray:        # [G, N] bool
-    """Evaluate every packed constraint row against every node."""
+    """Evaluate every packed constraint row against every node.
+
+    `xp` is the array module: `jnp` inside the kernels, `numpy` for the
+    engine's host-side candidate frames — one body, so the two cannot
+    drift, and the host copy needs no JAX backend at all."""
     cols = con[..., 0]                       # [G, C]
     ops = con[..., 1][..., None]             # [G, C, 1]
     args = con[..., 2]                       # [G, C]
 
     av = attrs[:, cols]                      # [N, G, C]
-    av = jnp.moveaxis(av, 0, -1)             # [G, C, N]
+    av = xp.moveaxis(av, 0, -1)              # [G, C, N]
     is_set = av != UNSET
 
     arg_b = args[..., None]                  # [G, C, 1]
-    lut_rows = jnp.clip(args, 0, luts.shape[0] - 1)
-    av_clip = jnp.clip(av, 0, luts.shape[1] - 1)
+    lut_rows = xp.clip(args, 0, luts.shape[0] - 1)
+    av_clip = xp.clip(av, 0, luts.shape[1] - 1)
     lut_val = luts[lut_rows[..., None], av_clip]   # [G, C, N]
 
-    res = jnp.where(
+    res = xp.where(
         ops == DOP_EQ, is_set & (av == arg_b),
-        jnp.where(
+        xp.where(
             ops == DOP_NEQ, (~is_set) | (av != arg_b),
-            jnp.where(
+            xp.where(
                 ops == DOP_IS_SET, is_set,
-                jnp.where(
+                xp.where(
                     ops == DOP_IS_NOT_SET, ~is_set,
-                    jnp.where(ops == DOP_LUT, is_set & lut_val,
-                              jnp.ones_like(is_set))))))
-    return jnp.all(res, axis=1)              # [G, N]
+                    xp.where(ops == DOP_LUT, is_set & lut_val,
+                             xp.ones_like(is_set))))))
+    return xp.all(res, axis=1)               # [G, N]
 
 
 def feasible_mask(attrs: jnp.ndarray,        # [N, A]

@@ -22,32 +22,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
-
-# jax.shard_map graduated from jax.experimental in newer releases (and
-# renamed check_rep -> check_vma); the container's baked-in jax may
-# predate the move — resolve once here so every sharded kernel builder
-# works on both vintages
-try:
-    shard_map = jax.shard_map
-except AttributeError:                      # pragma: no cover - old jax
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
-_pcast = getattr(jax.lax, "pcast", None)
-
-
-def pcast_varying(x, axes):
-    """`jax.lax.pcast(x, axes, to="varying")` where available; identity
-    on jax vintages without it — every shard_map here runs with varying
-    -manifestation checks off (check_vma/check_rep False), so the cast
-    is purely a tracker annotation and safe to skip."""
-    if _pcast is None:
-        return x
-    return _pcast(x, axes, to="varying")
 
 from nomad_tpu.ops.feasibility import constraint_mask
 from nomad_tpu.ops.scoring import affinity_score
@@ -176,8 +153,8 @@ def _place_local(inp: PlacementInputs) -> PlacementOutputs:
     # replicated carries become device-varying once updated with values
     # derived from collectives; pcast the initial values to match
     carry0 = (inp.used0, inp.job_count0,
-              pcast_varying(inp.sp_counts0, (AXIS,)),
-              pcast_varying(inp.pd_counts0, (AXIS,)))
+              pcast(inp.sp_counts0, (AXIS,), to="varying"),
+              pcast(inp.pd_counts0, (AXIS,), to="varying"))
     (used, job_count, _, _), outs = jax.lax.scan(
         step, carry0, (inp.tg_idx, inp.prev_row, inp.active))
     return PlacementOutputs(

@@ -1,0 +1,92 @@
+"""chip_smoke.py on the CPU: the explicit dry run passes and reports the
+contract's fields, the default refuses a CPU backend, the compile cache
+lands where it was placed, and the HTTP-only CLI stays off JAX."""
+
+import json
+import os
+import subprocess
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+
+def _last_json(out: str) -> dict:
+    return json.loads(out.strip().splitlines()[-1])
+
+
+def test_cpu_dry_run_passes_and_reports(capsys):
+    import jax
+
+    assert chip_smoke.main(["--cpu-dry-run", "--seed", "3"]) == 0
+    rep = _last_json(capsys.readouterr().out)
+    assert rep["ok"] is True and "failures" not in rep
+    assert rep["device"] == {"platform": "cpu",
+                             "kind": jax.devices()[0].device_kind,
+                             "count": jax.device_count()}
+    assert rep["sizes"] == chip_smoke.TINY and rep["seed"] == 3
+    leg = rep["legs"]["served"]
+    # conftest's 8 virtual devices: the default path is the sharded one
+    assert leg["mesh_devices"] == jax.device_count()
+    n_batch = chip_smoke.TINY["jobs"] + 2 * chip_smoke.ZONES
+    assert leg["evals"] == {"complete": n_batch + 1}
+    assert leg["placed"] == leg["asked"] == (
+        n_batch * chip_smoke.TINY["per_job"]
+        + chip_smoke.TINY["spread_count"])
+    assert leg["executor"]["dispatches"] >= 2
+    assert leg["executor"]["resident_waves"] >= 1
+    assert leg["executor"]["upload_bytes_by_cause"][
+        "invalidation-replay"] > 0
+    assert leg["workers"][0]["nacked"] == 0
+    assert leg["node_tensor_platforms"] == ["cpu"]
+    assert set(leg["smoke_observations"]["phase_wall_s"]) == {
+        "fleet", "register", "schedule", "readback", "check"}
+    sites = rep["smoke_observations"]["first_launch_s"]
+    assert any(s.startswith("engine.multi_compact/") for s in sites)
+    assert any(s.startswith("engine.multi_compact_chained/")
+               for s in sites)
+    assert set(rep["compile_cache"]) == {"dir", "entries_before",
+                                         "entries_after"}
+
+
+def test_default_refuses_a_cpu_backend(capsys):
+    assert chip_smoke.main([]) != 0
+    out = capsys.readouterr().out
+    assert "platform=cpu" in out
+    assert not any(line.startswith("{") for line in out.splitlines())
+
+
+def _run(code: str, **env) -> str:
+    full = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    full.update(JAX_PLATFORMS="cpu", **env)
+    return subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, env=full, check=True,
+        capture_output=True, text=True, timeout=120).stdout.strip()
+
+
+_CACHE_DIR = ("import nomad_tpu.ops, jax; "
+              "print(jax.config.jax_compilation_cache_dir)")
+
+
+def test_compile_cache_placed_by_environment():
+    assert _run(_CACHE_DIR, JAX_COMPILATION_CACHE_DIR="/x") == "/x"
+
+
+def test_compile_cache_defaults_to_checkout():
+    assert _run(_CACHE_DIR) == os.path.join(REPO, ".jax_cache")
+
+
+def test_http_only_cli_never_imports_jax():
+    """`job run|status` run beside a live agent that holds the chip:
+    drive both as far as the (refused) connection."""
+    out = _run(
+        "import sys\n"
+        "from nomad_tpu.cli import main\n"
+        "for argv in (['job', 'status', 'web'],\n"
+        "             ['job', 'run', 'examples/web.hcl']):\n"
+        "    assert main(['-address', 'http://127.0.0.1:9'] + argv) == 1\n"
+        "print('jax' in sys.modules)\n")
+    assert out == "False"

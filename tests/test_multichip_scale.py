@@ -7,7 +7,7 @@ zone-pinned CSI volumes) shrunk to a soak-sized placement count.  Two
 gauges, two sources:
 
   - per-STORAGE-zone nodes-used balance (bench.py's
-    quality_zone_balance_max_over_min; 1.0 at 50k in BENCH_r05) must
+    quality_zone_balance_max_over_min; 1.0 at 50k on the bench) must
     stay <= 1.05 at 200k — density never collapses a volume zone;
   - the live state-store aggregates behind
     nomad.quality.{zone_balance_max_over_min,binpack_fill} (PR 5, zone
@@ -131,7 +131,7 @@ def test_quality_gauges_hold_at_200k_sharded():
     q_200k, znb_200k = _run_workload(200_000)    # the scaled run
 
     # density never collapses a volume zone, at either scale (the 50k
-    # bench envelope: 1.0 in BENCH_r05; <= 1.05 is the ISSUE 7 gate)
+    # bench envelope is 1.0; <= 1.05 is the ISSUE 7 gate)
     assert znb_50k <= 1.05, znb_50k
     assert znb_200k <= 1.05, znb_200k
 

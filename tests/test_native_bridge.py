@@ -3,27 +3,34 @@ non-Python worker uses to run the placement kernels on TPU (SURVEY §7 P6).
 
 Export the bulk placement kernel as StableHLO, compile + execute it through
 the C++ bridge against the PJRT plugin, and check the resulting packed
-buffer against the in-process JAX (CPU) reference."""
+buffer against the in-process JAX (CPU) reference.
+
+The bridge has no default plug-in (it opens its own PJRT client, which a
+chip already held through JAX refuses): these tests run only when
+NOMAD_TPU_PJRT_PLUGIN names a plug-in library, and skip otherwise."""
+
+import os
 
 import numpy as np
 import pytest
 
 from nomad_tpu.native.bridge import (
-    DEFAULT_PLUGIN,
     bridge_available,
     compile_options_bytes,
     export_stablehlo,
 )
 
+PLUGIN = os.environ.get("NOMAD_TPU_PJRT_PLUGIN")
+
 pytestmark = pytest.mark.skipif(
-    not bridge_available(),
-    reason="PJRT plugin or native toolchain unavailable")
+    not bridge_available(PLUGIN),
+    reason="no PJRT plugin named, or native toolchain unavailable")
 
 
 @pytest.fixture(scope="module")
 def bridge():
     from nomad_tpu.native.bridge import PjrtBridge
-    br = PjrtBridge(DEFAULT_PLUGIN)
+    br = PjrtBridge(PLUGIN)
     yield br
     br.close()
 

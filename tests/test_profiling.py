@@ -45,6 +45,46 @@ def test_classify_device_wait_by_func_name():
     assert classify_stack(_frame_named("fetch")) == "device-wait"
 
 
+def test_classify_real_engine_fetch_is_device_wait():
+    """Sample a thread blocked in a REAL device->host fetch: jax.Array
+    converts in C++, so the frame the table must know is the engine's
+    own `_fetch` (a jax-internal name here goes stale silently and
+    books every fetch as host time)."""
+    import jax
+    import jax.numpy as jnp
+
+    from nomad_tpu.ops import PlacementEngine
+
+    @jax.jit
+    def slow(x):
+        return jax.lax.fori_loop(
+            0, 40, lambda i, a: jnp.sin(a) @ a.T * 1e-3 + a, x)
+
+    x = jnp.ones((600, 600))
+    slow(x).block_until_ready()            # compile outside the samples
+    eng = PlacementEngine(mesh=False)
+    done = threading.Event()
+
+    def fetcher():
+        try:
+            for _ in range(5):
+                eng._fetch(slow(x))
+        finally:
+            done.set()
+
+    t = threading.Thread(target=fetcher, name="worker-fetch", daemon=True)
+    t.start()
+    seen = set()
+    while not done.is_set():
+        frame = sys._current_frames().get(t.ident)
+        if frame is not None:
+            seen.add(classify_stack(frame))
+        time.sleep(0.001)
+    t.join(5.0)
+    assert not t.is_alive()
+    assert "device-wait" in seen, seen
+
+
 def test_classify_wire_and_idle_by_filename():
     ns = {}
     exec(compile("import sys\nf = sys._getframe()",
