@@ -23,8 +23,11 @@ so wave composition, kernel shapes and the per-eval tie-break seeds
 second run can then be shown to hit the compile cache, and the mesh-on
 and mesh-off legs can be compared node for node.
 
-The last line of stdout is one JSON object; exit code 0 iff every phase
-passed.  Wall times in it are smoke observations, not measurements.
+Stdout ends with two JSON lines: the full report (sizes, counters,
+compile cache, smoke observations), then the verdict, exactly
+{"ok": ..., "device": {"platform", "kind", "count"}}.  Exit code 0 iff
+every phase passed.  Wall times in the report are smoke observations,
+not measurements.
 """
 
 from __future__ import annotations
@@ -443,6 +446,8 @@ def main(argv=None) -> int:
         for f in failures:
             print(f"FAILED {f}", file=sys.stderr)
     print(json.dumps(report), flush=True)
+    # the verdict: these keys and no others, as the last line
+    print(json.dumps({"ok": report["ok"], "device": device}), flush=True)
     return 0 if not failures else 1
 
 

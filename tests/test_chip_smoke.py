@@ -13,15 +13,20 @@ sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402
 
 
-def _last_json(out: str) -> dict:
-    return json.loads(out.strip().splitlines()[-1])
+def _report_and_verdict(out: str) -> tuple:
+    """The last two stdout lines: the full report, then the verdict."""
+    report, verdict = out.strip().splitlines()[-2:]
+    return json.loads(report), json.loads(verdict)
 
 
 def test_cpu_dry_run_passes_and_reports(capsys):
     import jax
 
     assert chip_smoke.main(["--cpu-dry-run", "--seed", "3"]) == 0
-    rep = _last_json(capsys.readouterr().out)
+    rep, verdict = _report_and_verdict(capsys.readouterr().out)
+    # the last line carries exactly the contract's keys
+    assert verdict == {"ok": True, "device": rep["device"]}
+    assert list(verdict["device"]) == ["platform", "kind", "count"]
     assert rep["ok"] is True and "failures" not in rep
     assert rep["device"] == {"platform": "cpu",
                              "kind": jax.devices()[0].device_kind,
