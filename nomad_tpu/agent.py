@@ -321,7 +321,8 @@ class Agent:
             "nomad.state.jobs": counts["jobs"],
         }
         # wavepipe per-stage wall totals + the overlap gauges that prove
-        # host commit hides under device compute (core/wavepipe.py)
+        # host commit runs under an in-flight launch (core/wavepipe.py;
+        # the device's own busy time is the profiler trace's to say)
         timers = getattr(s, "stage_timers", None)
         if timers is not None:
             rep = timers.report()

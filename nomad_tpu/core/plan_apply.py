@@ -202,8 +202,9 @@ class PlanApplier:
         # check at plan submission)
         self.token_check = None
         # optional wavepipe.StageTimers (wired by the Server): each
-        # apply records one "commit" interval so the pipeline's overlap
-        # of host commit under device compute is measurable
+        # apply records one "commit" interval, and the state store's
+        # write inside it one "store_upsert", so the overlap of host
+        # commit with an in-flight launch is measurable
         self.timers = None
         # optional DeviceExecutor (wired by the Server): every committed
         # plan reports its origin so a resident usage chain the commit
@@ -278,12 +279,8 @@ class PlanApplier:
                               parent=span_id(plan.trace_id,
                                              "worker.schedule"),
                               eval_id=plan.eval_id)
-        with trace_scope(plan.trace_id):
-            if self.timers is not None:
-                with self.timers.time("commit"):
-                    self._apply_one(pending)
-            else:
-                self._apply_one(pending)
+        with trace_scope(plan.trace_id), self._stage("commit"):
+            self._apply_one(pending)
         t1 = self.clock.monotonic()
         REGISTRY.observe("nomad.plan.apply_s", t1 - t0)
         refuted = (len(pending.result.refuted_nodes)
@@ -300,6 +297,11 @@ class PlanApplier:
                           error=type(pending.error).__name__
                           if pending.error is not None else "",
                           refuted=refuted)
+
+    def _stage(self, name: str):
+        """One per-plan stage interval (no wave), where timers are wired."""
+        return (self.timers.time(name) if self.timers is not None
+                else _NULL_GUARD)
 
     def _apply_one(self, pending: PendingPlan) -> None:
         plan = pending.plan
@@ -332,17 +334,19 @@ class PlanApplier:
             result = self.evaluate_plan(plan, skip_fit=fast,
                                         fenced_first=fenced_first)
             self._stamp_trace(plan, result)
-            idx = self.state.upsert_plan_results(
-                plan, result,
-                expected_nodes=(touched, seq0, bid,
-                                getattr(result, "volume_seq", None))
-                if fast else None)
+            with self._stage("store_upsert"):
+                idx = self.state.upsert_plan_results(
+                    plan, result,
+                    expected_nodes=(touched, seq0, bid,
+                                    getattr(result, "volume_seq", None))
+                    if fast else None)
             if idx == -1:
                 # a foreign write landed on one of the plan's nodes between
                 # the fence read and the commit: redo with the full check
                 result = self.evaluate_plan(plan, skip_fit=False)
                 self._stamp_trace(plan, result)
-                self.state.upsert_plan_results(plan, result)
+                with self._stage("store_upsert"):
+                    self.state.upsert_plan_results(plan, result)
             self.stats.inc("plans")
             if self.executor is not None:
                 # chain-coupled plans carry their chain id; solo plans

@@ -121,9 +121,11 @@ class Server:
         self.plan_applier.clock = self.clock
         self.state.clock = self.clock
         # shared per-stage wall-interval timers (core/wavepipe.py): the
-        # workers' WavePipelines record dispatch/device/d2h/materialize,
-        # the applier records commit — one clock, so the device↔commit
-        # overlap is measurable (exported via /v1/metrics, bench.py)
+        # workers and their WavePipelines record the stages of a pass,
+        # the engine solo_place, the applier commit and store_upsert —
+        # one clock, so the in-flight-launch↔commit overlap is measurable
+        # (exported via /v1/metrics, bench.py) and every interval is a
+        # `nomad.<stage>` span in a profiler trace
         self.stage_timers = StageTimers()
         self.plan_applier.timers = self.stage_timers
         # stale-delivery gate: a worker that held evals past the
@@ -149,6 +151,7 @@ class Server:
         # (the bench's sharded-vs-single A/B and the sharded parity
         # suite both need the explicit override)
         self.engine = PlacementEngine(mesh=mesh)
+        self.engine.timers = self.stage_timers
         self.engine.packer.attach(self.state)
         # pluggable device executor (ops/executor.py, agent_config
         # server.device_executor): the seam the workers' wave pipelines

@@ -11,16 +11,20 @@ device's critical path:
   - `WavePipeline.dispatch` launches wave k+1's kernel (JAX async
     dispatch, optionally chained on wave k's device-resident proposed
     usage — see `ops.engine.dispatch_batch`) BEFORE wave k's host phase
-    runs, so the ~0.15s of materialize+commit hides under device compute
-    and the result fetch is paid concurrently, not serially.  Chained
-    launches donate the dead usage-chain buffer
+    runs, so the ~0.15s of materialize+commit runs under an in-flight
+    launch and the result fetch is paid concurrently, not serially.
+    Chained launches donate the dead usage-chain buffer
     (`ops.select.place_multi_chained`).
-  - `StageTimers` records per-stage WALL INTERVALS (dispatch / device /
-    d2h / materialize / commit), not just totals, so the overlap is
-    PROVABLE: `overlap("device", "commit") > 0` means commit time was
-    hidden under device time, and tests can assert wave k+1's dispatch
-    started before wave k's commit completed.  Exported via /v1/metrics
-    (agent.metrics) and printed by bench.py.
+  - `StageTimers` records per-stage WALL INTERVALS (the STAGES below),
+    not just totals, so the overlap is PROVABLE: `overlap("device",
+    "commit") > 0` means commit time ran under an IN-FLIGHT launch (the
+    `device` interval starts where dispatch returned and ends at the
+    collect, so it is no measure of device compute: on the TPU v5e the
+    device idles through 98 % of it, PERF.md), and tests can assert
+    wave k+1's dispatch started before wave k's commit completed.
+    Every interval a thread works through also lands in the profiler's
+    trace as a `nomad.<stage>` span, beside the device's events.
+    Exported via /v1/metrics (agent.metrics) and printed by bench.py.
   - Refute-repair: when the serialized applier refutes rows of an
     already-dispatched wave (a foreign write invalidated a node), the
     worker reports the refuted nodes here; the NEXT chained dispatch
@@ -42,7 +46,6 @@ import itertools
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Tuple
 
@@ -50,11 +53,40 @@ from nomad_tpu.core import profiling
 from nomad_tpu.core.flightrec import FLIGHT
 from nomad_tpu.core.telemetry import REGISTRY
 
-# stage names, in pipeline order.  "device" = kernel execution after the
-# dispatch returns (async); "d2h" = result fetch + host-side expansion;
-# "materialize" = plan construction from picks; "commit" = the applier's
-# evaluate + state-store upsert.
-STAGES = ("dispatch", "device", "d2h", "materialize", "commit")
+# stage names, in pipeline order.  Every stage but "device" is the wall
+# of work (or of a wait) on ONE thread, recorded through
+# `StageTimers.time`, which also emits it to the profiler as
+# `nomad.<stage>`:
+#   pass         worker: one batch, from the batch in hand to return
+#                (encloses the prefetched successor's prepare+dispatch);
+#                the parent of every worker stage below
+#   prepare      worker: wait_for_index, snapshot, scheduler + reconcile
+#                per eval (Worker._start_batch up to the dispatch)
+#   dispatch     worker: host input build + the kernel's async launch
+#   device       NOT a thread's wall: from the dispatch's return to the
+#                collect's wake-up, so it spans the predecessor's host
+#                phase (recorded through `record`, never emitted)
+#   device_wait  worker: block_until_ready alone, the worker's own wait
+#   d2h          worker: result fetch + host-side expansion
+#   solo_place   worker: one PlacementEngine.place call (input build,
+#                launch, wait, fetch, decision rows).  A redo that
+#                `_assign_devices` makes from inside a materialize is
+#                the one place a worker stage nests in another; no
+#                benchmark cell asks for devices
+#   materialize  worker: plan construction from picks, on both paths
+#   plan_wait    worker: blocked on the applier's verdict for one plan
+#   eval_update  worker: the eval status write
+#   ack          worker: per-eval records + broker ack/nack (Worker.
+#                _settle); one per eval on every path
+#   commit       applier: evaluate + state-store upsert of one plan
+#   store_upsert applier: the upsert alone (inside commit)
+# Worker stages other than "pass" never nest in one another, so the
+# unnamed part of a pass is its wall minus their sum.
+STAGES = ("pass", "prepare", "dispatch", "device", "device_wait", "d2h",
+          "solo_place", "materialize", "plan_wait", "eval_update", "ack",
+          "commit", "store_upsert")
+
+_SERIES = {s: f"nomad.wavepipe.{s}_s" for s in STAGES}
 
 # per-stage interval ring size: a bench run records a few thousand
 # intervals; the ring bounds memory on long-lived servers
@@ -64,6 +96,20 @@ _RING = 4096
 # records by wave id, and the StageTimers + applier are shared across
 # every worker's pipeline — per-pipeline numbering would collide
 _WAVE_SEQ = itertools.count(1)
+
+
+_trace_annotation = None
+
+
+def _annotation(stage: str, wave: int):
+    """The profiler span of one stage interval.  jax is imported at the
+    first use, not with this module; with no profiler session open an
+    annotation costs under a microsecond."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation("nomad." + stage, wave=wave)
 
 
 def _merged(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -78,6 +124,27 @@ def _merged(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
     return out
 
 
+class _Timed:
+    """`StageTimers.time`'s context manager.  A class with slots, not a
+    generator: several stages are entered once per plan or per eval, a
+    few hundred times a wave."""
+
+    __slots__ = ("_timers", "_stage", "_wave", "_t0", "_span")
+
+    def __init__(self, timers: "StageTimers", stage: str, wave: int) -> None:
+        self._timers, self._stage, self._wave = timers, stage, wave
+
+    def __enter__(self) -> None:
+        self._span = _annotation(self._stage, self._wave)
+        self._t0 = time.perf_counter()
+        self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        self._timers.record(self._stage, self._t0, time.perf_counter(),
+                            self._wave)
+
+
 class StageTimers:
     """Thread-safe per-stage wall-interval recorder.
 
@@ -85,8 +152,9 @@ class StageTimers:
     identically); intervals can: `overlap(a, b)` returns the seconds both
     stages had work in flight simultaneously.  With the pipeline live,
     `overlap("device", "commit")` and `overlap("device", "materialize")`
-    are the seconds of host work hidden under device compute — the
-    quantity the round-6 verdict asks to be proven, not asserted."""
+    are the seconds of host work that ran under an in-flight launch —
+    pipelining proven, not asserted; how much of that the device spent
+    computing is the profiler trace's to say."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -104,26 +172,32 @@ class StageTimers:
             if ring is None:
                 self._ring[stage] = ring = deque(maxlen=_RING)
             ring.append((wave, t0, t1))
+        # an interval that carries no wave is a per-plan or per-eval
+        # stage (plan_wait, ack, store_upsert: 64 of each a wave) or
+        # the solo path's: its total and count above reach /v1/metrics
+        # through report(), and that is all.  `commit` alone keeps the
+        # histogram it had before those stages existed.
+        if wave < 0 and stage != "commit":
+            return
         # per-stage latency distribution on the process registry
         # (core/telemetry.py): the interval ring above keeps proving the
         # overlap; the histogram adds p50/p95/p99 to /v1/metrics.  Device
         # time additionally feeds a ROLLING window (the health plane's
-        # per-wave device-time SLO view), and every stage interval lands
-        # on the wave's flight record.
+        # per-wave device-time SLO view), and every stage interval of a
+        # wave lands on the wave's flight record.
+        series = _SERIES.get(stage) or f"nomad.wavepipe.{stage}_s"
         if stage == "device":
-            REGISTRY.observe_windowed(f"nomad.wavepipe.{stage}_s",
-                                      t1 - t0)
+            REGISTRY.observe_windowed(series, t1 - t0)
         else:
-            REGISTRY.observe(f"nomad.wavepipe.{stage}_s", t1 - t0)
-        FLIGHT.record_wave(wave, **{f"{stage}_s": round(t1 - t0, 9)})
+            REGISTRY.observe(series, t1 - t0)
+        if wave >= 0:
+            FLIGHT.record_wave(wave, **{f"{stage}_s": round(t1 - t0, 9)})
 
-    @contextmanager
-    def time(self, stage: str, wave: int = -1):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(stage, t0, time.perf_counter(), wave)
+    def time(self, stage: str, wave: int = -1) -> "_Timed":
+        """Context manager: one interval of work on the calling thread,
+        recorded here and emitted to the profiler's trace as
+        `nomad.<stage>`."""
+        return _Timed(self, stage, wave)
 
     def totals(self) -> Dict[str, float]:
         with self._lock:
@@ -263,11 +337,11 @@ class WavePipeline:
             if used0_dev is not None:
                 self.stats["chained"] += 1
         t0 = time.perf_counter()
-        pending = self.executor.dispatch_batch(
-            snapshot, items, seed=seed, used0_dev=used0_dev,
-            masked_node_ids=mask)
+        with self.timers.time("dispatch", wave):
+            pending = self.executor.dispatch_batch(
+                snapshot, items, seed=seed, used0_dev=used0_dev,
+                masked_node_ids=mask)
         t1 = time.perf_counter()
-        self.timers.record("dispatch", t0, t1, wave)
         if isinstance(pending, dict) and pending.get("collective_bytes"):
             with self._lock:
                 self.stats["collective_bytes"] += \
@@ -292,9 +366,10 @@ class WavePipeline:
 
     def collect(self, handle: Optional[WaveHandle]):
         """Block on the wave's result and expand per-item decisions.
-        Records the device interval (dispatch end -> kernel ready) and
-        the d2h interval (ready -> decisions expanded) separately, so
-        the split between compute and fetch stays visible."""
+        Records the device interval (dispatch end -> kernel ready), the
+        worker's own wait inside it (device_wait) and the d2h interval
+        (ready -> decisions expanded) separately, so the split between
+        launch in flight, wait and fetch stays visible."""
         if handle is None:
             return []
         handle.collected = True
@@ -310,14 +385,13 @@ class WavePipeline:
             # here, so these samples are device-wait, not host time.
             # A device fault surfaces HERE (JaxRuntimeError) and must
             # reach the worker, which nacks the batch.
-            with profiling.activity("device-wait"):
+            with profiling.activity("device-wait"), \
+                    self.timers.time("device_wait", handle.wave):
                 buf.block_until_ready()   # analyze: ok purity
             self.timers.record("device", handle.t_dispatch[1],
                                time.perf_counter(), handle.wave)
-        t1 = time.perf_counter()
-        decisions = self.executor.collect_batch(pending)
-        self.timers.record("d2h", t1, time.perf_counter(), handle.wave)
-        return decisions
+        with self.timers.time("d2h", handle.wave):
+            return self.executor.collect_batch(pending)
 
     def chain_state(self, handle: Optional[WaveHandle]):
         """The (usage array, node version, padded n) triple a successor

@@ -158,15 +158,30 @@ class Worker:
         self._batch_tokens = {evaluation.id: token}
         self._batch_trace = {evaluation.id: evaluation.trace_id}
         self._sched_t0[evaluation.id] = TRACER.clock.monotonic()
-        try:
-            err = self._invoke(evaluation, t)
-        except Exception as e:  # noqa: BLE001 - a scheduler bug must nack,
-            err = e             # not kill the worker thread
-        self._settle(evaluation, token, err, t)
+        with self.stage("pass"):
+            try:
+                err = self._invoke(evaluation, t)
+            except Exception as e:  # noqa: BLE001 - a scheduler bug must
+                err = e             # nack, not kill the worker thread
+            self._settle(evaluation, token, err, t)
         return 1
+
+    def stage(self, name: str, wave: int = -1):
+        """Context manager: one interval of this worker's thread under
+        a core/wavepipe.py stage name (recorded on the server's shared
+        StageTimers, emitted to the profiler).  Schedulers reach it
+        through the Planner seam for the work they do on this thread."""
+        return self.pipeline.timers.time(name, wave)
 
     def _settle(self, evaluation: Evaluation, token: str,
                 err: Optional[Exception], t: float) -> None:
+        """Per-eval records + broker ack/nack: one "ack" stage interval
+        per eval on every path (what per-eval readings divide by)."""
+        with self.stage("ack"):
+            self._settle_eval(evaluation, token, err, t)
+
+    def _settle_eval(self, evaluation: Evaluation, token: str,
+                     err: Optional[Exception], t: float) -> None:
         broker = self.server.eval_broker
         # schedule duration = dequeue -> settle, per scheduler type: the
         # batched path's span covers its share of the shared device wait
@@ -232,20 +247,25 @@ class Worker:
         else:
             batch = pf["batch"]
         settled: set = set()
-        try:
-            if pf is None:
-                pf = self._start_batch(batch, t)
-            return self._finish_batch(pf, t, settled, max_n)
-        except Exception as e:  # noqa: BLE001 - the solo path nacks on
-            # any failure; the batched path must give every dequeued
-            # eval the same guarantee or a single bad snapshot kills the
-            # worker thread with the whole batch's tokens outstanding
-            log("worker", "error", "batch pass failed; nacking remainder",
-                worker=self.id, error=repr(e))
-            for ev, token in batch:
-                if ev.id not in settled:
-                    self._settle(ev, token, e, t)
-            return len(batch)
+        # "pass": the wall of one batch, the dequeue's wait left out; it
+        # encloses the prefetched successor's prepare + dispatch
+        with self.stage("pass"):
+            try:
+                if pf is None:
+                    pf = self._start_batch(batch, t)
+                return self._finish_batch(pf, t, settled, max_n)
+            except Exception as e:  # noqa: BLE001 - the solo path nacks
+                # on any failure; the batched path must give every
+                # dequeued eval the same guarantee or a single bad
+                # snapshot kills the worker thread with the whole
+                # batch's tokens outstanding
+                log("worker", "error",
+                    "batch pass failed; nacking remainder",
+                    worker=self.id, error=repr(e))
+                for ev, token in batch:
+                    if ev.id not in settled:
+                        self._settle(ev, token, e, t)
+                return len(batch)
 
     def _start_batch(self, batch, t: float, chain=None):
         """Phases 1-2: snapshot, per-eval reconcile, and the (async)
@@ -258,45 +278,8 @@ class Worker:
         from nomad_tpu.ops.engine import BatchItem
         from nomad_tpu.scheduler.generic import GenericScheduler
 
-        state = self.server.state
-        max_idx = max((ev.modify_index or 0) for ev, _ in batch)
-        if max_idx:
-            # waiting on the applier to reach the eval's index is a
-            # pipeline stall, not host work — lock-wait for the sampler
-            with profiling.activity("lock-wait"):
-                state.wait_for_index(max_idx, timeout=5.0)
-        # placement-write fence read ATOMICALLY with the snapshot: a
-        # foreign write between separate reads would be invisible to the
-        # fence yet missing from the snapshot (the applier would then
-        # skip the fit re-check against state the scheduler never saw)
-        snapshot, batch_seq0 = state.snapshot_and_placement_seq()
-
-        # phase 1: build schedulers, reconcile batch-eligible evals
-        t0m = TRACER.clock.monotonic()
-        work = []          # (ev, token, sched_or_None, prep_or_err)
-        for ev, token in batch:
-            self.stats.inc("invoked")
-            self._sched_t0.setdefault(ev.id, t0m)
-            if ev.type == "_core":
-                kwargs = {"now": t, "store": state}
-            else:
-                kwargs = {"now": t, "engine": self.server.engine}
-            try:
-                sched = new_scheduler(ev.type, snapshot, self, **kwargs)
-            except Exception as e:  # noqa: BLE001 - factory/init error
-                work.append((ev, token, None, e))
-                continue
-            prep = None
-            if (len(batch) > 1 and ev.type in BATCHABLE_TYPES
-                    and isinstance(sched, GenericScheduler)):
-                try:
-                    prep = sched.prepare_batch(ev)
-                except Exception as e:  # noqa: BLE001 - nack this eval:
-                    # "not batchable" is prepare_batch returning None;
-                    # a raise is a failure, not a reason to go solo
-                    work.append((ev, token, None, e))
-                    continue
-            work.append((ev, token, sched, prep))
+        with self.stage("prepare"):
+            snapshot, batch_seq0, work = self._reconcile_batch(batch, t)
 
         # phase 2: ONE device dispatch for all eligible placement blocks
         prepared = [(i, w) for i, w in enumerate(work)
@@ -353,6 +336,55 @@ class Worker:
         return {"batch": batch, "work": work, "pending": pending,
                 "prepared_idx": prepared_idx, "batch_id": batch_id,
                 "batch_seq0": batch_seq0, "snapshot": snapshot, "t": t}
+
+    def _reconcile_batch(self, batch, t: float):
+        """Phase 1 (the "prepare" stage): wait for the store to reach the
+        batch's index, snapshot, build each eval's scheduler and
+        reconcile the batch-eligible ones.  Returns (snapshot, placement
+        fence, work) with work = [(ev, token, sched_or_None,
+        prep_or_err)]."""
+        from nomad_tpu.scheduler.generic import GenericScheduler
+
+        state = self.server.state
+        max_idx = max((ev.modify_index or 0) for ev, _ in batch)
+        if max_idx:
+            # waiting on the applier to reach the eval's index is a
+            # pipeline stall, not host work — lock-wait for the sampler
+            with profiling.activity("lock-wait"):
+                state.wait_for_index(max_idx, timeout=5.0)
+        # placement-write fence read ATOMICALLY with the snapshot: a
+        # foreign write between separate reads would be invisible to the
+        # fence yet missing from the snapshot (the applier would then
+        # skip the fit re-check against state the scheduler never saw)
+        snapshot, batch_seq0 = state.snapshot_and_placement_seq()
+
+        # phase 1: build schedulers, reconcile batch-eligible evals
+        t0m = TRACER.clock.monotonic()
+        work = []          # (ev, token, sched_or_None, prep_or_err)
+        for ev, token in batch:
+            self.stats.inc("invoked")
+            self._sched_t0.setdefault(ev.id, t0m)
+            if ev.type == "_core":
+                kwargs = {"now": t, "store": state}
+            else:
+                kwargs = {"now": t, "engine": self.server.engine}
+            try:
+                sched = new_scheduler(ev.type, snapshot, self, **kwargs)
+            except Exception as e:  # noqa: BLE001 - factory/init error
+                work.append((ev, token, None, e))
+                continue
+            prep = None
+            if (len(batch) > 1 and ev.type in BATCHABLE_TYPES
+                    and isinstance(sched, GenericScheduler)):
+                try:
+                    prep = sched.prepare_batch(ev)
+                except Exception as e:  # noqa: BLE001 - nack this eval:
+                    # "not batchable" is prepare_batch returning None;
+                    # a raise is a failure, not a reason to go solo
+                    work.append((ev, token, None, e))
+                    continue
+            work.append((ev, token, sched, prep))
+        return snapshot, batch_seq0, work
 
     def _finish_batch(self, pf, t: float, settled: set,
                       max_n: int) -> int:
@@ -457,8 +489,9 @@ class Worker:
 
         def flush_window():
             if self._defer_evals:
-                self.server.apply_eval_update(self._defer_evals,
-                                              now=self._now)
+                with self.stage("eval_update"):
+                    self.server.apply_eval_update(self._defer_evals,
+                                                  now=self._now)
                 self._defer_evals.clear()
             for ev_, token_, err_ in to_settle:
                 self._settle(ev_, token_, err_, t)
@@ -516,21 +549,22 @@ class Worker:
     def _invoke(self, evaluation: Evaluation, now: float) -> Optional[Exception]:
         self._now = now
         state = self.server.state
-        # wait for the state to catch up to the eval (waitForIndex)
-        if evaluation.modify_index:
-            state.wait_for_index(evaluation.modify_index, timeout=5.0)
-        self._snapshot, self._snapshot_seq = \
-            state.snapshot_and_placement_seq()
-        self.stats.inc("invoked")
-        if evaluation.type == "_core":
-            kwargs = {"now": now, "store": state}
-        else:
-            kwargs = {"now": now, "engine": self.server.engine}
-        try:
-            sched = new_scheduler(evaluation.type, self._snapshot, self,
-                                  **kwargs)
-        except ValueError as e:
-            return e
+        with self.stage("prepare"):
+            # wait for the state to catch up to the eval (waitForIndex)
+            if evaluation.modify_index:
+                state.wait_for_index(evaluation.modify_index, timeout=5.0)
+            self._snapshot, self._snapshot_seq = \
+                state.snapshot_and_placement_seq()
+            self.stats.inc("invoked")
+            if evaluation.type == "_core":
+                kwargs = {"now": now, "store": state}
+            else:
+                kwargs = {"now": now, "engine": self.server.engine}
+            try:
+                sched = new_scheduler(evaluation.type, self._snapshot,
+                                      self, **kwargs)
+            except ValueError as e:
+                return e
         # log records emitted while scheduling carry the eval's trace id
         # (core/logging.trace_scope): a dump bundle's logs join its traces
         with trace_scope(evaluation.trace_id):
@@ -557,6 +591,14 @@ class Worker:
         self.server.maybe_apply_inline(pending)
         return pending
 
+    def wait_plan(self, pending):
+        """Block on the applier's verdict for one submitted plan: the
+        "plan_wait" stage, recorded here for the solo path
+        (submit_plan) and the batched one (GenericScheduler.
+        finalize_batched) alike.  Returns (result, error)."""
+        with self.stage("plan_wait"):
+            return pending.wait()
+
     def refreshed_snapshot(self):
         """Fresh state view after a partial commit (the retry loop must
         see the refuting writes) — the fence tracks it so the retry's
@@ -574,8 +616,7 @@ class Worker:
 
     def submit_plan(self, plan: Plan
                     ) -> Tuple[Optional[PlanResult], object, Optional[Exception]]:
-        pending = self.submit_plan_async(plan)
-        result, err = pending.wait()
+        result, err = self.wait_plan(self.submit_plan_async(plan))
         if err is not None:
             return None, None, err
         refreshed = None
@@ -587,7 +628,8 @@ class Worker:
         if self._defer_evals is not None:
             self._defer_evals.append(evaluation)
         else:
-            self.server.apply_eval_update([evaluation], now=self._now)
+            with self.stage("eval_update"):
+                self.server.apply_eval_update([evaluation], now=self._now)
 
     def update_eval(self, evaluation: Evaluation) -> None:
         self._apply_or_defer(evaluation)

@@ -9,6 +9,7 @@ bridge (SURVEY.md §7 P6); in-process it is plain Python.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from functools import partial
@@ -386,6 +387,9 @@ class PlacementEngine:
         # ("invalidation-replay"); the d2h twin meters result fetches
         self.h2d_observer = None
         self.d2h_observer = None
+        # optional core/wavepipe.StageTimers (wired by the Server): each
+        # `place` call records one "solo_place" interval
+        self.timers = None
         # say which backend JAX chose: a failed accelerator init would
         # otherwise serve from the CPU with nothing in the logs
         from nomad_tpu.core.logging import log
@@ -808,6 +812,15 @@ class PlacementEngine:
         it concurrent workers pick identical nodes and the plan applier
         refutes all but the first (see select._tiebreak_noise).
         """
+        # one "solo_place" stage interval per call (core/wavepipe.py)
+        with (self.timers.time("solo_place") if self.timers is not None
+              else contextlib.nullcontext()):
+            return self._place(snapshot, job, tgs, requests, tensors,
+                               stopped_allocs, bulk_api, seed,
+                               device_in_use, block)
+
+    def _place(self, snapshot, job, tgs, requests, tensors, stopped_allocs,
+               bulk_api, seed, device_in_use, block):
         if block is not None:
             block_tg, block_count = block
             if block_count <= 0:

@@ -13,6 +13,7 @@ in a single device round-trip.
 
 from __future__ import annotations
 
+import contextlib
 import zlib
 from typing import Dict, List, Optional
 
@@ -309,7 +310,10 @@ class GenericScheduler(Scheduler):
             plan, result, refreshed_state, err = payload
         else:
             plan, pending = payload
-            result, err = pending.wait()
+            # through the planner where it names the wait (the worker's
+            # "plan_wait" stage, core/wavepipe.py)
+            wait = getattr(self.planner, "wait_plan", None)
+            result, err = wait(pending) if wait else pending.wait()
             refreshed_state = None
         if err is not None:
             self._update_eval_status(evaluation, "failed", str(err))
@@ -507,12 +511,22 @@ class GenericScheduler(Scheduler):
         decisions = self.engine.place(self.state, job, tgs, reqs,
                                       stopped_allocs=stopped, bulk_api=True,
                                       seed=getattr(self, "_seed", 0))
-        if isinstance(decisions, BulkDecisions):
-            self._materialize_bulk(plan, job, places, decisions,
-                                   evaluation, results)
-            return
-        self._materialize_decisions(plan, job, places, reqs, decisions,
-                                    evaluation, results, stopped)
+        with self._materialize_stage():
+            if isinstance(decisions, BulkDecisions):
+                self._materialize_bulk(plan, job, places, decisions,
+                                       evaluation, results)
+                return
+            self._materialize_decisions(plan, job, places, reqs, decisions,
+                                        evaluation, results, stopped)
+
+    def _materialize_stage(self):
+        """The solo path's "materialize" stage (core/wavepipe.py), timed
+        through the planner where it offers stages (the Worker; not the
+        test Harness).  At the call that follows `engine.place`, not in
+        `_materialize_bulk`: the batched path runs that under the
+        worker's own per-wave materialize."""
+        stage = getattr(self.planner, "stage", None)
+        return stage("materialize") if stage else contextlib.nullcontext()
 
     def _materialize_decisions(self, plan: Plan, job: Job,
                                places: List[RPlace], reqs,
@@ -790,17 +804,18 @@ class GenericScheduler(Scheduler):
             stopped_allocs=stopped, bulk_api=True,
             seed=getattr(self, "_seed", 0),
             block=(block.tg.name, len(block.indexes)))
-        if isinstance(decisions, BulkDecisions):
-            self._materialize_bulk(plan, job, None, decisions,
-                                   evaluation, results, block=block)
-            return
-        # engine fell back (spread/devices/small count): expand and run
-        # the general path with the decisions it already computed
-        places = [RPlace(tg=block.tg, name=_name(job, block.tg, ix),
-                         index=ix) for ix in block.indexes]
-        reqs = [PlacementRequest(tg_name=block.tg.name)] * len(places)
-        self._materialize_decisions(plan, job, places, reqs, decisions,
-                                    evaluation, results, stopped)
+        with self._materialize_stage():
+            if isinstance(decisions, BulkDecisions):
+                self._materialize_bulk(plan, job, None, decisions,
+                                       evaluation, results, block=block)
+                return
+            # engine fell back (spread/devices/small count): expand and
+            # run the general path with the decisions it already computed
+            places = [RPlace(tg=block.tg, name=_name(job, block.tg, ix),
+                             index=ix) for ix in block.indexes]
+            reqs = [PlacementRequest(tg_name=block.tg.name)] * len(places)
+            self._materialize_decisions(plan, job, places, reqs, decisions,
+                                        evaluation, results, stopped)
 
     def _assign_devices(self, job, tgs, places, reqs, decisions, stopped):
         """Pick concrete device instances for every placement whose task

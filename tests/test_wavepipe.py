@@ -535,3 +535,80 @@ class TestExecutorResidentParity:
         wave_b_bytes = st["upload_bytes"] - st["upload_bytes_wave_a"]
         assert wave_b_bytes <= 2 * (st["upload_bytes_wave_a"] // 8) + 512, \
             (wave_b_bytes, st["upload_bytes_wave_a"])
+
+
+# ------------------------------------------------- the stages of a pass
+
+# recorded on the worker's thread, inside its `pass`; never nested in
+# one another, which is what makes "unnamed = pass - the rest" a
+# subtraction (benchmark/host_spans.py reads the same from a trace)
+WORKER_STAGES = ("prepare", "dispatch", "device_wait", "d2h", "solo_place",
+                 "materialize", "plan_wait", "eval_update", "ack")
+NEW_STAGES = ("pass", "prepare", "device_wait", "plan_wait", "eval_update",
+              "ack", "solo_place", "store_upsert")
+
+
+@pytest.fixture(scope="module")
+def small_pass():
+    """A batched wave and a solo eval on a threaded server
+    (tests/stage_pass.py)."""
+    from stage_pass import run_small_pass
+    return run_small_pass()
+
+
+def _spans(server, stage):
+    return [(a, b) for _, a, b in server.stage_timers.intervals(stage)]
+
+
+def _inside(span, outers):
+    return any(lo <= span[0] and span[1] <= hi for lo, hi in outers)
+
+
+class TestPassStages:
+    def test_stage_list_is_the_recorders(self, small_pass):
+        from nomad_tpu.core.wavepipe import STAGES
+        assert set(small_pass.stage_timers.counts()) == set(STAGES)
+        assert set(WORKER_STAGES) | {"pass", "device", "commit",
+                                     "store_upsert"} == set(STAGES)
+
+    @pytest.mark.parametrize("stage", NEW_STAGES)
+    def test_new_stage_recorded(self, small_pass, stage):
+        assert small_pass.stage_timers.counts().get(stage, 0) >= 1
+
+    def test_solo_path_records_materialize(self, small_pass):
+        # the batched path's intervals carry their wave; the solo
+        # path's, taken at the call that follows engine.place, do not
+        waves = [w for w, _, _ in
+                 small_pass.stage_timers.intervals("materialize")]
+        assert waves.count(-1) == 1 and len(waves) == 7
+
+    @pytest.mark.parametrize("stage", WORKER_STAGES)
+    def test_worker_stage_inside_a_pass_and_disjoint(self, small_pass,
+                                                     stage):
+        passes = _spans(small_pass, "pass")
+        others = [iv for s in WORKER_STAGES if s != stage
+                  for iv in _spans(small_pass, s)]
+        mine = _spans(small_pass, stage)
+        assert mine
+        for a, b in mine:
+            assert _inside((a, b), passes), (stage, a, b)
+            assert all(b <= lo or hi <= a for lo, hi in others), stage
+        # nor does a stage overlap itself
+        ordered = sorted(mine)
+        assert all(x[1] <= y[0] for x, y in zip(ordered, ordered[1:]))
+
+    def test_stages_account_for_the_pass(self, small_pass):
+        totals = small_pass.stage_timers.totals()
+        named = sum(totals[s] for s in WORKER_STAGES)
+        assert 0.8 * totals["pass"] <= named <= totals["pass"]
+
+    def test_one_ack_per_eval(self, small_pass):
+        worker = small_pass.workers[0]
+        assert (small_pass.stage_timers.counts()["ack"]
+                == worker.stats["acked"] + worker.stats["nacked"] == 7)
+
+    def test_store_upsert_inside_commit(self, small_pass):
+        commits = _spans(small_pass, "commit")
+        upserts = _spans(small_pass, "store_upsert")
+        assert len(upserts) == len(commits) == 7
+        assert all(_inside(u, commits) for u in upserts)
