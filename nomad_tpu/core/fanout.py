@@ -263,6 +263,7 @@ class _RingEntry:
     """One commit batch.  `payload` is the raw buffered form (alloc
     batches compressed to id stubs — see stream._AllocIds); `expanded`
     is the lazily-cached Event list, filled once by the first reader
+    whose topics it can match (others step over it and leave it None)
     OUTSIDE the ring lock (idempotent; the GIL makes the single
     attribute store safe).  `count` is the exact expanded event count,
     known at append time; `cum_end` the absolute event count through
@@ -298,6 +299,11 @@ class EventRing:
         self._capacity = capacity
         self._approx_bytes = 0       # shallow payload estimate, O(1)/append
         self.dropped_total = 0       # events skipped by lagging cursors
+        # per ENTRY, never per event: stepped over unexpanded by a
+        # subscription that named none of its event topics (once a
+        # subscriber), and expanded into Events (once an entry, shared)
+        self.entries_skipped = 0
+        self.entries_expanded = 0
         self.closed = False
 
     # -------------------------------------------------------- publisher
@@ -375,6 +381,19 @@ class EventRing:
             self.dropped_total += n
         telemetry.REGISTRY.inc("nomad.stream.dropped", n)
 
+    def note_skipped(self, topic: str) -> None:
+        """A cursor stepped over one `topic` entry without expanding it."""
+        with self._lock:
+            self.entries_skipped += 1
+        telemetry.REGISTRY.inc("nomad.stream.entries_skipped", topic=topic)
+
+    def note_expanded(self, topic: str) -> None:
+        """A reader expanded one `topic` entry (two racing first readers
+        may both count it; the expansion itself is idempotent)."""
+        with self._lock:
+            self.entries_expanded += 1
+        telemetry.REGISTRY.inc("nomad.stream.entries_expanded", topic=topic)
+
     def wait_for(self, seq: int, timeout: float,
                  closed_fn: Callable[[], bool]) -> None:
         """Park until the ring grows past `seq`, closes, or `timeout`.
@@ -394,6 +413,8 @@ class EventRing:
                 "base_seq": self._base_seq,
                 "next_seq": self._next_seq,
                 "dropped_total": self.dropped_total,
+                "entries_skipped": self.entries_skipped,
+                "entries_expanded": self.entries_expanded,
                 "bytes": self._approx_bytes,
                 "capacity": self._capacity,
             }

@@ -17,8 +17,13 @@ fanout design:
 Hot-path note: the store's commit callback runs under the store write
 lock (plan apply at bench scale lands here), so the callback only
 appends ONE raw entry per commit — per-alloc Event expansion happens
-lazily on first read, cached on the ring entry so K subscribers cost
-one expansion.
+lazily on first read BY A SUBSCRIBER THAT ASKED FOR ALLOCATIONS (or
+`*`), cached on the ring entry so K such subscribers cost one
+expansion.  A subscription decides by the entry's topic alone
+(`_EVENT_TOPICS`) whether anything in it can match, and steps over the
+rest unexpanded: following `Evaluation` through a 100k-alloc drain
+builds no alloc Event at all (counted per entry:
+`nomad.stream.entries_skipped` / `nomad.stream.entries_expanded`).
 
 Filter semantics (reference: SubscribeRequest): `topics` maps topic name
 to a list of keys; `"*"` as a topic or key matches everything.  Events
@@ -53,6 +58,20 @@ _TYPE_BY_TOPIC = {
     "HealthBreach": "HealthBreach",
 }
 
+# entry topic -> the event topics its entry can expand to: the first
+# always, any further one only from a blocked evaluation (`_blocked`).
+# The ONE statement of that fact — `_expand` names its events from it,
+# `_expected_count` counts from it, and a Subscription decides from it,
+# BEFORE expanding, whether an entry can hold anything it asked for
+_EVENT_TOPICS = {
+    "Node": ("Node",),
+    "Job": ("Job",),
+    "Evaluation": ("Evaluation", "PlacementFailure"),
+    "Allocations": ("Allocation",),
+    "Deployment": ("Deployment",),
+    "HealthBreach": ("HealthBreach",),
+}
+
 
 @dataclass
 class Event:
@@ -85,46 +104,49 @@ class _AllocIds:
         self.ids = ids
 
 
+def _blocked(topic: str, payload) -> bool:
+    """The one entry that expands past its own topic: a blocked eval."""
+    return (topic == "Evaluation"
+            and getattr(payload, "status", "") == "blocked")
+
+
 def _expand(topic: str, index: int, payload) -> List[Event]:
+    names = _EVENT_TOPICS.get(topic)
+    if names is None:
+        return []
+    own, etype = names[0], _TYPE_BY_TOPIC[topic]
     if topic == "Allocations":
         if isinstance(payload, _AllocIds):
-            return [Event("Allocation", "AllocationUpdated", aid, index,
-                          None) for aid in payload.ids]
-        return [Event("Allocation", "AllocationUpdated", a.id, index, None)
-                for a in payload]
-    if topic not in _TYPE_BY_TOPIC:
-        return []
+            return [Event(own, etype, aid, index, None)
+                    for aid in payload.ids]
+        return [Event(own, etype, a.id, index, None) for a in payload]
     if topic == "HealthBreach":
         key = payload.get("Rule", "") if isinstance(payload, dict) else ""
-        return [Event("HealthBreach", "HealthBreach", key, index, payload)]
+        return [Event(own, etype, key, index, payload)]
     if isinstance(payload, (str, tuple)):
         key = payload if isinstance(payload, str) else payload[-1]
-        return [Event(topic, f"{topic}Deregistered", key, index, None)]
-    events = [Event(topic, _TYPE_BY_TOPIC[topic],
-                    getattr(payload, "id", ""), index, payload)]
-    if topic == "Evaluation" and getattr(payload, "status", "") == "blocked":
+        return [Event(own, f"{topic}Deregistered", key, index, None)]
+    events = [Event(own, etype, getattr(payload, "id", ""), index, payload)]
+    if _blocked(topic, payload):
         # a blocked eval IS a placement failure: operators watching
         # /v1/event/stream see it live, keyed by job id so a watcher can
         # filter to its job.  The payload (the eval) carries the
         # failed_tg_allocs rollups that explain WHY it is pending.
         # Derived here so replay from the ring reproduces it too.
-        events.append(Event("PlacementFailure", "PlacementFailure",
-                            getattr(payload, "job_id", ""), index, payload))
+        events.extend(Event(name, name, getattr(payload, "job_id", ""),
+                            index, payload) for name in names[1:])
     return events
 
 
 def _expected_count(topic: str, payload) -> int:
     """Exact `_expand` output size, computed O(1) at append time (the
     drop ledger needs event counts for entries trimmed before any
-    reader expanded them) — keep in lockstep with `_expand`."""
+    reader expanded them, or that every reader stepped over) — in
+    lockstep with `_expand` through `_EVENT_TOPICS` and `_blocked`."""
     if topic == "Allocations":
         return (len(payload.ids) if isinstance(payload, _AllocIds)
                 else len(payload))
-    if topic == "HealthBreach" or isinstance(payload, (str, tuple)):
-        return 1
-    if topic == "Evaluation" and getattr(payload, "status", "") == "blocked":
-        return 2
-    return 1
+    return len(_EVENT_TOPICS[topic]) if _blocked(topic, payload) else 1
 
 
 class Subscription:
@@ -136,6 +158,11 @@ class Subscription:
     def __init__(self, topics: Dict[str, List[str]], ring: EventRing,
                  seq: int, abs_pos: int) -> None:
         self.topics = topics
+        # the entry topics that can hold an event this subscription
+        # named, worked out once; None = wildcard, every entry can
+        self._entry_topics = None if TOPIC_ALL in topics else frozenset(
+            entry_topic for entry_topic, names in _EVENT_TOPICS.items()
+            if not topics.keys().isdisjoint(names))
         self._ring = ring
         self._seq = seq
         self._intra = 0
@@ -157,7 +184,11 @@ class Subscription:
 
     def _scan(self) -> Optional[Event]:
         """Advance the cursor to the next matching event without
-        parking; None at the head.  Expansion happens OUTSIDE the ring
+        parking; None at the head.  An entry whose topic cannot expand
+        to anything this subscription named is stepped over UNEXPANDED
+        (an `Evaluation` follower builds no Event for a 260-alloc block
+        commit); its `cum_end`, known at append time, keeps the drop
+        ledger exact.  Otherwise expansion happens OUTSIDE the ring
         lock and is cached on the entry (idempotent, GIL-safe single
         store) so K subscribers cost one expansion per entry."""
         while True:
@@ -173,15 +204,20 @@ class Subscription:
             if probe[0] == "head":
                 return None
             entry = probe[1]
-            evs = entry.expanded
-            if evs is None:
-                evs = _expand(entry.topic, entry.index, entry.payload)
-                entry.expanded = evs
-            while self._intra < len(evs):
-                ev = evs[self._intra]
-                self._intra += 1
-                if self.matches(ev):
-                    return ev
+            if (self._entry_topics is None
+                    or entry.topic in self._entry_topics):
+                evs = entry.expanded
+                if evs is None:
+                    evs = _expand(entry.topic, entry.index, entry.payload)
+                    entry.expanded = evs
+                    self._ring.note_expanded(entry.topic)
+                while self._intra < len(evs):
+                    ev = evs[self._intra]
+                    self._intra += 1
+                    if self.matches(ev):
+                        return ev
+            else:
+                self._ring.note_skipped(entry.topic)
             self._seq += 1
             self._intra = 0
             self._abs_pos = entry.cum_end
@@ -229,7 +265,7 @@ class EventBroker:
             # null payloads (consumers re-fetch) — the ids list already
             # exists on the block, so this stays O(1) python work here
             topic, payload = "Allocations", _AllocIds(payload.ids)
-        if topic not in _TYPE_BY_TOPIC:
+        if topic not in _EVENT_TOPICS:
             return
         if topic == "Allocations" and not isinstance(payload, _AllocIds):
             payload = _AllocIds([a.id for a in payload])
@@ -282,6 +318,8 @@ class EventBroker:
             "Subscribers": len(subs),
             "Ring": ring,
             "DroppedTotal": ring["dropped_total"],
+            "EntriesSkipped": ring["entries_skipped"],
+            "EntriesExpanded": ring["entries_expanded"],
             "Cursors": [s.stats() for s in subs],
         }
 

@@ -11,11 +11,13 @@ from nomad_tpu import mock
 from nomad_tpu.agent import Agent
 from nomad_tpu.api.client import APIClient
 from nomad_tpu.chaos.clock import SystemClock
+from nomad_tpu.core import stream
 from nomad_tpu.core.fanout import EventRing, WatchHub
 from nomad_tpu.core.stream import EventBroker
 from nomad_tpu.core.telemetry import REGISTRY
 from nomad_tpu.state.state_store import StateStore
-from nomad_tpu.structs import Node, codec
+from nomad_tpu.structs import (AllocBlock, Deployment, Node, PlanResult,
+                               codec)
 
 
 def _wait(fn, timeout=30, period=0.1):
@@ -186,6 +188,224 @@ class TestEventRing:
         t.join(timeout=5)
         assert not t.is_alive()
         assert out == [None]
+
+
+# ---------------------------------------------------------------------------
+# The topic check before expansion (core/stream.py Subscription._scan)
+# ---------------------------------------------------------------------------
+
+_BLOCK_IDS = [f"block-alloc-{i:03d}" for i in range(260)]
+_ROW_ALLOCS = [mock.alloc() for _ in range(3)]
+_BLOCKED_EVAL = mock.eval(status="blocked", job_id="job-blocked")
+_COMPLETE_EVAL = mock.eval(status="complete", job_id="job-done")
+_BREACH = {"Rule": "eval_p99_s", "Observed": 2.0}
+
+
+def _mixed_commits():
+    """What a store (and the health watchdog) hands the broker, in
+    commit order, as (topic, index, payload)."""
+    return [
+        ("AllocBlock", 1, AllocBlock(id="blk", ids=list(_BLOCK_IDS))),
+        ("Allocations", 2, list(_ROW_ALLOCS)),
+        ("Evaluation", 3, _BLOCKED_EVAL),
+        ("Evaluation", 4, _COMPLETE_EVAL),
+        ("Job", 5, ("default", "job-gone")),
+        ("HealthBreach", 6, _BREACH),
+        ("PlanResult", 7, PlanResult()),          # the broker ignores it
+    ]
+
+
+def _reference_events(commits, topics, after_index=0):
+    """The stream's contract written plainly: expand EVERY commit into
+    its events, filter after (what `_scan` did before it looked at an
+    entry's topic first).  Events as (topic, type, key, index, payload)."""
+    events = []
+    for topic, index, payload in commits:
+        if index <= after_index:
+            continue
+        if topic == "AllocBlock":
+            events += [("Allocation", "AllocationUpdated", aid, index, None)
+                       for aid in payload.ids]
+        elif topic == "Allocations":
+            events += [("Allocation", "AllocationUpdated", a.id, index, None)
+                       for a in payload]
+        elif topic == "Evaluation":
+            events.append(("Evaluation", "EvaluationUpdated", payload.id,
+                           index, payload))
+            if payload.status == "blocked":
+                events.append(("PlacementFailure", "PlacementFailure",
+                               payload.job_id, index, payload))
+        elif topic == "Job":
+            events.append(("Job", "JobDeregistered", payload[-1], index,
+                           None))
+        elif topic == "HealthBreach":
+            events.append(("HealthBreach", "HealthBreach", payload["Rule"],
+                           index, payload))
+    return [ev for ev in events
+            if any(t in ("*", ev[0])
+                   and (not keys or "*" in keys or ev[2] in keys)
+                   for t, keys in topics.items())]
+
+
+def _publish(broker, commits):
+    for topic, index, payload in commits:
+        broker._on_state_event(topic, index, payload)
+
+
+def _drain(sub):
+    out = []
+    while True:
+        ev = sub.next(timeout=0.05)
+        if ev is None:
+            return out
+        out.append((ev.topic, ev.type, ev.key, ev.index, ev.payload))
+
+
+def _skipped_allocs():
+    return REGISTRY.counter("nomad.stream.entries_skipped",
+                            topic="Allocations")
+
+
+# subscription, ring entries it steps over unexpanded (of the 6 kept)
+_SUBSCRIPTIONS = {
+    "evaluation": ({"Evaluation": ["*"]}, 4),
+    "allocation": ({"Allocation": ["*"]}, 4),
+    "wildcard": ({"*": ["*"]}, 0),
+    "placement_failure": ({"PlacementFailure": ["*"]}, 4),
+    "allocation_key": ({"Allocation": [_BLOCK_IDS[17]]}, 4),
+    "job_and_evaluation": ({"Job": ["*"], "Evaluation": ["*"]}, 3),
+}
+
+
+class TestTopicSkip:
+    @pytest.mark.parametrize("name", sorted(_SUBSCRIPTIONS))
+    def test_delivers_what_expand_then_filter_delivers(self, name):
+        topics, n_skipped = _SUBSCRIPTIONS[name]
+        commits = _mixed_commits()
+        broker = EventBroker()
+        skipped_allocs = _skipped_allocs()
+        sub = broker.subscribe(topics, from_index=0)
+        _publish(broker, commits)
+        want = _reference_events(commits, topics)
+        assert want, "every subscription here is owed something"
+        assert _drain(sub) == want
+        entries = list(broker._ring._entries)
+        assert [e.topic for e in entries] == [
+            "Allocations", "Allocations", "Evaluation", "Evaluation",
+            "Job", "HealthBreach"]
+        st = broker.stats()
+        assert st["EntriesSkipped"] == n_skipped
+        assert st["EntriesExpanded"] == len(entries) - n_skipped
+        assert sum(e.expanded is None for e in entries) == n_skipped
+        asks_allocs = bool({"*", "Allocation"} & set(topics))
+        for e in entries[:2]:
+            # no Event object was ever built for a subscriber that did
+            # not ask for allocations
+            assert (e.expanded is not None) == asks_allocs
+        assert (_skipped_allocs() - skipped_allocs
+                == (0 if asks_allocs else 2))
+        # a second subscriber of the same shape re-uses the cached
+        # expansions: one expansion an entry, however many readers
+        cached = [e.expanded for e in entries]
+        sub2 = broker.subscribe(topics, from_index=0)
+        assert _drain(sub2) == want
+        assert all(a is b for a, b in
+                   zip(cached, (e.expanded for e in entries)))
+        assert broker.stats()["EntriesExpanded"] == len(entries) - n_skipped
+        assert broker.stats()["EntriesSkipped"] == 2 * n_skipped
+        broker.close()
+
+    def test_drop_ledger_counts_stepped_over_entries(self):
+        """Two cursors lag off a small ring, one stepping over the alloc
+        entries and one expanding them: both lose, and count, the same
+        events — the arithmetic on the entries' append-time counts."""
+        cap = 4
+        broker = EventBroker(buffer_size=cap)
+        before = REGISTRY.counter("nomad.stream.dropped")
+        subs = {"evaluation": broker.subscribe({"Evaluation": ["*"]}),
+                "wildcard": broker.subscribe()}
+        head = [("AllocBlock", 1, AllocBlock(id="b0", ids=list(_BLOCK_IDS))),
+                ("Evaluation", 2, _COMPLETE_EVAL)]
+        _publish(broker, head)
+        for name, sub in subs.items():
+            assert _drain(sub) == _reference_events(head, sub.topics), name
+            assert sub.dropped == 0
+        # both cursors now sit past the block; lag them
+        tail = []
+        for i in range(5):
+            tail += [("AllocBlock", 10 + 2 * i,
+                      AllocBlock(id=f"b{i + 1}", ids=list(_BLOCK_IDS))),
+                     ("Evaluation", 11 + 2 * i,
+                      _BLOCKED_EVAL if i % 2 else _COMPLETE_EVAL)]
+        _publish(broker, tail)
+        counts = [260 if t == "AllocBlock"
+                  else 2 if p.status == "blocked" else 1
+                  for t, _, p in tail]
+        lost = sum(counts[:-cap])
+        assert lost == 3 * 260 + 1 + 2 + 1
+        for name, sub in subs.items():
+            assert (_drain(sub)
+                    == _reference_events(tail[-cap:], sub.topics)), name
+            assert sub.dropped == lost, name
+            assert sub.stats()["Dropped"] == lost
+        assert broker.stats()["DroppedTotal"] == 2 * lost
+        assert REGISTRY.counter("nomad.stream.dropped") - before == 2 * lost
+        broker.close()
+
+    @pytest.mark.parametrize("name", ["evaluation", "placement_failure",
+                                      "wildcard"])
+    def test_replay_from_index(self, name):
+        """A late subscriber's replay is the same whether the entries
+        it seeks over were never expanded or already are."""
+        topics, _ = _SUBSCRIPTIONS[name]
+        commits = _mixed_commits()
+        broker = EventBroker()
+        _publish(broker, commits)
+        for after in (0, 2, 3):
+            want = _reference_events(commits, topics, after_index=after)
+            assert _drain(broker.subscribe(topics, from_index=after)) == want
+        _drain(broker.subscribe())           # expands every entry
+        for after in (0, 2, 3):
+            want = _reference_events(commits, topics, after_index=after)
+            assert _drain(broker.subscribe(topics, from_index=after)) == want
+        broker.close()
+
+
+_LOCKSTEP_CASES = {
+    "Node": ("Node", Node()),
+    "Node-deregistered": ("Node", "node-1"),
+    "Job": ("Job", mock.job()),
+    "Job-deregistered": ("Job", ("default", "job-1")),
+    "Evaluation": ("Evaluation", _COMPLETE_EVAL),
+    "Evaluation-blocked": ("Evaluation", _BLOCKED_EVAL),
+    "Evaluation-deleted": ("Evaluation", "eval-1"),
+    "Allocations-ids": ("Allocations", stream._AllocIds(list(_BLOCK_IDS))),
+    "Allocations-rows": ("Allocations", list(_ROW_ALLOCS)),
+    "Deployment": ("Deployment", Deployment()),
+    "HealthBreach": ("HealthBreach", _BREACH),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LOCKSTEP_CASES))
+def test_event_topics_table_in_lockstep(case):
+    """`_EVENT_TOPICS` is what a subscription trusts when it steps over
+    an entry unexpanded, and `_expected_count` what the drop ledger
+    trusts: both must say what `_expand` does, for every topic."""
+    assert set(stream._EVENT_TOPICS) == set(stream._TYPE_BY_TOPIC)
+    assert {t for t, _ in _LOCKSTEP_CASES.values()} == set(
+        stream._TYPE_BY_TOPIC), "a topic without a case here"
+    topic, payload = _LOCKSTEP_CASES[case]
+    events = stream._expand(topic, 9, payload)
+    assert len(events) == stream._expected_count(topic, payload) >= 1
+    names = stream._EVENT_TOPICS[topic]
+    got = [e.topic for e in events]
+    if topic == "Allocations":
+        assert set(got) == set(names)
+    elif case == "Evaluation-blocked":
+        assert got == list(names) and len(names) == 2
+    else:
+        assert got == [names[0]]
+    assert all(e.index == 9 for e in events)
 
 
 # ---------------------------------------------------------------------------
