@@ -73,7 +73,10 @@ from nomad_tpu.core.telemetry import REGISTRY
 #                `_assign_devices` makes from inside a materialize is
 #                the one place a worker stage nests in another; no
 #                benchmark cell asks for devices
-#   materialize  worker: plan construction from picks, on both paths
+#   system_place worker: one PlacementEngine.place_system call, a system
+#                eval's whole placement (input build, the launch, the
+#                wait for it, the fetch of the verdicts)
+#   materialize  worker: plan construction from picks, on every path
 #   plan_wait    worker: blocked on the applier's verdict for one plan
 #   eval_update  worker: the eval status write
 #   ack          worker: per-eval records + broker ack/nack (Worker.
@@ -83,8 +86,8 @@ from nomad_tpu.core.telemetry import REGISTRY
 # Worker stages other than "pass" never nest in one another, so the
 # unnamed part of a pass is its wall minus their sum.
 STAGES = ("pass", "prepare", "dispatch", "device", "device_wait", "d2h",
-          "solo_place", "materialize", "plan_wait", "eval_update", "ack",
-          "commit", "store_upsert")
+          "solo_place", "system_place", "materialize", "plan_wait",
+          "eval_update", "ack", "commit", "store_upsert")
 
 _SERIES = {s: f"nomad.wavepipe.{s}_s" for s in STAGES}
 

@@ -30,7 +30,8 @@ from nomad_tpu.structs import (
     TaskGroup,
 )
 
-from .feasibility import constraint_mask, feasible_mask_jit
+from .feasibility import (constraint_mask, feasible_mask_jit,
+                          place_system_jit)
 from .preempt import Preemptor, preemption_enabled
 from .select import (
     BulkInputs, FILL_K, MultiEvalInputs, PlacementInputs, TOP_K,
@@ -818,6 +819,49 @@ class PlacementEngine:
             return self._place(snapshot, job, tgs, requests, tensors,
                                stopped_allocs, bulk_api, seed,
                                device_in_use, block)
+
+    def place_system(self, snapshot, job: Job, tgs: Sequence[TaskGroup],
+                     node_ids: Sequence[str]):
+        """A system eval's placement in one launch (ops/feasibility.py
+        `place_system`): one allocation of every group of `tgs` on every
+        node of `node_ids` that passes, over the resident node tensors
+        and usage — no per-eval upload but the eval's own domain and
+        constraint rows.  For groups that ask for no devices and no
+        ports (the caller's to check: neither is a tensor here).
+
+        Returns (tensors, rows, verdicts): `rows[i]` is `node_ids[i]`'s
+        tensor row (-1: not in the tensors), `verdicts` the kernel's
+        [G, n] int8 SYS_* table, fetched once.  One "system_place" stage
+        interval per call (core/wavepipe.py)."""
+        with (self.timers.time("system_place") if self.timers is not None
+              else contextlib.nullcontext()):
+            t = self.packer.update(snapshot)
+            tg_tensors = self.packer.lower_task_groups(job, tgs,
+                                                       snapshot=snapshot)
+            ctx = self.packer.job_context(job, snapshot, t)
+            npad = self._padded_n(t.n)
+            row_of = t.id_to_row
+            rows = np.fromiter((row_of.get(nid, -1) for nid in node_ids),
+                               np.int64, len(node_ids))
+            domain = np.zeros(npad, bool)
+            domain[rows[rows >= 0]] = True
+            dev = self._node_arrays(t)
+            used = self._used_device(t)
+            dcm = self._dev_const(
+                ("dc", t.version, npad, tuple(job.datacenters)),
+                lambda: _pad_rows(ctx.dc_mask, npad, False))
+            pm = self._dev_const(
+                ("pool", t.version, npad, job.node_pool),
+                lambda: _pad_rows(ctx.pool_mask, npad, False))
+            luts = self._dev_const(
+                ("luts", self.packer.lut_epoch, tg_tensors.luts.shape),
+                lambda: tg_tensors.luts)
+            verdicts = self._launch(
+                "system", (len(tgs), tg_tensors.con.shape[1], npad),
+                place_system_jit, dev["attrs"], dev["elig"], dcm, pm,
+                jnp.asarray(tg_tensors.con), luts, dev["cap"], used,
+                jnp.asarray(tg_tensors.req), jnp.asarray(domain))
+            return t, rows, self._fetch(verdicts)[:, :t.n]
 
     def _place(self, snapshot, job, tgs, requests, tensors, stopped_allocs,
                bulk_api, seed, device_in_use, block):

@@ -78,3 +78,54 @@ def feasible_mask(attrs: jnp.ndarray,        # [N, A]
 
 
 feasible_mask_jit = jax.jit(feasible_mask)
+
+# place_system's per-(group, node) verdict, in allocs_fit's order of
+# dimensions; anything but SYS_PLACED names why the node took nothing
+SYS_VERDICTS = 5
+SYS_PLACED, SYS_FILTERED, SYS_CPU, SYS_MEMORY, SYS_DISK = range(SYS_VERDICTS)
+SYS_DIMENSIONS = {SYS_CPU: "cpu", SYS_MEMORY: "memory", SYS_DISK: "disk"}
+
+
+def place_system(attrs: jnp.ndarray,         # [N, A]
+                 elig: jnp.ndarray,          # [N] bool
+                 dc_mask: jnp.ndarray,       # [N] bool
+                 pool_mask: jnp.ndarray,     # [N] bool
+                 con: jnp.ndarray,           # [G, C, 3]
+                 luts: jnp.ndarray,          # [L, V]
+                 cap: jnp.ndarray,           # [N, 3] int32, net of reserved
+                 used: jnp.ndarray,          # [N, 3] int32
+                 req: jnp.ndarray,           # [G, 3] int32
+                 domain: jnp.ndarray,        # [N] bool
+                 ) -> jnp.ndarray:           # [G, N] int8
+    """A system eval's whole placement: one allocation of every task
+    group on every node that passes (reference: SystemScheduler's
+    per-node loop).  No scan over placements and no sort, since selection
+    is "every node that passes"; the groups are walked in order, because
+    group g's placement on a node counts against group g+1 there.
+
+    Returns a SYS_* verdict per (group, node): `feasible_mask`'s terms,
+    then `used + ask <= cap` on cpu, memory and disk, the first
+    dimension over naming the verdict as `allocs_fit` names it.  Only
+    `domain` rows take a placement (the rest are the caller's: nodes it
+    walks on the host, or outside a node-update eval), but every row's
+    verdict is what it would be in the domain, so the host walk reads
+    its static feasibility here too."""
+    mask = feasible_mask(attrs, elig, dc_mask, pool_mask, con, luts)
+
+    def group(used, xs):
+        ok, ask = xs                         # [N] bool, [3]
+        over = used + ask[None, :] > cap     # [N, 3]
+        verdict = jnp.where(
+            ~ok, SYS_FILTERED,
+            jnp.where(over[:, 0], SYS_CPU,
+                      jnp.where(over[:, 1], SYS_MEMORY,
+                                jnp.where(over[:, 2], SYS_DISK,
+                                          SYS_PLACED)))).astype(jnp.int8)
+        take = domain & (verdict == SYS_PLACED)
+        return used + take[:, None] * ask[None, :], verdict
+
+    _, verdicts = jax.lax.scan(group, used, (mask, req))
+    return verdicts
+
+
+place_system_jit = jax.jit(place_system)

@@ -1,7 +1,7 @@
 """One small worker pass on a threaded server, shared by the stage-span
 tests (tests/test_wavepipe.py, tests/test_host_spans.py): a full batched
 wave of plain batch jobs, then one spread job, which takes the solo
-path.  Threaded (applier and worker threads of their own), because in
+path, then one system job, which `place_system` places.  Threaded (applier and worker threads of their own), because in
 dev_mode the worker applies plans inline and `commit` would nest in the
 worker's stages."""
 
@@ -11,7 +11,7 @@ from nomad_tpu import mock
 from nomad_tpu.core.server import Server
 from nomad_tpu.structs import Spread, SpreadTarget
 
-N_BATCHED, N_SOLO = 6, 1
+N_BATCHED, N_SOLO, N_SYSTEM = 6, 1, 1
 
 
 def run_small_pass(between=None):
@@ -56,6 +56,12 @@ def run_small_pass(between=None):
                                       SpreadTarget("dc3", 20)))]
         s.register_job(solo, now=now)
         _drain(s, N_BATCHED + N_SOLO)
+        daemon = mock.system_job()
+        daemon.datacenters = ["dc1", "dc2", "dc3"]
+        daemon.task_groups[0].tasks[0].resources.cpu = 50
+        daemon.task_groups[0].tasks[0].resources.memory_mb = 16
+        s.register_job(daemon, now=now)
+        _drain(s, N_BATCHED + N_SOLO + N_SYSTEM)
     finally:
         s.stop_scheduling()
         s.shutdown()

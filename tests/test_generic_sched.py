@@ -269,6 +269,13 @@ class TestBatchScheduler:
             assert placed == []
 
 
+def _system_placed(plan):
+    """A system plan's placements: per-alloc rows (the per-node walk) and
+    the rows of its columnar blocks (the device path)."""
+    return ([a for allocs in plan.node_allocation.values() for a in allocs]
+            + [a for b in plan.alloc_blocks for a in b.materialize_all()])
+
+
 class TestSystemScheduler:
     def test_one_alloc_per_eligible_node(self):
         h, nodes = make_harness(4)
@@ -277,8 +284,7 @@ class TestSystemScheduler:
         e = register_and_eval(h, job)
         err = h.process("system", e, now=NOW)
         assert err is None
-        plan = h.plans[0]
-        placed = [a for allocs in plan.node_allocation.values() for a in allocs]
+        placed = _system_placed(h.plans[0])
         assert len(placed) == 4
         assert len({a.node_id for a in placed}) == 4
 
@@ -292,8 +298,7 @@ class TestSystemScheduler:
         e2 = mock.eval(job_id=job.id, type="system",
                        triggered_by="node-update", node_id=newbie.id)
         h.process("system", e2, now=NOW)
-        plan = h.plans[-1]
-        placed = [a for allocs in plan.node_allocation.values() for a in allocs]
+        placed = _system_placed(h.plans[-1])
         assert len(placed) == 1 and placed[0].node_id == newbie.id
 
     def test_node_down_stops_system_alloc(self):

@@ -97,10 +97,11 @@ class TestSpansInTheTrace:
 
     def test_worker_stages_nest_under_pass(self, traced):
         _, _, view = traced
-        assert len(view.passes) == 2
+        assert len(view.passes) == 3
         assert set(view.worker) == {
             "prepare", "dispatch", "device_wait", "d2h", "solo_place",
-            "materialize", "plan_wait", "eval_update", "ack"}
+            "system_place", "materialize", "plan_wait", "eval_update",
+            "ack"}
         for stage, spans in view.worker.items():
             for a, b in spans:
                 assert any(lo <= a and b <= hi for lo, hi in view.passes), \
@@ -111,7 +112,8 @@ class TestSpansInTheTrace:
 
     @pytest.mark.parametrize("stages", [
         ("prepare",), ("plan_wait",), ("eval_update", "ack"),
-        ("store_upsert",), ("solo_place",), ("materialize",)],
+        ("store_upsert",), ("solo_place",), ("system_place",),
+        ("materialize",)],
         ids="+".join)
     def test_ms_per_eval_agrees_with_the_timers(self, traced, stages):
         server, run, _ = traced
@@ -120,7 +122,7 @@ class TestSpansInTheTrace:
         # timers' two stamps: under a microsecond a span, but the first
         # span a thread closes in a session allocates that thread's
         # event buffer (25-330 us here: on the applier's thread it is
-        # a `store_upsert` of 0.2 ms, on a pass of seven evals)
+        # a `store_upsert` of 0.2 ms, on a run of eight evals)
         spans = sum(counts[s] for s in stages)
         allowed_ms = (0.5 + 0.005 * spans) / counts["ack"]
         assert hs.ms_per_eval(run, *stages) == pytest.approx(
@@ -131,7 +133,8 @@ class TestSpansInTheTrace:
         totals = server.stage_timers.totals()
         named = sum(totals[s] for s in (
             "prepare", "dispatch", "device_wait", "d2h", "solo_place",
-            "materialize", "plan_wait", "eval_update", "ack"))
+            "system_place", "materialize", "plan_wait", "eval_update",
+            "ack"))
         expected = 100.0 * (1.0 - named / totals["pass"])
         got = hs.unnamed_share(run)
         # within 5 % of the named share, which is what is measured

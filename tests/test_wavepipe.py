@@ -543,15 +543,16 @@ class TestExecutorResidentParity:
 # one another, which is what makes "unnamed = pass - the rest" a
 # subtraction (benchmark/host_spans.py reads the same from a trace)
 WORKER_STAGES = ("prepare", "dispatch", "device_wait", "d2h", "solo_place",
-                 "materialize", "plan_wait", "eval_update", "ack")
+                 "system_place", "materialize", "plan_wait", "eval_update",
+                 "ack")
 NEW_STAGES = ("pass", "prepare", "device_wait", "plan_wait", "eval_update",
-              "ack", "solo_place", "store_upsert")
+              "ack", "solo_place", "system_place", "store_upsert")
 
 
 @pytest.fixture(scope="module")
 def small_pass():
-    """A batched wave and a solo eval on a threaded server
-    (tests/stage_pass.py)."""
+    """A batched wave, a solo eval and a system eval on a threaded
+    server (tests/stage_pass.py)."""
     from stage_pass import run_small_pass
     return run_small_pass()
 
@@ -577,10 +578,18 @@ class TestPassStages:
 
     def test_solo_path_records_materialize(self, small_pass):
         # the batched path's intervals carry their wave; the solo
-        # path's, taken at the call that follows engine.place, do not
+        # path's, taken at the call that follows engine.place, and the
+        # system path's, round its block build, do not
         waves = [w for w, _, _ in
                  small_pass.stage_timers.intervals("materialize")]
-        assert waves.count(-1) == 1 and len(waves) == 7
+        assert waves.count(-1) == 2 and len(waves) == 8
+
+    def test_system_eval_is_one_system_place(self, small_pass):
+        # one launch an eval, and its block build follows it
+        (place,) = _spans(small_pass, "system_place")
+        last = _spans(small_pass, "materialize")[-1]
+        assert place[1] <= last[0]
+        assert small_pass.stage_timers.counts()["solo_place"] == 1
 
     @pytest.mark.parametrize("stage", WORKER_STAGES)
     def test_worker_stage_inside_a_pass_and_disjoint(self, small_pass,
@@ -605,10 +614,10 @@ class TestPassStages:
     def test_one_ack_per_eval(self, small_pass):
         worker = small_pass.workers[0]
         assert (small_pass.stage_timers.counts()["ack"]
-                == worker.stats["acked"] + worker.stats["nacked"] == 7)
+                == worker.stats["acked"] + worker.stats["nacked"] == 8)
 
     def test_store_upsert_inside_commit(self, small_pass):
         commits = _spans(small_pass, "commit")
         upserts = _spans(small_pass, "store_upsert")
-        assert len(upserts) == len(commits) == 7
+        assert len(upserts) == len(commits) == 8
         assert all(_inside(u, commits) for u in upserts)
