@@ -192,7 +192,10 @@ class PlanApplier:
         # per-node granularity).
         self.stats = StatCounters("nomad.plan", (
             "fast_path", "full_check", "stale_token",
-            "plans", "plans_refuted"))
+            "plans", "plans_refuted",
+            # fence reads answered without a look at a node / by the
+            # walk, in nodes (StateStore.nodes_unchanged_since)
+            "fence_fast", "fence_walked"))
         # queue-wait/apply timebase (Server injects its clock)
         self.clock: Clock = SystemClock()
         # optional (eval_id, token) -> bool gate, wired by the Server to
@@ -325,12 +328,14 @@ class PlanApplier:
             if plan.coupled_batch is not None:
                 bid, seq0 = plan.coupled_batch
                 touched = self._plan_nodes(plan)
-                fast = self.state.nodes_unchanged_since(touched, seq0, bid)
+                fast = self.state.nodes_unchanged_since(
+                    touched, seq0, bid, tally=self.stats)
                 # "first" = not even the plan's own chain has written these
                 # nodes: that is where batch-mate port collisions hide, so
                 # the port/device demotion keys off it
                 fenced_first = fast and self.state.nodes_unchanged_since(
-                    touched, seq0, bid, own_chain_ok=False)
+                    touched, seq0, bid, own_chain_ok=False,
+                    tally=self.stats)
             result = self.evaluate_plan(plan, skip_fit=fast,
                                         fenced_first=fenced_first)
             self._stamp_trace(plan, result)
@@ -563,10 +568,8 @@ class PlanApplier:
                 # carriers at the chain head (fenced_first), where the
                 # scheduler's NetworkIndex provably saw every live port
                 return False
-            for nid in block.node_table:
-                node = snap.node_by_id(nid)
-                if node is None or node.status == "down":
-                    return False
+            if not snap.nodes_up(block.node_table):
+                return False
             job = tmpl.job
             tg = job.lookup_task_group(tmpl.task_group) if job else None
             if tg is not None and tg.volumes:

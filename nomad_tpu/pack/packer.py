@@ -24,6 +24,7 @@ from __future__ import annotations
 import re
 import threading
 from dataclasses import dataclass, field
+from itertools import repeat
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -171,14 +172,14 @@ class ClusterPacker:
         # terminal allocs linger until GC — so rescans get slower forever).
         self._alloc_node: Dict[str, str] = {}       # alloc id -> node id
         self._counted: Dict[str, Dict[str, Tuple[int, int, int]]] = {}
-        # columnar-block usage, tracked as UNITS (node id -> block id ->
-        # (per-alloc res tuple, alloc count)): an AllocBlock event is one
-        # vectorized scatter, no per-alloc ledger entries.  When the store
+        # columnar-block usage, tracked as UNITS (block id -> the block,
+        # whose node table and node_counts() say where its allocs count):
+        # an AllocBlock event is one vectorized scatter and ONE entry, no
+        # per-alloc and no per-node ledger entries.  When the store
         # materializes a block (a member is about to be updated), the
-        # BlockMaterialized event migrates its nodes into the per-alloc
+        # BlockMaterialized event migrates its rows into the per-alloc
         # ledger with zero net usage change.
-        self._block_counted: Dict[str, Dict[str, Tuple[Tuple[int, int, int],
-                                                       int]]] = {}
+        self._block_counted: Dict[str, object] = {}
         # replay log of usage deltas for device-resident `used` tensors:
         # entries are (used_version, rows, vals) or (used_version, None,
         # None) — the sentinel marks a full/row rescan (device copies must
@@ -300,36 +301,30 @@ class ClusterPacker:
         """A columnar block committed: ONE vectorized usage scatter over
         its unique nodes (the block path's whole point — no per-alloc
         python work), tracked as a unit in _block_counted."""
+        self._block_counted[block.id] = block
         t = self._tensors
-        res = block.resources_tuple()
-        counts = block.node_counts()
-        rows: List[int] = []
-        vals: List[Tuple[int, int, int]] = []
-        for bi, nid in enumerate(block.node_table):
-            c = int(counts[bi])
-            if c == 0:
-                continue
-            per_node = self._block_counted.get(nid)
-            if per_node is None:
-                self._block_counted[nid] = per_node = {}
-            per_node[block.id] = (res, c)
-            if t is not None:
-                row = t.id_to_row.get(nid)
-                if row is not None:
-                    rows.append(row)
-                    vals.append((res[0] * c, res[1] * c, res[2] * c))
-        if t is not None and rows:
-            r = np.asarray(rows, np.intp)
-            v = np.asarray(vals, np.int32)
+        if t is None:
+            return
+        node_table = block.node_table
+        r = np.fromiter(map(t.id_to_row.get, node_table, repeat(-1)),
+                        np.intp, len(node_table))
+        v = (block.node_counts()[:, None]
+             * block.resources_tuple()).astype(np.int32)
+        if r.size and r.min() < 0:      # nodes the tensors do not hold
+            known = r >= 0
+            r, v = r[known], v[known]
+        if r.size:
             np.add.at(t.used, r, v)
             t.used_version = self._log_delta(r, v)
 
     def _on_block_materialized_locked(self, block) -> None:
         """Representation change only (block -> table rows): migrate the
         unit entry into the per-alloc ledger with ZERO usage delta so the
-        follow-up Allocations events find their predecessors.  Nodes
-        whose ledger was re-anchored by a rescan (their block rows were
-        counted per alloc already) are skipped via the alloc_node guard."""
+        follow-up Allocations events find their predecessors.  A block
+        whose unit a rebuild re-anchored away (its rows were counted per
+        alloc from the snapshot) has nothing to migrate."""
+        if self._block_counted.pop(block.id, None) is None:
+            return
         res = block.resources_tuple()
         alloc_node = self._alloc_node
         counted = self._counted
@@ -338,20 +333,11 @@ class ClusterPacker:
             if aid in alloc_node:
                 continue        # a rescan already counted it per alloc
             nid = a.node_id
-            per_node = self._block_counted.get(nid)
-            if per_node is None or block.id not in per_node:
-                continue        # this node was re-anchored; unit gone
             c = counted.get(nid)
             if c is None:
                 counted[nid] = c = {}
             c[aid] = res
             alloc_node[aid] = nid
-        for nid in block.node_table:
-            per_node = self._block_counted.get(nid)
-            if per_node is not None:
-                per_node.pop(block.id, None)
-                if not per_node:
-                    del self._block_counted[nid]
 
     def _log_delta(self, rows, vals, refreshed_rows=None) -> int:
         """Append one used-version bump to the replay log.  `rows is None`
@@ -560,6 +546,7 @@ class ClusterPacker:
             self._last_index = getattr(snapshot, "index", self._last_index)
             return t
         refreshed: List[int] = []
+        unit_used = self._unit_usage_locked(self._dirty)
         for nid in self._dirty:
             row = t.id_to_row.get(nid)
             if row is None:
@@ -571,7 +558,8 @@ class ClusterPacker:
             for k in pm:
                 self.ensure_column(k)
             t.attrs[row, :] = UNSET
-            self._fill_row(t, row, nd, snapshot, pm, from_ledger=True)
+            self._fill_row(t, row, nd, snapshot, pm,
+                           unit_used=unit_used.get(nid, (0, 0, 0)))
             refreshed.append(row)
         self._seq += 1
         t.version = self._seq
@@ -583,27 +571,45 @@ class ClusterPacker:
         self._last_index = getattr(snapshot, "index", self._last_index)
         return t
 
+    def _unit_usage_locked(self, node_ids: Set[str]
+                           ) -> Dict[str, List[int]]:
+        """What the block units count on each of `node_ids`: one pass
+        over the units, a C-level membership test over each node table."""
+        out: Dict[str, List[int]] = {}
+        for block in self._block_counted.values():
+            table = block.node_table
+            if node_ids.isdisjoint(table):
+                continue
+            hit = np.flatnonzero(np.fromiter(
+                map(node_ids.__contains__, table), bool, len(table)))
+            res = block.resources_tuple()
+            for bi, c in zip(hit.tolist(),
+                             block.node_counts()[hit].tolist()):
+                used = out.setdefault(table[bi], [0, 0, 0])
+                used[0] += res[0] * c
+                used[1] += res[1] * c
+                used[2] += res[2] * c
+        return out
+
     def _fill_row(self, t: NodeTensors, i: int, nd: Node, snapshot, pm,
-                  from_ledger: bool = False) -> None:
+                  unit_used: Optional[Sequence[int]] = None) -> None:
+        """`unit_used`: the dirty-row refill (usage from the ledger, the
+        block units' share of it handed in); None: a full rescan."""
         t.cap[i] = (nd.resources.cpu - nd.reserved.cpu,
                     nd.resources.memory_mb - nd.reserved.memory_mb,
                     nd.resources.disk_mb - nd.reserved.disk_mb)
-        if from_ledger:
+        if unit_used is not None:
             # dirty-row refill while attached: the counted/_alloc_node
             # ledger is advanced synchronously by Allocations events and
             # may be AHEAD of the worker's snapshot — re-anchoring from
             # the snapshot would durably desync it (a terminal alloc's
             # removal event never re-fires).  Usage comes from the ledger;
             # node attrs/capacity come from the snapshot's node object.
-            used = [0, 0, 0]
+            used = list(unit_used)
             for res in self._counted.get(nd.id, {}).values():
                 used[0] += res[0]
                 used[1] += res[1]
                 used[2] += res[2]
-            for res, c in self._block_counted.get(nd.id, {}).values():
-                used[0] += res[0] * c
-                used[1] += res[1] * c
-                used[2] += res[2] * c
             t.used[i] = used
         else:
             # full usage rescan for this row: re-anchor the delta accounting
@@ -612,9 +618,9 @@ class ClusterPacker:
                 for aid in old:
                     if self._alloc_node.get(aid) == nd.id:
                         del self._alloc_node[aid]
-            # block rows come back per-alloc from the snapshot read below,
-            # so this node's block UNITS are re-anchored away with the rest
-            self._block_counted.pop(nd.id, None)
+            # block rows come back per-alloc from the snapshot read below;
+            # the block UNITS went with the rest (_build_locked, the one
+            # caller of a full rescan, cleared them)
             counted: Dict[str, Tuple[int, int, int]] = {}
             used = [0, 0, 0]
             for alc in snapshot.allocs_by_node(nd.id):

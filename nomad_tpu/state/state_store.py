@@ -17,10 +17,12 @@ are rebuildable from here at any time (checkpoint/resume, SURVEY.md §6.4).
 from __future__ import annotations
 
 import itertools
+import operator
 import sys
 import threading
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
+from nomad_tpu.state.live_ledger import LiveLedger
 from nomad_tpu.structs import (
     ACLAuthMethod,
     ACLBindingRule,
@@ -44,6 +46,19 @@ from nomad_tpu.structs import (
     ServiceRegistration,
     compute_class,
 )
+
+
+_node_status = operator.attrgetter("status")
+
+
+def _all_up(nodes: Dict[str, Node], node_ids) -> bool:
+    """Every one of `node_ids` is in the node table and not `down`: one
+    C-level pass, no call a node (a block's node table has 49,000)."""
+    try:
+        return "down" not in map(_node_status,
+                                 map(nodes.__getitem__, node_ids))
+    except KeyError:
+        return False
 
 
 def _entry_cost(entry: tuple) -> int:
@@ -138,6 +153,14 @@ class StateStore:
         # workers (zone-partitioned batches) therefore never demote each
         # other to full checks, unlike a global fence.
         self._node_place_seq: Dict[str, Tuple[int, Optional[str]]] = {}
+        # the placement writes as RUNS by origin: every write after
+        # _run_floor carries _run_origin (a chain id; None is foreign to
+        # every chain).  With _placement_seq itself it answers a fence
+        # read in O(1) when nothing, or nothing but the reader's own
+        # chain, has written since the plan's snapshot
+        # (nodes_unchanged_since); anything else falls to the walk
+        self._run_floor = 0
+        self._run_origin: Optional[str] = None
         # after a restore the per-node history is gone: every node is
         # treated as touched at the floor, so pre-restore fences full-check
         self._node_seq_floor = 0
@@ -155,23 +178,16 @@ class StateStore:
         from collections import OrderedDict
         self._eval_decisions: "OrderedDict[str, object]" = OrderedDict()
         self._eval_decision_cap = 512
-        # incremental live-allocation ledger: node id -> [count, cpu,
-        # mem_mb, disk_mb, fill_cpu, fill_mem, fill_disk, zone, zcount]
-        # summed over NON-TERMINAL allocs.  The WRITE path only mutates
-        # the first four ints and marks the node dirty (O(1), no float
-        # math — the 100k-alloc plan insert must not pay it); a row's
-        # standing zone/fill contributions ([4:]) reconcile LAZILY at
-        # quality_summary() time, O(nodes dirtied since the last read).
-        # The summary itself is then O(zones): a 1s scrape or per-commit
-        # refresh never walks the cluster (50k in-use nodes measured
-        # ~200ms per full walk; the soak budget is 2% — PERF.md §11).
-        # Observability only; drift-tolerant on the rare paths the
-        # aggregates can't see (node deleted/re-typed under live
-        # allocs) and rebuilt exactly on snapshot restore.
-        self._node_live: Dict[str, List] = {}
-        self._live_dirty: set = set()
-        self._zone_live: Dict[str, int] = {}   # datacenter -> live allocs
-        self._fill_sums = [0.0, 0.0, 0.0]      # clamped fill fractions
+        # incremental live-allocation ledger behind quality_summary()
+        # (state/live_ledger.py): per-node sums over NON-TERMINAL allocs
+        # as columns.  The WRITE path pays four int adds an alloc or one
+        # list append a block; the fold and the zone/fill aggregates
+        # reconcile LAZILY at quality_summary() time over the rows
+        # dirtied since the last read, in numpy — a 1s scrape or
+        # per-commit refresh never walks the cluster (50k in-use nodes
+        # measured ~200ms per full walk; the soak budget is 2% —
+        # PERF.md §11).
+        self._live = LiveLedger()
         # listeners for state-change events (event broker seam, SURVEY §6.5)
         self._listeners: List[Callable[[str, int, object], None]] = []
         # dirty-key journal for worker-plane replicas (core/workerpool):
@@ -247,78 +263,6 @@ class StateStore:
             out = [d for d in out if d.job_id == job_id]
         return out
 
-    def _live_add_locked(self, node_id: str, d: int, cpu: int, mem: int,
-                         disk: int) -> None:
-        """Apply one delta to the live-allocation ledger (lock held).
-        Int adds + a set add only — the zone/fill aggregate math is
-        deferred to _live_flush_locked so the alloc-insert hot path
-        never pays it.  Rows that reach count<=0 are retired (and their
-        standing contributions reversed) at the next flush."""
-        row = self._node_live.get(node_id)
-        if row is None:
-            self._node_live[node_id] = row = [0, 0, 0, 0,
-                                              0.0, 0.0, 0.0, None, 0]
-        row[0] += d
-        row[1] += cpu
-        row[2] += mem
-        row[3] += disk
-        self._live_dirty.add(node_id)
-
-    def _live_flush_locked(self) -> None:
-        """Reconcile dirty ledger rows into the zone/fill aggregates:
-        retire each row's standing contributions, re-add them from the
-        current counts, and drop emptied rows.  O(nodes dirtied since
-        the last flush) — after a bulk plan that is O(unique touched
-        nodes), never O(cluster)."""
-        dirty = self._live_dirty
-        if not dirty:
-            return
-        live = self._node_live
-        nodes = self._nodes
-        zl = self._zone_live
-        fs = self._fill_sums
-        for nid in dirty:
-            row = live.get(nid)
-            if row is None:
-                continue
-            # retire the standing contributions
-            fs[0] -= row[4]
-            fs[1] -= row[5]
-            fs[2] -= row[6]
-            if row[7] is not None:
-                left = zl.get(row[7], 0) - row[8]
-                if left > 0:
-                    zl[row[7]] = left
-                else:
-                    zl.pop(row[7], None)
-            row[4] = row[5] = row[6] = 0.0
-            row[7] = None
-            row[8] = 0
-            if row[0] <= 0:
-                live.pop(nid)
-                continue
-            node = nodes.get(nid)
-            if node is None:
-                continue        # unknown node: counted in nodes_in_use only
-            res, rsv = node.resources, node.reserved
-            avail = res.cpu - rsv.cpu
-            if avail > 0:
-                row[4] = min(row[1] / avail, 1.0)
-            avail = res.memory_mb - rsv.memory_mb
-            if avail > 0:
-                row[5] = min(row[2] / avail, 1.0)
-            avail = res.disk_mb - rsv.disk_mb
-            if avail > 0:
-                row[6] = min(row[3] / avail, 1.0)
-            fs[0] += row[4]
-            fs[1] += row[5]
-            fs[2] += row[6]
-            z = node.datacenter
-            zl[z] = zl.get(z, 0) + row[0]
-            row[7] = z
-            row[8] = row[0]
-        dirty.clear()
-
     def quality_summary(self) -> Dict[str, float]:
         """Scheduling-quality snapshot from the incremental aggregates
         (the runtime counterpart of bench.py's `quality_nodes_used_tpu`
@@ -327,30 +271,22 @@ class StateStore:
         nodes.  O(dirty nodes + zones) — cheap by construction; safe
         per commit and per scrape at any cluster size."""
         with self._lock:
-            self._live_flush_locked()
-            in_use = len(self._node_live)
-            zvals = list(self._zone_live.values())
-            fills = list(self._fill_sums)
-        zmax = max(zvals, default=0)
-        zmin = min(zvals, default=0)
-        return {
-            "nodes_in_use": in_use,
-            "zone_allocs_max": zmax,
-            "zone_allocs_min": zmin,
-            "zone_balance_max_over_min": (zmax / zmin) if zmin else 0.0,
-            "fill_cpu": max(fills[0], 0.0) / in_use if in_use else 0.0,
-            "fill_memory": max(fills[1], 0.0) / in_use if in_use else 0.0,
-            "fill_disk": max(fills[2], 0.0) / in_use if in_use else 0.0,
-        }
+            return self._live.summary()
 
     def _bump(self) -> int:
         self._index += 1
         self._index_cv.notify_all()
         return self._index
 
-    def _bump_placement(self) -> int:
+    def _bump_placement(self, origin: Optional[str] = None) -> int:
         """_bump for writes that can change placement validity (nodes,
-        allocs, CSI volumes) — advances the applier's fast-path fence."""
+        allocs, CSI volumes) — advances the applier's fast-path fence.
+        `origin`: the chain whose plan this write commits."""
+        if origin is None or origin != self._run_origin:
+            # floor first: a lock-free reader between the two stores
+            # then sees a run too short, and walks
+            self._run_floor = self._placement_seq
+            self._run_origin = origin
         self._placement_seq += 1
         return self._bump()
 
@@ -367,10 +303,15 @@ class StateStore:
 
     def nodes_unchanged_since(self, node_ids, seq0: int,
                               chain_id: Optional[str] = None,
-                              own_chain_ok: bool = True) -> bool:
+                              own_chain_ok: bool = True,
+                              tally=None) -> bool:
         """True when every node in `node_ids` had no fit-relevant write
         after placement_seq `seq0` — writes by `chain_id` itself
         tolerated when `own_chain_ok` (chain plans are co-computed).
+        Answered without looking at a node when NO placement write
+        landed since `seq0`, or none but the chain's own run; else by
+        the walk.  `tally` (the applier's StatCounters) is told which,
+        in nodes: `fence_fast` / `fence_walked`.
         Point reads; values monotone, so a stale read can only cause a
         spurious full check, never a wrong skip — and the commit re-checks
         under the lock via upsert_plan_results' expected_nodes."""
@@ -378,6 +319,14 @@ class StateStore:
         floor = self._node_seq_floor
         if floor > seq0:
             return False
+        fast = self._placement_seq == seq0 or (
+            own_chain_ok and chain_id is not None
+            and chain_id == self._run_origin and self._run_floor <= seq0)
+        if tally is not None:
+            tally.inc("fence_fast" if fast else "fence_walked",
+                      len(node_ids))
+        if fast:
+            return True
         for nid in node_ids:
             e = nps.get(nid)
             if e is None or e[0] <= seq0:
@@ -663,6 +612,8 @@ class StateStore:
             else:
                 self._index = max(int(export["index"]), self._index)
             self._placement_seq = int(export["fence"])
+            self._run_floor = self._placement_seq
+            self._run_origin = None
             self._index_cv.notify_all()
 
     def _apply_delta(self, export: Dict) -> None:
@@ -831,6 +782,7 @@ class StateStore:
             node.computed_class = compute_class(node)
             self._nodes = {**self._nodes, node.id: node}
             self._touch_node(node.id)
+            self._live.note_nodes([node])
             self._emit_locked("Node", idx, node)
             return idx
 
@@ -852,6 +804,7 @@ class StateStore:
                 self._touch_node(node.id)
                 inserted.append(node)
             self._nodes = table          # publish before events fire
+            self._live.note_nodes(inserted)
             for node in inserted:
                 self._emit_locked("Node", idx, node)
             return idx
@@ -863,6 +816,7 @@ class StateStore:
             nodes.pop(node_id, None)
             self._nodes = nodes
             self._touch_node(node_id)
+            self._live.forget_node(node_id)
             self._emit_locked("Node", idx, node_id)
             return idx
 
@@ -1123,7 +1077,7 @@ class StateStore:
         inserted = []
         ins_append = inserted.append
         dead: set = set()
-        live_add = self._live_add_locked
+        live_add = self._live.add
         for a in allocs:
             aid = a.id
             prev = table_get(aid)
@@ -1377,7 +1331,9 @@ class StateStore:
                     # checks — redo them against current state
                     return -1
             self._refute_replayed_placements_locked(result)
-            idx = self._bump_placement()
+            origin = (plan.coupled_batch[0]
+                      if plan.coupled_batch is not None else None)
+            idx = self._bump_placement(origin)
             allocs: List[Allocation] = []
             for node_allocs in result.node_update.values():
                 allocs.extend(node_allocs)
@@ -1391,8 +1347,6 @@ class StateStore:
             # go-memdb convention the reference itself relies on, objects
             # are immutable once inserted (state.UpsertPlanResults stores
             # the submitted pointers directly).
-            origin = (plan.coupled_batch[0]
-                      if plan.coupled_batch is not None else None)
             self._insert_allocs_locked(allocs, idx, copy=False, origin=origin)
             # CSI claims ride the plan commit (reference: the client's
             # claim RPC; the applier's claim_ok re-check reads these).
@@ -1438,22 +1392,27 @@ class StateStore:
     def _commit_block_locked(self, block, idx: int, changed_vols,
                              origin: Optional[str] = None) -> None:
         """Insert a columnar alloc block: registry publishes + bulk CSI
-        claims.  O(unique nodes) python work — never O(count)."""
+        claims.  By columns: a constant number of Python-level calls a
+        block, C-level or numpy work over its nodes — a Python step only
+        for a node that already holds a block."""
         block.create_index = idx
         block.modify_index = idx
-        for nid in block.node_table:
-            self._touch_node(nid, origin)
-        # live-allocation ledger: whole-block demand in O(unique nodes)
-        # (rows retire per alloc later — materialization keeps liveness)
-        for nid, (cnt, cpu, mem, disk) in block.demand_by_node().items():
-            self._live_add_locked(nid, cnt, cpu, mem, disk)
+        node_table = block.node_table
+        # the per-node fence, _touch_node over the whole table
+        self._node_place_seq.update(
+            dict.fromkeys(node_table, (self._placement_seq, origin)))
+        # live-allocation ledger: the whole block as one unit (rows
+        # retire per alloc later — materialization keeps liveness)
+        self._live.add_block(block)
         blocks, bj, bn = self._writable_block_tables()
         blocks[block.id] = block
         tmpl = block.template
         jkey = (tmpl.namespace, tmpl.job_id)
         bj[jkey] = bj.get(jkey, ()) + (block,)
-        for nid in block.node_table:
-            bn[nid] = bn.get(nid, ()) + (block,)
+        held = dict.fromkeys(node_table, (block,))
+        for nid in filter(bn.__contains__, node_table):
+            held[nid] = bn[nid] + (block,)
+        bn.update(held)
         # CSI claims for the whole block in one dict update per volume
         job = tmpl.job
         tg = job.lookup_task_group(tmpl.task_group) if job else None
@@ -2052,10 +2011,8 @@ class StateStore:
             self._fresh_job_buckets = set()
             self._fresh_eval_buckets = set()
             self._fresh_claim_vols = set()
-            self._node_live = {}
-            self._live_dirty = set()
-            self._zone_live = {}
-            self._fill_sums = [0.0, 0.0, 0.0]
+            self._live.reset()
+            self._live.note_nodes(list(self._nodes.values()))
             for d in doc["Allocs"]:
                 a = codec.decode(Allocation, d)
                 a.job = self._job_versions.get(
@@ -2066,8 +2023,8 @@ class StateStore:
                     self._allocs_by_node.setdefault(a.node_id, {})[a.id] = a
                     if not a.terminal_status():
                         r = a.resources
-                        self._live_add_locked(a.node_id, 1, r.cpu,
-                                              r.memory_mb, r.disk_mb)
+                        self._live.add(a.node_id, 1, r.cpu,
+                                       r.memory_mb, r.disk_mb)
                 self._allocs_by_job.setdefault(
                     (a.namespace, a.job_id), {})[a.id] = a
             self._evals_by_job = {}
@@ -2118,6 +2075,8 @@ class StateStore:
             self._placement_seq = int(doc.get("PlacementSeq", 0))
             self._node_place_seq = {}
             self._node_seq_floor = self._placement_seq
+            self._run_floor = self._placement_seq
+            self._run_origin = None
             self._index = max(int(doc.get("Index", 0)), self._index) + 1
             self._index_cv.notify_all()
             self._emit_locked("Restore", self._index, None)
@@ -2173,6 +2132,10 @@ class StateStore:
     # since the last snapshot are mutated in place by _insert_allocs_locked.
     def node_by_id(self, node_id: str) -> Optional[Node]:
         return self._nodes.get(node_id)
+
+    def nodes_up(self, node_ids) -> bool:
+        """Every one of `node_ids` exists and is not `down`."""
+        return _all_up(self._nodes, node_ids)
 
     def job_by_id(self, namespace: str, job_id: str) -> Optional[Job]:
         return self._jobs.get((namespace, job_id))
@@ -2262,6 +2225,10 @@ class StateSnapshot:
 
     def node_by_id(self, node_id: str) -> Optional[Node]:
         return self._nodes.get(node_id)
+
+    def nodes_up(self, node_ids) -> bool:
+        """Every one of `node_ids` exists and is not `down`."""
+        return _all_up(self._nodes, node_ids)
 
     def ready_nodes_in_pool(self, datacenters: List[str],
                             pool: str = "default") -> List[Node]:
