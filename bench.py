@@ -657,8 +657,7 @@ def _build_bench_cluster(n_nodes: int, seed: int = 0):
 
 
 def _sustained_reference_1worker(worker_mode, batch, n_nodes, n_evals,
-                                 per_eval, sus_waves, executor="jax",
-                                 mesh_off=False):
+                                 per_eval, sus_waves, mesh_off=False):
     """The 1-worker leg of the worker A/B: same cluster shape, same
     sustained drain, num_workers=1, same worker_mode.  Runs in the same
     process AFTER the main leg so every kernel compile is already
@@ -669,7 +668,6 @@ def _sustained_reference_1worker(worker_mode, batch, n_nodes, n_evals,
 
     s = Server(dev_mode=False, num_workers=1, eval_batch=batch,
                heartbeat_ttl=1e9, nack_timeout=600.0,
-               device_executor=executor,
                mesh=False if mesh_off else None,
                worker_mode=worker_mode)
     s.establish_leadership()
@@ -781,10 +779,6 @@ def run_config_5(args):
                # TPU v5e — PERF.md Findings PR 21) must not trip eval
                # redelivery mid-warmup
                nack_timeout=600.0,
-               # pluggable device executor (ops/executor.py): the REAL
-               # eval-driven path rides retained buffer handles — no
-               # --bridge side-channel needed for the resident chain
-               device_executor=(args.executor or "jax"),
                mesh=False if mesh_off else None,
                # host sampling profiler (core/profiling.py): None keeps
                # the always-on default; --sampler-hz 0 disables (the
@@ -800,9 +794,6 @@ def run_config_5(args):
         parity_evals = _sharded_parity_gate()
         print(f"sharded parity gate ok: {parity_evals} evals, "
               f"{n_devices} devices", file=sys.stderr)
-    # --resident off: the A/B lever for PERF.md §12 — every wave
-    # re-syncs used0 from the packer through the host (no chaining)
-    s.executor.chain_enabled = (args.resident != "off")
     # timeline plane (core/timeline.py): the bench has no tick loop, so
     # the drain poll below samples explicitly; reset() pins the counter
     # base so the headline's timeline covers this run only
@@ -1138,7 +1129,6 @@ def run_config_5(args):
         if by_cause1.get(cause, 0) != by_cause0.get(cause, 0)} \
         if ex_waves else {}
     compile_summary = _prof.COMPILE.snapshot()
-    executor_backend = s.executor.name
 
     # networked tier (ISSUE 8): one wave of the SAME shape with a
     # dynamic-port ask per task — the batched per-node carve keeps it on
@@ -1239,7 +1229,7 @@ def run_config_5(args):
     if n_workers > 1:
         ref = _sustained_reference_1worker(
             worker_mode, batch, n_nodes, n_evals, per_eval, sus_waves,
-            executor=(args.executor or "jax"), mesh_off=mesh_off)
+            mesh_off=mesh_off)
         sus_by_workers["1"] = round(ref, 2)
         print(f"worker A/B ({worker_mode}): "
               f"{sus_by_workers['1']} evals/s at 1 worker, "
@@ -1300,9 +1290,8 @@ def run_config_5(args):
                if gil_by_process else {}),
             **({"pool_stats": pool_stats} if pool_stats else {}),
             "plan_refute_rate": round(refute_rate, 4),
-            # device-resident executor (ops/executor.py): backend +
-            # steady-state chain residency over the sustained section
-            "executor_backend": executor_backend,
+            # device-resident executor (ops/executor.py): steady-state
+            # chain residency over the sustained section
             "resident_chain_hit_rate": round(resident_hit, 4),
             "h2d_bytes_per_wave": round(h2d_per_wave, 1),
             # the same bytes split by CAUSE (core/profiling plane):
@@ -1440,7 +1429,7 @@ def run_config_5(args):
 
 def _build_bench_items(args):
     """Shared bench-scale batch: the zoned CSI cluster + one BatchItem
-    per eval, identical across --kernel, --bridge, and config 5's job
+    per eval, identical across --kernel and config 5's job
     shape (three copies of this block would silently drift — code-review
     r5)."""
     from nomad_tpu import mock
@@ -2020,130 +2009,6 @@ def run_kernel(args):
             "vs_c1m_anchor": round(rate / C1M_PLACEMENTS_PER_SEC, 2)}
 
 
-def run_bridge(args):
-    """--bridge: the PRODUCTION multi-eval kernel at bench scale through
-    the C++ PJRT bridge (native/pjrt_bridge/bridge.cc) — compile once,
-    then a launch loop with NO Python in it beyond one ctypes call per
-    wave (VERDICT r3 #3).  Reports the bridge's own placements/sec next
-    to the Python-driven pipeline number."""
-    from functools import partial
-
-    import jax
-    import numpy as np
-
-    from nomad_tpu.native.bridge import (
-        PjrtBridge, bridge_available, export_stablehlo)
-    from nomad_tpu.ops import PlacementEngine
-    from nomad_tpu.ops.select import (
-        FILL_K, place_multi_compact_packed, place_multi_packed)
-
-    if not bridge_available(args.bridge):
-        return {"metric": "bridge_multi_eval_placements_per_sec",
-                "value": 0.0, "unit": "placements/sec",
-                "error": "bridge or plugin unavailable"}
-
-    h, nodes, items, n_nodes, n_evals, per_eval = _build_bench_items(args)
-    snap = h.state.snapshot()
-    eng = PlacementEngine(mesh=False)
-    built = eng.build_multi_inputs(snap, items, seed=13)
-    inp, rs = built["inp"], built["rs"]
-    # the builder emits the compact laned layout for the zoned bench
-    # batch — export THAT kernel (the flat kernel cannot consume the
-    # compact [J', Nc] job-count table; code-review r5)
-    if built["cand_rows"] is not None:
-        kernel = partial(place_multi_compact_packed, round_size=rs,
-                         n_lanes=built["n_lanes"])
-        kargs = (inp, jax.numpy.asarray(built["cand_rows"]),
-                 jax.numpy.asarray(built["cand_valid"]))
-        meta_off = min(FILL_K, rs)
-    else:
-        kernel = partial(place_multi_packed, round_size=rs)
-        kargs = (inp,)
-        meta_off = rs
-
-    hlo = export_stablehlo(kernel, *kargs)
-    br = PjrtBridge(args.bridge)
-    handles = []
-    try:
-        ex = br.compile(hlo)
-        flat = [np.asarray(x) for x in jax.tree_util.tree_leaves(kargs)]
-        shapes = [(tuple(s.shape), np.dtype(s.dtype)) for s in
-                  jax.eval_shape(kernel, *kargs)]
-        # PERSISTENT device buffers (round-5 verdict #4): node tensors
-        # upload ONCE; each wave executes on resident handles and
-        # fetches only the compact result buffer — the old per-execute
-        # re-upload of every argument was the 4x gap vs the JAX path
-        handles = [br.upload(a) for a in flat]
-        # used0 is flat-INPUT index 2 on both paths (MultiEvalInputs
-        # field order); the used OUTPUT index differs: compact returns
-        # (buf_small, fills, used), flat returns (buf, used, jc)
-        used0_idx = 2
-        used_out_idx = 2 if built["cand_rows"] is not None else 1
-        outs = br.execute_resident(ex, handles, len(shapes))   # warm
-        buf0 = br.fetch(outs[0], *shapes[0])
-        placed_wave = int(buf0[:, meta_off:][:, 12].sum())
-        iters = max(args.iters, 1) * 4
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            prev = outs
-            outs = br.execute_resident(ex, handles, len(shapes))
-            for h in prev:
-                br.buffer_free(h)
-            buf0 = br.fetch(outs[0], *shapes[0])
-        dt = (time.perf_counter() - t0) / iters
-        rate = placed_wave / dt if dt > 0 else 0.0
-        for h in outs:
-            br.buffer_free(h)
-        # device-resident STATE CHAIN: wave k+1 starts from wave k's
-        # proposed-usage OUTPUT handle — cluster state never crosses to
-        # the host; placements shrink as capacity fills (the production
-        # Go-worker pattern)
-        chained_placed = []
-        chained_used_cpu = []
-        chain_used = None
-        for _ in range(3):
-            chain = list(handles)
-            if chain_used is not None:
-                chain[used0_idx] = chain_used
-            outs_c = br.execute_resident(ex, chain, len(shapes))
-            if chain_used is not None:
-                br.buffer_free(chain_used)
-            b0 = br.fetch(outs_c[0], *shapes[0])
-            chained_placed.append(int(b0[:, meta_off:][:, 12].sum()))
-            # the used tensor's total is the chain's proof: it grows
-            # wave over wave only if wave k+1 really started from wave
-            # k's device-side output (this fetch is demo-only, not part
-            # of the measured loop)
-            used_np = br.fetch(outs_c[used_out_idx],
-                               *shapes[used_out_idx])
-            chained_used_cpu.append(int(used_np[:, 0].sum()))
-            for oi, h in enumerate(outs_c):
-                if oi != used_out_idx:
-                    br.buffer_free(h)
-            chain_used = outs_c[used_out_idx]    # used rides on device
-        if chain_used is not None:
-            br.buffer_free(chain_used)
-        return {"metric": "bridge_multi_eval_placements_per_sec",
-                "value": round(rate, 1), "unit": "placements/sec",
-                "vs_c1m_anchor": round(rate / C1M_PLACEMENTS_PER_SEC, 2),
-                "platform": br.platform(),
-                "placed_per_wave": placed_wave,
-                "resident_buffers": len(handles),
-                "chained_waves_placed": chained_placed,
-                # strictly increasing = the device-side usage chain is
-                # live (wave k+1 consumed wave k's output handle)
-                "chained_used_cpu_totals": chained_used_cpu,
-                "wave_s": round(dt, 4), "n_evals": n_evals,
-                "nodes": n_nodes}
-    finally:
-        for h in handles:
-            try:
-                br.buffer_free(h)
-            except Exception:  # noqa: BLE001 - teardown best-effort
-                pass
-        br.close()
-
-
 def _apply_mesh_arg(args):
     """`--mesh N`: force N virtual host devices BEFORE the first JAX
     backend init (tests/conftest.py's trick, as a bench flag) so the
@@ -2319,14 +2184,6 @@ def main():
                     help="config 5: one giant-eval warm run and one "
                          "2-wave sustained run instead of the full "
                          "ladder (CI multichip smoke + scale sweeps)")
-    ap.add_argument("--executor", choices=("jax", "bridge"), default="jax",
-                    help="config 5: device-executor backend for the "
-                         "worker loop (ops/executor.py); 'bridge' errors "
-                         "when the native build/plugin is absent")
-    ap.add_argument("--resident", choices=("on", "off"), default="on",
-                    help="config 5: retain the device-resident usage "
-                         "chain across waves (off = host round-trip "
-                         "every wave; the PERF.md §12 A/B lever)")
     ap.add_argument("--sampler-hz", dest="sampler_hz", type=float,
                     default=None, metavar="HZ",
                     help="config 5: host sampling-profiler rate "
@@ -2355,13 +2212,6 @@ def main():
                     help="kernel-only microbench: the production "
                          "multi-eval kernel's device rate at bench scale "
                          "(launch loop amortized, one final fetch)")
-    ap.add_argument("--bridge", metavar="PLUGIN_SO", default=None,
-                    help="run the production multi-eval kernel at bench "
-                         "scale through the C++ PJRT bridge (no Python "
-                         "in the launch loop) against this PJRT plug-in "
-                         "library and report its rate.  The bridge opens "
-                         "its own PJRT client: not in a process (or "
-                         "beside one) that holds the chip through JAX")
     ap.add_argument("--phases", action="store_true",
                     help="report the measured wave's wall-time split "
                          "across pipeline phases (host vs device)")
@@ -2408,10 +2258,6 @@ def main():
 
     if args.kernel:
         print(json.dumps(run_kernel(args)))
-        return
-
-    if args.bridge:
-        print(json.dumps(run_bridge(args)))
         return
 
     if args.all:

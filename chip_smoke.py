@@ -6,7 +6,7 @@
     python chip_smoke.py --cpu-dry-run    # tiny size on the CPU backend
 
 Drives `job register` on the HTTP API -> broker -> worker -> WavePipeline
--> JaxExecutor -> PlacementEngine kernels -> D2H -> materialize -> plan
+-> DeviceExecutor -> PlacementEngine kernels -> D2H -> materialize -> plan
 queue -> applier commit -> allocations read back over HTTP, at BASELINE
 config 5's full size (50,000 nodes x 5 CSI zones x 3 DCs, 384 batch jobs
 x 260 placements, plus one spread+affinity service job, then ten more

@@ -36,7 +36,6 @@ class Agent:
                  transport: str = "tcp",
                  clock: str = "wall",
                  log_level: str = "",
-                 device_executor: str = "jax",
                  slo: Optional[Dict[str, float]] = None,
                  profile_hz: Optional[float] = None,
                  worker_mode: str = "thread",
@@ -133,8 +132,7 @@ class Agent:
                 num_workers=num_workers, heartbeat_ttl=heartbeat_ttl,
                 acl_enabled=acl_enabled,
                 transport=self.transport, clock=self.clock,
-                device_executor=device_executor, slo=slo,
-                profile_hz=profile_hz, worker_mode=worker_mode,
+                slo=slo, profile_hz=profile_hz, worker_mode=worker_mode,
                 mesh=mesh)
         else:
             self.transport = resolve_transport(transport, node_name="agent",
@@ -142,7 +140,6 @@ class Agent:
             self.server = Server(num_workers=num_workers, dev_mode=False,
                                  heartbeat_ttl=heartbeat_ttl,
                                  acl_enabled=acl_enabled, clock=self.clock,
-                                 device_executor=device_executor,
                                  slo=slo, profile_hz=profile_hz,
                                  worker_mode=worker_mode, mesh=mesh)
         self.clients: List[Client] = []

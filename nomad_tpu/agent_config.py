@@ -13,7 +13,6 @@ Supported shape (a practical subset of the reference's):
       acl_enabled     = false
       transport       = "tcp"      # or "sim"  (nomad_tpu/chaos/)
       clock           = "wall"     # or "virtual"
-      device_executor = "jax"      # or "bridge" (nomad_tpu/ops/executor.py)
       profile_hz      = 19         # host sampler rate; 0 disables
       scheduler_workers = 2        # alias of num_schedulers
       worker_mode     = "thread"   # or "process" (core/workerpool.py)
@@ -78,13 +77,6 @@ class AgentConfig:
     # test-only monkeypatch
     transport: str = "tcp"
     clock: str = "wall"
-    # device-executor backend for the scheduling workers'
-    # wave launches (nomad_tpu/ops/executor.py): "jax" runs the
-    # donation-chained in-process kernels (CPU/TPU); "bridge" drives the
-    # same kernels through the C++ PJRT bridge with persistent device
-    # buffers and errors at agent start when the native build or PJRT
-    # plugin is absent (never a silent fallback)
-    device_executor: str = "jax"
     # continuous-profiling sampler rate (core/profiling.py): the host
     # stack sampler is always-on at profiling.DEFAULT_HZ when this is
     # None; a positive value re-tunes it and <= 0 disables it
@@ -113,8 +105,7 @@ _BLOCK_KEYS = {
     "ports": {"http"},
     "server": {"enabled", "num_schedulers", "scheduler_workers",
                "worker_mode", "heartbeat_ttl",
-               "acl_enabled", "transport", "clock", "device_executor",
-               "profile_hz"},
+               "acl_enabled", "transport", "clock", "profile_hz"},
     "client": {"enabled", "count", "node_class", "datacenter"},
     "acl": {"enabled"},
 }
@@ -202,15 +193,6 @@ def parse_agent_config(src: str):
                             f"server clock must be 'wall' or 'virtual', "
                             f"got {v!r}")
                     put("clock", v)
-                if "device_executor" in body:
-                    v = str(body["device_executor"])
-                    # mirror ops.executor.EXECUTOR_BACKENDS; literal so
-                    # config parsing never imports the jax stack
-                    if v not in ("jax", "bridge"):
-                        raise ValueError(
-                            "server device_executor must be 'jax' or "
-                            f"'bridge', got {v!r}")
-                    put("device_executor", v)
                 if "profile_hz" in body:
                     v = body["profile_hz"]
                     if isinstance(v, bool) or not isinstance(

@@ -134,7 +134,6 @@ def cmd_agent(args) -> int:
                   transport=cfg.transport,
                   clock=cfg.clock,
                   log_level=cfg.log_level,
-                  device_executor=cfg.device_executor,
                   slo=cfg.slo or None,
                   profile_hz=cfg.profile_hz,
                   worker_mode=cfg.worker_mode,

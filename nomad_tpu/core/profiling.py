@@ -35,8 +35,7 @@ terminal history:
   - `CompileLedger` (the device ledger's compile half): per-site,
     per-shape-bucket compile-cache hits / misses / first-launch
     seconds vs steady-call split.  ops/engine.py records `_sharded_fn`
-    cache traffic here; ops/executor.py records the PJRT bridge's
-    StableHLO compiles.  The HBM-residency half lives on the executor
+    cache traffic here.  The HBM-residency half lives on the executor
     (`DeviceExecutor.ledger()`), built from retained buffer handle
     sizes.
 
@@ -240,9 +239,8 @@ def _fold(frame) -> Tuple[str, ...]:
 class CompileLedger:
     """Per-shape-bucket compile-cache accounting (the device ledger's
     compile half).  A SITE is one compile cache keyed by shape bucket —
-    `engine.multi/1024x50000`, `bridge/...` — and per site the ledger
-    splits first-launch seconds (trace+lower+compile+run) from steady
-    calls, the split PERF.md §13 measured by hand."""
+    `engine.multi/1024x50000` — and per site the ledger splits
+    first-launch seconds (trace+lower+compile+run) from steady calls."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()

@@ -68,7 +68,6 @@ class Server:
                  eval_batch: int = 64,
                  nack_timeout: Optional[float] = None,
                  clock: Optional[Clock] = None,
-                 device_executor: str = "jax",
                  mesh=None,
                  slo: Optional[Dict[str, float]] = None,
                  profile_hz: Optional[float] = None,
@@ -153,14 +152,12 @@ class Server:
         self.engine = PlacementEngine(mesh=mesh)
         self.engine.timers = self.stage_timers
         self.engine.packer.attach(self.state)
-        # pluggable device executor (ops/executor.py, agent_config
-        # server.device_executor): the seam the workers' wave pipelines
-        # launch through — "jax" (default) or the C++ PJRT "bridge",
-        # both riding retained device buffers with the proposed-usage
-        # chain held resident ACROSS worker passes.  Raises loudly when
-        # "bridge" is configured without the native build.
-        from nomad_tpu.ops.executor import make_executor
-        self.executor = make_executor(device_executor, self.engine)
+        # the device executor (ops/executor.py): the seam the workers'
+        # wave pipelines launch through, riding retained device buffers
+        # with the proposed-usage chain held resident ACROSS worker
+        # passes
+        from nomad_tpu.ops.executor import DeviceExecutor
+        self.executor = DeviceExecutor(self.engine)
         # chain hygiene: node writes / restores / capacity-freeing alloc
         # writes invalidate the resident chain (it cannot see them)...
         self.executor.attach_store(self.state)

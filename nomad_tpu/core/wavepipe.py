@@ -283,17 +283,16 @@ class WavePipeline:
     arbitrarily far from committed state for no wall-clock gain on one
     device.
 
-    Waves launch through the pluggable device-executor seam
-    (ops/executor.py): the default JAX backend or the C++ PJRT bridge,
-    both keeping node state in retained device buffers.  A bare
-    PlacementEngine is accepted for compatibility (tests, harnesses) and
-    wrapped in a JaxExecutor."""
+    Waves launch through the device-executor seam (ops/executor.py),
+    which keeps node state in retained device buffers.  A bare
+    PlacementEngine is accepted (tests, harnesses) and wrapped in a
+    DeviceExecutor."""
 
     def __init__(self, executor, timers: Optional[StageTimers] = None
                  ) -> None:
-        from nomad_tpu.ops.executor import DeviceExecutor, JaxExecutor
+        from nomad_tpu.ops.executor import DeviceExecutor
         if not isinstance(executor, DeviceExecutor):
-            executor = JaxExecutor(executor)
+            executor = DeviceExecutor(executor)
         self.executor = executor
         self.engine = executor.engine
         self.timers = timers if timers is not None else StageTimers()

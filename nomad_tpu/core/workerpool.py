@@ -342,7 +342,12 @@ def _make_remote_executor(chan: _Channel, engine):
         name = "pool-remote"
 
         def __init__(self) -> None:
+            # the base constructor points the engine's transfer meters
+            # at its own stats; the ledger that counts is the parent's,
+            # so the child's engine keeps the observers it had
+            observers = engine.h2d_observer, engine.d2h_observer
             super().__init__(engine)
+            engine.h2d_observer, engine.d2h_observer = observers
             self._chan = chan
 
         def dispatch_batch(self, snapshot, items, seed=0,

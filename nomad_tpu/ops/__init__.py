@@ -5,12 +5,7 @@ from .compile_cache import place_compile_cache
 place_compile_cache()      # before any kernel below can compile
 
 from .engine import PlacementDecision, PlacementEngine, PlacementRequest  # noqa: F401
-from .executor import (  # noqa: F401
-    DeviceExecutor,
-    ExecutorUnavailable,
-    JaxExecutor,
-    make_executor,
-)
+from .executor import DeviceExecutor  # noqa: F401
 from .feasibility import constraint_mask, feasible_mask  # noqa: F401
 from .scoring import (  # noqa: F401
     affinity_score,

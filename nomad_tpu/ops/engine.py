@@ -1144,10 +1144,9 @@ class PlacementEngine:
 
     # device preemption: the victim tables are COMPACT (candidate nodes x
     # pow2 depth ladder), so the upload is bounded by live victims, not
-    # cluster size — no node-count cap (VERDICT r3 #4; previously gated
-    # to 2k..8192 nodes by the O(N x 32) upload).  One launch per
-    # failing task group; mixed-TG batches chain launches through the
-    # same usage state.  The host Preemptor covers tiny batches,
+    # cluster size — no node-count cap.  One launch per failing task
+    # group; mixed-TG batches chain launches through the same usage
+    # state.  The host Preemptor covers tiny batches,
     # >MAX_VICTIMS-deep nodes, oversized tables, and anything the
     # kernel left unplaced.
     PREEMPT_DEVICE_MIN_FAILED = 4
@@ -1482,9 +1481,8 @@ class PlacementEngine:
                            seed: int = 0, used0_dev=None,
                            masked_node_ids=None):
         """Host half of dispatch_batch: pack + lower a multi-eval batch
-        into MultiEvalInputs WITHOUT launching.  Exposed so non-JAX
-        launchers (the C++ PJRT bridge, bench --bridge) can export the
-        exact production kernel + inputs at any scale.  Returns a dict
+        into MultiEvalInputs WITHOUT launching (bench.py --kernel times
+        the production kernel on exactly these inputs).  Returns a dict
         {inp, rs, spans, counts, t, ctxs, n, npad, t0, chained} or the
         empty-cluster sentinel tuple.
 

@@ -1,28 +1,20 @@
 """Device executor seam — resident buffer handles for the worker loop.
 
-The C++ PJRT bridge proved the production shape (PERF.md §5): upload
-node tensors ONCE into retained device buffers, execute every wave on
-handles, and chain each wave's proposed-usage OUTPUT handle into the
-next wave's `used0` so steady-state scheduling never materializes node
-state on the host.  Before this seam that chain existed only inside one
-worker pass (core/worker.py's prefetch) and in `bench.py --bridge`;
-this module makes it the production contract between the wave pipeline
-(core/wavepipe.py) and the kernels:
+Node tensors are uploaded ONCE into retained device buffers, every wave
+executes on handles, and each wave's proposed-usage OUTPUT handle chains
+into the next wave's `used0`, so steady-state scheduling never
+materializes node state on the host.  This module is the contract
+between the wave pipeline (core/wavepipe.py) and the kernels:
 
-  - `DeviceExecutor` is the seam: dispatch/collect a multi-eval wave,
-    hand out a wave's chain state, and RETAIN the final wave's
-    proposed-usage handle across worker passes so the next dequeued
-    batch starts device-resident instead of re-syncing `used0` from the
-    packer through the host.
-  - `JaxExecutor` (default backend, CPU/TPU): delegates to
+  - `DeviceExecutor` dispatches and collects a multi-eval wave through
     `PlacementEngine.dispatch_batch`, whose chained launches ride the
     `donate_argnums` jit variants (select.place_multi_chained) — XLA
-    reuses the dead chain buffer in place.
-  - `BridgeExecutor` (fast backend): the same kernels exported as
-    StableHLO and driven through the C++ PJRT bridge
-    (native/bridge.py) with `ntb_upload`/`ntb_execute_resident` —
-    no per-wave argument re-upload, outputs stay device-resident as
-    retained handles.
+    reuses the dead chain buffer in place.  It hands out a wave's chain
+    state and RETAINS the final wave's proposed-usage handle across
+    worker passes, so the next dequeued batch starts device-resident
+    instead of re-syncing `used0` from the packer through the host.
+  - `SubmissionFrontEnd` serializes the process worker plane's
+    submissions into one shared executor (core/workerpool.py).
 
 Safety of the retained chain: proposed usage is a SUPERSET of what the
 chain's own plans commit, so a chained wave can under-pack but never
@@ -33,7 +25,7 @@ INVALIDATES the retained chain (dropping back to a packer-synced
 re-upload, counted in `nomad.executor.invalidations`) on every
 state-store write that changes node state the chain cannot observe:
 
-  - node writes (register / drain / eligibility / attribute change),
+  - node writes (register / delete / status / drain / eligibility),
   - snapshot restore,
   - capacity-freeing alloc writes (terminal transitions),
   - a committed plan from OUTSIDE the chain (solo/system/foreign
@@ -56,69 +48,41 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Optional, Sequence
-
-import numpy as np
+from typing import Sequence
 
 from nomad_tpu.core.flightrec import FLIGHT
 from nomad_tpu.core.timeline import TIMELINE
 from nomad_tpu.core.telemetry import REGISTRY
 
-EXECUTOR_BACKENDS = ("jax", "bridge")
-
-
-class ExecutorUnavailable(RuntimeError):
-    """The requested executor backend cannot run in this process."""
-
-
-def make_executor(name: str, engine, plugin: Optional[str] = None,
-                  chain_enabled: bool = True) -> "DeviceExecutor":
-    """Build the configured executor backend over `engine`
-    (agent_config `server.device_executor`).  Raises ValueError on an
-    unknown name OR on a config the engine cannot honor (bridge over a
-    multi-device mesh), and ExecutorUnavailable when `bridge` is
-    requested but the native build or PJRT plugin is absent.  All three
-    raise at SERVER CONSTRUCTION — agent start — never mid-worker-loop."""
-    if name in ("", None, "jax"):
-        return JaxExecutor(engine, chain_enabled=chain_enabled)
-    if name == "bridge":
-        if getattr(engine, "mesh", None) is not None:
-            # config validation, not availability: the C++ PJRT bridge
-            # drives exactly one device, and this runtime exposes a
-            # multi-device mesh the engine shards the node axis over.
-            # There is no silent fallback — the operator picks one.
-            raise ValueError(
-                "agent_config: server.device_executor = \"bridge\" "
-                "drives a single PJRT device, but this engine shards "
-                f"the node axis over a {engine.mesh.devices.size}-device "
-                "mesh; set server.device_executor = \"jax\" (the "
-                "sharded backend), or run single-device (e.g. "
-                "JAX_PLATFORMS with one visible device) — see README "
-                "\"Scaling out\"")
-        return BridgeExecutor(engine, plugin=plugin,
-                              chain_enabled=chain_enabled)
-    raise ValueError(
-        f"unknown device_executor {name!r} "
-        f"(expected one of {EXECUTOR_BACKENDS})")
-
 
 class DeviceExecutor:
-    """Pluggable device-execution seam between the wave pipeline and the
+    """The device-execution seam between the wave pipeline and the
     kernels.  One instance per Server, shared by its workers — each
     retained chain lives in a per-CLIENT slot CLAIMED atomically
     (claim_chain pops; in-process workers share the default "" slot),
     so two workers can never chain concurrently on the same
     donated/retained buffer under one chain id (which would exempt each
-    other from the applier's per-node fence)."""
+    other from the applier's per-node fence).
 
-    name = "base"
+    Node tensors are device-resident in the engine's version-keyed
+    caches; the executor meters every host->device sync and
+    device->host fetch the engine performs."""
 
-    def __init__(self, engine, chain_enabled: bool = True) -> None:
+    name = "jax"
+
+    def __init__(self, engine) -> None:
         self.engine = engine
-        # chain_enabled=False is the A/B lever (bench --resident off and
-        # the parity suite's serial reference): every wave re-syncs
-        # `used0` from the packer through the host
-        self.chain_enabled = chain_enabled
+        # True in every deployment.  False, which only tests set, is
+        # the PARITY REFERENCE (the pattern of generic.PORT_BATCHED):
+        # every wave re-syncs `used0` from the packer through the host,
+        # and tests/test_wavepipe.py TestExecutorResidentParity and
+        # tests/test_executor.py hold the resident chain to it
+        self.chain_enabled = True
+        # meter the engine's host->device node-state syncs
+        # (_node_arrays full uploads + _used_device delta replays) and
+        # its device->host result fetches
+        engine.h2d_observer = self._observe_h2d
+        engine.d2h_observer = self._observe_d2h
         self._lock = threading.Lock()
         # client -> (batch_id, seq0, (used, node_version, npad),
         # masked_nodes).  One slot per chain CLIENT: the in-process
@@ -150,10 +114,16 @@ class DeviceExecutor:
 
     def dispatch_batch(self, snapshot, items: Sequence, seed=0,
                        used0_dev=None, masked_node_ids=None):
-        raise NotImplementedError
+        if not self.chain_enabled:
+            used0_dev = None
+        pending = self.engine.dispatch_batch(
+            snapshot, items, seed=seed, used0_dev=used0_dev,
+            masked_node_ids=masked_node_ids)
+        self._note_dispatch(pending, used0_dev is not None)
+        return pending
 
     def collect_batch(self, pending):
-        raise NotImplementedError
+        return self.engine.collect_batch(pending)
 
     def chain_state(self, pending):
         """The (usage, node version, padded n) triple a successor wave
@@ -189,11 +159,8 @@ class DeviceExecutor:
         if not self.chain_enabled or used_triple is None or not batch_id:
             return
         with self._lock:
-            old = self._chains.get(client)
             self._chains[client] = (
                 batch_id, seq0, used_triple, frozenset(masked or ()))
-        if old is not None:
-            self._release_chain(old)
 
     def claim_chain(self, client: str = ""):
         """Pop the client's retained chain (single consumer per slot —
@@ -211,11 +178,10 @@ class DeviceExecutor:
         capacity-freeing allocs) blind ALL chains equally, so there is
         no per-client variant."""
         with self._lock:
-            dropped = list(self._chains.values())
+            dropped = len(self._chains)
             self._chains.clear()
-        for c in dropped:
+        for _ in range(dropped):
             self._count_invalidation(reason)
-            self._release_chain(c)
 
     def drop_client(self, client: str) -> None:
         """Forget one client's slot (pool worker exited/crashed)."""
@@ -223,7 +189,6 @@ class DeviceExecutor:
             c = self._chains.pop(client, None)
         if c is not None:
             self._count_invalidation("client-drop")
-            self._release_chain(c)
 
     def _count_invalidation(self, reason: str) -> None:
         with self._lock:
@@ -237,9 +202,6 @@ class DeviceExecutor:
         # so `nomad report` can line storms up against breaches
         TIMELINE.annotate("executor.invalidation", reason=reason)
 
-    def _release_chain(self, chain) -> None:
-        """Backend hook: free device resources a dropped chain held."""
-
     # ------------------------------------------------- store coupling
 
     def note_plan_commit(self, origin: str) -> None:
@@ -248,14 +210,12 @@ class DeviceExecutor:
         chain EXCEPT the one that proposed it — drop the others so
         their next wave re-syncs."""
         with self._lock:
-            dropped = [c for c in self._chains.values()
-                       if c[0] != origin]
-            if dropped:
-                self._chains = {k: c for k, c in self._chains.items()
-                                if c[0] == origin}
-        for c in dropped:
+            kept = {k: c for k, c in self._chains.items()
+                    if c[0] == origin}
+            dropped = len(self._chains) - len(kept)
+            self._chains = kept
+        for _ in range(dropped):
             self._count_invalidation("foreign-plan")
-            self._release_chain(c)
 
     def attach_store(self, store) -> None:
         """Subscribe to state-store events that change node state the
@@ -353,286 +313,6 @@ class DeviceExecutor:
 
     def close(self) -> None:
         self.invalidate("close")
-
-
-class JaxExecutor(DeviceExecutor):
-    """Default backend: the in-process JAX engine.  Chained launches go
-    through the donated-usage jit variants (select.place_multi_chained),
-    so the previous wave's dead buffer is reused in place; node tensors
-    are device-resident in the engine's version-keyed caches and the
-    executor's H2D observer meters every sync the engine performs."""
-
-    name = "jax"
-
-    def __init__(self, engine, chain_enabled: bool = True) -> None:
-        super().__init__(engine, chain_enabled=chain_enabled)
-        # meter the engine's host->device node-state syncs
-        # (_node_arrays full uploads + _used_device delta replays) and
-        # its device->host result fetches
-        engine.h2d_observer = self._observe_h2d
-        engine.d2h_observer = self._observe_d2h
-
-    def dispatch_batch(self, snapshot, items, seed=0, used0_dev=None,
-                       masked_node_ids=None):
-        if not self.chain_enabled:
-            used0_dev = None
-        pending = self.engine.dispatch_batch(
-            snapshot, items, seed=seed, used0_dev=used0_dev,
-            masked_node_ids=masked_node_ids)
-        self._note_dispatch(pending, used0_dev is not None)
-        return pending
-
-    def collect_batch(self, pending):
-        return self.engine.collect_batch(pending)
-
-
-class _BridgeArray:
-    """A device-resident PJRT bridge buffer masquerading as an array:
-    carries shape/dtype for shape-bucket keys and fetches to host
-    lazily on np.asarray() — the compact-fills overflow path then pays
-    its fetch only when the prefix actually overflowed."""
-
-    __slots__ = ("shape", "dtype", "_bridge", "handle", "_host")
-
-    def __init__(self, bridge, handle, shape, dtype) -> None:
-        self._bridge = bridge
-        self.handle = handle
-        self.shape = tuple(shape)
-        self.dtype = np.dtype(dtype)
-        self._host = None
-
-    def fetch(self) -> np.ndarray:
-        if self._host is None:
-            self._host = self._bridge.fetch(self.handle, self.shape,
-                                            self.dtype)
-        return self._host
-
-    # wavepipe.collect's device-interval stamp calls this on the result
-    # buffer; for the bridge the fetch IS the synchronization point
-    def block_until_ready(self) -> "_BridgeArray":
-        self.fetch()
-        return self
-
-    def __array__(self, dtype=None, copy=None):
-        a = self.fetch()
-        return a if dtype is None else a.astype(dtype)
-
-    def free(self) -> None:
-        if self.handle:
-            try:
-                self._bridge.buffer_free(self.handle)
-            except Exception:  # noqa: BLE001 - teardown best-effort
-                pass
-            self.handle = 0
-
-
-class BridgeExecutor(DeviceExecutor):
-    """Fast backend: the production multi-eval kernels exported once as
-    StableHLO per shape bucket and driven through the C++ PJRT bridge
-    (native/pjrt_bridge) with persistent device buffers.  Stable inputs
-    (node tensors, LUTs, cached masks) upload once and are reused by
-    object identity; each wave uploads only its small per-wave tensors
-    and fetches only the compact result buffer; the proposed-usage
-    output handle chains into the next wave's `used0` untouched by the
-    host — the `bench.py --bridge` pattern, in the worker loop."""
-
-    name = "bridge"
-
-    # stable-input handle cache bound (entries are freed on eviction)
-    _CACHE_CAP = 256
-
-    def __init__(self, engine, plugin: Optional[str] = None,
-                 chain_enabled: bool = True) -> None:
-        # mesh FIRST: a config contradiction (make_executor raises the
-        # agent_config-worded ValueError before ever constructing this
-        # class) must win over mere plugin absence for direct callers
-        if engine.mesh is not None:
-            raise ValueError(
-                "device_executor 'bridge' drives a single PJRT device; "
-                "this engine shards over a mesh — use 'jax'")
-        from nomad_tpu.native import bridge as nb
-        if not nb.bridge_available(plugin):
-            raise ExecutorUnavailable(
-                "device_executor 'bridge' requires the native bridge "
-                "build (`make -C native`) and an explicit PJRT plugin "
-                f"path (got {plugin!r}); falling back is not automatic "
-                "— configure device_executor = \"jax\" instead")
-        super().__init__(engine, chain_enabled=chain_enabled)
-        self._bridge = nb.PjrtBridge(plugin)
-        # the engine's collect path materializes bridge result buffers
-        # (np.asarray on _BridgeArray) — meter those d2h fetches; h2d
-        # stays unmetered on the engine side for the bridge (its real
-        # uploads go through _leaf_handle below)
-        engine.d2h_observer = self._observe_d2h
-        self._compiled = {}       # shape signature -> (exec, out_specs)
-        self._h2d_cache = {}      # id(leaf) -> (leaf ref, handle)
-        self._h2d_order = []      # insertion order for eviction
-
-    # ------------------------------------------------------- uploads
-
-    def _leaf_handle(self, leaf) -> int:
-        """Device handle for one input leaf, cached by object identity:
-        the engine's version-keyed caches keep node tensors as the SAME
-        objects across waves, so they upload once; fresh per-wave
-        arrays miss and age out of the bounded cache."""
-        key = id(leaf)
-        hit = self._h2d_cache.get(key)
-        if hit is not None and hit[0] is leaf:
-            return hit[1]
-        arr = np.ascontiguousarray(np.asarray(leaf))
-        t0 = time.perf_counter()
-        handle = self._bridge.upload(arr)
-        self._observe_h2d(arr.nbytes, time.perf_counter() - t0)
-        self._h2d_cache[key] = (leaf, handle)
-        self._h2d_order.append(key)
-        if len(self._h2d_order) > self._CACHE_CAP:
-            for old in self._h2d_order[:self._CACHE_CAP // 4]:
-                stale = self._h2d_cache.pop(old, None)
-                if stale is not None:
-                    try:
-                        self._bridge.buffer_free(stale[1])
-                    except Exception:  # noqa: BLE001 - best-effort
-                        pass
-            del self._h2d_order[:self._CACHE_CAP // 4]
-        return handle
-
-    def _compile(self, kernel, spec_args):
-        """Compile (once per shape bucket) and return (exec handle,
-        out_specs)."""
-        import jax
-        from nomad_tpu.native.bridge import export_stablehlo
-        from nomad_tpu.core.profiling import COMPILE
-        sig = tuple((tuple(s.shape), str(s.dtype))
-                    for s in jax.tree_util.tree_leaves(spec_args))
-        # shape-bucket site label: the largest leaf (the node-axis
-        # tensor) tells buckets apart without dumping the whole sig
-        dims = max((s[0] for s in sig if s[0]), default=(),
-                   key=lambda t: int(np.prod(t)))
-        site = "bridge/" + "x".join(map(str, dims))
-        hit = self._compiled.get(sig)
-        if hit is not None:
-            COMPILE.note_hit(site)
-            return hit
-        t0 = time.perf_counter()
-        hlo = export_stablehlo(kernel, *spec_args)
-        ex = self._bridge.compile(hlo)
-        outs = [(tuple(o.shape), np.dtype(o.dtype))
-                for o in jax.tree_util.tree_leaves(
-                    jax.eval_shape(kernel, *spec_args))]
-        COMPILE.note_miss(site, time.perf_counter() - t0)
-        self._compiled[sig] = (ex, outs)
-        return ex, outs
-
-    # --------------------------------------------------------- waves
-
-    def dispatch_batch(self, snapshot, items, seed=0, used0_dev=None,
-                       masked_node_ids=None):
-        import jax
-        from functools import partial
-
-        from .select import FILL_K, place_multi_compact_packed, \
-            place_multi_packed
-
-        if not self.chain_enabled:
-            used0_dev = None
-        if not items:
-            return None
-        built = self.engine.build_multi_inputs(
-            snapshot, items, seed=seed, used0_dev=used0_dev,
-            masked_node_ids=masked_node_ids)
-        if isinstance(built, tuple):
-            return built                       # empty-cluster sentinel
-        inp, rs = built["inp"], built["rs"]
-        chained = built.get("chained", False)
-        if used0_dev is not None and not chained:
-            # version guard rejected the chain: its handle is dead
-            arr = used0_dev[0]
-            if isinstance(arr, _BridgeArray):
-                arr.free()
-        compact = built["cand_rows"] is not None
-        if compact:
-            kernel = partial(place_multi_compact_packed, round_size=rs,
-                             n_lanes=built["n_lanes"])
-            kargs = (inp, built["cand_rows"], built["cand_valid"])
-            used_out, fill_k = 2, min(FILL_K, rs)
-        else:
-            kernel = partial(place_multi_packed, round_size=rs)
-            kargs = (inp,)
-            used_out, fill_k = 1, None
-
-        leaves, treedef = jax.tree_util.tree_flatten(kargs)
-        spec_args = jax.tree_util.tree_unflatten(treedef, [
-            jax.ShapeDtypeStruct(tuple(lf.shape), np.dtype(lf.dtype))
-            for lf in leaves])
-        ex, out_specs = self._compile(kernel, spec_args)
-        consumed = None
-        handles = []
-        for lf in leaves:
-            if isinstance(lf, _BridgeArray):
-                handles.append(lf.handle)      # the chained used0
-                consumed = lf
-            else:
-                handles.append(self._leaf_handle(lf))
-        outs = self._bridge.execute_resident(ex, handles, len(out_specs))
-        if consumed is not None:
-            consumed.free()
-        wrapped = [_BridgeArray(self._bridge, h, *spec)
-                   for h, spec in zip(outs, out_specs)]
-        free_now = [w for i, w in enumerate(wrapped)
-                    if i not in (0, 1 if compact else None, used_out)]
-        for w in free_now:
-            w.free()
-        t = built["t"]
-        pending = {
-            "bridge": True,
-            "buf": wrapped[0],
-            "fills_full": wrapped[1] if compact else None,
-            "fill_k": fill_k,
-            "used": wrapped[used_out],
-            "items": list(items),
-            "spans": built["spans"], "counts": built["counts"],
-            "rs": rs, "t": t, "ctxs": built["ctxs"],
-            "n": built["n"], "npad": built["npad"],
-            "node_version": t.version, "perm": built["perm"],
-            "chained": chained,
-            "padded_fraction":
-                (built["npad"] - built["n"]) / built["npad"],
-            "prep_ns": time.perf_counter_ns() - built["t0"],
-        }
-        self._note_dispatch(pending, used0_dev is not None)
-        return pending
-
-    def collect_batch(self, pending):
-        if not isinstance(pending, dict) or not pending.get("bridge"):
-            return self.engine.collect_batch(pending)
-        try:
-            # engine.collect_batch np.asarray()s buf (and fills only on
-            # prefix overflow) — _BridgeArray fetches on demand
-            return self.engine.collect_batch(pending)
-        finally:
-            buf = pending.get("buf")
-            if isinstance(buf, _BridgeArray):
-                buf.free()
-            fills = pending.get("fills_full")
-            if isinstance(fills, _BridgeArray):
-                fills.free()
-            # pending["used"] stays alive: it is the chain candidate
-
-    def _release_chain(self, chain) -> None:
-        arr = chain[2][0]
-        if isinstance(arr, _BridgeArray):
-            arr.free()
-
-    def close(self) -> None:
-        super().close()
-        for _, handle in self._h2d_cache.values():
-            try:
-                self._bridge.buffer_free(handle)
-            except Exception:  # noqa: BLE001 - teardown best-effort
-                pass
-        self._h2d_cache.clear()
-        self._h2d_order.clear()
-        self._bridge.close()
 
 
 class SubmissionFrontEnd:

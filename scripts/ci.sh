@@ -243,8 +243,7 @@ from nomad_tpu.agent import Agent
 from nomad_tpu.api.client import APIClient
 from nomad_tpu.structs import codec
 
-agent = Agent(num_clients=1, num_workers=1, heartbeat_ttl=3600,
-              device_executor="jax").start()
+agent = Agent(num_clients=1, num_workers=1, heartbeat_ttl=3600).start()
 api = APIClient(address=agent.address)
 try:
     def wave():
@@ -301,8 +300,7 @@ from nomad_tpu.agent import Agent
 from nomad_tpu.api.client import APIClient
 from nomad_tpu.structs import codec
 
-agent = Agent(num_clients=1, num_workers=1, heartbeat_ttl=3600,
-              device_executor="jax").start()
+agent = Agent(num_clients=1, num_workers=1, heartbeat_ttl=3600).start()
 api = APIClient(address=agent.address)
 try:
     evals = []

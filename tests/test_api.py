@@ -232,11 +232,16 @@ client { count = 3 meta { rack = "r9" } }
         assert cfg.num_workers == 2 and cfg.heartbeat_ttl == 60.0
         assert cfg.node_class == "compute"
 
-    def test_unknown_setting_rejected(self):
-        import pytest as _pytest
+    @pytest.mark.parametrize("text,named", [
+        ('data_dir_typo = "/x"', "unknown agent setting 'data_dir_typo'"),
+        # a retired option is refused by name, never silently ignored
+        ('server { device_executor = "jax" }',
+         "unknown server setting 'device_executor'"),
+    ])
+    def test_unknown_setting_rejected(self, text, named):
         from nomad_tpu.agent_config import parse_agent_config
-        with _pytest.raises(ValueError):
-            parse_agent_config("data_dir_typo = \"/x\"")
+        with pytest.raises(ValueError, match=named):
+            parse_agent_config(text)
 
 
 class TestScaleAndVolumes:

@@ -1,7 +1,7 @@
-"""Device-executor seam (nomad_tpu/ops/executor.py): backend selection
-and validation, the retained resident-chain slot (claim/retain/
-invalidate semantics, store-write coupling), and the telemetry meters
-the seam exports.  The cross-backend bit-for-bit parity proof lives in
+"""Device-executor seam (nomad_tpu/ops/executor.py): the retained
+resident-chain slot (claim/retain/invalidate semantics, store-write
+coupling), and the telemetry meters the seam exports.  The bit-for-bit
+proof that the resident chain equals its serial reference lives in
 tests/test_wavepipe.py (TestExecutorResidentParity)."""
 
 import pytest
@@ -10,14 +10,9 @@ from nomad_tpu import mock
 from nomad_tpu.core.server import Server
 from nomad_tpu.core.telemetry import REGISTRY
 from nomad_tpu.ops import PlacementEngine
-from nomad_tpu.ops.executor import (
-    EXECUTOR_BACKENDS,
-    ExecutorUnavailable,
-    JaxExecutor,
-    make_executor,
-)
+from nomad_tpu.ops.executor import DeviceExecutor
 from nomad_tpu.state import StateStore
-from nomad_tpu.structs import Allocation, Resources
+from nomad_tpu.structs import Allocation, DrainStrategy, Resources
 
 NOW = 1.7e9
 
@@ -26,61 +21,35 @@ def _engine():
     return PlacementEngine(mesh=False)
 
 
-class TestMakeExecutor:
-    def test_default_and_jax(self):
-        eng = _engine()
-        for name in ("", "jax"):
-            ex = make_executor(name, eng)
-            assert isinstance(ex, JaxExecutor)
-            assert ex.name == "jax"
-            assert ex.engine is eng
-
-    def test_unknown_backend_rejected(self):
-        with pytest.raises(ValueError, match="device_executor"):
-            make_executor("cuda", _engine())
-
-    def test_bridge_errors_when_unavailable(self):
-        from nomad_tpu.native.bridge import bridge_available
-        if bridge_available():
-            pytest.skip("bridge available: covered by the parity suite")
-        with pytest.raises(ExecutorUnavailable, match="bridge"):
-            make_executor("bridge", _engine())
-
-    def test_backends_registry(self):
-        assert EXECUTOR_BACKENDS == ("jax", "bridge")
-
-    def test_bridge_rejected_on_mesh_at_construction(self):
-        """bridge + a multi-device engine is a CONFIG contradiction: it
-        must fail as an agent_config validation error (ValueError, not
-        ExecutorUnavailable) at make_executor time — i.e. at agent
-        start — whether or not the native build exists."""
-        import jax
-        if jax.device_count() < 2:
-            pytest.skip("needs the virtual multi-device mesh")
-        eng = PlacementEngine()
-        assert eng.mesh is not None
-        with pytest.raises(ValueError, match="agent_config.*mesh"):
-            make_executor("bridge", eng)
+def _alloc(alloc_id, client_status):
+    return Allocation(id=alloc_id, namespace="default", job_id="j",
+                      task_group="tg", node_id="n1",
+                      resources=Resources(cpu=10, memory_mb=10),
+                      desired_status="run", client_status=client_status)
 
 
-class TestAgentConfigKnob:
-    def test_parse_and_default(self):
-        from nomad_tpu.agent_config import AgentConfig, parse_agent_config
-        assert AgentConfig().device_executor == "jax"
-        cfg, fields = parse_agent_config(
-            'server { device_executor = "bridge" }')
-        assert cfg.device_executor == "bridge"
-        assert "device_executor" in fields
-
-    def test_invalid_value_rejected(self):
-        from nomad_tpu.agent_config import parse_agent_config
-        with pytest.raises(ValueError, match="device_executor"):
-            parse_agent_config('server { device_executor = "cuda" }')
+# cause -> the write under test, on a store that holds node "n1" with
+# the running alloc "a-1" on it
+STORE_WRITES = {
+    "upsert_node": lambda st: st.upsert_node(mock.node()),
+    "delete_node": lambda st: st.delete_node("n1"),
+    "update_node_status": lambda st: st.update_node_status("n1", "down"),
+    "update_node_eligibility":
+        lambda st: st.update_node_eligibility("n1", "ineligible"),
+    "update_node_drain":
+        lambda st: st.update_node_drain("n1", DrainStrategy()),
+    "terminal_alloc":
+        lambda st: st.upsert_allocs([_alloc("a-1", "complete")]),
+    "snapshot_restore":
+        lambda st: st.snapshot_restore(st.snapshot_save()),
+    "live_placement":
+        lambda st: st.upsert_allocs([_alloc("a-2", "running")]),
+}
 
 
 class TestChainSlot:
     def test_claim_pops_single_consumer(self):
-        ex = JaxExecutor(_engine())
+        ex = DeviceExecutor(_engine())
         triple = (object(), 1, 8)
         ex.retain_chain("bid", 3, triple, masked={"n1"})
         got = ex.claim_chain()
@@ -88,12 +57,13 @@ class TestChainSlot:
         assert ex.claim_chain() is None
 
     def test_chain_disabled_is_inert(self):
-        ex = JaxExecutor(_engine(), chain_enabled=False)
+        ex = DeviceExecutor(_engine())
+        ex.chain_enabled = False
         ex.retain_chain("bid", 3, (object(), 1, 8))
         assert ex.claim_chain() is None
 
     def test_invalidate_counts_only_real_drops(self):
-        ex = JaxExecutor(_engine())
+        ex = DeviceExecutor(_engine())
         ex.invalidate("noop")
         assert ex.stats["invalidations"] == 0
         ex.retain_chain("bid", 3, (object(), 1, 8))
@@ -101,66 +71,46 @@ class TestChainSlot:
         assert ex.stats["invalidations"] == 1
         assert ex.claim_chain() is None
 
-    def test_foreign_plan_invalidates_own_does_not(self):
-        ex = JaxExecutor(_engine())
-        ex.retain_chain("bid", 3, (object(), 1, 8))
-        ex.note_plan_commit("bid")            # the chain's own commit
-        assert ex.stats["invalidations"] == 0
-        ex.note_plan_commit("someone-else")   # foreign plan
-        assert ex.stats["invalidations"] == 1
-        assert ex.claim_chain() is None
+    @pytest.mark.parametrize("origin,dropped", [
+        ("bid", 0),              # the chain's own commit
+        ("someone-else", 1),     # a plan from outside the chain
+    ])
+    def test_foreign_plan_invalidates_own_does_not(self, origin, dropped):
+        ex = DeviceExecutor(_engine())
+        triple = (object(), 1, 8)
+        ex.retain_chain("bid", 3, triple)
+        ex.note_plan_commit(origin)
+        assert ex.stats["invalidations"] == dropped
+        kept = ex.claim_chain()
+        assert kept is None if dropped else kept[2] is triple
 
-    def test_store_writes_invalidate(self):
+    @pytest.mark.parametrize("cause", sorted(STORE_WRITES))
+    def test_store_writes_invalidate(self, cause):
+        """Every store write that changes node state the chain cannot
+        observe drops it; a live placement (what the chain itself
+        proposes) does not."""
         store = StateStore()
-        ex = JaxExecutor(_engine())
+        node = mock.node()
+        node.id = "n1"
+        store.upsert_node(node)
+        store.upsert_allocs([_alloc("a-1", "running")])
+        ex = DeviceExecutor(_engine())
         ex.attach_store(store)
-
-        # node write (register/drain/eligibility)
         ex.retain_chain("bid", 1, (object(), 1, 8))
-        store.upsert_node(mock.node())
-        assert ex.stats["invalidations"] == 1
-
-        # capacity-freeing (terminal) alloc write
-        ex.retain_chain("bid", 2, (object(), 1, 8))
-        live = Allocation(id="a-live", namespace="default", job_id="j",
-                          task_group="tg", node_id="n1",
-                          resources=Resources(cpu=10, memory_mb=10),
-                          desired_status="run", client_status="running")
-        store.upsert_allocs([live])
-        assert ex.stats["invalidations"] == 1, \
-            "a live placement must NOT invalidate"
-        done = live.copy()
-        done.client_status = "complete"
-        store.upsert_allocs([done])
-        assert ex.stats["invalidations"] == 2
-
-        # snapshot restore
-        ex.retain_chain("bid", 3, (object(), 1, 8))
-        store.snapshot_restore(store.snapshot_save())
-        assert ex.stats["invalidations"] == 3
+        STORE_WRITES[cause](store)
+        kept = cause == "live_placement"
+        assert ex.stats["invalidations"] == (0 if kept else 1)
+        assert (ex.claim_chain() is not None) == kept
 
 
 class TestServerWiring:
     def test_server_builds_and_wires_executor(self):
-        s = Server(dev_mode=True, device_executor="jax")
+        s = Server(dev_mode=True)
         assert s.executor.name == "jax"
         assert s.executor.engine is s.engine
         assert s.plan_applier.executor is s.executor
         for w in s.workers:
             assert w.pipeline.executor is s.executor
-
-    def test_server_rejects_unknown_backend(self):
-        with pytest.raises(ValueError, match="device_executor"):
-            Server(dev_mode=True, device_executor="cuda")
-
-    def test_server_rejects_bridge_on_mesh_at_start(self):
-        """The guard fires at SERVER CONSTRUCTION (agent start), never
-        mid-worker-loop (ISSUE 7 satellite)."""
-        import jax
-        if jax.device_count() < 2:
-            pytest.skip("needs the virtual multi-device mesh")
-        with pytest.raises(ValueError, match="agent_config"):
-            Server(dev_mode=True, device_executor="bridge")
 
     def test_residency_metrics_ride_the_registry(self):
         c0 = REGISTRY.counter("nomad.executor.resident_waves")
@@ -187,9 +137,9 @@ class TestServerWiring:
         assert REGISTRY.histogram("nomad.executor.h2d_s") is not None
 
     def test_serial_vs_resident_same_aggregate_state(self):
-        """The worker-loop A/B the bench's --resident flag runs: chain
-        off (host round-trip every wave) and chain on land identical
-        live-alloc counts with zero refutes."""
+        """Chain off (the serial reference: host round-trip every
+        wave) and chain on land identical live-alloc counts with zero
+        refutes."""
         def run(resident):
             s = Server(dev_mode=True, eval_batch=4)
             s.executor.chain_enabled = resident

@@ -3,6 +3,7 @@
 real subprocess plugins over the handshake protocol)."""
 
 import os
+import threading
 import time
 
 import pytest
@@ -93,15 +94,15 @@ class TestExternalDriver:
 
 
 class TestSupervision:
-    def test_crashed_plugin_relaunched(self, tmp_path):
-        m = PluginManager(PLUGDIR, socket_dir=str(tmp_path / "socks"))
+    def test_crashed_plugin_relaunched(self, tmp_path_factory):
+        # a SHORT socket dir: launch_plugin names the socket
+        # plugin-<pid>-<thread>-<seq>.sock under it, and a unix socket
+        # path holds 107 bytes.  Under xdist the per-test tmp_path
+        # (.../popen-gwN/test_crashed_plugin_relaunched0/socks) went
+        # past that, and the plugin died at bind before any relaunch
+        m = PluginManager(PLUGDIR,
+                          socket_dir=str(tmp_path_factory.mktemp("s")))
         m.scan()
-        if "hello" not in m.drivers:
-            # cold interpreter starts on a loaded host can outlast even
-            # the manager's internal retries; one more scan, and carry
-            # the log ring into the assertion so a real failure explains
-            # itself
-            m.scan()
         from nomad_tpu.core.logging import RING
         assert "hello" in m.drivers, RING.tail(6)
         try:
@@ -110,17 +111,24 @@ class TestSupervision:
             # kill the plugin process behind the shim
             drv.client.proc.kill()
             drv.client.proc.wait(timeout=5)
-            time.sleep(0.2)
+            assert not drv.client.alive()
             assert drv.fingerprint() == {}      # dead connection
-            m.start_supervisor(interval=0.5)
-            # relaunch spawns a fresh interpreter; allow for a loaded host
-            deadline = time.time() + 90
-            while time.time() < deadline:
-                if drv.fingerprint().get("driver.hello") == "1":
-                    break
-                time.sleep(0.3)
+            # the supervisor's step is scan(); signal when its first
+            # rescan (the relaunch and handshake, synchronous) returns,
+            # so the test waits on that and polls nothing
+            rescanned = threading.Event()
+            scan = m.scan
+
+            def scan_then_signal():
+                scan()
+                rescanned.set()
+
+            m.scan = scan_then_signal
+            m.start_supervisor(interval=0.05)
+            # a bound, not a wait: two launch attempts of 60 s each
+            assert rescanned.wait(timeout=150), RING.tail(6)
             # the SAME shim object works again after relaunch
-            assert drv.fingerprint()["driver.hello"] == "1"
+            assert drv.fingerprint()["driver.hello"] == "1", RING.tail(6)
         finally:
             m.shutdown()
 

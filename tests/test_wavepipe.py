@@ -27,19 +27,6 @@ from nomad_tpu.structs import Allocation, Resources, new_id
 NOW = 1.7e9
 
 
-def executor_backends():
-    """Every device-executor backend runnable in this process: 'jax'
-    always; 'bridge' when the native build + PJRT plugin exist."""
-    backs = ["jax"]
-    try:
-        from nomad_tpu.native.bridge import bridge_available
-        if bridge_available():
-            backs.append("bridge")
-    except Exception:  # noqa: BLE001 - no native stack at all
-        pass
-    return backs
-
-
 def build_cluster(n_nodes=12, cpu=4000, mem=8192):
     h = Harness()
     nodes = []
@@ -413,20 +400,21 @@ class TestBlockColumnarRefute:
 
 
 class TestExecutorResidentParity:
-    """The device-resident executor contract (ops/executor.py), per
-    backend: multi-pass scheduling that rides the retained usage chain
-    lands BIT-FOR-BIT the same state as the serial host-round-trip path
-    — including across a forced invalidation (a node knocked out of the
-    table mid-run)."""
+    """The device-resident executor contract (ops/executor.py), on the
+    single-device and on the sharded engine: multi-pass scheduling that
+    rides the retained usage chain lands BIT-FOR-BIT the same state as
+    the serial host-round-trip path — including across a forced
+    invalidation (a node knocked out of the table mid-run)."""
 
-    def _run_waves(self, nodes, backend, resident, drain_mid=False,
-                   mesh=None):
-        """`mesh`: None = the engine's auto choice (the conftest's
-        8-virtual-device mesh -> sharded), False = force the
-        single-device engine (the serial reference the sharded runs
-        must match bit-for-bit)."""
-        s = Server(dev_mode=True, eval_batch=4, device_executor=backend,
-                   mesh=mesh)
+    # the engine's mesh: None = its auto choice (the conftest's
+    # 8-virtual-device mesh -> sharded), False = the single device
+    MESHES = pytest.mark.parametrize(
+        "mesh", [None, False], ids=["mesh8", "single-device"])
+
+    def _run_waves(self, nodes, resident, drain_mid=False, mesh=None):
+        """`resident=False` is the serial reference
+        (DeviceExecutor.chain_enabled)."""
+        s = Server(dev_mode=True, eval_batch=4, mesh=mesh)
         s.executor.chain_enabled = resident
         s.establish_leadership()
         for n in nodes:
@@ -463,24 +451,25 @@ class TestExecutorResidentParity:
         refuted = s.plan_applier.stats["plans_refuted"]
         return _contents(s.state), stats, refuted
 
-    @pytest.mark.parametrize("backend", executor_backends())
-    def test_resident_chain_bitwise_equals_serial(self, backend):
+    @MESHES
+    def test_resident_chain_bitwise_equals_serial(self, mesh):
         nodes = _fixed_cluster_nodes(n_nodes=12, seed=7)
-        serial, st_serial, _ = self._run_waves(nodes, backend, False)
-        resident, st_res, refuted = self._run_waves(nodes, backend, True)
+        serial, st_serial, _ = self._run_waves(nodes, False, mesh=mesh)
+        resident, st_res, refuted = self._run_waves(nodes, True,
+                                                    mesh=mesh)
         assert resident == serial
         # the serial reference never chained; the resident run did
         assert st_serial["resident_waves"] == 0
         assert st_res["resident_waves"] >= 1, st_res
         assert refuted == 0
 
-    @pytest.mark.parametrize("backend", executor_backends())
-    def test_forced_invalidation_mid_run(self, backend):
+    @MESHES
+    def test_forced_invalidation_mid_run(self, mesh):
         nodes = _fixed_cluster_nodes(n_nodes=12, seed=7)
-        serial, _, _ = self._run_waves(nodes, backend, False,
-                                       drain_mid=True)
-        resident, st_res, refuted = self._run_waves(nodes, backend, True,
-                                                    drain_mid=True)
+        serial, _, _ = self._run_waves(nodes, False, drain_mid=True,
+                                       mesh=mesh)
+        resident, st_res, refuted = self._run_waves(
+            nodes, True, drain_mid=True, mesh=mesh)
         assert resident == serial
         assert st_res["invalidations"] >= 1, st_res
         assert refuted == 0
@@ -490,7 +479,7 @@ class TestExecutorResidentParity:
 
     def test_executor_upload_accounting(self):
         nodes = _fixed_cluster_nodes(n_nodes=12, seed=7)
-        _, stats, _ = self._run_waves(nodes, "jax", True)
+        _, stats, _ = self._run_waves(nodes, True)
         # node tensors + used uploaded at least once, metered in bytes
         assert stats["uploads"] >= 1
         assert stats["upload_bytes"] > 0
@@ -502,9 +491,9 @@ class TestExecutorResidentParity:
         riding the retained resident chain lands BIT-FOR-BIT the same
         state as the serial single-device host-round-trip path."""
         nodes = _fixed_cluster_nodes(n_nodes=28, seed=7)  # 28 % 8 != 0
-        serial_1dev, st_1, _ = self._run_waves(nodes, "jax", False,
+        serial_1dev, st_1, _ = self._run_waves(nodes, False,
                                                mesh=False)
-        sharded_res, st_s, refuted = self._run_waves(nodes, "jax", True)
+        sharded_res, st_s, refuted = self._run_waves(nodes, True)
         assert sharded_res == serial_1dev
         assert st_1["resident_waves"] == 0
         assert st_s["resident_waves"] >= 1, st_s
@@ -519,9 +508,9 @@ class TestExecutorResidentParity:
         upload_bytes meter), and still match the single-device serial
         run bit-for-bit."""
         nodes = _fixed_cluster_nodes(n_nodes=64, seed=7)
-        serial_1dev, _, _ = self._run_waves(nodes, "jax", False,
+        serial_1dev, _, _ = self._run_waves(nodes, False,
                                             mesh=False, drain_mid=True)
-        sharded_res, st, refuted = self._run_waves(nodes, "jax", True,
+        sharded_res, st, refuted = self._run_waves(nodes, True,
                                                    drain_mid=True)
         assert sharded_res == serial_1dev
         assert refuted == 0
