@@ -380,6 +380,9 @@ class PlacementEngine:
         self.shard_h2d_bytes: int = 0
         self.collective_bytes: int = 0
         self._const_cache: Dict[tuple, object] = {}
+        # compact-lane candidate frames (_candidate_frames), by what they
+        # were derived from; a handful, oldest out
+        self._frame_cache: Dict[tuple, tuple] = {}
         self._dc_cache: Optional[Tuple[int, Dict[str, int]]] = None
         # host->device sync meter (ops/executor.py installs it): called
         # with (bytes, seconds, cause) for every node-state upload —
@@ -443,11 +446,11 @@ class PlacementEngine:
 
     def device_resident_bytes(self) -> int:
         """Estimated HBM residency of this engine's retained device
-        buffers (node tensors, resident `used`, const cache).  Reads
-        WITHOUT the packer lock — callers sit inside _note_h2d, some of
-        whose call sites already hold it — so a concurrent eviction can
-        tear an iteration; this is a gauge, skip and report the partial
-        sum rather than block the hot path."""
+        buffers (node tensors, resident `used`, const cache, candidate
+        frames).  Reads WITHOUT the packer lock — callers sit inside
+        _note_h2d, some of whose call sites already hold it — so a
+        concurrent eviction can tear an iteration; this is a gauge, skip
+        and report the partial sum rather than block the hot path."""
         total = 0
         try:
             for v in tuple(self._dev_cache.values()):
@@ -457,6 +460,8 @@ class PlacementEngine:
                 total += int(getattr(u, "nbytes", 0))
             for v in tuple(self._const_cache.values()):
                 total += int(getattr(v, "nbytes", 0))
+            for _, _, frames_dev in tuple(self._frame_cache.values()):
+                total += sum(int(a.nbytes) for a in frames_dev)
         except RuntimeError:
             pass
         return total
@@ -1406,8 +1411,7 @@ class PlacementEngine:
         coll_bytes = 0
         skey = (rs, aux["npad"], aux["n_lanes"])
         if aux["cand_rows"] is not None:
-            cr = jnp.asarray(aux["cand_rows"])
-            cv = jnp.asarray(aux["cand_valid"])
+            cr, cv = aux["cand_dev"]
             if self.mesh is not None:
                 if chained:
                     # donated sharded chain: wave k's dead sharded usage
@@ -1652,7 +1656,7 @@ class PlacementEngine:
         n_real = len(round_g)
         n_lanes = 1
         perm = None
-        cand_rows = cand_valid = None
+        cand_rows = cand_valid = cand_dev = None
         luts = tgts[-1].luts      # the most complete LUT matrix
         if n_real > 1 and len(static_con) > 1:
             weights = [0] * len(static_con)
@@ -1663,47 +1667,21 @@ class PlacementEngine:
             # the flat kernel (no lane parallelism to win, and flat is
             # what the mesh/bridge parity suites pin)
             if len(cliques) == 1 and len(cliques[0]) > 1:
-                clique = cliques[0]
+                # lanes in the order of their signatures' bytes, not of
+                # the wave's weights: lanes are symmetric in the kernel
+                # (disjoint frames, per-item seeds, rows mapped back by
+                # `perm`), and a drain's waves weigh the same signatures
+                # differently each time, so any other order would give
+                # every wave frames of its own
+                mask_key_of = list(mask_keys)
+                clique = sorted(cliques[0], key=lambda s: (
+                    static_con[s].tobytes(), mask_key_of[static_mi[s]]))
                 width = len(clique)
-                # host-side candidate frames: the SAME constraint code
-                # run on CPU over the packed host tensors
-                masks = _host_signature_masks(
-                    t.attrs, t.elig,
-                    [mask_np[static_mi[s]] for s in clique],
-                    [static_con[s] for s in clique], luts)
-                if node_ok is not None:
-                    # the frame IS the static mask on the compact path:
-                    # refuted nodes leave the candidate set here
-                    masks = masks & node_ok[:n][None, :]
-                rows_l = [np.nonzero(masks[i])[0].astype(np.int32)
-                          for i in range(width)]
-                if self.mesh is None:
-                    nc = max(max((len(r) for r in rows_l), default=1), 1)
-                    nc = ((nc + 2047) // 2048) * 2048
-                    cand_rows = np.full((width, nc), npad, np.int32)
-                    cand_valid = np.zeros((width, nc), bool)
-                    for li, rows in enumerate(rows_l):
-                        cand_rows[li, :len(rows)] = rows
-                        cand_valid[li, :len(rows)] = True
-                else:
-                    # per-shard frame blocks: shard s holds its slice of
-                    # every lane's candidates (global row ids; padding =
-                    # npad is past every shard's range)
-                    ndev = self._ndev
-                    nloc = npad // ndev
-                    shard_rows = [
-                        [rows[(rows // nloc) == sh] for rows in rows_l]
-                        for sh in range(ndev)]
-                    nc = max(max((len(r) for per in shard_rows
-                                  for r in per), default=1), 1)
-                    nc = ((nc + 511) // 512) * 512
-                    cand_rows = np.full((ndev, width, nc), npad,
-                                        np.int32)
-                    cand_valid = np.zeros((ndev, width, nc), bool)
-                    for sh in range(ndev):
-                        for li, rows in enumerate(shard_rows[sh]):
-                            cand_rows[sh, li, :len(rows)] = rows
-                            cand_valid[sh, li, :len(rows)] = True
+                cand_rows, cand_valid, cand_dev = self._candidate_frames(
+                    t, npad, luts, [static_con[s] for s in clique],
+                    [mask_key_of[static_mi[s]] for s in clique],
+                    [mask_np[static_mi[s]] for s in clique], node_ok)
+                nc = cand_rows.shape[-1]
                 lane_of = {s: li for li, s in enumerate(clique)}
                 lanes: List[List[int]] = [[] for _ in range(width)]
                 for r_idx in range(n_real):
@@ -1791,7 +1769,84 @@ class PlacementEngine:
         return {"inp": inp, "rs": rs, "spans": spans, "counts": counts,
                 "t": t, "ctxs": ctxs, "n": n, "npad": npad, "t0": t0,
                 "n_lanes": n_lanes, "perm": perm, "chained": chained,
-                "cand_rows": cand_rows, "cand_valid": cand_valid}
+                "cand_rows": cand_rows, "cand_valid": cand_valid,
+                "cand_dev": cand_dev}
+
+    def _candidate_frames(self, t: NodeTensors, npad: int, luts,
+                          con_by_lane, mask_key_by_lane, base_by_lane,
+                          node_ok):
+        """The compact path's candidate frames: per lane, the rows of
+        the nodes its signature's static mask admits, padded ([L, Nc];
+        on a mesh split by owner shard, [S, L, Nc_loc]).  Returns
+        (cand_rows, cand_valid, their device copies).
+
+        Everything here is derived from the node table, the LUT matrix
+        and the signatures alone, so it is derived when one of those
+        changes and not when a wave launches: `t.version` moves with
+        every row write and rebuild, `lut_epoch` and the matrix's shape
+        with every LUT row and vocabulary growth, and `base_by_lane` is
+        `job_context`'s per-version mask of `mask_key_by_lane`.
+        `node_ok` (refute repair) is one launch's overlay: such a launch
+        builds its own frames and leaves none behind."""
+        key = (t.version, npad, self._ndev, self.packer.lut_epoch,
+               luts.shape, tuple(mask_key_by_lane),
+               tuple(c.tobytes() for c in con_by_lane))
+        if node_ok is None:
+            with self.packer.lock:
+                hit = self._frame_cache.pop(key, None)
+                if hit is not None:
+                    self._frame_cache[key] = hit
+            if hit is not None:
+                _registry().inc("nomad.engine.frames_reused")
+                return hit
+        # the SAME constraint code run on CPU over the packed host tensors
+        masks = _host_signature_masks(t.attrs, t.elig, base_by_lane,
+                                      con_by_lane, luts)
+        if node_ok is not None:
+            # the frame IS the static mask on the compact path: refuted
+            # nodes leave the candidate set here
+            masks = masks & node_ok[:t.n][None, :]
+        width = len(con_by_lane)
+        rows_l = [np.nonzero(masks[i])[0].astype(np.int32)
+                  for i in range(width)]
+        if self.mesh is None:
+            nc = max(max((len(r) for r in rows_l), default=1), 1)
+            nc = ((nc + 2047) // 2048) * 2048
+            cand_rows = np.full((width, nc), npad, np.int32)
+            cand_valid = np.zeros((width, nc), bool)
+            for li, rows in enumerate(rows_l):
+                cand_rows[li, :len(rows)] = rows
+                cand_valid[li, :len(rows)] = True
+        else:
+            # per-shard frame blocks: shard s holds its slice of every
+            # lane's candidates (global row ids; padding = npad is past
+            # every shard's range)
+            ndev = self._ndev
+            nloc = npad // ndev
+            shard_rows = [
+                [rows[(rows // nloc) == sh] for rows in rows_l]
+                for sh in range(ndev)]
+            nc = max(max((len(r) for per in shard_rows
+                          for r in per), default=1), 1)
+            nc = ((nc + 511) // 512) * 512
+            cand_rows = np.full((ndev, width, nc), npad, np.int32)
+            cand_valid = np.zeros((ndev, width, nc), bool)
+            for sh in range(ndev):
+                for li, rows in enumerate(shard_rows[sh]):
+                    cand_rows[sh, li, :len(rows)] = rows
+                    cand_valid[sh, li, :len(rows)] = True
+        _registry().inc("nomad.engine.frames_built")
+        # shared from here on: a writer would corrupt every later wave
+        cand_rows.setflags(write=False)
+        cand_valid.setflags(write=False)
+        out = (cand_rows, cand_valid,
+               (jnp.asarray(cand_rows), jnp.asarray(cand_valid)))
+        if node_ok is None:
+            with self.packer.lock:
+                if len(self._frame_cache) >= 4:
+                    self._frame_cache.pop(next(iter(self._frame_cache)))
+                self._frame_cache[key] = out
+        return out
 
     def collect_batch(self, pending) -> List[Optional[BulkDecisions]]:
         """Blocking half of place_batch: fetch the packed buffer and

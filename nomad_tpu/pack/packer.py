@@ -49,6 +49,14 @@ from nomad_tpu.utils.version import check_constraint as check_version
 
 from .interner import Interner, UNSET
 
+
+def _registry():
+    """Process metrics registry, imported lazily (ops/engine.py's reason:
+    `nomad_tpu.core`'s package __init__ reaches this package)."""
+    from nomad_tpu.core.telemetry import REGISTRY
+    return REGISTRY
+
+
 # Device-side constraint opcodes (see ops/feasibility.py):
 DOP_TRUE = 0        # padding row, always satisfied
 DOP_EQ = 1          # set(col) and attrs[col] == arg
@@ -148,6 +156,9 @@ class ClusterPacker:
         self._attached = False
         self._store = None            # set by attach()
         self._events_index = -1       # highest store index seen via events
+        # the store's node table (copy-on-write: a node write publishes a
+        # new dict) the tensors were last read from, while attached
+        self._node_table = None
         self._seq = 0                 # monotone tensor version source
         self._last_index = -1         # state index the tensors reflect
         self._last_store = None       # store identity the tensors reflect
@@ -509,6 +520,7 @@ class ClusterPacker:
         self._log_row_dirty(None)
         t.used_version = self._log_delta(None, None)
         self._tensors = t
+        self._node_table = snapshot.node_table() if self._attached else None
         self._dirty.clear()
         self._all_dirty = False
         self._last_index = getattr(snapshot, "index", -1)
@@ -536,12 +548,30 @@ class ClusterPacker:
             if getattr(snapshot, "index", -1) == self._last_index:
                 return t
             return self._build_locked(snapshot)
-        live_ids = {nd.id for nd in snapshot.nodes()}
-        removed = [nid for nid in t.node_ids if nid not in live_ids]
-        added = [nid for nid in live_ids if nid not in t.id_to_row]
-        if removed or added:
+        # membership by event: every node add, update and delete on the
+        # attached store lands in `_dirty` ("Node") and a restore sets
+        # `_all_dirty`, so only a dirty id can have joined or left.  The
+        # store publishes a fresh node table on every node write, so a
+        # table that is not the one the tensors were last read from, with
+        # no event to say why, came from a feed that emits none (the
+        # replica's StateStore.apply_export): there the walk stays.
+        id_to_row = t.id_to_row
+        node_table = snapshot.node_table()
+        if self._dirty:
+            checked = len(self._dirty)
+            moved = any((nid in id_to_row) != (nid in node_table)
+                        for nid in self._dirty)
+        elif node_table is self._node_table:
+            checked, moved = 0, False
+        else:
+            checked = len(node_table)
+            moved = (checked != len(id_to_row)
+                     or not all(map(id_to_row.__contains__, node_table)))
+        _registry().inc("nomad.packer.membership_checked", checked)
+        if moved:
             # membership change: full rebuild keeps row mapping simple
             return self._build_locked(snapshot)
+        self._node_table = node_table
         if not self._dirty:
             self._last_index = getattr(snapshot, "index", self._last_index)
             return t

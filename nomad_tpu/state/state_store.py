@@ -2223,6 +2223,12 @@ class StateSnapshot:
     def nodes(self) -> List[Node]:
         return list(self._nodes.values())
 
+    def node_table(self) -> Dict[str, Node]:
+        """The node table itself, id -> node, read-only.  Every node
+        write publishes a new dict, so two snapshots that hold the same
+        object hold the same nodes (pack/packer.py update)."""
+        return self._nodes
+
     def node_by_id(self, node_id: str) -> Optional[Node]:
         return self._nodes.get(node_id)
 
