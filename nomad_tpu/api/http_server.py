@@ -1833,6 +1833,12 @@ class HTTPAPIServer:
 
         class Handler(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
+            # a response leaves as two writes (headers, then body) and a
+            # stream chunk as two more: under Nagle's algorithm the second
+            # waits for the peer's delayed ACK of the first, 40 ms on
+            # every request after a connection's first (measured: a
+            # DELETE over a kept-alive connection 3.4 ms, then 44 ms each)
+            disable_nagle_algorithm = True
 
             def log_message(self, *a):      # quiet
                 pass

@@ -560,6 +560,10 @@ class PlanApplier:
             tmpl = block.template
             if tmpl.allocated_ports or tmpl.allocated_devices:
                 return False
+            if block.device_ids is not None:
+                # device columns are host-assigned: the per-node audit
+                # of _eval_blocks reads them, wholesale admission not
+                return False
             if tmpl.resources.networks and block.ports is None:
                 # a networked block must CARRY its columnar port
                 # assignment (ISSUE 8) to ride any block path; with it,
@@ -597,7 +601,8 @@ class PlanApplier:
         placements (their fit must be checked TOGETHER, which only the
         expanded per-node path does).  Networked blocks CARRYING their
         columnar port assignment stay columnar: _eval_blocks audits
-        their ports per node straight off the array (ISSUE 8)."""
+        their ports per node straight off the array (ISSUE 8); so do
+        blocks carrying device columns (ISSUE 30)."""
         tmpl = block.template
         if (tmpl.allocated_ports or tmpl.allocated_devices
                 or (tmpl.resources.networks and block.ports is None)):
@@ -692,6 +697,23 @@ class PlanApplier:
                     if port in claimed:
                         _mark((nid,), "in-plan-dup-port")
                     claimed.add(port)
+        # per-node DEVICE audit input (ISSUE 30), the same way: the
+        # plan's instance claims per node across device-carrying blocks,
+        # straight off the id columns.  An instance claimed twice within
+        # the plan refutes the node outright.
+        plan_devs: Dict[str, tuple] = {}      # node -> (group, {ids})
+        for b in columnar:
+            for nid, (group, ids) in b.devices_by_node().items():
+                held = plan_devs.get(nid)
+                if held is None:
+                    plan_devs[nid] = held = (group, set())
+                elif held[0] != group:
+                    _mark((nid,), "in-plan-device-group")
+                    continue
+                before = len(held[1])
+                held[1].update(ids)
+                if len(held[1]) != before + len(ids):
+                    _mark((nid,), "in-plan-dup-device")
         # per-node demand aggregated ACROSS blocks (two blocks on one
         # node were fit-checked together on the expanded path)
         total: Dict[str, List[int]] = {}
@@ -711,6 +733,18 @@ class PlanApplier:
             if node is None or node.status == "down":
                 _mark((nid,), "node-down")
                 continue
+            claimed_dev = plan_devs.get(nid)
+            if claimed_dev is not None:
+                # every claimed instance is one of the group's own (so
+                # the claims, distinct by the check above, are within
+                # its count)
+                group, dev_ids = claimed_dev
+                inventory = next(
+                    (d.instance_ids for d in node.resources.devices
+                     if (d.vendor, d.type, d.name) == group), ())
+                if not dev_ids.issubset(inventory):
+                    _mark((nid,), "device-unknown")
+                    continue
             if skip_fit:
                 continue
             removals = {a.id for a in plan.node_update.get(nid, ())}
@@ -722,6 +756,7 @@ class PlanApplier:
             # node, never a per-alloc allocs_fit materialization)
             claimed = plan_ports.get(nid) if not skip_fit else None
             used_ports: Optional[NetworkIndex] = None
+            held_dev = False
             if claimed:
                 used_ports = NetworkIndex()
                 used_ports.set_node(node)
@@ -733,9 +768,19 @@ class PlanApplier:
                 disk += a.resources.disk_mb
                 if used_ports is not None:
                     used_ports.add_allocs((a,))
+                if claimed_dev is not None and a.allocated_devices:
+                    for ad in a.allocated_devices:
+                        if not dev_ids.isdisjoint(ad.device_ids) and (
+                                ad.vendor, ad.type, ad.name) == group:
+                            held_dev = True
             if used_ports is not None and not claimed.isdisjoint(
                     used_ports.used_ports):
                 _mark((nid,), "port-collision")
+                continue
+            if held_dev:
+                # an instance a live allocation already holds: a foreign
+                # write took it between the carve and this commit
+                _mark((nid,), "device-collision")
                 continue
             res, rsv = node.resources, node.reserved
             if (cpu > res.cpu - rsv.cpu
@@ -761,7 +806,8 @@ class PlanApplier:
     def _carries_host_assigned(plan: Plan) -> bool:
         """Any placement carrying a port/device assignment — or even just
         a network ask (allocs_fit counts reserved-port asks too).  Block
-        TEMPLATES are inspected too: networked blocks carry their port
+        TEMPLATES are inspected too, and a block's device columns (ISSUE
+        30): networked blocks carry their port
         columns (ISSUE 8) and must demote off the skip — their port
         values were host-assigned against a snapshot a batch-mate's
         commit may have invalidated; the re-check (columnar per-node
@@ -774,7 +820,8 @@ class PlanApplier:
         for block in plan.alloc_blocks:
             tmpl = block.template
             if (tmpl.allocated_ports or tmpl.allocated_devices
-                    or tmpl.resources.networks):
+                    or tmpl.resources.networks
+                    or block.device_ids is not None):
                 return True
         return False
 

@@ -70,24 +70,29 @@ from nomad_tpu.core.telemetry import REGISTRY
 #   d2h          worker: result fetch + host-side expansion
 #   solo_place   worker: one PlacementEngine.place call (input build,
 #                launch, wait, fetch, decision rows).  A redo that
-#                `_assign_devices` makes from inside a materialize is
-#                the one place a worker stage nests in another; no
-#                benchmark cell asks for devices
+#                `_assign_devices` makes from inside a materialize nests
+#                in it (the solo device path; no cell takes it)
 #   system_place worker: one PlacementEngine.place_system call, a system
 #                eval's whole placement (input build, the launch, the
 #                wait for it, the fetch of the verdicts)
 #   materialize  worker: plan construction from picks, on every path
+#   device_carve worker: the batched device path's carve of instance ids
+#                for one eval's picks (scheduler/device.py carve_block);
+#                lies INSIDE that eval's materialize
 #   plan_wait    worker: blocked on the applier's verdict for one plan
 #   eval_update  worker: the eval status write
 #   ack          worker: per-eval records + broker ack/nack (Worker.
 #                _settle); one per eval on every path
 #   commit       applier: evaluate + state-store upsert of one plan
 #   store_upsert applier: the upsert alone (inside commit)
-# Worker stages other than "pass" never nest in one another, so the
-# unnamed part of a pass is its wall minus their sum.
+# Worker stages other than "pass" do not nest in one another, but for
+# device_carve (and the solo device path's redo), each inside a
+# materialize: the unnamed part of a pass is its wall minus their UNION
+# (benchmark/host_spans.py View.named), which a nested span leaves as it
+# was; a per-stage sum must leave device_carve out or count it twice.
 STAGES = ("pass", "prepare", "dispatch", "device", "device_wait", "d2h",
-          "solo_place", "system_place", "materialize", "plan_wait",
-          "eval_update", "ack", "commit", "store_upsert")
+          "solo_place", "system_place", "materialize", "device_carve",
+          "plan_wait", "eval_update", "ack", "commit", "store_upsert")
 
 _SERIES = {s: f"nomad.wavepipe.{s}_s" for s in STAGES}
 
@@ -302,6 +307,13 @@ class WavePipeline:
         # chained launches must not re-pick them (the chain's usage
         # buffer predates the foreign write that refuted them)
         self._masked: set = set()
+        # device instance ids carved by this pipeline's plans that a
+        # later wave's snapshot may not show yet (scheduler/device.py
+        # CarveLedger): one in-use index for a wave's mates and for the
+        # waves of a cycle.  A second worker's pipeline has its own; what
+        # two workers carve alike the applier's device audit refutes
+        from nomad_tpu.scheduler.device import CarveLedger
+        self.device_ledger = CarveLedger()
         self.stats = {"waves": 0, "chained": 0, "masked_nodes": 0,
                       "repairs": 0,
                       # mesh launches: cumulative cross-shard collective

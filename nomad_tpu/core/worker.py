@@ -475,7 +475,8 @@ class Worker:
                     handles[i] = sched.submit_batched(
                         ev, prep, bds[i],
                         coupled_batch=(batch_id, batch_seq0),
-                        net_index_cache=shared_net)
+                        net_index_cache=shared_net,
+                        device_ledger=self.pipeline.device_ledger)
                 self.pipeline.note_ports_batched(sched.last_port_carve,
                                                  wave)
             except Exception as e:  # noqa: BLE001 - finalize pass nacks

@@ -26,6 +26,8 @@ from nomad_tpu.structs import (
     AllocMetric,
     Job,
     NodeScoreMeta,
+    RES_DIMS,
+    RES_NAMES,
     SCHED_ALGO_SPREAD,
     TaskGroup,
 )
@@ -313,6 +315,11 @@ def _unpack_bulk_compact(buf: np.ndarray, round_size: int, p_real: int,
     return picks[:p_real], scores[:p_real], meta
 
 
+# the meta block's per-dimension exhaustion columns, in RES_NAMES' order
+# (select.pack_round_buffer: three before `placed` at 12, the rest after)
+_META_DIM_EX = [9, 10, 11] + list(range(13, 10 + RES_DIMS))
+
+
 def _unpack_bulk(buf: np.ndarray, round_size: int, p_real: int, n: int):
     """Per-placement expansion of the compact buffer (exact-API path)."""
     picks, scores, meta = _unpack_bulk_compact(
@@ -322,7 +329,8 @@ def _unpack_bulk(buf: np.ndarray, round_size: int, p_real: int, n: int):
     m = meta[rep]
     return (picks, scores,
             m[:, 0:3], m[:, 3:6].view(np.float32),
-            m[:, 6], m[:, 7], m[:, 8], m[:, 9:12])
+            m[:, 6], m[:, 7], m[:, 8], m[:, _META_DIM_EX])
+
 
 
 @dataclass
@@ -383,6 +391,10 @@ class PlacementEngine:
         # compact-lane candidate frames (_candidate_frames), by what they
         # were derived from; a handful, oldest out
         self._frame_cache: Dict[tuple, tuple] = {}
+        # device requests' static masks (device_static_mask), by node
+        # table version and request signature; a handful, oldest out
+        self._device_mask_cache: Dict[tuple, np.ndarray] = {}
+        self._single_group: Optional[Tuple[int, bool]] = None
         self._dc_cache: Optional[Tuple[int, Dict[str, int]]] = None
         # host->device sync meter (ops/executor.py installs it): called
         # with (bytes, seconds, cause) for every node-state upload —
@@ -675,7 +687,7 @@ class PlacementEngine:
                 # fewer distinct rows; the upload shrinks with it
                 if len(rows) > SCATTER_CHUNK:
                     uniq, inv = np.unique(rows, return_inverse=True)
-                    agg = np.zeros((len(uniq), 3), vals.dtype)
+                    agg = np.zeros((len(uniq), RES_DIMS), vals.dtype)
                     np.add.at(agg, inv, vals)
                     rows, vals = uniq, agg
                 # fixed-size chunks -> one compiled scatter shape, ever
@@ -695,7 +707,8 @@ class PlacementEngine:
                         r_c = np.concatenate(
                             [r_c, np.zeros(pad - n_c, r_c.dtype)])
                         v_c = np.concatenate(
-                            [v_c, np.zeros((pad - n_c, 3), v_c.dtype)])
+                            [v_c, np.zeros((pad - n_c, RES_DIMS),
+                                           v_c.dtype)])
                     dev = self._launch(
                         "scatter", (int(dev.shape[0]), pad),
                         self._scatter_fn,
@@ -750,6 +763,55 @@ class PlacementEngine:
 
     # -------------------------------------------------------------- solve
 
+    def device_static_mask(self, t: NodeTensors, snapshot, req
+                           ) -> np.ndarray:
+        """The STATIC half of the DeviceChecker for one device request:
+        a read-only [n] bool, True where the node carries a device group
+        the request's name and constraints accept (scheduler/device.py
+        group_feasible).  It moves with the node table alone, so it is
+        built once per (request signature, node-table version), over the
+        nodes that advertise a device at all, and reused after:
+        `_candidate_frames`' pattern.  How many instances are FREE is
+        the tensors' device dimension, the kernels' to account."""
+        from nomad_tpu.scheduler.device import (group_accepts,
+                                                request_signature)
+        key = (t.version, request_signature(req))
+        with self.packer.lock:
+            hit = self._device_mask_cache.pop(key, None)
+            if hit is not None:
+                self._device_mask_cache[key] = hit
+        if hit is not None:
+            _registry().inc("nomad.engine.device_masks_reused")
+            return hit
+        mask = np.zeros(t.n, bool)
+        memo: Dict[tuple, bool] = {}
+        node_ids = t.node_ids
+        for row in np.flatnonzero(t.dev_groups > 0).tolist():
+            node = snapshot.node_by_id(node_ids[row])
+            if node is not None and any(
+                    group_accepts(dev, req, memo)
+                    for dev in node.resources.devices):
+                mask[row] = True
+        mask.setflags(write=False)
+        _registry().inc("nomad.engine.device_masks_built")
+        with self.packer.lock:
+            if len(self._device_mask_cache) >= 8:
+                self._device_mask_cache.pop(
+                    next(iter(self._device_mask_cache)))
+            self._device_mask_cache[key] = mask
+        return mask
+
+    def single_group_fleet(self, t: NodeTensors) -> bool:
+        """No node advertises more than one device group: then "instances
+        in use on the node" is one number whatever a request's name, and
+        the tensors' device dimension says exactly what a single request
+        can take (the batched device path's admission rule)."""
+        hit = self._single_group
+        if hit is None or hit[0] != t.version:
+            hit = self._single_group = (
+                t.version, not bool((t.dev_groups > 1).any()))
+        return hit[1]
+
     def _device_mask(self, tgs: Sequence[TaskGroup], t: NodeTensors,
                      snapshot, stopped_ids, device_in_use=None):
         """Host-side DeviceChecker analog (scheduler/device.py): a
@@ -757,35 +819,60 @@ class PlacementEngine:
         requests", ANDed into the kernel's static feasibility.  None when
         no group asks for devices (the common case — zero cost).
 
+        A group's row is the AND of its requests' static masks
+        (`device_static_mask`).  For ONE request on a fleet of
+        single-group nodes that is all: the free count is the kernel's
+        device dimension.  A group with several requests, or a fleet
+        with a multi-group node, cannot be said in one number a node, so
+        there the nodes the static masks admit are checked exactly on
+        the host, against the instances their live allocations hold.
+
         `device_in_use` overlays in-plan assignments the snapshot can't
         see yet (the scheduler's retry loop threads it through so a node
         whose instances were consumed earlier in the same plan stops
-        looking feasible)."""
+        looking feasible): its nodes are checked exactly too."""
         from nomad_tpu.scheduler.device import (
             InUseIndex, node_feasible, tg_device_requests)
         reqs_by_g = [tg_device_requests(tg) for tg in tgs]
         if not any(reqs_by_g):
             return None
-        dev_nodes = []
-        for row, nid in enumerate(t.node_ids):
-            node = snapshot.node_by_id(nid)
-            if node is not None and node.resources.devices:
-                dev_nodes.append((row, node))
-        in_use = InUseIndex()
-        for row, node in dev_nodes:
-            for a in snapshot.allocs_by_node(node.id):
-                if a.terminal_status() or a.id in stopped_ids:
-                    continue
-                in_use.add_alloc(node.id, a)
+        single = self.single_group_fleet(t)
+        overlay_rows = None
         if device_in_use is not None:
-            for node_id, gid, ids in device_in_use.items():
-                in_use.add(node_id, gid, ids)
-        mask = np.zeros((len(tgs), t.n), bool)
+            overlay_rows = {t.id_to_row[nid]
+                            for nid, _, _ in device_in_use.items()
+                            if nid in t.id_to_row}
+        in_use = InUseIndex()
+        seeded: set = set()
+        mask = np.ones((len(tgs), t.n), bool)
         for g, tg in enumerate(tgs):
             if not reqs_by_g[g]:
-                mask[g, :] = True
                 continue
-            for row, node in dev_nodes:
+            row_mask = self.device_static_mask(t, snapshot,
+                                               reqs_by_g[g][0][1])
+            for _task, req in reqs_by_g[g][1:]:
+                row_mask = row_mask & self.device_static_mask(
+                    t, snapshot, req)
+            mask[g] = row_mask
+            if single and len(reqs_by_g[g]) == 1:
+                exact = sorted(r for r in overlay_rows or ()
+                               if row_mask[r])
+            else:
+                exact = np.flatnonzero(row_mask).tolist()
+            for row in exact:
+                node = snapshot.node_by_id(t.node_ids[row])
+                if node is None:
+                    mask[g, row] = False
+                    continue
+                if row not in seeded:
+                    seeded.add(row)
+                    for a in snapshot.allocs_by_node(node.id):
+                        if not (a.terminal_status()
+                                or a.id in stopped_ids):
+                            in_use.add_alloc(node.id, a)
+                    if device_in_use is not None:
+                        for gid, ids in device_in_use.groups(node.id):
+                            in_use.add(node.id, gid, ids)
                 mask[g, row] = node_feasible(node, tg, in_use)
         return mask
 
@@ -899,15 +986,13 @@ class PlacementEngine:
         used0 = self._used_device(t)
         job_count = ctx.job_count
         if stopped_allocs:
-            delta = np.zeros((npad, 3), np.int32)
+            delta = np.zeros((npad, RES_DIMS), np.int32)
             job_count = job_count.copy()
             for a in stopped_allocs:
                 row = t.id_to_row.get(a.node_id)
                 if row is None:
                     continue
-                delta[row, 0] -= a.resources.cpu
-                delta[row, 1] -= a.resources.memory_mb
-                delta[row, 2] -= a.resources.disk_mb
+                delta[row] -= a.usage()
                 if a.job_id == job.id and job_count[row] > 0:
                     job_count[row] -= 1
             used0 = used0 + jnp.asarray(delta)
@@ -1088,7 +1173,7 @@ class PlacementEngine:
             topk_scores = b[:, 5:8].view(np.float32)
             n_filt = b[:, 9] - (npad - n)
             n_exh = b[:, 10]
-            dim_exh = b[:, 11:14]
+            dim_exh = b[:, 11:11 + RES_DIMS]
         elapsed = (time.perf_counter_ns() - t0) // max(p_real, 1)
 
         # ---- preemption fallback for failed placements ----
@@ -1114,7 +1199,6 @@ class PlacementEngine:
         # distinct top-k (read-only by convention, like the shared job ptr)
         smd_cache: Dict[tuple, list] = {}
         decisions: List[PlacementDecision] = []
-        dims = ("cpu", "memory", "disk")
         for i, r in enumerate(requests):
             metric = AllocMetric(
                 nodes_evaluated=n,
@@ -1125,10 +1209,10 @@ class PlacementEngine:
                 allocation_time_ns=elapsed,
             )
             de = dim_exh_l[i]
-            if de[0] or de[1] or de[2]:
-                for d in range(3):
+            if any(de):
+                for d in range(RES_DIMS):
                     if de[d]:
-                        metric.dimension_exhausted[dims[d]] = de[d]
+                        metric.dimension_exhausted[RES_NAMES[d]] = de[d]
             key = (tuple(topk_rows_l[i]), tuple(topk_scores_l[i]))
             smd = smd_cache.get(key)
             if smd is None:
@@ -1320,7 +1404,6 @@ class PlacementEngine:
                            elapsed) -> List[AllocMetric]:
         """Per-round AllocMetric objects from the bulk kernels' compact
         meta block (shared by the single-eval bulk path and place_batch)."""
-        dims = ("cpu", "memory", "disk")
         tsc = meta[:, 3:6].view(np.float32).tolist()
         metrics: List[AllocMetric] = []
         for r, row in enumerate(meta.tolist()):
@@ -1332,10 +1415,9 @@ class PlacementEngine:
                 nodes_exhausted=row[8],
                 allocation_time_ns=elapsed,
             )
-            if row[9] or row[10] or row[11]:
-                for d in range(3):
-                    if row[9 + d]:
-                        metric.dimension_exhausted[dims[d]] = row[9 + d]
+            for d, col in enumerate(_META_DIM_EX):
+                if row[col]:
+                    metric.dimension_exhausted[RES_NAMES[d]] = row[col]
             metric.score_meta_data = [
                 NodeScoreMeta(node_id=node_ids[kr],
                               scores={"final": ks}, norm_score=ks)
@@ -1495,6 +1577,7 @@ class PlacementEngine:
         elig tensor for the flat/sharded kernels and into the host-side
         signature masks the compact candidate frames are built from, so
         both kernel layouts honor the mask identically."""
+        from nomad_tpu.scheduler.device import request_signature
         t = self.packer.update(snapshot)
         n = t.n
         if n == 0:
@@ -1549,7 +1632,7 @@ class PlacementEngine:
         # batches land on a handful of compiled shapes
         c_max = _pad_pow2(max(tt.con.shape[1] for tt in tgts), lo=1)
         a_max = _pad_pow2(max(tt.aff.shape[1] for tt in tgts), lo=1)
-        req = np.zeros((g_pad, 3), np.int32)
+        req = np.zeros((g_pad, RES_DIMS), np.int32)
         desired = np.ones(g_pad, np.int32)
         dh_limit = np.zeros(g_pad, np.int32)
         # Constraint/affinity signatures dedupe across the batch: the
@@ -1572,16 +1655,26 @@ class PlacementEngine:
             req[gi] = tt.req[0]
             desired[gi] = max(it.tg.count, 1)
             dh_limit[gi] = tt.dh_limit[0]
-            key = (tuple(it.job.datacenters), it.job.node_pool)
+            # a device request's static mask (which nodes carry a group
+            # it accepts) is one more [N] landscape of the node table:
+            # it joins the datacenter and pool masks, so a signature
+            # still names everything static about the item's nodes
+            dev_reqs = [d for task in it.tg.tasks
+                        for d in task.resources.devices]
+            key = (tuple(it.job.datacenters), it.job.node_pool) + tuple(
+                request_signature(d) for d in dev_reqs)
             mi = mask_keys.get(key)
             if mi is None:
                 mi = len(mask_rows)
                 mask_keys[key] = mi
+                host_mask = ctx.dc_mask & ctx.pool_mask
+                for d in dev_reqs:
+                    host_mask = host_mask & self.device_static_mask(
+                        t, snapshot, d)
                 mask_rows.append(self._dev_const(
                     ("basemask", t.version, npad) + key,
-                    lambda ctx=ctx: _pad_rows(
-                        ctx.dc_mask & ctx.pool_mask, npad, False)))
-                mask_np.append(ctx.dc_mask & ctx.pool_mask)
+                    lambda m=host_mask: _pad_rows(m, npad, False)))
+                mask_np.append(host_mask)
             con_row = np.zeros((c_max, 3), np.int32)
             con_row[:tt.con.shape[1]] = tt.con[0]
             skey = con_row.tobytes() + mi.to_bytes(4, "little")

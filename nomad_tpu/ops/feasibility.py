@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from nomad_tpu.pack.interner import UNSET
+from nomad_tpu.structs import RES_DIMS, RES_NAMES
 from nomad_tpu.pack.packer import (
     DOP_EQ,
     DOP_IS_NOT_SET,
@@ -81,9 +82,10 @@ feasible_mask_jit = jax.jit(feasible_mask)
 
 # place_system's per-(group, node) verdict, in allocs_fit's order of
 # dimensions; anything but SYS_PLACED names why the node took nothing
-SYS_VERDICTS = 5
-SYS_PLACED, SYS_FILTERED, SYS_CPU, SYS_MEMORY, SYS_DISK = range(SYS_VERDICTS)
-SYS_DIMENSIONS = {SYS_CPU: "cpu", SYS_MEMORY: "memory", SYS_DISK: "disk"}
+SYS_PLACED, SYS_FILTERED = 0, 1
+SYS_VERDICTS = 2 + RES_DIMS
+# verdict 2 + d: capacity dimension d (structs.RES_NAMES) is over
+SYS_DIMENSIONS = {2 + d: name for d, name in enumerate(RES_NAMES)}
 
 
 def place_system(attrs: jnp.ndarray,         # [N, A]
@@ -92,9 +94,9 @@ def place_system(attrs: jnp.ndarray,         # [N, A]
                  pool_mask: jnp.ndarray,     # [N] bool
                  con: jnp.ndarray,           # [G, C, 3]
                  luts: jnp.ndarray,          # [L, V]
-                 cap: jnp.ndarray,           # [N, 3] int32, net of reserved
-                 used: jnp.ndarray,          # [N, 3] int32
-                 req: jnp.ndarray,           # [G, 3] int32
+                 cap: jnp.ndarray,           # [N, RES_DIMS], net of reserved
+                 used: jnp.ndarray,          # [N, RES_DIMS] int32
+                 req: jnp.ndarray,           # [G, RES_DIMS] int32
                  domain: jnp.ndarray,        # [N] bool
                  ) -> jnp.ndarray:           # [G, N] int8
     """A system eval's whole placement: one allocation of every task
@@ -104,8 +106,9 @@ def place_system(attrs: jnp.ndarray,         # [N, A]
     group g's placement on a node counts against group g+1 there.
 
     Returns a SYS_* verdict per (group, node): `feasible_mask`'s terms,
-    then `used + ask <= cap` on cpu, memory and disk, the first
-    dimension over naming the verdict as `allocs_fit` names it.  Only
+    then `used + ask <= cap` on every capacity dimension (cpu, memory,
+    disk, device instances), the first dimension over naming the verdict
+    as `allocs_fit` names it.  Only
     `domain` rows take a placement (the rest are the caller's: nodes it
     walks on the host, or outside a node-update eval), but every row's
     verdict is what it would be in the domain, so the host walk reads
@@ -113,14 +116,13 @@ def place_system(attrs: jnp.ndarray,         # [N, A]
     mask = feasible_mask(attrs, elig, dc_mask, pool_mask, con, luts)
 
     def group(used, xs):
-        ok, ask = xs                         # [N] bool, [3]
-        over = used + ask[None, :] > cap     # [N, 3]
-        verdict = jnp.where(
-            ~ok, SYS_FILTERED,
-            jnp.where(over[:, 0], SYS_CPU,
-                      jnp.where(over[:, 1], SYS_MEMORY,
-                                jnp.where(over[:, 2], SYS_DISK,
-                                          SYS_PLACED)))).astype(jnp.int8)
+        ok, ask = xs                         # [N] bool, [RES_DIMS]
+        over = used + ask[None, :] > cap     # [N, RES_DIMS]
+        # the first dimension over names the verdict
+        verdict = SYS_PLACED
+        for d in reversed(range(RES_DIMS)):
+            verdict = jnp.where(over[:, d], 2 + d, verdict)
+        verdict = jnp.where(~ok, SYS_FILTERED, verdict).astype(jnp.int8)
         take = domain & (verdict == SYS_PLACED)
         return used + take[:, None] * ask[None, :], verdict
 

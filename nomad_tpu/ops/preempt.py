@@ -46,6 +46,7 @@ from nomad_tpu.structs import (
     JOB_TYPE_SYSBATCH,
     JOB_TYPE_SYSTEM,
     PreemptionConfig,
+    RES_DIMS,
     SchedulerConfiguration,
 )
 
@@ -69,7 +70,7 @@ def build_victim_tables(job: Job, snapshot, tensors
     8192-node cap.
 
     Returns (cand_rows [M] int32 — tensor row per table row, prio [M,A],
-    res [M,A,3], allocs {TENSOR row: [Allocation in sorted order]}).
+    res [M,A,RES_DIMS], allocs {TENSOR row: [Allocation in sorted order]}).
     Padding entries carry prio=2^30, res=0 — they can never help fill an
     ask."""
     by_row: Dict[int, list] = {}
@@ -96,12 +97,11 @@ def build_victim_tables(job: Job, snapshot, tensors
     m = len(by_row)
     cand_rows = np.fromiter(by_row.keys(), np.int32, m)
     prio = np.full((m, a_eff), 1 << 30, np.int32)
-    res = np.zeros((m, a_eff, 3), np.int32)
+    res = np.zeros((m, a_eff, RES_DIMS), np.int32)
     for ci, (row, allocs) in enumerate(by_row.items()):
         for i, a in enumerate(allocs):
             prio[ci, i] = (a.job.priority if a.job is not None else 50)
-            res[ci, i] = (a.resources.cpu, a.resources.memory_mb,
-                          a.resources.disk_mb)
+            res[ci, i] = a.usage()
     return cand_rows, prio, res, by_row
 
 
@@ -122,8 +122,8 @@ def preempt_bulk(cap, used0, static_g, dh_limit_g, job_count0,
         used, job_count, consumed = carry
         alive = ~consumed                                       # [N, A]
         res_alive = pre_res * alive[..., None]
-        freed = jnp.cumsum(res_alive, axis=1)                   # [N, A, 3]
-        free = (cap - used)[:, None, :]                         # [N, 1, 3]
+        freed = jnp.cumsum(res_alive, axis=1)                   # [N, A, D]
+        free = (cap - used)[:, None, :]                         # [N, 1, D]
         ok_k = jnp.all(free + freed >= req[None, None, :], axis=2)  # [N,A]
         any_ok = jnp.any(ok_k, axis=1)
         k_idx = jnp.argmax(ok_k, axis=1)                        # first fit
@@ -202,7 +202,7 @@ class Preemptor:
         self.job = job
         self.tensors = tensors
         self.static = static_mask            # [G, N] bool
-        self.used = used.copy()              # [N, 3] int32 (proposed usage)
+        self.used = used.copy()              # [N, RES_DIMS] (proposed usage)
         # dynamic constraints the kernel enforces must hold here too:
         self.job_count = (job_count.copy() if job_count is not None
                           else np.zeros(tensors.n, np.int32))
@@ -212,7 +212,7 @@ class Preemptor:
         # candidate allocs per node row: (priority, resources array, alloc)
         self.cands: Dict[int, List[Tuple[int, np.ndarray, Allocation]]] = {}
         # incrementally-maintained sum of preemptible resources per row
-        self._preemptible = np.zeros((tensors.n, 3), np.int64)
+        self._preemptible = np.zeros((tensors.n, RES_DIMS), np.int64)
         # eviction-plan cache: req-bytes -> row -> (evictions, cost).
         # Evictions are strictly row-local, so a placement invalidates
         # ONLY its chosen row — every other row's plan stays exact.  This
@@ -234,9 +234,7 @@ class Preemptor:
                     continue
                 if a.job_id == self.job.id:
                     continue
-                res = np.array([a.resources.cpu, a.resources.memory_mb,
-                                a.resources.disk_mb], np.int64)
-                lst.append((prio, res, a))
+                lst.append((prio, np.array(a.usage(), np.int64), a))
             if lst:
                 self.cands[row] = lst
                 self._preemptible[row] = np.sum([c[1] for c in lst], axis=0)
@@ -275,13 +273,10 @@ class Preemptor:
                 best_row, best_cost, best_evict = row, cost, evict
         if best_evict is None:
             return None
-        freed = np.zeros(3, np.int64)
+        freed = np.zeros(RES_DIMS, np.int64)
         for a in best_evict:
             self.evicted_ids.add(a.id)
-            res = np.array(
-                [a.resources.cpu, a.resources.memory_mb, a.resources.disk_mb],
-                np.int64)
-            freed += res
+            freed += np.array(a.usage(), np.int64)
         self.used[best_row] -= freed.astype(np.int32)
         self.used[best_row] += req.astype(np.int32)
         self.job_count[best_row] += 1

@@ -26,6 +26,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from nomad_tpu.structs import RES_DIMS
+
 from .feasibility import constraint_mask, feasible_mask
 from .scoring import (
     affinity_score,
@@ -60,8 +62,8 @@ class PlacementInputs(NamedTuple):
     """Device inputs for one eval's placement batch."""
     # node state
     attrs: jnp.ndarray       # [N, A] int32
-    cap: jnp.ndarray         # [N, 3] int32
-    used0: jnp.ndarray       # [N, 3] int32
+    cap: jnp.ndarray         # [N, RES_DIMS] int32
+    used0: jnp.ndarray       # [N, RES_DIMS] int32
     elig: jnp.ndarray        # [N] bool
     dc_mask: jnp.ndarray     # [N] bool
     pool_mask: jnp.ndarray   # [N] bool
@@ -69,7 +71,7 @@ class PlacementInputs(NamedTuple):
     # per-task-group statics
     con: jnp.ndarray         # [G, C, 3] int32
     aff: jnp.ndarray         # [G, Af, 4] int32
-    req: jnp.ndarray         # [G, 3] int32
+    req: jnp.ndarray         # [G, RES_DIMS] int32
     desired: jnp.ndarray     # [G] int32 (tg count, anti-affinity denominator)
     dh_limit: jnp.ndarray    # [G] int32 distinct_hosts limit (0 = none)
     # job-level spread state
@@ -114,8 +116,8 @@ class PlacementOutputs(NamedTuple):
     n_feasible: jnp.ndarray   # [P] int32 feasible candidates at this step
     n_filtered: jnp.ndarray   # [P] int32 statically filtered nodes
     n_exhausted: jnp.ndarray  # [P] int32 feasible-but-full nodes
-    dim_exhausted: jnp.ndarray  # [P, 3] int32 per-dimension exhaustion
-    used: jnp.ndarray         # [N, 3] final proposed usage
+    dim_exhausted: jnp.ndarray  # [P, RES_DIMS] per-dimension exhaustion
+    used: jnp.ndarray         # [N, RES_DIMS] final proposed usage
     job_count: jnp.ndarray    # [N] final job counts
 
 
@@ -269,13 +271,15 @@ place_jit = jax.jit(place)
 
 
 def pack_outputs(out: PlacementOutputs):
-    """Pack per-placement outputs into ONE int32 buffer `[P, 14]` (floats
+    """Pack per-placement outputs into ONE int32 buffer `[P, 11 +
+    RES_DIMS]` (floats
     bitcast) so the host pays a single device→host round trip instead of
     one per array (the engine used to fetch ten arrays per batch, and
     the fixed cost per fetch dominated eval latency).
 
     Column layout: 0 pick | 1 score | 2-4 topk_rows | 5-7 topk_scores |
-    8 n_feasible | 9 n_filtered | 10 n_exhausted | 11-13 dim_exhausted.
+    8 n_feasible | 9 n_filtered | 10 n_exhausted | 11.. dim_exhausted
+    (one column a capacity dimension, structs.RES_NAMES).
     Returns (buf, used, job_count); used/job_count are fetched lazily by
     the engine only on the preemption fallback path.
     """
@@ -308,15 +312,15 @@ class BulkInputs(NamedTuple):
     batches here).  Uploading [P]-sized index arrays cost more than the
     kernel at 100k placements — the transport moves ~3MB/s."""
     attrs: jnp.ndarray       # [N, A] int32
-    cap: jnp.ndarray         # [N, 3] int32
-    used0: jnp.ndarray       # [N, 3] int32
+    cap: jnp.ndarray         # [N, RES_DIMS] int32
+    used0: jnp.ndarray       # [N, RES_DIMS] int32
     elig: jnp.ndarray        # [N] bool
     dc_mask: jnp.ndarray     # [N] bool
     pool_mask: jnp.ndarray   # [N] bool
     luts: jnp.ndarray        # [L, V] bool
     con: jnp.ndarray         # [G, C, 3] int32
     aff: jnp.ndarray         # [G, Af, 4] int32
-    req: jnp.ndarray         # [G, 3] int32
+    req: jnp.ndarray         # [G, RES_DIMS] int32
     desired: jnp.ndarray     # [G] int32
     dh_limit: jnp.ndarray    # [G] int32
     job_count0: jnp.ndarray  # [N] int32
@@ -532,7 +536,9 @@ def pack_round_buffer(rows_p, cnt_p, top_rows, top_sc, n_feas, n_filt,
     """Shared per-round output assembly for every rounds-based kernel
     (single-eval bulk, multi-eval flat/compact, and the sharded
     variants): the packed fill slots (row*2048 + count) and the 16-word
-    meta block — layout documented on place_bulk_packed.  Returns
+    meta block — layout documented on place_bulk_packed.  `dim_ex` has
+    one column a capacity dimension: the first three sit before
+    `placed`, where they always have, the rest after it.  Returns
     (fills, meta)."""
     f2i = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
     fills = jnp.where(cnt_p > 0, rows_p * 2048 + cnt_p, 0)
@@ -544,8 +550,8 @@ def pack_round_buffer(rows_p, cnt_p, top_rows, top_sc, n_feas, n_filt,
         jnp.concatenate([f2i(top_sc),
                          jnp.zeros((r, 3 - tk), jnp.int32)], axis=1),
         n_feas[:, None], n_filt[:, None], n_exh[:, None],
-        dim_ex, placed[:, None],
-        jnp.zeros((r, 3), jnp.int32),
+        dim_ex[:, :3], placed[:, None], dim_ex[:, 3:],
+        jnp.zeros((r, 6 - dim_ex.shape[1]), jnp.int32),
     ], axis=1)
     return fills, meta
 
@@ -562,7 +568,9 @@ def place_bulk_packed(inp: BulkInputs, round_size: int, n_rounds: int,
                                      asserts n < 2^20 nodes)
       [round_size : +16)             topk_rows(3) | bitcast topk_scores(3) |
                                      n_feasible | n_filtered | n_exhausted |
-                                     dim_exhausted(3) | placed_total | pad(3)
+                                     dim_exhausted(cpu, memory, disk) |
+                                     placed_total | dim_exhausted(devices)
+                                     | pad(2)
 
     With `with_scores=True` a bitcast per-slot score block is inserted
     between fills and meta (buffer `[R, 2*round_size + 16]`) so the host
@@ -690,8 +698,8 @@ class MultiEvalInputs(NamedTuple):
     dynamic state, not a signature)."""
     # node state (shared across the batch)
     attrs: jnp.ndarray       # [N, A] int32
-    cap: jnp.ndarray         # [N, 3] int32
-    used0: jnp.ndarray       # [N, 3] int32
+    cap: jnp.ndarray         # [N, RES_DIMS] int32
+    used0: jnp.ndarray       # [N, RES_DIMS] int32
     elig: jnp.ndarray        # [N] bool
     luts: jnp.ndarray        # [L, V] bool
     base_mask: jnp.ndarray   # [M, N] bool   deduped dc∧pool masks
@@ -700,7 +708,7 @@ class MultiEvalInputs(NamedTuple):
     u_mask: jnp.ndarray      # [U] int32  -> base_mask row per signature
     aff: jnp.ndarray         # [Ua, Af, 4] int32 unique affinity rows
     # per-task-group values (G spans all evals of the batch)
-    req: jnp.ndarray         # [G, 3] int32
+    req: jnp.ndarray         # [G, RES_DIMS] int32
     desired: jnp.ndarray     # [G] int32
     dh_limit: jnp.ndarray    # [G] int32
     g_static: jnp.ndarray    # [G] int32  -> static signature row (U)
@@ -960,7 +968,7 @@ def place_multi_compact_packed(inp: MultiEvalInputs, cand_rows, cand_valid,
     # scatter the per-lane usage slices back to cluster rows (disjoint
     # frames ⇒ no collisions; padding indices == n drop out of range)
     used = inp.used0.at[cand_rows.reshape(-1)].set(
-        used_c.reshape(-1, 3), mode="drop")
+        used_c.reshape(-1, RES_DIMS), mode="drop")
 
     def flat(x):                          # [T, L, ...] -> [T*L, ...]
         return x.reshape((-1,) + x.shape[2:])

@@ -3,9 +3,10 @@
 SURVEY.md §7 P1.  Nodes become packed int32/float32 rows; every string
 predicate is pre-lowered so the device kernels (nomad_tpu.ops) see only:
 
-  - `cap`   [N, 3] int32   usable capacity (cpu MHz, memory MB, disk MB),
+  - `cap`   [N, RES_DIMS] int32  usable capacity (cpu MHz, memory MB, disk
+                           MB, device instances: structs.RES_NAMES),
                            node reservations already subtracted
-  - `used`  [N, 3] int32   sum of non-terminal alloc resources per node
+  - `used`  [N, RES_DIMS] int32  sum of non-terminal alloc usage per node
   - `attrs` [N, A] int32   interned value id per attribute column (-1 unset)
   - `elig`  [N]    bool    node.ready() (status+drain+eligibility collapsed)
   - `dc`, `pool`, `klass` [N] int32   interned ids for the hot synthetics
@@ -43,6 +44,7 @@ from nomad_tpu.structs import (
     OP_SET_CONTAINS_ALL,
     OP_SET_CONTAINS_ANY,
     OP_VERSION,
+    RES_DIMS,
     TaskGroup,
 )
 from nomad_tpu.utils.version import check_constraint as check_version
@@ -119,8 +121,8 @@ class NodeTensors:
 
     node_ids: List[str]
     id_to_row: Dict[str, int]
-    cap: np.ndarray          # [N,3] int32
-    used: np.ndarray         # [N,3] int32
+    cap: np.ndarray          # [N,RES_DIMS] int32
+    used: np.ndarray         # [N,RES_DIMS] int32
     attrs: np.ndarray        # [N,A] int32
     elig: np.ndarray         # [N] bool
     dc: np.ndarray           # [N] int32
@@ -129,6 +131,11 @@ class NodeTensors:
     version: int = 0         # bumped on every row change (device cache key)
     used_version: int = 0    # bumped on usage-only deltas (separate upload
                              # key: plan applies touch used, not attrs)
+    # device groups a node advertises, [N] int32.  cap's device column
+    # is their instances TOGETHER, which is "the instances a request can
+    # take" only where a node has one group: the batched device path
+    # asks (scheduler/generic.py prepare_batch)
+    dev_groups: Optional[np.ndarray] = None
 
     @property
     def n(self) -> int:
@@ -182,7 +189,7 @@ class ClusterPacker:
         # of rescanning a node's alloc list (the alloc list only grows —
         # terminal allocs linger until GC — so rescans get slower forever).
         self._alloc_node: Dict[str, str] = {}       # alloc id -> node id
-        self._counted: Dict[str, Dict[str, Tuple[int, int, int]]] = {}
+        self._counted: Dict[str, Dict[str, Tuple[int, ...]]] = {}
         # columnar-block usage, tracked as UNITS (block id -> the block,
         # whose node table and node_counts() say where its allocs count):
         # an AllocBlock event is one vectorized scatter and ONE entry, no
@@ -266,13 +273,13 @@ class ClusterPacker:
         if t is None:
             return                      # next build() scans from scratch
         rows: List[int] = []
-        vals: List[Tuple[int, int, int]] = []
+        vals: List[Tuple[int, ...]] = []
         alloc_node = self._alloc_node
         counted = self._counted
         id_to_row = t.id_to_row
         # bulk plans share ONE resources object across a whole round:
         # build its usage tuple once, not per alloc
-        res_cache: Dict[int, Tuple[int, int, int]] = {}
+        res_cache: Dict[int, Tuple[int, ...]] = {}
         for a in allocs:
             aid = a.id
             old_node = alloc_node.get(aid)
@@ -283,13 +290,16 @@ class ClusterPacker:
                     row = id_to_row.get(old_node)
                     if row is not None:
                         rows.append(row)
-                        vals.append((-res[0], -res[1], -res[2]))
+                        vals.append(tuple(-v for v in res))
             nid = a.node_id
             if nid and not a.terminal_status():
                 r = a.resources
-                res = res_cache.get(id(r))
-                if res is None:
-                    res_cache[id(r)] = res = (r.cpu, r.memory_mb, r.disk_mb)
+                if a.allocated_devices:
+                    res = a.usage()     # instances are the alloc's own
+                else:
+                    res = res_cache.get(id(r))
+                    if res is None:
+                        res_cache[id(r)] = res = a.usage()
                 c = counted.get(nid)
                 if c is None:
                     counted[nid] = c = {}
@@ -502,13 +512,14 @@ class ClusterPacker:
         t = NodeTensors(
             node_ids=[nd.id for nd in nodes],
             id_to_row={nd.id: i for i, nd in enumerate(nodes)},
-            cap=np.zeros((n, 3), np.int32),
-            used=np.zeros((n, 3), np.int32),
+            cap=np.zeros((n, RES_DIMS), np.int32),
+            used=np.zeros((n, RES_DIMS), np.int32),
             attrs=np.full((n, a), UNSET, np.int32),
             elig=np.zeros(n, bool),
             dc=np.zeros(n, np.int32),
             pool=np.zeros(n, np.int32),
             klass=np.zeros(n, np.int32),
+            dev_groups=np.zeros(n, np.int32),
         )
         self._alloc_node.clear()
         self._counted.clear()
@@ -589,7 +600,7 @@ class ClusterPacker:
                 self.ensure_column(k)
             t.attrs[row, :] = UNSET
             self._fill_row(t, row, nd, snapshot, pm,
-                           unit_used=unit_used.get(nid, (0, 0, 0)))
+                           unit_used=unit_used.get(nid, (0,) * RES_DIMS))
             refreshed.append(row)
         self._seq += 1
         t.version = self._seq
@@ -615,19 +626,17 @@ class ClusterPacker:
             res = block.resources_tuple()
             for bi, c in zip(hit.tolist(),
                              block.node_counts()[hit].tolist()):
-                used = out.setdefault(table[bi], [0, 0, 0])
-                used[0] += res[0] * c
-                used[1] += res[1] * c
-                used[2] += res[2] * c
+                used = out.setdefault(table[bi], [0] * RES_DIMS)
+                for d in range(RES_DIMS):
+                    used[d] += res[d] * c
         return out
 
     def _fill_row(self, t: NodeTensors, i: int, nd: Node, snapshot, pm,
                   unit_used: Optional[Sequence[int]] = None) -> None:
         """`unit_used`: the dirty-row refill (usage from the ledger, the
         block units' share of it handed in); None: a full rescan."""
-        t.cap[i] = (nd.resources.cpu - nd.reserved.cpu,
-                    nd.resources.memory_mb - nd.reserved.memory_mb,
-                    nd.resources.disk_mb - nd.reserved.disk_mb)
+        t.cap[i] = nd.capacity()
+        t.dev_groups[i] = len(nd.resources.devices)
         if unit_used is not None:
             # dirty-row refill while attached: the counted/_alloc_node
             # ledger is advanced synchronously by Allocations events and
@@ -637,9 +646,8 @@ class ClusterPacker:
             # node attrs/capacity come from the snapshot's node object.
             used = list(unit_used)
             for res in self._counted.get(nd.id, {}).values():
-                used[0] += res[0]
-                used[1] += res[1]
-                used[2] += res[2]
+                for d in range(RES_DIMS):
+                    used[d] += res[d]
             t.used[i] = used
         else:
             # full usage rescan for this row: re-anchor the delta accounting
@@ -651,16 +659,14 @@ class ClusterPacker:
             # block rows come back per-alloc from the snapshot read below;
             # the block UNITS went with the rest (_build_locked, the one
             # caller of a full rescan, cleared them)
-            counted: Dict[str, Tuple[int, int, int]] = {}
-            used = [0, 0, 0]
+            counted: Dict[str, Tuple[int, ...]] = {}
+            used = [0] * RES_DIMS
             for alc in snapshot.allocs_by_node(nd.id):
                 if alc.terminal_status():
                     continue
-                r = alc.resources
-                used[0] += r.cpu
-                used[1] += r.memory_mb
-                used[2] += r.disk_mb
-                counted[alc.id] = (r.cpu, r.memory_mb, r.disk_mb)
+                counted[alc.id] = res = alc.usage()
+                for d in range(RES_DIMS):
+                    used[d] += res[d]
                 self._alloc_node[alc.id] = nd.id
             self._counted[nd.id] = counted
             t.used[i] = used
@@ -777,7 +783,7 @@ class ClusterPacker:
         distinct_hosts / distinct_property become dynamic specs handled by
         the selection kernel, not static rows."""
         g = len(tgs)
-        req = np.zeros((g, 3), np.int32)
+        req = np.zeros((g, RES_DIMS), np.int32)
         dh_limit = np.zeros(g, np.int32)
         rows: List[List[Tuple[int, int, int]]] = []
         aff_rows: List[List[Tuple[int, int, int, int]]] = []
@@ -787,7 +793,11 @@ class ClusterPacker:
         distinct: List[List[Tuple[int, int, Optional[str]]]] = []
         for gi, tg in enumerate(tgs):
             ask = tg.combined_resources()
-            req[gi] = (ask.cpu, ask.memory_mb, ask.disk_mb)
+            # the device column: the instances the group's requests ask
+            # together (a count; which ones is the host's to assign)
+            req[gi] = (ask.cpu, ask.memory_mb, ask.disk_mb,
+                       sum(max(d.count, 1) for task in tg.tasks
+                           for d in task.resources.devices))
             crows: List[Tuple[int, int, int]] = []
             dist: List[Tuple[int, int, Optional[str]]] = []
             for task in tg.tasks:

@@ -28,6 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from nomad_tpu.ops.feasibility import constraint_mask
 from nomad_tpu.ops.scoring import affinity_score
+from nomad_tpu.structs import RES_DIMS
 from nomad_tpu.ops.select import (
     NEG_INF,
     TOP_K,
@@ -560,7 +561,7 @@ def _multi_compact_local(inp: MultiEvalInputs, cand_rows, cand_valid,
     # and foreign rows drop out of range)
     scatter_idx = jnp.where(cand_valid, cand_rows - offset, n_loc)
     used = inp.used0.at[scatter_idx.reshape(-1)].set(
-        used_c.reshape(-1, 3), mode="drop")
+        used_c.reshape(-1, RES_DIMS), mode="drop")
     return outs + (used, jnp.zeros(n_loc, jnp.int32))
 
 

@@ -9,16 +9,25 @@ attributes, reference: structs.NodeDeviceResource); a task asks for
 constraints/affinities over device attributes (reference:
 structs.RequestedDevice).
 
-Unlike cpu/memory — which the placement kernels water-fill on device —
-device assignment is an exact small-cardinality matching problem over
+HOW MANY instances a node has free is a capacity like cpu and memory:
+the packed tensors carry it as a dimension (structs.RES_NAMES, "devices")
+and the kernels account it placement by placement.  WHICH instance an
+allocation gets is an exact small-cardinality matching problem over
 string-keyed inventories, so it stays host-side (SURVEY.md §7 P1's
 "strings never reach the device" stance):
 
-  * `feasibility_mask` produces a per-(taskgroup, node) boolean the engine
-    ANDs into the kernel's static feasibility (the DeviceChecker analog);
+  * `request_signature` / `group_accepts` give the STATIC half of the
+    DeviceChecker: whether a node carries a group a request's name and
+    constraints accept.  The engine keeps it as a per-signature mask by
+    node-table version (ops/engine.py device_static_mask);
+  * `node_feasible` is the exact DYNAMIC check, which only a group with
+    several requests or a node with several groups still needs;
   * `assign_devices` picks concrete instance IDs for a chosen node after
     the kernel has placed (the AllocateDevice analog), with affinity
-    scoring across eligible device groups.
+    scoring across eligible device groups;
+  * `carve_block` is its columnar twin for a whole eval's picks (the
+    batched path, scheduler/generic.py), against a `CarveLedger` that
+    the wave's mates and the cycle's earlier waves share.
 
 Both consult an `InUseIndex` built from live allocations so instances are
 never double-assigned; the plan applier re-checks via
@@ -28,7 +37,10 @@ never double-assigned; the plan applier re-checks via
 
 from __future__ import annotations
 
+import threading
 from typing import Dict, Iterable, List, Optional, Set, Tuple
+
+import numpy as np
 
 from nomad_tpu.structs import (
     AllocatedDeviceResource,
@@ -113,6 +125,28 @@ def group_feasible(dev: NodeDeviceResource, req: RequestedDevice) -> bool:
     return True
 
 
+def request_signature(req: RequestedDevice) -> tuple:
+    """What decides which device groups a request accepts: its name and
+    constraints, not its count (hashable; the static mask's key)."""
+    return (req.name, tuple((c.ltarget, c.operand, c.rtarget)
+                            for c in req.constraints))
+
+
+def group_accepts(dev: NodeDeviceResource, req: RequestedDevice,
+                  memo: Dict[tuple, bool]) -> bool:
+    """`group_feasible` through `memo`: a fleet has a handful of group
+    shapes (vendor, type, model, attributes) and one verdict each, unless
+    a constraint reads the instance ids themselves."""
+    if any("ids" in c.ltarget for c in req.constraints):
+        return group_feasible(dev, req)
+    key = (dev.vendor, dev.type, dev.name,
+           tuple(sorted(dev.attributes.items())))
+    hit = memo.get(key)
+    if hit is None:
+        memo[key] = hit = group_feasible(dev, req)
+    return hit
+
+
 def group_affinity_score(dev: NodeDeviceResource,
                          req: RequestedDevice) -> float:
     """Normalized [-1, 1] affinity score of a group (reference:
@@ -147,6 +181,10 @@ class InUseIndex:
         for node_id, groups in self._used.items():
             for gid, ids in groups.items():
                 yield node_id, gid, ids
+
+    def groups(self, node_id: str):
+        """(group_id, instance_id_set) pairs of one node."""
+        return self._used.get(node_id, {}).items()
 
     def add(self, node_id: str, group_id: str,
             instance_ids: Iterable[str]) -> None:
@@ -250,3 +288,145 @@ def assign_devices(node: Node, tg: TaskGroup, in_use: InUseIndex,
     for gid, _task, ids in staged:
         in_use.add(node.id, gid, ids)
     return assigned, ""
+
+
+# ---------------------------------------------------------------------------
+# the batched carve (scheduler/generic.py _materialize_bulk)
+# ---------------------------------------------------------------------------
+
+def held_instances(allocs) -> Set[str]:
+    """The instance ids the live allocations among `allocs` hold."""
+    held: Set[str] = set()
+    for a in allocs:
+        devs = a.allocated_devices
+        if devs and not a.terminal_status():
+            for ad in devs:
+                held.update(ad.device_ids)
+    return held
+
+
+class CarveLedger:
+    """Instance ids carved by plans that a later scheduler's snapshot may
+    not hold yet: ONE in-use index for a wave's mates (materialized one
+    after another before the first of them commits) and for the cycle's
+    earlier waves (a prefetched wave's snapshot predates its
+    predecessor's commits, while its usage buffer already counts them).
+
+    A plan's carve is a record {node id: ids}, open until the plan's
+    verdict: `settle` stamps it with the index its allocations committed
+    at and drops the nodes the applier refuted; a plan that never
+    commits is dropped whole.  A settled record is forgotten once a
+    carve arrives from a snapshot at or past its index: from there the
+    snapshot itself shows the instances (or shows them given back)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._records: List[list] = []     # [commit index | None, {..}]
+        # node id -> the records that carved on it: a carve asks about
+        # its own handful of nodes, not about every record
+        self._by_node: Dict[str, List[list]] = {}
+
+    def _drop(self, record: list, node_ids) -> None:
+        by_node = self._by_node
+        for nid in node_ids:
+            recs = by_node.get(nid)
+            if recs is not None:
+                recs[:] = [r for r in recs if r is not record]
+                if not recs:
+                    del by_node[nid]
+
+    def open(self, snapshot_index: int) -> list:
+        record = [None, {}]
+        with self._lock:
+            keep = []
+            for r in self._records:
+                if r[0] is None or r[0] > snapshot_index:
+                    keep.append(r)
+                else:
+                    self._drop(r, r[1])
+            keep.append(record)
+            self._records = keep
+        return record
+
+    def note(self, record: list, node_id: str, ids) -> None:
+        """`record`'s plan carved `ids` on `node_id`."""
+        with self._lock:
+            record[1][node_id] = set(ids)
+            self._by_node.setdefault(node_id, []).append(record)
+
+    def held(self, node_id: str) -> Set[str]:
+        out: Set[str] = set()
+        with self._lock:
+            for record in self._by_node.get(node_id, ()):
+                out.update(record[1].get(node_id, ()))
+        return out
+
+    def settle(self, record: list, commit_index: Optional[int],
+               refuted_nodes=()) -> None:
+        with self._lock:
+            if commit_index is None:
+                self._records = [r for r in self._records
+                                 if r is not record]
+                self._drop(record, record[1])
+                record[1].clear()
+                return
+            record[0] = commit_index
+            gone = [nid for nid in refuted_nodes if nid in record[1]]
+            self._drop(record, gone)
+            for nid in gone:
+                del record[1][nid]
+
+    def __len__(self) -> int:
+        return len(self._records)
+
+
+def carve_block(snapshot, ledger: CarveLedger, record: list,
+                req: RequestedDevice, picks: np.ndarray,
+                node_ids: List[str]):
+    """Instance ids for every row of one eval's picks, in one pass over
+    its nodes: a node's rows take its group's first free ids in the
+    group's own order, row after row, which is what a sequence of
+    `assign_devices` calls gives them.  For ONE request on nodes of ONE
+    device group (prepare_batch's admission rule).
+
+    `picks[i]` is row i's node (an index into `node_ids`).  In use on a
+    node: what its live allocations hold in `snapshot`, and what the
+    ledger's open and not yet visible records carved.  Returns
+    (ids [rows, count] unicode array, {pick: (vendor, type, name)},
+    short): `short` is the picks whose node has too few free instances
+    (a foreign write took them after the kernel looked).  Such a node
+    gives NONE of its rows ids, nothing of it enters the record, and its
+    rows' entries in `ids` are empty strings: the caller drops them."""
+    need = max(req.count, 1)
+    uniq, inv = np.unique(picks, return_inverse=True)
+    counts = np.bincount(inv, minlength=len(uniq)).tolist()
+    order = np.argsort(inv, kind="stable")
+    rows_ids: List[Optional[List[str]]] = []
+    groups: Dict[int, tuple] = {}
+    short: List[int] = []
+    memo: Dict[tuple, bool] = {}
+    for pick, k in zip(uniq.tolist(), counts):
+        nid = node_ids[pick]
+        node = snapshot.node_by_id(nid)
+        dev = next((d for d in (node.resources.devices if node else ())
+                    if group_accepts(d, req, memo)), None)
+        free: List[str] = []
+        if dev is not None:
+            busy = held_instances(snapshot.allocs_by_node(nid))
+            busy |= ledger.held(nid)
+            free = [i for i in dev.instance_ids if i not in busy]
+        if len(free) < k * need:
+            short.append(pick)
+            rows_ids.append(None)
+            continue
+        take = free[:k * need]
+        ledger.note(record, nid, take)
+        groups[pick] = (dev.vendor, dev.type, dev.name)
+        rows_ids.append(take)
+    flat: List[str] = []
+    for take, k in zip(rows_ids, counts):
+        flat.extend(take if take is not None else [""] * (k * need))
+    by_node = np.asarray(flat, np.str_).reshape(len(picks), need)
+    out = np.empty_like(by_node)
+    out[order] = by_node            # node-major back to the rows' order
+    return out, groups, short

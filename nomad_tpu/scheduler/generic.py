@@ -55,6 +55,17 @@ MAX_BATCH_ATTEMPTS = 2
 # equality is the promotion contract, like PR 7's sharded-vs-single).
 PORT_BATCHED = True
 
+# Batched device path (ISSUE 30): when True, a group whose ONE device
+# request has no affinities, that asks for no ports, on a fleet whose
+# nodes advertise at most one device group each, rides the wave: the
+# kernel accounts free instances as a capacity dimension and
+# `_materialize_bulk` carves the ids per node as columns of the
+# AllocBlock.  False sends every device-asking eval down the solo path
+# (the exact scan, then `_assign_devices`): the PARITY REFERENCE
+# tests/test_device_batched.py compares against.  A module constant that
+# only tests set; no server option reaches it.
+DEVICE_BATCHED = True
+
 # Shared engines so packed node tensors + jit caches persist across evals
 # of one in-process scheduler session (the worker wires its own).  Keyed
 # by the backing store's identity: two Harness/Server instances in one
@@ -104,6 +115,13 @@ class GenericScheduler(Scheduler):
         # rows whose ports the last _materialize_bulk carved COLUMNAR
         # (the worker mirrors it into the wave pipeline's stats)
         self.last_port_carve = 0
+        # the batched device path: why prepare_batch kept a device-asking
+        # eval off the wave ("" = it did not; None = the solo path has
+        # counted the eval), the carve's open ledger record, and the rows
+        # whose node was short at carve time
+        self._device_solo_rule = ""
+        self._carve = None            # (ledger, record)
+        self._carve_short = (0, ())   # (rows dropped, their node ids)
 
     # ------------------------------------------------------------- process
 
@@ -244,8 +262,15 @@ class GenericScheduler(Scheduler):
         if any(c.operand == OP_DISTINCT_PROPERTY for c in cons):
             return None
         from .device import tg_device_requests
-        if tg_device_requests(tg):
-            return None
+        dev_reqs = tg_device_requests(tg)
+        if dev_reqs:
+            # a device-asking group rides the wave where the kernel's
+            # device dimension says all there is to say (see
+            # DEVICE_BATCHED); anything else takes the solo path, which
+            # counts itself under the rule that refused (_assign_devices)
+            self._device_solo_rule = self._device_batch_refusal(tg, dev_reqs)
+            if self._device_solo_rule:
+                return None
         # Networked groups RIDE the batch (round-5 verdict #6), and
         # since ISSUE 8 they ride the COLUMNAR block path too: the
         # worker threads ONE NetworkIndex cache through every batch
@@ -260,8 +285,32 @@ class GenericScheduler(Scheduler):
         # _eval_blocks).
         return self.BatchPrep(job, tg, count, block, places, results)
 
+    def _device_batch_refusal(self, tg, dev_reqs) -> str:
+        """The admission rule that keeps a device-asking group off the
+        wave, or "" when it rides: ONE request, without affinities (and no
+        port ask beside it: one host-assigned column set a block), on a
+        fleet where no node advertises more than one device group.  Then
+        "instances in use on the node" is one number whatever the
+        request's name, which the node tensors carry, and the request's
+        name and constraints are a static mask (ops/engine.py
+        device_static_mask); the carve takes the node's first free ids
+        and has no group to choose."""
+        if not DEVICE_BATCHED:
+            return "off"
+        if len(dev_reqs) != 1:
+            return "requests"
+        if dev_reqs[0][1].affinities:
+            return "affinity"
+        if tg.networks or any(t.resources.networks for t in tg.tasks):
+            return "ports"
+        engine = self.engine
+        if not engine.single_group_fleet(engine.packer.update(self.state)):
+            return "multi_group_node"
+        return ""
+
     def submit_batched(self, evaluation: Evaluation, prep, bd,
-                       coupled_batch=None, net_index_cache=None):
+                       coupled_batch=None, net_index_cache=None,
+                       device_ledger=None):
         """Phase 2a of the batched path: materialize + ENQUEUE the plan
         without waiting for the applier — the worker submits a whole
         coupled chain first, so plan apply overlaps the next plan's
@@ -282,10 +331,21 @@ class GenericScheduler(Scheduler):
         self._tg_stats = {}
         plan = Plan(eval_id=evaluation.id, priority=evaluation.priority,
                     job=job, coupled_batch=coupled_batch)
-        self._materialize_bulk(plan, job, prep.places, bd, evaluation,
-                               results, block=prep.block,
-                               net_idx=net_index_cache)
+        try:
+            self._materialize_bulk(plan, job, prep.places, bd, evaluation,
+                                   results, block=prep.block,
+                                   net_idx=net_index_cache,
+                                   device_ledger=device_ledger)
+        except BaseException:
+            self._settle_carve(None)
+            raise
         if plan.is_no_op():
+            self._settle_carve(None)
+            short, nodes = self._carve_short
+            if short:
+                # every row's node was short of instances at carve time
+                self._repair_refuted(evaluation, plan, nodes, short, None)
+                return ("done", None)
             self._finalize(evaluation)
             return ("done", None)
         submit = getattr(self.planner, "submit_plan_async", None)
@@ -316,18 +376,25 @@ class GenericScheduler(Scheduler):
             result, err = wait(pending) if wait else pending.wait()
             refreshed_state = None
         if err is not None:
+            self._settle_carve(None)
             self._update_eval_status(evaluation, "failed", str(err))
             return err
+        refuted = list(result.refuted_nodes) if result is not None else []
+        self._settle_carve(result.alloc_index if result is not None
+                           else None, refuted)
+        # rows whose node was short of device instances at carve time
+        # never entered the plan: they re-enter as the refuted rows do
+        short, short_nodes = self._carve_short
         if result is not None:
             full, expected, actual = result.full_commit(plan)
-            if not full:
-                if (pipeline is not None and result.refuted_nodes
+            if not full or short:
+                if (pipeline is not None and (refuted or short)
                         and plan.alloc_blocks
                         and not plan.node_allocation
                         and evaluation.triggered_by != TRIGGER_PLAN_REFUTE):
                     return self._repair_refuted(
-                        evaluation, plan, result, expected - actual,
-                        pipeline)
+                        evaluation, plan, refuted + list(short_nodes),
+                        expected - actual + short, pipeline)
                 # partial commit: some nodes were refuted against newer
                 # state — re-run the normal retry loop, which reconciles
                 # the committed remainder on a fresh snapshot
@@ -341,8 +408,16 @@ class GenericScheduler(Scheduler):
         self._finalize(evaluation)
         return None
 
+    def _settle_carve(self, commit_index, refuted_nodes=()) -> None:
+        """Close this eval's record in the carve ledger: committed at
+        `commit_index` less the refuted nodes, or (None) never."""
+        if self._carve is not None:
+            ledger, record = self._carve
+            self._carve = None
+            ledger.settle(record, commit_index, refuted_nodes)
+
     def _repair_refuted(self, evaluation: Evaluation, plan: Plan,
-                        result, missing: int, pipeline
+                        refuted_nodes, missing: int, pipeline
                         ) -> Optional[Exception]:
         """Refute-repair (core/wavepipe.py): the applier refuted rows of
         this eval's block against newer state.  Instead of re-running
@@ -354,8 +429,10 @@ class GenericScheduler(Scheduler):
         nothing double-commits).  Repair evals that refute AGAIN fall
         back to the normal retry loop (the TRIGGER_PLAN_REFUTE guard in
         finalize_batched) — the repair never recurses."""
-        pipeline.note_refuted(result.refuted_nodes)
-        tg_name = plan.alloc_blocks[0].template.task_group
+        if pipeline is not None:
+            pipeline.note_refuted(refuted_nodes)
+        tg_name = (plan.alloc_blocks[0].template.task_group
+                   if plan.alloc_blocks else next(iter(self.queued_allocs)))
         self.queued_allocs[tg_name] = (
             self.queued_allocs.get(tg_name, 0) + missing)
         follow = Evaluation(
@@ -822,9 +899,12 @@ class GenericScheduler(Scheduler):
         group requests devices (reference: scheduler/device.go
         AllocateDevice called from BinPackIterator).
 
-        The kernel's [G, N] device mask was computed against the snapshot,
-        so a node can run out of instances mid-plan (several placements
-        landing on it).  Failed assignments are re-placed through the
+        The scan accounts instances as a capacity dimension, so one
+        request on single-group nodes is assigned in the first round.  A
+        group with several requests, or a node with several groups, is
+        held to a mask computed against the snapshot and to the
+        instances of the node TOGETHER, so it can still run out of a
+        group mid-plan.  Failed assignments are re-placed through the
         engine with the in-plan usage overlay visible (up to 3 rounds —
         the host-side twin of the kernel's sequential-capacity scan);
         still-failing placements become normal placement failures with the
@@ -835,6 +915,14 @@ class GenericScheduler(Scheduler):
         tg_has_dev = {tg.name: bool(tg_device_requests(tg)) for tg in tgs}
         if not any(tg_has_dev.values()):
             return {}
+        # a device-asking eval on the solo path, under the admission rule
+        # that refused it (prepare_batch) or, where none did, "unbatched":
+        # no mate to share a wave with, or a retry after a refute
+        if self._device_solo_rule is not None:
+            from nomad_tpu.core.telemetry import REGISTRY
+            REGISTRY.inc("nomad.device.evals_solo",
+                         rule=self._device_solo_rule or "unbatched")
+            self._device_solo_rule = None       # once an eval
         dev_assign: Dict[int, list] = {}
         stopped_ids = {a.id for a in stopped}
         dev_index = InUseIndex()
@@ -898,16 +986,28 @@ class GenericScheduler(Scheduler):
                           places: Optional[List[RPlace]], bd,
                           evaluation: Evaluation,
                           results: ReconcileResults,
-                          block=None, net_idx=None) -> None:
+                          block=None, net_idx=None,
+                          device_ledger=None) -> None:
         """Materialize allocations straight from a BulkDecisions array —
         the per-placement twin loop of `_compute_placements`, with every
         per-alloc object cost stripped: template-dict clones, batched ids,
         a shared per-round AllocMetric, and a shared resources object when
         the group asks for no ports.  With `block` (compact path) names
-        come straight from the index list — no RPlace objects exist."""
+        come straight from the index list — no RPlace objects exist.
+
+        A device-asking group reaches this only off a wave
+        (prepare_batch admitted it: ONE request; the solo path's scan
+        never returns BulkDecisions for one).  Its rows stay columnar
+        whatever their count, and their instance ids are carved per node
+        against `device_ledger` (scheduler/device.py CarveLedger, the
+        worker's: shared by the wave's mates and the cycle's waves)."""
+        from .device import CarveLedger, carve_block, tg_device_requests
         tg = block.tg if block is not None else places[0].tg
         ask = tg.combined_resources()
         has_net = bool(ask.networks)
+        dev_reqs = tg_device_requests(tg)
+        dev_task, dev_req = dev_reqs[0] if dev_reqs else ("", None)
+        self._carve_short = (0, ())
         tmpl = Allocation(
             namespace=job.namespace,
             eval_id=evaluation.id,
@@ -940,14 +1040,22 @@ class GenericScheduler(Scheduler):
             net_idx = {}
         last_nid = None
         last_list = None
-        if block is not None:
+        # fresh rows named from an index list: a PlaceBlock's, or (device
+        # groups, whose small evals must not fall to per-alloc objects)
+        # the fresh PlaceRequests prepare_batch admitted
+        rows_fresh = block is not None or (
+            dev_req is not None
+            and all(p.previous_alloc is None and not p.canary
+                    for p in places))
+        if rows_fresh:
             prefix = f"{job.id}.{tg.name}["     # matches reconcile._name
-            indexes = block.indexes
+            indexes = (block.indexes if block is not None
+                       else [p.index for p in places])
 
         net_labels = (self._net_columnar_labels(ask)
                       if has_net and PORT_BATCHED and block is not None
                       else None)
-        if (block is not None and not bd.evictions
+        if (rows_fresh and not bd.evictions
                 and results.deployment is None
                 and (not has_net or net_labels is not None)):
             # hottest shape (the bench/batch pattern): fresh block, no
@@ -975,13 +1083,47 @@ class GenericScheduler(Scheduler):
                 ports_arr = self._carve_ports_batch(
                     picks_ok, node_ids, len(net_labels), net_idx,
                     victim_ids)
+            dev_ids = dev_groups = None
+            n_short = 0
+            if dev_req is not None and n_ok:
+                # (no ports to carve: prepare_batch keeps a group that
+                # asks for both off the wave)
+                ledger = (device_ledger if device_ledger is not None
+                          else CarveLedger())
+                record = ledger.open(getattr(self.state, "index", 0))
+                self._carve = (ledger, record)
+                stage = getattr(self.planner, "stage", None)
+                with (stage("device_carve") if stage
+                      else contextlib.nullcontext()):
+                    dev_ids, dev_groups, short = carve_block(
+                        self.state, ledger, record, dev_req, picks_ok,
+                        node_ids)
+                from nomad_tpu.core.telemetry import REGISTRY
+                REGISTRY.inc("nomad.device.evals_batched")
+                if short:
+                    # a foreign write took these nodes' instances after
+                    # the kernel looked: their rows leave the block whole
+                    # (never a partial claim) and re-enter through the
+                    # repair eval (finalize_batched)
+                    REGISTRY.inc("nomad.device.carve_short", len(short))
+                    gone = np.isin(picks, np.asarray(short, picks.dtype))
+                    n_short = int(gone.sum())
+                    self._carve_short = (
+                        n_short, tuple(node_ids[r] for r in short))
+                    keep_ok = ~(gone[ok_mask] if n_fail else gone)
+                    dev_ids = dev_ids[keep_ok]
+                    picks_ok = picks_ok[keep_ok]
+                    ok_mask = ok_mask & ~gone
+                    n_ok -= n_short
+                REGISTRY.inc("nomad.device.instances_carved",
+                             int(dev_ids.size))
             if not has_net or n_ok == 0 or ports_arr is not None:
                 if n_fail:
                     # aggregate failure accounting: one stored metric
                     # (the first failing round's), coalesced + queued
                     # counters match the per-pick loop's totals
                     tg_name = tg.name
-                    first_fail = int(np.argmin(ok_mask))
+                    first_fail = int(np.argmax(picks < 0))
                     m = metrics[min(first_fail // rs, len(metrics) - 1)]
                     self._record_failure_shared(tg_name, m)
                     if n_fail > 1:
@@ -991,7 +1133,7 @@ class GenericScheduler(Scheduler):
                             self.queued_allocs.get(tg_name, 0) + n_fail - 1
                 if n_ok == 0:
                     return
-                if n_fail:
+                if n_fail or n_short:
                     import itertools
                     sel = ok_mask.tolist()
                     ids_ok = list(itertools.compress(ids, sel))
@@ -1020,12 +1162,23 @@ class GenericScheduler(Scheduler):
                     port_labels=(list(net_labels)
                                  if ports_arr is not None else []),
                     ports=ports_arr,
+                    device_task=dev_task if dev_ids is not None else "",
+                    device_groups=([dev_groups[int(r)] for r in uniq]
+                                   if dev_ids is not None else []),
+                    device_ids=dev_ids,
                 ))
                 return
             # a node's dynamic pool was short of the wave's demand:
             # sequential per-alloc oracle below (runner-up redirects,
             # per-port exhaustion dimensions)
 
+        if dev_req is not None:
+            # the loop below assigns no instance: rather nack the eval
+            # than commit device-asking allocations that hold none
+            raise RuntimeError(
+                f"{job.id}.{tg.name}: a device-asking group left the "
+                "columnar path (evictions, a deployment, or rows that "
+                "are not fresh)")
         picks_l = bd.picks.tolist()
         placed_n = 0          # decision-record capture, noted ONCE below
         victims_sample: List = []

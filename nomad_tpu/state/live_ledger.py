@@ -1,8 +1,10 @@
 """Live-allocation ledger — the state store's scheduling-quality sums.
 
-Per node, over NON-TERMINAL allocations: count, cpu, memory and disk;
+Per node, over NON-TERMINAL allocations: count, then one sum a capacity
+dimension (structs.RES_NAMES: cpu, memory, disk, device instances held);
 from them the quality gauges (`StateStore.quality_summary`): nodes in
-use, allocations per zone, mean bin-pack fill per dimension.
+use, allocations per zone, mean bin-pack fill of cpu, memory and disk,
+device instances in use.
 
 Columnar: a node id maps to a row once and keeps it; the sums, the
 node's capacity and zone, and each row's STANDING contribution to the
@@ -29,6 +31,8 @@ from typing import Dict, List
 
 import numpy as np
 
+from nomad_tpu.structs import RES_DIMS
+
 
 class LiveLedger:
 
@@ -37,7 +41,8 @@ class LiveLedger:
 
     def reset(self) -> None:
         self._row: Dict[str, int] = {}              # node id -> row
-        self._sum = np.zeros((0, 4), np.int64)      # count, cpu, mem, disk
+        # count, then usage by capacity dimension
+        self._sum = np.zeros((0, 1 + RES_DIMS), np.int64)
         # what a fill is computed from, as of the node's last write:
         # resources - reserved, and the datacenter's id (-1: the store
         # does not hold the node)
@@ -52,8 +57,8 @@ class LiveLedger:
         self._held_zone = np.full(0, -1, np.int32)
         self._dirty = np.zeros(0, bool)
         # not yet folded into _sum: per-alloc deltas, node id -> [count,
-        # cpu, mem, disk], and whole blocks (with the nodes they name,
-        # so the list can be kept to the order of the rows)
+        # cpu, mem, disk, devices], and whole blocks (with the nodes they
+        # name, so the list can be kept to the order of the rows)
         self._pending: Dict[str, List[int]] = {}
         self._pending_blocks: List = []
         self._pending_nodes = 0
@@ -121,17 +126,16 @@ class LiveLedger:
 
     # ------------------------------------------------------- alloc writes
 
-    def add(self, node_id: str, d: int, cpu: int, mem: int,
-            disk: int) -> None:
-        """One allocation's delta.  Int adds only: the fold and the
-        aggregate math wait for the flush."""
+    def add(self, node_id: str, d: int, usage) -> None:
+        """One allocation's delta: `d` is +1 or -1, `usage` its
+        `Allocation.usage()`.  Int adds only: the fold and the aggregate
+        math wait for the flush."""
         row = self._pending.get(node_id)
         if row is None:
-            self._pending[node_id] = row = [0, 0, 0, 0]
+            self._pending[node_id] = row = [0] * (1 + RES_DIMS)
         row[0] += d
-        row[1] += cpu
-        row[2] += mem
-        row[3] += disk
+        for k, v in enumerate(usage, 1):
+            row[k] += d * v
 
     def add_block(self, block) -> None:
         """A columnar AllocBlock: `node_counts()[i]` allocations of its
@@ -192,7 +196,7 @@ class LiveLedger:
         known = zone >= 0           # else: counted in nodes-in-use only
         avail = self._avail[rows]
         fill = np.zeros((len(rows), 3), np.float64)
-        np.divide(sums[:, 1:], avail, out=fill,
+        np.divide(sums[:, 1:4], avail, out=fill,
                   where=known[:, None] & (avail > 0))
         np.minimum(fill, 1.0, out=fill)
         self._fill[rows] = fill
@@ -217,4 +221,5 @@ class LiveLedger:
             "fill_cpu": fills[0],
             "fill_memory": fills[1],
             "fill_disk": fills[2],
+            "devices_in_use": int(self._sum[:, RES_DIMS].sum()),
         }

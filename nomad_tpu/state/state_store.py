@@ -1101,14 +1101,11 @@ class StateStore:
             # terminal transitions and node moves in one delta pair
             if prev is not None and prev.node_id \
                     and not prev.terminal_status():
-                r = prev.resources
-                live_add(prev.node_id, -1, -r.cpu, -r.memory_mb,
-                         -r.disk_mb)
+                live_add(prev.node_id, -1, prev.usage())
             if a.terminal_status():
                 dead.add(aid)
             elif nid:
-                r = a.resources
-                live_add(nid, 1, r.cpu, r.memory_mb, r.disk_mb)
+                live_add(nid, 1, a.usage())
             if prev is not None and prev.node_id and prev.node_id != nid:
                 pnid = prev.node_id
                 if pnid not in fresh_node:
@@ -2022,9 +2019,7 @@ class StateStore:
                 if a.node_id:
                     self._allocs_by_node.setdefault(a.node_id, {})[a.id] = a
                     if not a.terminal_status():
-                        r = a.resources
-                        self._live.add(a.node_id, 1, r.cpu,
-                                       r.memory_mb, r.disk_mb)
+                        self._live.add(a.node_id, 1, a.usage())
                 self._allocs_by_job.setdefault(
                     (a.namespace, a.job_id), {})[a.id] = a
             self._evals_by_job = {}

@@ -29,7 +29,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from .structs import Allocation, AllocMetric
+from .structs import AllocatedDeviceResource, Allocation, AllocMetric
 
 
 @dataclass
@@ -58,6 +58,17 @@ class AllocBlock:
     # networked hot path either.
     port_labels: List[str] = field(default_factory=list)
     ports: Optional[np.ndarray] = None
+    # COLUMNAR device assignment (ISSUE 30), None for a group that asks
+    # for no device: device_ids[i] are the instance ids of row i, a
+    # [count, k] unicode array with k the request's count, held by task
+    # `device_task`; device_groups[j] is the (vendor, type, name) of the
+    # device group on node_table[j] they were carved from.  The batched
+    # carve in scheduler/generic.py fills these, rows materialize with
+    # their own allocated_devices, and the applier audits them per node
+    # off the array (plan_apply._eval_blocks).
+    device_task: str = ""
+    device_groups: List[tuple] = field(default_factory=list)
+    device_ids: Optional[np.ndarray] = None
     create_index: int = 0
     modify_index: int = 0
 
@@ -78,8 +89,12 @@ class AllocBlock:
         return self.node_table
 
     def resources_tuple(self):
+        """What ONE row counts against its node, by capacity dimension
+        (structs.RES_NAMES)."""
         r = self.template.resources
-        return (r.cpu, r.memory_mb, r.disk_mb)
+        return (r.cpu, r.memory_mb, r.disk_mb,
+                self.device_ids.shape[1] if self.device_ids is not None
+                else 0)
 
     def node_counts(self) -> np.ndarray:
         """allocs per node_table row (for vectorized usage scatters)."""
@@ -108,6 +123,24 @@ class AllocBlock:
         for nid, c in zip(self.node_table, counts.tolist()):
             if c:
                 out[nid] = grouped[pos:pos + c].ravel().tolist()
+                pos += c
+        return out
+
+    def devices_by_node(self) -> Dict[str, tuple]:
+        """{node_id: (group, [instance id, ...])} claimed by this block's
+        rows, the applier's per-node device audit input: one argsort
+        over the picks, no per-alloc objects."""
+        if self.device_ids is None or not self.device_ids.size:
+            return {}
+        order = np.argsort(self.picks, kind="stable")
+        grouped = self.device_ids[order]
+        out: Dict[str, tuple] = {}
+        pos = 0
+        for nid, group, c in zip(self.node_table, self.device_groups,
+                                 self.node_counts().tolist()):
+            if c:
+                out[nid] = (tuple(group),
+                            grouped[pos:pos + c].ravel().tolist())
                 pos += c
         return out
 
@@ -145,6 +178,11 @@ class AllocBlock:
             round_size=self.round_size,
             port_labels=list(self.port_labels),
             ports=self.ports[keep] if self.ports is not None else None,
+            device_task=self.device_task,
+            device_groups=[self.device_groups[int(r)] for r in uniq]
+            if self.device_ids is not None else [],
+            device_ids=(self.device_ids[keep]
+                        if self.device_ids is not None else None),
         )
 
     def index_of(self, alloc_id: str) -> Optional[int]:
@@ -173,6 +211,10 @@ class AllocBlock:
             plabels = self.port_labels
             prows = (self.ports.tolist()
                      if self.ports is not None and plabels else None)
+            drows = (self.device_ids.tolist()
+                     if self.device_ids is not None else None)
+            dgroups = self.device_groups
+            dtask = self.device_task
             rows = []
             alloc_new = Allocation.__new__
             n_m = len(metrics) - 1
@@ -190,6 +232,11 @@ class AllocBlock:
                 d["modify_index"] = mi
                 if prows is not None:
                     d["allocated_ports"] = dict(zip(plabels, prows[i]))
+                if drows is not None:
+                    vendor, dtype, dname = dgroups[picks[i]]
+                    d["allocated_devices"] = [AllocatedDeviceResource(
+                        task=dtask, vendor=vendor, type=dtype, name=dname,
+                        device_ids=drows[i])]
                 rows.append(a)
             self._rows = rows
         return self._rows

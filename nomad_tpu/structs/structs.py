@@ -225,6 +225,15 @@ class Resources:
         )
 
 
+# The capacity dimensions a node has and an allocation uses, in the
+# order of the packed tensors' last axis (pack/packer.py `cap` / `used`,
+# the kernels' `req`): the ONE place their number is written.  Device
+# instances are a count: which instance an allocation holds is host
+# state (scheduler/device.py), how many are free is a capacity.
+RES_NAMES = ("cpu", "memory", "disk", "devices")
+RES_DIMS = len(RES_NAMES)
+
+
 @dataclass
 class RequestedDevice:
     name: str = ""            # e.g. "gpu", "nvidia/gpu", "nvidia/gpu/1080ti"
@@ -354,6 +363,15 @@ class Node:
         return (self.status == NODE_STATUS_READY
                 and self.drain is None
                 and self.scheduling_eligibility == NODE_SCHED_ELIGIBLE)
+
+    def capacity(self) -> Tuple[int, ...]:
+        """What the node offers allocations, one int per capacity
+        dimension (RES_NAMES): resources net of reserved, and the
+        instances of its device groups together."""
+        res, rsv = self.resources, self.reserved
+        return (res.cpu - rsv.cpu, res.memory_mb - rsv.memory_mb,
+                res.disk_mb - rsv.disk_mb,
+                sum(len(d.instance_ids) for d in res.devices))
 
     def copy(self) -> "Node":
         import copy as _copy
@@ -743,6 +761,15 @@ class Allocation:
         if self.desired_status in (ALLOC_DESIRED_STOP, ALLOC_DESIRED_EVICT):
             return True
         return self.client_terminal_status()
+
+    def usage(self) -> Tuple[int, ...]:
+        """What this allocation counts against its node, one int per
+        capacity dimension (RES_NAMES): cpu, memory, disk, and the
+        device instances it holds."""
+        r = self.resources
+        devs = self.allocated_devices
+        return (r.cpu, r.memory_mb, r.disk_mb,
+                sum(len(d.device_ids) for d in devs) if devs else 0)
 
     def client_terminal_status(self) -> bool:
         return self.client_status in (
@@ -1410,5 +1437,5 @@ __all__ = [
     _n for _n, _v in list(globals().items())
     if not _n.startswith("_")
     and (getattr(_v, "__module__", None) == __name__
-         or (_n.isupper() and isinstance(_v, (str, int, float))))
+         or (_n.isupper() and isinstance(_v, (str, int, float, tuple))))
 ]

@@ -26,6 +26,7 @@ from nomad_tpu.structs import (
     Allocation,
     Plan,
     PlanResult,
+    RES_DIMS,
     Resources,
 )
 
@@ -97,9 +98,9 @@ class Reference:
     def __init__(self, nodes, packed):
         self.nodes = {n.id: n for n in nodes}   # the store's node table
         self.fence = {}                         # nid -> (seq, origin)
-        self.live = {}                          # nid -> [count, cpu, mem, disk]
+        self.live = {}              # nid -> [count, usage by dimension]
         self.blocks = {}                        # nid -> [block id, ...]
-        self.used = {n.id: [0, 0, 0] for n in packed}   # the packer's rows
+        self.used = {n.id: [0] * RES_DIMS for n in packed}  # packer's rows
         self.counted = {}                       # alloc id -> (nid, res)
         self.units = {}                         # block id -> block
         self.used_bumps = 0
@@ -110,9 +111,9 @@ class Reference:
         self.fence[node.id] = (seq, None)
 
     def _live(self, nid, d, res):
-        row = self.live.setdefault(nid, [0, 0, 0, 0])
+        row = self.live.setdefault(nid, [0] * (1 + RES_DIMS))
         row[0] += d
-        for k in range(3):
+        for k in range(RES_DIMS):
             row[1 + k] += d * res[k]
 
     def block_committed(self, block, seq, origin):
@@ -124,7 +125,7 @@ class Reference:
             self.blocks.setdefault(nid, []).append(block.id)
             self._live(nid, c, res)
             if c and nid in self.used:
-                for k in range(3):
+                for k in range(RES_DIMS):
                     self.used[nid][k] += c * res[k]
                 touched = True
         self.used_bumps += touched
@@ -133,21 +134,20 @@ class Reference:
         """Per-alloc rows: fresh ones, or successors of counted ones."""
         touched = False
         for a in allocs:
-            res = (a.resources.cpu, a.resources.memory_mb,
-                   a.resources.disk_mb)
+            res = a.usage()
             self.fence[a.node_id] = (seq, origin)
             old = self.counted.pop(a.id, None)
             if old is not None:
                 self._live(old[0], -1, old[1])
                 if old[0] in self.used:
-                    for k in range(3):
+                    for k in range(RES_DIMS):
                         self.used[old[0]][k] -= old[1][k]
                     touched = True
             if not a.terminal_status():
                 self.counted[a.id] = (a.node_id, res)
                 self._live(a.node_id, 1, res)
                 if a.node_id in self.used:
-                    for k in range(3):
+                    for k in range(RES_DIMS):
                         self.used[a.node_id][k] += res[k]
                     touched = True
         self.used_bumps += touched
@@ -190,6 +190,7 @@ class Reference:
             "fill_cpu": fills[0] / n if n else 0.0,
             "fill_memory": fills[1] / n if n else 0.0,
             "fill_disk": fills[2] / n if n else 0.0,
+            "devices_in_use": sum(r[RES_DIMS] for r in in_use.values()),
         }
 
 
@@ -351,15 +352,15 @@ def test_commit_reads_as_the_per_node_walk(shape, seed):
     state.upsert_node(again)
     ref.node_written(again, state.placement_seq())
     t = packer.update(state.snapshot())
-    live = {nid: [0, 0, 0] for nid in ref.nodes}
+    live = {nid: [0] * RES_DIMS for nid in ref.nodes}
     for nid, res in ref.counted.values():
-        for k in range(3):
+        for k in range(RES_DIMS):
             live[nid][k] += res[k]
     for block in ref.units.values():
         res = block.resources_tuple()
         for nid, c in zip(block.node_table, block.node_counts().tolist()):
             if nid in live:
-                for k in range(3):
+                for k in range(RES_DIMS):
                     live[nid][k] += c * res[k]
     assert set(t.id_to_row) == set(live)
     for nid, used in live.items():
