@@ -165,11 +165,11 @@ def run_leg(sizes: dict, seed: int, mesh, platform: str) -> dict:
             and poll until every eval settles; returns final statuses."""
             t0 = time.perf_counter()
             server.stop_scheduling()
-            structs_mod._ID_POOL[:] = seeded_ids(random.Random(id_seed),
+            structs_mod._id_pool[:] = seeded_ids(random.Random(id_seed),
                                                  16 * len(round_jobs))
             eval_ids = [api.jobs.register(codec.encode(j))["EvalID"]
                         for j in round_jobs]
-            structs_mod._ID_POOL.clear()      # random ids from here on
+            structs_mod._id_pool.clear()      # random ids from here on
             server.start_scheduling()
             check(all(eval_ids), "every registration returned an eval id")
             phase_s["register"] = (phase_s.get("register", 0.0)

@@ -29,7 +29,7 @@ import bisect
 import threading
 from collections import deque
 from contextlib import contextmanager
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 from nomad_tpu.chaos.clock import Clock, SystemClock
 
@@ -41,6 +41,13 @@ DEFAULT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
 _QUANTILES = (("p50", 0.50), ("p95", 0.95), ("p99", 0.99))
 
 LabelKey = Tuple[str, Tuple[Tuple[str, str], ...]]
+
+
+def _module_counters() -> Dict[LabelKey, float]:
+    """Counters kept as plain module ints by code that may not import
+    core/ (structs/ sits below it): read here, at scrape time."""
+    from nomad_tpu.structs.structs import ID_STATS
+    return {(f"nomad.ids.{k}", ()): v for k, v in ID_STATS.items()}
 
 
 def _key(name: str, labels: Dict[str, str]) -> LabelKey:
@@ -152,8 +159,13 @@ class MetricsRegistry:
     record call."""
 
     def __init__(self, clock: Optional[Clock] = None,
-                 window_s: float = 60.0, window_subs: int = 6) -> None:
+                 window_s: float = 60.0, window_subs: int = 6,
+                 module_counters: Callable[[], Dict[LabelKey, float]] = dict
+                 ) -> None:
         self._lock = threading.Lock()
+        # process-wide counters the scrape adds to this registry's own
+        # (REGISTRY's alone: a private registry shows what it recorded)
+        self._module_counters = module_counters
         self.clock: Clock = clock if clock is not None else SystemClock()
         self._counters: Dict[LabelKey, float] = {}
         self._gauges: Dict[LabelKey, float] = {}
@@ -283,6 +295,12 @@ class MetricsRegistry:
                     n += 1
         return n
 
+    def _scraped_counters_locked(self) -> List[Tuple[LabelKey, float]]:
+        """What snapshot() and prometheus() show; since process start
+        where a module keeps the count (reset() does not reach those)."""
+        return sorted({**self._module_counters(),
+                       **self._counters}.items())
+
     @staticmethod
     def _flat(k: LabelKey) -> str:
         name, labels = k
@@ -298,7 +316,7 @@ class MetricsRegistry:
         with self._lock:
             return {
                 "counters": {self._flat(k): v
-                             for k, v in sorted(self._counters.items())},
+                             for k, v in self._scraped_counters_locked()},
                 "gauges": {self._flat(k): v
                            for k, v in sorted(self._gauges.items())},
                 "histograms": {self._flat(k): h.summary()
@@ -336,7 +354,7 @@ class MetricsRegistry:
         `_sum`/`_count`, and `_p50/_p95/_p99` estimate gauges."""
         now = self.clock.monotonic()
         with self._lock:
-            counters = sorted(self._counters.items())
+            counters = self._scraped_counters_locked()
             gauges = sorted(self._gauges.items())
             hists = sorted((k, (h.buckets, list(h.counts), h.sum, h.count,
                                 {q: h.quantile(val)
@@ -611,7 +629,7 @@ class Tracer:
 
 # -------------------------------------------------------------- globals
 
-REGISTRY = MetricsRegistry()
+REGISTRY = MetricsRegistry(module_counters=_module_counters)
 TRACER = Tracer()
 
 

@@ -17,6 +17,7 @@ Design departures from the reference (deliberate, TPU-first):
 from __future__ import annotations
 
 import os
+import threading
 import uuid
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional, Tuple
@@ -115,17 +116,9 @@ MIN_DYNAMIC_PORT = 20000
 MAX_DYNAMIC_PORT = 32000
 
 
-def new_ids(count: int) -> List[str]:
-    """Batch of UUIDv4-shaped random ids: one urandom syscall + one hex
-    conversion + vectorized dash insertion for the whole batch (a
-    100k-alloc plan mints 100k ids; the per-id f-string assembly this
-    replaces was ~0.15s per 100k wave)."""
-    if count <= 0:
-        return []
-    if count < 32:
-        h = os.urandom(16 * count).hex()
-        return [f"{s[:8]}-{s[8:12]}-4{s[13:16]}-{s[16:20]}-{s[20:]}"
-                for s in (h[i:i + 32] for i in range(0, 32 * count, 32))]
+def _mint_ids(count: int) -> List[str]:
+    """`count` UUIDv4-shaped random ids in ONE run: one urandom syscall,
+    one hex conversion, vectorized dash insertion, one decode."""
     import numpy as np
     v = np.frombuffer(os.urandom(16 * count).hex().encode(),
                       np.uint8).reshape(count, 32)
@@ -144,21 +137,70 @@ def new_ids(count: int) -> List[str]:
     return [big[i:i + 36] for i in range(0, 36 * count, 36)]
 
 
-_ID_POOL: List[str] = []
+# Every id of the process comes off ONE pool, refilled ID_POOL_REFILL at a
+# time by whichever caller finds it short (no thread mints ahead, so a
+# drain pays for every id it uses).  One run of `_mint_ids` costs ~0.4 us
+# an id at any size from 8,192 up; as sixty-four cold runs of 260 a wave
+# (one an eval, since the wave pipeline) it was 0.75-0.9 ms a run on the
+# worker's thread, each handing the interpreter lock to the applier inside
+# os.urandom.  The size: 8,192 to 65,536 read the same mean on the chip's
+# host and the refill's step, inside one eval's materialize, grows with it
+# (7 to 32 ms); this is the smallest power of two that holds a full wave's
+# ids (64 evals x 260), so no wave refills twice (PERF.md section 6, PR
+# 31).  A count over ID_DIRECT_MIN is one amortised run already and mints
+# past the pool (a system eval's 49,000).
+ID_POOL_REFILL = 32768
+ID_DIRECT_MIN = ID_POOL_REFILL // 8
+_id_pool: List[str] = []
+_id_lock = threading.Lock()    # take-and-delete is two steps: one holder
+# what /v1/metrics shows as nomad.ids.* (core/telemetry.py reads these:
+# structs/ imports nothing of core/); written under `_id_lock`
+ID_STATS = {"pool_refills": 0, "pool_served": 0, "minted_direct": 0}
+
+
+def _id_pool_after_fork() -> None:
+    """A forked child shares no id with its parent, and no held lock."""
+    global _id_lock
+    _id_lock = threading.Lock()
+    _id_pool.clear()
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_id_pool_after_fork)
+
+
+def _refill_id_pool() -> None:
+    """The caller holds `_id_lock`."""
+    _id_pool.extend(_mint_ids(ID_POOL_REFILL))
+    ID_STATS["pool_refills"] += 1
+
+
+def new_ids(count: int) -> List[str]:
+    """`count` UUIDv4-shaped random ids, never handed out twice."""
+    if count <= 0:
+        return []
+    if count > ID_DIRECT_MIN:
+        ids = _mint_ids(count)
+        with _id_lock:
+            ID_STATS["minted_direct"] += count
+        return ids
+    with _id_lock:
+        if len(_id_pool) < count:
+            _refill_id_pool()
+        ids = _id_pool[-count:]
+        del _id_pool[-count:]
+        ID_STATS["pool_served"] += count
+    return ids
 
 
 def new_id() -> str:
-    """Single id from a pre-minted pool (one urandom syscall per 256
-    ids): a wave mints ~4 singles per eval — plan ids, block ids,
-    delivery tokens — and per-call urandom+hex was ~20µs each.  Pop is
-    atomic under the GIL, so concurrent workers never share an id; a
-    torn pool refill at worst wastes entropy, never duplicates."""
-    pool = _ID_POOL
-    while True:
-        try:
-            return pool.pop()     # atomic under the GIL
-        except IndexError:        # empty (or raced empty): refill+retry
-            pool.extend(new_ids(256))
+    """One id off the same pool (plan ids, block ids, delivery tokens,
+    the solo path's per-alloc ids, every default factory below)."""
+    with _id_lock:
+        if not _id_pool:
+            _refill_id_pool()
+        ID_STATS["pool_served"] += 1
+        return _id_pool.pop()
 
 
 # ---------------------------------------------------------------------------
