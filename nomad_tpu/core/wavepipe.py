@@ -63,6 +63,10 @@ from nomad_tpu.core.telemetry import REGISTRY
 #   prepare      worker: wait_for_index, snapshot, scheduler + reconcile
 #                per eval (Worker._start_batch up to the dispatch)
 #   dispatch     worker: host input build + the kernel's async launch
+#   spread_lower worker: the host lowering of a wave's spread items
+#                (ops/engine.py _lower_wave_spreads: landscapes by node
+#                table version, the jobs' expected and existing counts);
+#                lies INSIDE that wave's dispatch
 #   device       NOT a thread's wall: from the dispatch's return to the
 #                collect's wake-up, so it spans the predecessor's host
 #                phase (recorded through `record`, never emitted)
@@ -87,12 +91,14 @@ from nomad_tpu.core.telemetry import REGISTRY
 #   store_upsert applier: the upsert alone (inside commit)
 # Worker stages other than "pass" do not nest in one another, but for
 # device_carve (and the solo device path's redo), each inside a
-# materialize: the unnamed part of a pass is its wall minus their UNION
-# (benchmark/host_spans.py View.named), which a nested span leaves as it
-# was; a per-stage sum must leave device_carve out or count it twice.
-STAGES = ("pass", "prepare", "dispatch", "device", "device_wait", "d2h",
-          "solo_place", "system_place", "materialize", "device_carve",
-          "plan_wait", "eval_update", "ack", "commit", "store_upsert")
+# materialize, and spread_lower, inside a dispatch: the unnamed part of a
+# pass is its wall minus their UNION (benchmark/host_spans.py
+# View.named), which a nested span leaves as it was; a per-stage sum must
+# leave device_carve and spread_lower out or count them twice.
+STAGES = ("pass", "prepare", "dispatch", "spread_lower", "device",
+          "device_wait", "d2h", "solo_place", "system_place", "materialize",
+          "device_carve", "plan_wait", "eval_update", "ack", "commit",
+          "store_upsert")
 
 _SERIES = {s: f"nomad.wavepipe.{s}_s" for s in STAGES}
 
@@ -367,6 +373,8 @@ class WavePipeline:
                                      "chained": used0_dev is not None,
                                      "masked_nodes": len(mask or ())}
         if isinstance(pending, dict):
+            # real rounds of the launch's schedule, padding left out
+            fields["rounds"] = int(pending.get("rounds", 0))
             fields["resident"] = bool(pending.get("chained"))
             for key in ("collective_bytes", "shard_h2d_bytes"):
                 if pending.get(key):

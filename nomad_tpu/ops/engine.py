@@ -21,7 +21,14 @@ import numpy as np
 
 from nomad_tpu.pack.interner import UNSET
 from nomad_tpu.pack.packer import ClusterPacker, JobContext, NodeTensors, TGTensors
-from nomad_tpu.pack.spread import SpreadTensors, lower_spreads
+from nomad_tpu.pack.spread import (
+    SpreadTensors,
+    job_spreads,
+    lower_spreads,
+    spread_job_rows,
+    spread_landscape,
+    spread_signature,
+)
 from nomad_tpu.structs import (
     AllocMetric,
     Job,
@@ -394,6 +401,9 @@ class PlacementEngine:
         # device requests' static masks (device_static_mask), by node
         # table version and request signature; a handful, oldest out
         self._device_mask_cache: Dict[tuple, np.ndarray] = {}
+        # spread stanzas' value landscapes (_spread_landscapes), by node
+        # table version and spread signature; a handful, oldest out
+        self._spread_cache: Dict[tuple, tuple] = {}
         self._single_group: Optional[Tuple[int, bool]] = None
         self._dc_cache: Optional[Tuple[int, Dict[str, int]]] = None
         # host->device sync meter (ops/executor.py installs it): called
@@ -800,6 +810,95 @@ class PlacementEngine:
                     next(iter(self._device_mask_cache)))
             self._device_mask_cache[key] = mask
         return mask
+
+    def _spread_landscapes(self, t: NodeTensors, sig: tuple,
+                           spreads) -> tuple:
+        """The STATIC half of a job's spread lowering
+        (pack/spread.py spread_landscape): per stanza, each node's value
+        index and the number of tracked values.  A function of the node
+        table and the stanzas' (attribute, target values) alone, so it is
+        walked once per (signature, node-table version) and shared by
+        every eval that spreads alike: `device_static_mask`'s pattern.
+        `sig` is `spread_signature(spreads)`."""
+        key = (t.version, sig)
+        with self.packer.lock:
+            hit = self._spread_cache.pop(key, None)
+            if hit is not None:
+                self._spread_cache[key] = hit
+        if hit is not None:
+            _registry().inc("nomad.engine.spread_landscapes_reused")
+            return hit
+        out = tuple(spread_landscape(self.packer, t, sp) for sp in spreads)
+        for nodeval, _ in out:
+            nodeval.setflags(write=False)
+        _registry().inc("nomad.engine.spread_landscapes_built")
+        with self.packer.lock:
+            if len(self._spread_cache) >= 8:
+                self._spread_cache.pop(next(iter(self._spread_cache)))
+            self._spread_cache[key] = out
+        return out
+
+    def _lower_wave_spreads(self, t: NodeTensors, npad: int, snapshot,
+                            items: Sequence[BatchItem], g_pad: int):
+        """The spread fields of MultiEvalInputs for a wave, or None where
+        no item carries a stanza.  One [S, N] landscape a distinct
+        signature goes up once a node-table version (`_dev_const`, as the
+        base masks do); what is the job's own (weight, expected and
+        existing counts) is a few floats an item.  S and K are padded to
+        the wave's largest on the power-of-two ladder; row 0 of the
+        landscapes is inert, for the items with no stanza."""
+        by_item = [job_spreads(it.job) for it in items]
+        if not any(by_item):
+            return None
+        if self.mesh is not None:
+            raise ValueError("the sharded wave kernels carry no spread "
+                             "state: a spread eval takes the solo path")
+        with (self.timers.time("spread_lower") if self.timers is not None
+              else contextlib.nullcontext()):
+            sigs = [spread_signature(sp) for sp in by_item]
+            lands = [self._spread_landscapes(t, sig, sp) if sp else ()
+                     for sig, sp in zip(sigs, by_item)]
+            s_pad = _pad_pow2(max(len(sp) for sp in by_item), lo=1)
+            k_pad = _pad_pow2(max(k for ld in lands for _, k in ld), lo=1)
+            g_spread = np.zeros(g_pad, np.int32)
+            weight = np.zeros((g_pad, s_pad), np.float32)
+            expected = np.zeros((g_pad, s_pad, k_pad), np.float32)
+            counts0 = np.zeros((g_pad, s_pad, k_pad), np.float32)
+            sig_rows: Dict[tuple, int] = {}
+            rows = [self._dev_const(
+                ("spreadland0", npad, s_pad),
+                lambda: np.full((s_pad, npad), -1, np.int32))]
+            for gi, (it, sp, sig, ld) in enumerate(
+                    zip(items, by_item, sigs, lands)):
+                if not sp:
+                    continue
+                ui = sig_rows.get(sig)
+                if ui is None:
+                    ui = sig_rows[sig] = len(rows)
+
+                    def stacked(ld=ld):
+                        out = np.full((s_pad, npad), -1, np.int32)
+                        for i, (nodeval, _) in enumerate(ld):
+                            out[i, :t.n] = nodeval
+                        return out
+                    rows.append(self._dev_const(
+                        ("spreadland", t.version, npad, s_pad) + sig,
+                        stacked))
+                g_spread[gi] = ui
+                w, exp, cnt = spread_job_rows(it.job, sp, ld, t, snapshot)
+                weight[gi, :len(sp)] = w
+                expected[gi, :len(sp), :exp.shape[1]] = exp
+                counts0[gi, :len(sp), :cnt.shape[1]] = cnt
+            rows.extend([rows[0]] * (_pad_pow2(len(rows), lo=1)
+                                     - len(rows)))
+            _registry().inc("nomad.spread.evals_batched",
+                            sum(1 for sp in by_item if sp))
+            return {"sp_nodeval": jnp.stack(rows),
+                    "g_spread": jnp.asarray(g_spread),
+                    "sp_weight": jnp.asarray(weight),
+                    "sp_expected": jnp.asarray(expected),
+                    "sp_counts0": jnp.asarray(counts0),
+                    "by_item": by_item}
 
     def single_group_fleet(self, t: NodeTensors) -> bool:
         """No node advertises more than one device group: then "instances
@@ -1553,6 +1652,7 @@ class PlacementEngine:
         # scheduling time and must not inflate AllocMetric latency
         return {"buf": buf, "used": used_out, "items": list(items),
                 "spans": aux["spans"], "counts": aux["counts"], "rs": rs,
+                "item_rs": aux["item_rs"], "rounds": aux["rounds"],
                 "t": aux["t"], "ctxs": aux["ctxs"], "n": aux["n"],
                 "npad": aux["npad"], "node_version": aux["t"].version,
                 "perm": aux["perm"], "fills_full": fills_full,
@@ -1716,12 +1816,19 @@ class PlacementEngine:
         # round schedule: item gi -> ceil(count / rs) consecutive rounds.
         # The ladder matters: round cost is dominated by top_k(N, rs) and
         # the [R, rs+16] buffer transfer, so the smallest bucket covering
-        # the biggest item wins (finer buckets would multiply compiles)
+        # the biggest item wins (finer buckets would multiply compiles).
+        # An item with a spread stanza takes ONE ROUND A PLACEMENT: the
+        # boost moves with every commit, so a `want`-1 round is the exact
+        # scan's step (same mask, same mean of the same components, same
+        # noise, arg-max) and `item_rs` says how its rows expand.
         counts = [max(it.count, 0) for it in items]
+        spread = self._lower_wave_spreads(t, npad, snapshot, items, g_pad)
         biggest = max(counts) if counts else 0
         for rs in (64, 256, 512, 1024):
             if biggest <= rs:
                 break
+        item_rs = [1 if spread is not None and spread["by_item"][gi]
+                   else rs for gi in range(G)]
         round_g: List[int] = []
         round_want: List[int] = []
         spans: List[Tuple[int, int]] = []
@@ -1730,9 +1837,12 @@ class PlacementEngine:
             left = c
             while left > 0:
                 round_g.append(gi)
-                round_want.append(min(left, rs))
-                left -= rs
+                round_want.append(min(left, item_rs[gi]))
+                left -= item_rs[gi]
             spans.append((start, len(round_g)))
+        if spread is not None:
+            _registry().inc("nomad.spread.rounds", sum(
+                c for c, irs in zip(counts, item_rs) if irs == 1))
 
         # ---- compact lane-parallel schedule (round-5 verdict #2/#3) ----
         # When the batch's signatures form ONE clique of pairwise
@@ -1751,7 +1861,9 @@ class PlacementEngine:
         perm = None
         cand_rows = cand_valid = cand_dev = None
         luts = tgts[-1].luts      # the most complete LUT matrix
-        if n_real > 1 and len(static_con) > 1:
+        # (a wave that holds a spread item keeps the flat schedule: the
+        # laned kernel carries no per-lane spread state)
+        if n_real > 1 and len(static_con) > 1 and spread is None:
             weights = [0] * len(static_con)
             for r_idx in range(n_real):
                 weights[int(g_static[round_g[r_idx]])] += 1
@@ -1859,7 +1971,12 @@ class PlacementEngine:
             round_want=jnp.asarray(np.array(round_want, np.int32)),
             seed=jnp.asarray(seed_g),
         )
+        if spread is not None:
+            inp = inp._replace(**{k: spread[k] for k in (
+                "sp_nodeval", "g_spread", "sp_weight", "sp_expected",
+                "sp_counts0")})
         return {"inp": inp, "rs": rs, "spans": spans, "counts": counts,
+                "item_rs": item_rs, "rounds": n_real,
                 "t": t, "ctxs": ctxs, "n": n, "npad": npad, "t0": t0,
                 "n_lanes": n_lanes, "perm": perm, "chained": chained,
                 "cand_rows": cand_rows, "cand_valid": cand_valid,
@@ -1983,9 +2100,11 @@ class PlacementEngine:
                     node_ids=t.node_ids, round_size=rs, metrics=[],
                     nodes_evaluated=n))
                 continue
+            # a spread item's rounds hold one placement each
+            irs = pending["item_rs"][gi]
             picks, _, meta = _unpack_bulk_compact(
-                buf_np[lo:hi], rs, counts[gi],
-                slot_k=rs_eff if rs_eff != rs else 0)
+                buf_np[lo:hi], irs, counts[gi],
+                slot_k=rs_eff if rs_eff != irs else 0)
             if npad != n:
                 meta = meta.copy()
                 meta[:, 7] -= npad - n
@@ -1994,7 +2113,7 @@ class PlacementEngine:
                 t.node_ids, int(elapsed))
             decisions.append(BulkDecisions(
                 tg_name=it.tg.name, picks=picks, node_ids=t.node_ids,
-                round_size=rs, metrics=metrics, nodes_evaluated=n))
+                round_size=irs, metrics=metrics, nodes_evaluated=n))
         return decisions
 
     def _no_nodes_decision(self, r: PlacementRequest, snapshot, job: Job

@@ -10,7 +10,7 @@ score_meta_data stays comparable.
 Score components (all bounded like the reference's):
   binpack     [0, 18]   structs.ScoreFit exponential (or inverted for spread
                         scheduler algorithm)
-  job-anti-affinity  [-1, 0]   -(collisions / desired_count)
+  job-anti-affinity  [-1, 0]   -((collisions + 1) / desired_count)
   node-reschedule-penalty  {-1, 0}  previous node of a rescheduled alloc
   node-affinity  [-1, 1]  sum(matched weights)/sum(|weights|)
   allocation-spread  [-1, 1]  per-property boost toward target percentages
@@ -58,9 +58,11 @@ def job_anti_affinity(job_count: jnp.ndarray,   # [N] int32
                       desired_count: jnp.ndarray | float,
                       ) -> jnp.ndarray:          # [N] float32
     """reference: JobAntiAffinityIterator — penalize nodes already running
-    allocs of the same job: -(collisions / desired_total)."""
+    allocs of the same job: -((collisions + 1) / desired_total), the
+    placement being scored counted with them as the reference counts it
+    (rank.go: `collisions+1`).  Callers apply it where collisions > 0."""
     d = jnp.maximum(desired_count, 1.0)
-    return -(job_count.astype(jnp.float32) / d)
+    return -((job_count.astype(jnp.float32) + 1.0) / d)
 
 
 def affinity_score(attrs: jnp.ndarray,       # [N, A]
@@ -109,13 +111,17 @@ def spread_boost(sp_nodeval: jnp.ndarray,    # [S, N] int32 local value idx, -1 
                  ) -> jnp.ndarray:           # [N] float32
     """reference: SpreadIterator/propertySet — boost toward target
     percentages.  For node n with value v on spread s:
-        boost = (expected_v - count_v) / max(expected_v, 1)   clipped to <=1
-    weighted by sp_weight/100 and averaged over non-padding spreads."""
+        boost = (expected_v - (count_v + 1)) / max(expected_v, 1)
+    clipped to [-1, 1]: like the reference (spread.go: `usedCount += 1`)
+    the count includes the placement being scored, so a value that is
+    one short of its target scores 0, not 1 / expected.  Weighted by
+    sp_weight/100 and averaged over non-padding spreads.  `sp_counts`
+    stays the count BEFORE this placement, as every caller carries it."""
     k = sp_counts.shape[1]
     val = jnp.clip(sp_nodeval, 0, k - 1)
     exp_n = jnp.take_along_axis(sp_expected, val, axis=1)     # [S, N]
     cnt_n = jnp.take_along_axis(sp_counts, val, axis=1)       # [S, N]
-    boost = (exp_n - cnt_n) / jnp.maximum(exp_n, 1.0)
+    boost = (exp_n - (cnt_n + 1.0)) / jnp.maximum(exp_n, 1.0)
     boost = jnp.clip(boost, -1.0, 1.0)
     # nodes whose value is not a spread target get no boost
     boost = jnp.where(sp_nodeval >= 0, boost, 0.0)

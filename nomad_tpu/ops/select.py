@@ -343,14 +343,20 @@ def _to_bulk_inputs(inp: PlacementInputs) -> BulkInputs:
 
 
 def round_scores_g(cap, req, desired, dh_limit, static, aff_sc, aff_any,
-                   used, job_count, spread_algo, round_size: int):
+                   used, job_count, spread_algo, round_size: int,
+                   spread=None):
     """Per-node intake capacity (k_i) and rank-chain score for one
     water-fill round at the current proposed state, parameterized on the
     round's task group values — THE shared scoring core of every bulk
     deployment: the single-device bulk kernel (fixed g via
     bulk_round_scores), the sharded variant (parallel/mesh._bulk_local),
     and the multi-eval batch kernel (dynamic g per round), so none of
-    the three can drift."""
+    the three can drift.
+
+    `spread`: None, or the round's (spread_boost [N], whether its item
+    has a stanza at all) — one more component of the mean, as the scan's
+    step_scores has it; only the flat multi-eval kernel passes it, and
+    only on a wave that holds a spread item."""
     n = cap.shape[0]
     capf = cap.astype(jnp.float32)
     big = jnp.int32(round_size)
@@ -372,13 +378,17 @@ def round_scores_g(cap, req, desired, dh_limit, static, aff_sc, aff_any,
     bp = binpack_score(capf, used.astype(jnp.float32),
                        req.astype(jnp.float32), spread_algo) / 18.0
     aa = job_anti_affinity(job_count, desired)
-    comps = jnp.stack([bp, aa, aff_sc])
-    act_mask = jnp.stack([
+    comps = [bp, aa, aff_sc]
+    act_mask = [
         jnp.ones(n, bool),
         job_count > 0,
         jnp.broadcast_to(aff_any, (n,)),
-    ])
-    score = normalize_scores(comps, act_mask)
+    ]
+    if spread is not None:
+        sp, sp_any = spread
+        comps.append(sp)
+        act_mask.append(jnp.broadcast_to(sp_any, (n,)))
+    score = normalize_scores(jnp.stack(comps), jnp.stack(act_mask))
     return k_i, score
 
 
@@ -695,7 +705,18 @@ class MultiEvalInputs(NamedTuple):
     (the bench's 384 zone-pinned evals → 5 signatures) pays the O(N·C)
     constraint gather work 5 times, not 512 — measured 1.15s → ~20ms per
     launch at 50k nodes.  `job_count0[g_job[g]]` remains per-job (it is
-    dynamic state, not a signature)."""
+    dynamic state, not a signature).
+
+    Spread stanzas ride the same way: `sp_nodeval` holds one [S, N]
+    value-index landscape per DISTINCT (attribute, target values)
+    signature (row 0 inert, for the items with no stanza), and per item
+    `g_spread` names its row, `sp_weight` / `sp_expected` / `sp_counts0`
+    carry the job's own few floats (S and K padded to the wave's
+    largest).  An item with a stanza is scheduled one round a placement
+    (`round_want` 1), and the round scan carries its per-value counts as
+    it carries the job's count row, so each placement sees the counts
+    the one before it left: the exact scan's sequence.  All five are None
+    on a wave that holds no stanza, and the program is the one it was."""
     # node state (shared across the batch)
     attrs: jnp.ndarray       # [N, A] int32
     cap: jnp.ndarray         # [N, RES_DIMS] int32
@@ -726,6 +747,12 @@ class MultiEvalInputs(NamedTuple):
     # wave-wide seed made batched picks diverge from the solo path on
     # every exact score tie)
     seed: jnp.ndarray = jnp.uint32(0)
+    # spread state, or None x 5 (see the docstring)
+    sp_nodeval: jnp.ndarray = None   # [Us, S, N] int32 (-1 = not a target)
+    g_spread: jnp.ndarray = None     # [G] int32 -> sp_nodeval row
+    sp_weight: jnp.ndarray = None    # [G, S] float32 (0 = padding / none)
+    sp_expected: jnp.ndarray = None  # [G, S, K] float32
+    sp_counts0: jnp.ndarray = None   # [G, S, K] float32 (existing allocs)
 
 
 def round_seeds(seed, rg):
@@ -778,10 +805,20 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
                               jobs_r[1:] == jobs_r[:-1]])
     seed_r = round_seeds(inp.seed, rg)
     rows_all = jnp.arange(n)
+    xs_r = (u_r, a_r, jc_r, req_r, des_r, dh_r, inp.round_want, same_r,
+            seed_r)
+    carry0 = (inp.used0, inp.job_count0[0])
+    has_spread = inp.sp_nodeval is not None
+    if has_spread:
+        # the job's own spread rows ride as scan xs, its per-value
+        # counts in the carry beside the count row
+        xs_r += (inp.g_spread[rg], inp.sp_weight[rg], inp.sp_expected[rg],
+                 inp.sp_counts0[rg])
+        carry0 += (inp.sp_counts0[0],)
 
     def round_step(carry, xs):
-        used, cur_count = carry
-        (u, a, jc0_row, req, desired, dh_limit, want, same, sd) = xs
+        used, cur_count = carry[:2]
+        (u, a, jc0_row, req, desired, dh_limit, want, same, sd) = xs[:9]
         static = static_u[u]          # [N]; U is tiny — cheap gather
         aff_sc = aff_u[a]
         aff_any = aff_any_u[a]
@@ -790,15 +827,32 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
         # solo bulk kernel computes for the same eval id
         noise = tiebreak_noise(sd, rows_all)
         job_count = jnp.where(same, cur_count, jc0_row)
+        spread = None
+        if has_spread:
+            us, sp_w, sp_exp, sp_c0 = xs[9:]
+            sp_nv = inp.sp_nodeval[us]                  # [S, N]
+            sp_counts = jnp.where(same, carry[2], sp_c0)
+            spread = (spread_boost(sp_nv, sp_w, sp_exp, sp_counts),
+                      jnp.any(sp_w > 0))
         k_i, score = round_scores_g(
             inp.cap, req, desired, dh_limit, static,
             aff_sc, aff_any, used, job_count,
-            inp.spread_algo, round_size)
+            inp.spread_algo, round_size, spread=spread)
         rows_p, cnt_p, sc_p, c_i, placed_total, k_round = waterfill_round(
             k_i, score, noise, want, inp.spread_algo, round_size)
 
         used = used + c_i[:, None] * req[None, :]
         job_count = job_count + c_i
+        carry = (used, job_count)
+        if has_spread:
+            # the round's commits summed by value, off the fill prefix
+            # (every committed node is in it): general in `want`, a
+            # one-hot where it is 1
+            k = sp_counts.shape[1]
+            val_p = sp_nv[:, rows_p]                    # [S, round_size]
+            hot = (jax.nn.one_hot(jnp.clip(val_p, 0, k - 1), k)
+                   * (cnt_p * (val_p >= 0))[..., None])
+            carry += (sp_counts + jnp.sum(hot, axis=1),)
 
         top_sc = sc_p[:top_k]
         top_rows = jnp.where(top_sc > NEG_INF / 2, rows_p[:top_k], -1)
@@ -810,13 +864,9 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
         out = (rows_p, cnt_p, sc_p, top_rows, top_sc,
                n_feas, n_filt, n_exh.astype(jnp.int32),
                dim_ex.astype(jnp.int32), placed_total.astype(jnp.int32))
-        return (used, job_count), out
+        return carry, out
 
-    carry0 = (inp.used0, inp.job_count0[0])
-    (used, jc), outs = jax.lax.scan(
-        round_step, carry0,
-        (u_r, a_r, jc_r, req_r, des_r, dh_r, inp.round_want, same_r,
-         seed_r))
+    (used, jc, *_), outs = jax.lax.scan(round_step, carry0, xs_r)
     (rows_p, cnt_p, sc_p, top_rows, top_sc,
      n_feas, n_filt, n_exh, dim_ex, placed) = outs
     fills, meta = pack_round_buffer(rows_p, cnt_p, top_rows, top_sc,

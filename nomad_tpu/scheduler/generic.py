@@ -20,6 +20,7 @@ from typing import Dict, List, Optional
 from nomad_tpu.chaos.clock import SystemClock
 from nomad_tpu.ops import PlacementEngine, PlacementRequest
 from nomad_tpu.ops.engine import BulkDecisions
+from nomad_tpu.pack.spread import job_spreads
 from nomad_tpu.structs import (
     Allocation,
     AllocMetric,
@@ -65,6 +66,15 @@ PORT_BATCHED = True
 # tests/test_device_batched.py compares against.  A module constant that
 # only tests set; no server option reaches it.
 DEVICE_BATCHED = True
+
+# The largest eval with a spread stanza that rides the wave.  The flat
+# multi-eval kernel gives such an eval one round a placement (the boost
+# moves with every commit: ops/engine.py build_multi_inputs), so its
+# count is its round count; 64 is the smallest round bucket.  A larger
+# eval would be a program shape of its own doing the device work the
+# exact scan already does, and keeps the scan.  A size the mechanism
+# needs, not a switch: nothing sets it.
+SPREAD_WAVE_MAX = 64
 
 # Shared engines so packed node tensors + jit caches persist across evals
 # of one in-process scheduler session (the worker wires its own).  Keyed
@@ -216,16 +226,28 @@ class GenericScheduler(Scheduler):
         evals share ONE device launch): run the reconcile phase only and
         decide whether this eval is the batchable shape — ONLY fresh
         placements of one task group and nothing else (no stops, updates,
-        reschedules, deployment activity), with no spread /
-        distinct_property / device asks (those need the exact scan
-        kernel's per-placement state).  Returns a BatchPrep or None
-        (caller processes the eval through the normal path)."""
+        reschedules, deployment activity) and no distinct_property (the
+        exact scan kernel's per-placement state).  A device ask rides
+        under `_device_batch_refusal`'s rules, a spread stanza under
+        `_spread_batch_refusal`'s.  Returns a BatchPrep or None (caller
+        processes the eval through the normal path); a job with a spread
+        stanza that is refused counts itself, by rule, in
+        `nomad.spread.evals_solo`."""
         if evaluation.annotate_plan:
             return None          # dry-run diffs ride the normal path
         state = self.state
         job = state.job_by_id(evaluation.namespace, evaluation.job_id)
         if job is None or job.stopped():
             return None
+        prep, rule = self._prepare_batch(evaluation, job)
+        if prep is None and job_spreads(job):
+            from nomad_tpu.core.telemetry import REGISTRY
+            REGISTRY.inc("nomad.spread.evals_solo", rule=rule)
+        return prep
+
+    def _prepare_batch(self, evaluation: Evaluation, job: Job):
+        """(BatchPrep, "") or (None, the rule that refused)."""
+        state = self.state
         allocs = state.allocs_by_job(evaluation.namespace, evaluation.job_id)
         tainted = tainted_nodes(state, allocs)
         deployment = state.latest_deployment_by_job(
@@ -233,10 +255,10 @@ class GenericScheduler(Scheduler):
         results = reconcile(job, False, allocs, tainted, self.now,
                             existing_deployment=deployment)
         if (results.stop or results.inplace_update
-                or results.destructive_update or results.reschedule_later
-                or results.deployment is not None
-                or results.deployment_updates):
-            return None
+                or results.destructive_update or results.reschedule_later):
+            return None, "reconcile"
+        if results.deployment is not None or results.deployment_updates:
+            return None, "deployment"
         block = None
         places = None
         if len(results.place_blocks) == 1 and not results.place:
@@ -248,19 +270,20 @@ class GenericScheduler(Scheduler):
             tg = places[0].tg
             if any(p.tg is not tg or p.previous_alloc is not None
                    or p.canary for p in places):
-                return None      # reschedules/canaries: exact path
+                return None, "shape"   # reschedules/canaries: exact path
             count = len(places)
         else:
-            return None
+            return None, "shape"
         if count < 1:
-            return None
-        if job.spreads or tg.spreads:
-            return None
+            return None, "shape"
         from nomad_tpu.structs import OP_DISTINCT_PROPERTY
         cons = (list(job.constraints) + list(tg.constraints)
                 + [c for task in tg.tasks for c in task.constraints])
         if any(c.operand == OP_DISTINCT_PROPERTY for c in cons):
-            return None
+            return None, "distinct_property"
+        rule = self._spread_batch_refusal(job, count)
+        if rule:
+            return None, rule
         from .device import tg_device_requests
         dev_reqs = tg_device_requests(tg)
         if dev_reqs:
@@ -270,7 +293,7 @@ class GenericScheduler(Scheduler):
             # counts itself under the rule that refused (_assign_devices)
             self._device_solo_rule = self._device_batch_refusal(tg, dev_reqs)
             if self._device_solo_rule:
-                return None
+                return None, "device"
         # Networked groups RIDE the batch (round-5 verdict #6), and
         # since ISSUE 8 they ride the COLUMNAR block path too: the
         # worker threads ONE NetworkIndex cache through every batch
@@ -283,7 +306,26 @@ class GenericScheduler(Scheduler):
         # applier's skip-fit to the full re-check, which audits block
         # ports per node (plan_apply._carries_host_assigned /
         # _eval_blocks).
-        return self.BatchPrep(job, tg, count, block, places, results)
+        return self.BatchPrep(job, tg, count, block, places, results), ""
+
+    def _spread_batch_refusal(self, job: Job, count: int) -> str:
+        """The admission rule that keeps an eval with a spread stanza
+        off the wave, or "" when it rides (or has none): every stanza
+        with explicit targets (a target-less one lowers to an even split
+        over the values observed, which the wave does not stand on), at
+        most SPREAD_WAVE_MAX placements (a round each), and an engine
+        that is not sharded (the mesh's wave kernel carries no spread
+        state)."""
+        spreads = job_spreads(job)
+        if not spreads:
+            return ""
+        if not all(sp.targets for sp in spreads):
+            return "targets"
+        if count > SPREAD_WAVE_MAX:
+            return "count"
+        if self.engine.mesh is not None:
+            return "mesh"
+        return ""
 
     def _device_batch_refusal(self, tg, dev_reqs) -> str:
         """The admission rule that keeps a device-asking group off the

@@ -217,9 +217,11 @@ class TestBatchedWorkerPath:
             if not a.terminal_status())
         assert placed == 9
 
-    def test_spread_job_falls_back_to_exact_path_in_batch(self):
+    def _spread_beside_plain(self, update):
+        """A plain batch job and a spread service job of nine in one
+        batch; returns (server, spread job's per-datacenter counts)."""
         from nomad_tpu.structs import Spread, SpreadTarget
-        s = Server(dev_mode=True, eval_batch=64)
+        s = Server(dev_mode=True, eval_batch=64, mesh=False)
         s.establish_leadership()
         for i in range(30):
             n = mock.node()
@@ -232,6 +234,7 @@ class TestBatchedWorkerPath:
         spread = mock.job()
         spread.datacenters = ["dc1", "dc2", "dc3"]
         spread.task_groups[0].count = 9
+        spread.update = update
         spread.spreads = [Spread(attribute="${node.datacenter}", weight=50,
                                  targets=[SpreadTarget("dc1", 34),
                                           SpreadTarget("dc2", 33),
@@ -243,12 +246,34 @@ class TestBatchedWorkerPath:
             live = [a for a in snap.allocs_by_job(job.namespace, job.id)
                     if not a.terminal_status()]
             assert len(live) == want
-        # the spread job actually spread across the three DCs
         by_dc = {}
         for a in snap.allocs_by_job(spread.namespace, spread.id):
             node = snap.node_by_id(a.node_id)
             by_dc[node.datacenter] = by_dc.get(node.datacenter, 0) + 1
+        return s, by_dc
+
+    def test_spread_job_with_update_stanza_falls_back_to_exact_path(self):
+        """mock.job's update stanza makes the eval create a deployment,
+        which keeps it off the wave: counted, and spread by the scan."""
+        from nomad_tpu.core.telemetry import REGISTRY
+        solo0 = REGISTRY.counter_labels("nomad.spread.evals_solo").get(
+            "rule=deployment", 0.0)
+        _, by_dc = self._spread_beside_plain(mock.job().update)
+        assert mock.job().update is not None
         assert sorted(by_dc.values()) == [3, 3, 3], by_dc
+        assert REGISTRY.counter_labels("nomad.spread.evals_solo").get(
+            "rule=deployment", 0.0) - solo0 == 1
+
+    def test_spread_job_without_update_stanza_rides_the_batch(self):
+        from nomad_tpu.core.telemetry import REGISTRY
+        rode0 = REGISTRY.counter_sum("nomad.spread.evals_batched")
+        solo0 = REGISTRY.counter_sum("nomad.spread.evals_solo")
+        s, by_dc = self._spread_beside_plain(None)
+        assert sorted(by_dc.values()) == [3, 3, 3], by_dc
+        assert REGISTRY.counter_sum("nomad.spread.evals_batched") \
+            - rode0 == 1
+        assert REGISTRY.counter_sum("nomad.spread.evals_solo") == solo0
+        assert s.workers[0].pipeline.stats["waves"] == 1
 
     def test_applier_fast_path_and_fence(self):
         """Coupled-batch plans skip the redundant AllocsFit re-check; a

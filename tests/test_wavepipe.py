@@ -557,14 +557,16 @@ def _inside(span, outers):
 class TestPassStages:
     def test_stage_list_is_the_recorders(self, small_pass):
         from nomad_tpu.core.wavepipe import STAGES
-        # device_carve is the one stage this pass does not take (no eval
-        # in it asks for a device) and the one that nests, in its eval's
-        # materialize: tests/test_device_batched.py holds it to that
+        # device_carve and spread_lower are the stages this pass does
+        # not take (no eval in it asks for a device or carries a spread
+        # stanza) and the ones that nest, in their eval's materialize
+        # and their wave's dispatch: tests/test_device_batched.py and
+        # tests/test_spread_batched.py hold them to that
         assert set(small_pass.stage_timers.counts()) | {
-            "device_carve"} == set(STAGES)
+            "device_carve", "spread_lower"} == set(STAGES)
         assert set(WORKER_STAGES) | {"pass", "device", "commit",
-                                     "store_upsert",
-                                     "device_carve"} == set(STAGES)
+                                     "store_upsert", "device_carve",
+                                     "spread_lower"} == set(STAGES)
 
     @pytest.mark.parametrize("stage", NEW_STAGES)
     def test_new_stage_recorded(self, small_pass, stage):
