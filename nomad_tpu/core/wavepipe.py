@@ -373,8 +373,10 @@ class WavePipeline:
                                      "chained": used0_dev is not None,
                                      "masked_nodes": len(mask or ())}
         if isinstance(pending, dict):
-            # real rounds of the launch's schedule, padding left out
+            # real rounds of the launch's schedule, and the flat
+            # schedule's padding to its power of two beside them
             fields["rounds"] = int(pending.get("rounds", 0))
+            fields["rounds_padded"] = int(pending.get("rounds_padded", 0))
             fields["resident"] = bool(pending.get("chained"))
             for key in ("collective_bytes", "shard_h2d_bytes"):
                 if pending.get(key):

@@ -1653,6 +1653,7 @@ class PlacementEngine:
         return {"buf": buf, "used": used_out, "items": list(items),
                 "spans": aux["spans"], "counts": aux["counts"], "rs": rs,
                 "item_rs": aux["item_rs"], "rounds": aux["rounds"],
+                "rounds_padded": aux["rounds_padded"],
                 "t": aux["t"], "ctxs": aux["ctxs"], "n": aux["n"],
                 "npad": aux["npad"], "node_version": aux["t"].version,
                 "perm": aux["perm"], "fills_full": fills_full,
@@ -1814,13 +1815,17 @@ class PlacementEngine:
             aff[ai] = row
 
         # round schedule: item gi -> ceil(count / rs) consecutive rounds.
-        # The ladder matters: round cost is dominated by top_k(N, rs) and
-        # the [R, rs+16] buffer transfer, so the smallest bucket covering
-        # the biggest item wins (finer buckets would multiply compiles).
+        # The ladder matters: a water-fill round's cost is dominated by
+        # top_k(N, rs), which the TPU lowers to a full sort of the nodes,
+        # and the [R, rs+16] buffer transfer, so the smallest bucket
+        # covering the biggest item wins (finer buckets would multiply
+        # compiles).
         # An item with a spread stanza takes ONE ROUND A PLACEMENT: the
         # boost moves with every commit, so a `want`-1 round is the exact
         # scan's step (same mask, same mean of the same components, same
-        # noise, arg-max) and `item_rs` says how its rows expand.
+        # noise, arg-max) and `item_rs` says how its rows expand.  The
+        # flat kernel takes such a round by arg-max, with no sort
+        # (select.pick_one_round).
         counts = [max(it.count, 0) for it in items]
         spread = self._lower_wave_spreads(t, npad, snapshot, items, g_pad)
         biggest = max(counts) if counts else 0
@@ -1916,9 +1921,18 @@ class PlacementEngine:
                 n_lanes = width
                 round_g, round_want = sched_g, sched_want
 
+        pad_r = 0
         if cand_rows is None:
             r_pad = _pad_pow2(max(len(round_g), 1), lo=1)
             pad_r = r_pad - len(round_g)
+            if self.mesh is None:
+                # what select.place_multi_packed's branch on `want` will
+                # meet: an arg-max, a water-fill, a round that runs nothing
+                pick_one = round_want.count(1)
+                for kind, rounds in (("pick_one", pick_one),
+                                     ("fill", n_real - pick_one),
+                                     ("padded", pad_r)):
+                    _registry().inc("nomad.engine.rounds", rounds, kind=kind)
             round_g.extend([0] * pad_r)
             round_want.extend([0] * pad_r)
 
@@ -1976,7 +1990,7 @@ class PlacementEngine:
                 "sp_nodeval", "g_spread", "sp_weight", "sp_expected",
                 "sp_counts0")})
         return {"inp": inp, "rs": rs, "spans": spans, "counts": counts,
-                "item_rs": item_rs, "rounds": n_real,
+                "item_rs": item_rs, "rounds": n_real, "rounds_padded": pad_r,
                 "t": t, "ctxs": ctxs, "n": n, "npad": npad, "t0": t0,
                 "n_lanes": n_lanes, "perm": perm, "chained": chained,
                 "cand_rows": cand_rows, "cand_valid": cand_valid,

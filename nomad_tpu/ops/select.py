@@ -470,6 +470,64 @@ def waterfill_round(k_i, score, noise, want, spread_algo, round_size: int):
     return rows_p, cnt_p, sc_p, c_i, placed_total, k_round
 
 
+def _first_max(x, rows, *payload):
+    """(the maximum of x, its row, each payload's value there) in ONE
+    reduce over the nodes; among equal maxima the lowest row, which is
+    what `argmax` returns and where `lax.top_k` starts.  The payloads
+    ride the reduce so that no scalar is gathered afterwards: on the TPU
+    every such gather is an op of its own, as dear as the reduce."""
+    def first(a, b):
+        take_a = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+        return tuple(jnp.where(take_a, u, v) for u, v in zip(a, b))
+
+    init = (jnp.array(-jnp.inf, x.dtype),
+            jnp.array(jnp.iinfo(rows.dtype).max, rows.dtype),
+            *(jnp.zeros((), p.dtype) for p in payload))
+    return jax.lax.reduce((x, rows, *payload), init, first, (0,))
+
+
+def pick_one_round(k_i, score, noise, want, spread_algo, round_size: int):
+    """waterfill_round for a round whose `want` is at most 1, without the
+    top-k sort: one node takes one allocation, so the selection is the
+    arg-max of the same masked, jittered scores, and the TOP_K reported
+    rows are the next arg-maxes of the same vector.  `lax.top_k` orders
+    equal values by lower index and _first_max returns the first
+    maximum, so the order is the sort's.  Same signature and return
+    tuple; every value that reaches the packed buffer (the fills, the
+    first TOP_K rows and scores, c_i, placed_total, k_round) is
+    waterfill_round's bit for bit.  Slots past TOP_K of the prefix, which
+    hold zero counts there too, are row 0 with NEG_INF."""
+    n = k_i.shape[0]
+    big = jnp.int32(round_size)
+    # the spread algorithm's cap, want // viable + 1: with `want` at most
+    # 1 and one viable node at least, 2 where both are 1 and else 1
+    viable = jnp.maximum(jnp.sum(k_i > 0), 1)
+    cap_round = jnp.where(
+        spread_algo, jnp.where(want >= viable, 2, 1).astype(k_i.dtype), big)
+    k_round = jnp.minimum(k_i, cap_round)
+
+    rows_all = jnp.arange(n, dtype=jnp.int32)
+    left = jnp.where(k_round > 0, score, NEG_INF) + noise
+    tops = []
+    for _ in range(min(TOP_K, n, round_size)):
+        best, row, sc, k_row = _first_max(left, rows_all, score, k_round)
+        tops.append((row, jnp.where(best > NEG_INF / 2, sc, NEG_INF), k_row))
+        left = jnp.where(rows_all == row, -jnp.inf, left)
+    row, sc, k_row = tops[0]
+    placed_total = jnp.clip(
+        want, 0, jnp.where(sc > NEG_INF / 2, k_row, 0)).astype(jnp.int32)
+
+    pad = round_size - len(tops)
+    rows_p = jnp.concatenate([jnp.stack([t[0] for t in tops]),
+                              jnp.zeros(pad, jnp.int32)])
+    cnt_p = jnp.concatenate([placed_total[None],
+                             jnp.zeros(round_size - 1, jnp.int32)])
+    sc_p = jnp.concatenate([jnp.stack([t[1] for t in tops]),
+                            jnp.full(pad, NEG_INF, score.dtype)])
+    c_i = jnp.where(rows_all == row, placed_total, 0)
+    return rows_p, cnt_p, sc_p, c_i, placed_total, k_round
+
+
 def _bulk_step(inp: BulkInputs, round_size: int, top_k: int, static_t,
                carry, want):
     """One water-fill round of the bulk kernel.  Returns compact per-round
@@ -738,7 +796,10 @@ class MultiEvalInputs(NamedTuple):
     job_count0: jnp.ndarray  # [J, N] int32
     spread_algo: jnp.ndarray  # [] bool
     # round schedule (host-computed: eval e with count c contributes
-    # ceil(c / round_size) consecutive rounds; padding rounds want=0)
+    # ceil(c / round_size) consecutive rounds; padding rounds want=0.
+    # The flat kernel's loop ends before its padding, which costs a zero
+    # row of the output and nothing else; the laned kernel still runs
+    # its inert slots in full)
     round_g: jnp.ndarray     # [R] int32
     round_want: jnp.ndarray  # [R] int32
     # PER-ITEM tie-break seeds, [G] uint32 (a scalar broadcasts): each
@@ -771,7 +832,16 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
     count row) varies per round.  Output is the compact per-round packed
     buffer of place_bulk_packed, `[R, round_size + 16]`, one device→host
     transfer for the WHOLE batch; the host slices rows per eval.
-    Returns (buf, used, last job's count row [N])."""
+
+    A round does what its own `want` can use.  `want` 0 (the schedule's
+    padding to a power of two, always its tail): the round loop ends
+    before it, and its row of the buffer is zeros.  `want` 1 (every round
+    of an item with a spread stanza, a plain item of one): the scores,
+    then pick_one_round's arg-max; its one commit is a one-hot over the
+    nodes and one spread value, not a scatter of the prefix's 64 slots.
+    Any other `want`: waterfill_round's top-k and its scattered commits.
+    Real rounds' rows and `used` are the same bits whichever branch ran.
+    Returns (buf, used, last REAL round's job count row [N])."""
     n = inp.attrs.shape[0]
     assert n < (1 << 20), "packed fill rows support < 2^20 nodes"
     assert round_size <= 1024, "packed fill counts support rounds <= 1024"
@@ -822,10 +892,6 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
         static = static_u[u]          # [N]; U is tiny — cheap gather
         aff_sc = aff_u[a]
         aff_any = aff_any_u[a]
-        # per-item noise (elementwise hash — no [R, N] pre-gather): the
-        # round draws its EVAL's tie-break stream, matching what the
-        # solo bulk kernel computes for the same eval id
-        noise = tiebreak_noise(sd, rows_all)
         job_count = jnp.where(same, cur_count, jc0_row)
         spread = None
         if has_spread:
@@ -838,21 +904,49 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
             inp.cap, req, desired, dh_limit, static,
             aff_sc, aff_any, used, job_count,
             inp.spread_algo, round_size, spread=spread)
-        rows_p, cnt_p, sc_p, c_i, placed_total, k_round = waterfill_round(
-            k_i, score, noise, want, inp.spread_algo, round_size)
 
+        def select(select_round):
+            # per-item noise (elementwise hash — no [R, N] pre-gather):
+            # the round draws its EVAL's tie-break stream, matching what
+            # the solo bulk kernel computes for the same eval id
+            return select_round(k_i, score, tiebreak_noise(sd, rows_all),
+                                want, inp.spread_algo, round_size)
+
+        def by_value(rows, cnt):
+            # `cnt` commits on node `rows`, one-hot by its spread value
+            k = sp_counts.shape[1]
+            val = sp_nv[:, rows]
+            return (jax.nn.one_hot(jnp.clip(val, 0, k - 1), k)
+                    * (cnt * (val >= 0))[..., None])
+
+        def fill():
+            # any `want`: the top-k water-fill, its commits summed by
+            # value off the fill prefix (every committed node is in it)
+            sel = select(waterfill_round)
+            if has_spread:
+                sel += (jnp.sum(by_value(sel[0], sel[1]), axis=1),)
+            return sel
+
+        def pick_one():
+            # `want` 1: the arg-max, its one commit the prefix's first slot
+            sel = select(pick_one_round)
+            if has_spread:
+                sel += (by_value(sel[0][0], sel[1][0]),)
+            return sel
+
+        sel = jax.lax.cond(want == 1, pick_one, fill)
+        rows_p, cnt_p, sc_p, c_i, placed_total, k_round, *sp_commits = sel
+        # the commit stays outside the branches, which hand out [N]
+        # vectors alone: an [N, RES_DIMS] tensor computed inside one takes
+        # the row-major layout on the TPU, four columns padded to 128,
+        # and the carried `used` with it (the barrier keeps the compiler
+        # from sinking the product into them)
+        c_i = jax.lax.optimization_barrier(c_i)
         used = used + c_i[:, None] * req[None, :]
         job_count = job_count + c_i
         carry = (used, job_count)
         if has_spread:
-            # the round's commits summed by value, off the fill prefix
-            # (every committed node is in it): general in `want`, a
-            # one-hot where it is 1
-            k = sp_counts.shape[1]
-            val_p = sp_nv[:, rows_p]                    # [S, round_size]
-            hot = (jax.nn.one_hot(jnp.clip(val_p, 0, k - 1), k)
-                   * (cnt_p * (val_p >= 0))[..., None])
-            carry += (sp_counts + jnp.sum(hot, axis=1),)
+            carry += (sp_counts + sp_commits[0],)
 
         top_sc = sc_p[:top_k]
         top_rows = jnp.where(top_sc > NEG_INF / 2, rows_p[:top_k], -1)
@@ -861,13 +955,32 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
         n_filt = jnp.sum(~static).astype(jnp.int32)
         n_exh, dim_ex = round_metrics_g(
             inp.cap, req, dh_limit, static, used, job_count)
-        out = (rows_p, cnt_p, sc_p, top_rows, top_sc,
+        out = (rows_p, cnt_p, top_rows, top_sc,
                n_feas, n_filt, n_exh.astype(jnp.int32),
                dim_ex.astype(jnp.int32), placed_total.astype(jnp.int32))
         return carry, out
 
-    (used, jc, *_), outs = jax.lax.scan(round_step, carry0, xs_r)
-    (rows_p, cnt_p, sc_p, top_rows, top_sc,
+    # the schedule's padding (`want` 0) is its tail, so the loop stops one
+    # past the last round that wants anything: a padding round runs
+    # nothing, the carry passes it by and its rows of the outputs stay
+    # zeros, which no span reads
+    _, out_shapes = jax.eval_shape(
+        round_step, carry0, jax.tree.map(lambda x: x[0], xs_r))
+    r_pad = inp.round_want.shape[0]
+    outs0 = jax.tree.map(
+        lambda s: jnp.zeros((r_pad,) + s.shape, s.dtype), out_shapes)
+    n_run = jnp.max(jnp.where(inp.round_want > 0, jnp.arange(r_pad) + 1, 0))
+
+    def body(i, state):
+        carry, outs = state
+        carry, out = round_step(carry, jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, False), xs_r))
+        return carry, jax.tree.map(
+            lambda o, v: jax.lax.dynamic_update_index_in_dim(o, v, i, 0),
+            outs, out)
+
+    (used, jc, *_), outs = jax.lax.fori_loop(0, n_run, body, (carry0, outs0))
+    (rows_p, cnt_p, top_rows, top_sc,
      n_feas, n_filt, n_exh, dim_ex, placed) = outs
     fills, meta = pack_round_buffer(rows_p, cnt_p, top_rows, top_sc,
                                     n_feas, n_filt, n_exh, dim_ex, placed)

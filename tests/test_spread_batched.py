@@ -293,6 +293,160 @@ def test_item_without_a_stanza_scores_bit_for_bit():
     assert bits(bd_plain) == bits(alone)
 
 
+# ------------------------------------------ the branch on a round's `want`
+
+def selection_case(case: str):
+    """(k_i, score, seed) for one selection: 97 nodes (the prefix is the
+    round's 64), or 40 (the prefix is padded) with two of them feasible."""
+    n = 40 if case == "two_feasible" else 97
+    rng = np.random.default_rng(len(case) * 1009 + n)
+    score = rng.uniform(-1.0, 1.0, n).astype(np.float32)
+    k_i = rng.integers(0, 5, n).astype(np.int32)
+    seed = 0x5EED
+    if case.startswith("ties"):
+        # four equal maxima, one of them on a node with no intake
+        score[[5, 40, 41, 90]] = np.float32(1.25)
+        k_i[[5, 41, 90]] = 3
+        k_i[40] = 0
+        seed = 0 if case == "ties_seed_0" else 2147932103
+    elif case in ("two_feasible", "one_feasible", "none_feasible"):
+        keep = {"two_feasible": [7, 33], "one_feasible": [60],
+                "none_feasible": []}[case]
+        mask = np.zeros(n, bool)
+        mask[keep] = True
+        k_i = np.where(mask, 2, 0).astype(np.int32)
+    return k_i, score, seed
+
+
+def reaches_the_buffer(sel, round_size):
+    """What round_step and pack_round_buffer make of one selection: the
+    packed fills, the reported rows and the scores' bits, the commit, the
+    count placed and the feasible count."""
+    import jax.numpy as jnp
+    from nomad_tpu.ops import select
+    rows_p, cnt_p, sc_p, c_i, placed, k_round = sel
+    assert rows_p.shape == cnt_p.shape == sc_p.shape == (round_size,)
+    top_sc = sc_p[:select.TOP_K]
+    top_rows = jnp.where(top_sc > select.NEG_INF / 2,
+                         rows_p[:select.TOP_K], -1)
+    top_sc = jnp.where(top_sc > select.NEG_INF / 2, top_sc, 0.0)
+    zero = jnp.zeros((1,), jnp.int32)
+    fills, meta = select.pack_round_buffer(
+        rows_p[None], cnt_p[None], top_rows[None], top_sc[None],
+        jnp.sum(k_round > 0).astype(jnp.int32)[None], zero, zero,
+        jnp.zeros((1, 4), jnp.int32), placed.astype(jnp.int32)[None])
+    return [np.asarray(x) for x in (fills, meta, c_i, placed, k_round)]
+
+
+@pytest.mark.parametrize("spread_algo", [False, True],
+                         ids=["binpack", "spread_algo"])
+@pytest.mark.parametrize("case", ["random", "ties_seed_0", "ties_live_seed",
+                                  "two_feasible", "one_feasible",
+                                  "none_feasible"])
+def test_pick_one_round_is_the_water_fill_at_want_1(case, spread_algo):
+    """The arg-max selection against the top-64 sort on the same intake,
+    scores and noise: every value that reaches the buffer, bit for bit."""
+    import jax.numpy as jnp
+    from nomad_tpu.ops import select
+    k_i, score, seed = selection_case(case)
+    noise = select.tiebreak_noise(jnp.uint32(seed), jnp.arange(len(k_i)))
+    args = (jnp.asarray(k_i), jnp.asarray(score), noise, jnp.int32(1),
+            jnp.asarray(spread_algo), 64)
+    want = reaches_the_buffer(select.waterfill_round(*args), 64)
+    got = reaches_the_buffer(select.pick_one_round(*args), 64)
+    for a, b in zip(want, got):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+    feasible = int((k_i > 0).sum())
+    fills, meta = got[0][0], got[1][0]
+    assert int(got[3]) == min(feasible, 1) == int(got[2].sum())
+    assert (meta[:3] >= 0).sum() == min(feasible, 3)
+    if case.startswith("ties"):
+        # the first of the equal maxima that has intake, or the noise's
+        assert fills[0] // 2048 in (5, 41, 90) and fills[0] % 2048 == 1
+        assert seed or fills[0] // 2048 == 5
+        assert sorted(meta[:3]) == [5, 41, 90]
+
+
+def mixed_wave(n_nodes=150, fleet_seed=61):
+    """One launch's inputs for a wave that meets every branch: two spread
+    items (one with two stanzas), a plain item of ten (a water-fill
+    round) and a plain item of one (an arg-max round with no stanza)."""
+    nodes = fleet(n_nodes, fleet_seed)
+    jobs = [service("mw-a", 10), service("mw-plain", 10, stanzas=0),
+            service("mw-one", 1, stanzas=0),
+            service("mw-b", 12, stanzas=2, affinity=True)]
+    h = harness(nodes)
+    for job in jobs:
+        h.state.upsert_job(job)
+    snap = h.state.snapshot()
+    items = [BatchItem(j, j.task_groups[0], j.task_groups[0].count)
+             for j in jobs]
+    seeds = [4101, 4102, 4103, 4104]
+    eng = PlacementEngine(mesh=False)
+    built = eng.build_multi_inputs(snap, items, seed=seeds)
+    return nodes, jobs, snap, items, seeds, built
+
+
+def exact_schedule(inp, n_real):
+    return inp._replace(round_g=inp.round_g[:n_real],
+                        round_want=inp.round_want[:n_real])
+
+
+@pytest.mark.parametrize("launch", ["exact", "chained", "chained_exact",
+                                    "sort_for_arg_max"])
+def test_padding_and_arg_max_leave_the_launch_bit_for_bit(launch,
+                                                          monkeypatch):
+    """The mixed wave padded to its power of two against the same wave at
+    its exact round count, through the chained program, and with the sort
+    put back where the arg-max is: real rounds' rows and `used` equal."""
+    import jax
+    import jax.numpy as jnp
+    from nomad_tpu.ops import select
+    *_, built = mixed_wave()
+    inp, rs, n_real = built["inp"], built["rs"], built["rounds"]
+    assert n_real == 10 + 1 + 1 + 12 and inp.round_want.shape == (32,)
+    assert built["rounds_padded"] == 8
+    assert sorted(set(np.asarray(inp.round_want).tolist())) == [0, 1, 10]
+    buf, used, _ = select.place_multi_packed_jit(inp, rs)
+    buf, used = np.asarray(buf), np.asarray(used)
+    # every real round placed what it wanted; a padding round's row is 0
+    assert buf[:n_real, rs + 12].tolist() == [1] * 10 + [10, 1] + [1] * 12
+    assert not buf[n_real:].any()
+    other = exact_schedule(inp, n_real) if "exact" in launch else inp
+    if launch.startswith("chained"):
+        got = select.place_multi_chained_jit(
+            jnp.array(inp.used0), other._replace(used0=None), rs)
+    elif launch == "sort_for_arg_max":
+        monkeypatch.setattr(select, "pick_one_round", select.waterfill_round)
+        got = jax.jit(select.place_multi_packed, static_argnums=1)(other, rs)
+    else:
+        got = select.place_multi_packed_jit(other, rs)
+    assert np.asarray(got[0])[:n_real].tobytes() == buf[:n_real].tobytes()
+    assert np.asarray(got[1]).tobytes() == used.tobytes()
+
+
+def test_mixed_wave_rounds_pick_what_the_scan_picks():
+    """What test_rounds_pick_what_the_scan_picks holds for a launch of
+    one item, for the first spread item of the mixed wave; and the items
+    behind it place whole."""
+    nodes, jobs, snap, items, seeds, built = mixed_wave()
+    eng = PlacementEngine(mesh=False)
+    decisions = eng.place(
+        snap, jobs[0], jobs[0].task_groups,
+        [PlacementRequest(tg_name=jobs[0].task_groups[0].name)] * 10,
+        seed=seeds[0])
+    top2 = [tuple(m.norm_score for m in d.metric.score_meta_data[:2])
+            for d in decisions]
+    bds = PlacementEngine(mesh=False).place_batch(snap, items, seed=seeds)
+    assert [bd.round_size for bd in bds] == [1, 64, 64, 1]
+    rounds = [bds[0].node_ids[p] for p in bds[0].picks.tolist()]
+    assert assert_same_picks([d.node_id for d in decisions], top2, rounds)
+    assert all((bd.picks >= 0).all() for bd in bds)
+    assert counts_of(nodes, rounds, "datacenter") == {
+        "dc1": 5, "dc2": 3, "dc3": 2}
+
+
 # ----------------------------------------------------------- served path
 
 def mixed_jobs(tag: str, n: int):
@@ -472,6 +626,7 @@ def served():
             "nomad.spread.evals_solo",
             "nomad.engine.spread_landscapes_built",
             "nomad.engine.spread_landscapes_reused")}
+        before["kinds"] = REGISTRY.counter_labels("nomad.engine.rounds")
         srv.stop_scheduling()
         for job in jobs:
             srv.register_job(job)
@@ -499,7 +654,7 @@ def http_get(agent, path):
 def test_served_wave_moves_the_spread_counters(served):
     agent, jobs, before = served
     moved = {name: REGISTRY.counter_sum(name) - v
-             for name, v in before.items()}
+             for name, v in before.items() if name != "kinds"}
     assert moved["nomad.spread.evals_batched"] == 6
     assert moved["nomad.spread.rounds"] == 60
     assert moved["nomad.spread.evals_solo"] == 0
@@ -532,6 +687,44 @@ def test_wave_record_carries_its_real_rounds(served):
     waves = [w for w in FLIGHT.snapshot()["Waves"]
              if w.get("items") == 8 and "rounds" in w]
     assert any(w["rounds"] == 6 * 10 + 2 for w in waves), waves[-3:]
+
+
+def test_served_wave_counts_its_rounds_by_kind(served):
+    """The schedule's own counts: sixty `want`-1 rounds of the six spread
+    evals, one water-fill round each of the two plain evals of five, and
+    the two rounds that pad 62 to 64; the record carries the padding."""
+    from nomad_tpu.core.flightrec import FLIGHT
+    agent, _, before = served
+    now = REGISTRY.counter_labels("nomad.engine.rounds")
+    moved = {kind: now.get(f"kind={kind}", 0.0)
+             - before["kinds"].get(f"kind={kind}", 0.0)
+             for kind in ("pick_one", "fill", "padded")}
+    assert moved == {"pick_one": 60, "fill": 2, "padded": 2}
+    assert sorted(now) == ["kind=fill", "kind=padded", "kind=pick_one"]
+    assert "nomad.engine.rounds" in json.dumps(http_get(agent, "/v1/metrics"))
+    waves = [w for w in FLIGHT.snapshot()["Waves"]
+             if w.get("items") == 8 and w.get("rounds") == 62]
+    assert waves and all(w["rounds_padded"] == 2 for w in waves), waves[-3:]
+
+
+def test_served_mixed_drain_launches_the_programs_it_did():
+    """Three waves of eight on a fleet no other test builds: the flat
+    launch is keyed as it was (round bucket, padded nodes, one lane) and
+    compiles one fresh and one chained program, each once."""
+    from nomad_tpu.ops import engine, select
+    nodes = fleet(310, 71)
+    jobs = mixed_jobs("keys", 24)
+    seen0 = set(engine._KERNEL_SHAPES_SEEN)
+    sizes0 = (select.place_multi_packed_jit._cache_size(),
+              select.place_multi_chained_jit._cache_size())
+    s = cluster(nodes, eval_batch=8)
+    wave(s, jobs, [f"keys-{i:03d}" for i in range(24)])
+    snap = s.state.snapshot()
+    assert all(len(placed(snap, j)) == j.task_groups[0].count for j in jobs)
+    assert engine._KERNEL_SHAPES_SEEN - seen0 == {
+        ("multi", (64, 310, 1)), ("multi_chained", (64, 310, 1))}
+    assert (select.place_multi_packed_jit._cache_size() - sizes0[0],
+            select.place_multi_chained_jit._cache_size() - sizes0[1]) == (1, 1)
 
 
 # ------------------------------------------------ the benchmark's own files
