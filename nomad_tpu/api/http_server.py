@@ -15,6 +15,7 @@ carry `X-Nomad-Index`.
 from __future__ import annotations
 
 import base64
+import contextlib
 import json
 import threading
 import urllib.parse
@@ -1830,6 +1831,9 @@ class HTTPAPIServer:
     def __init__(self, agent, host: str = "127.0.0.1", port: int = 0) -> None:
         self.agent = agent
         router = Router(agent)
+        # here, not with this module: the HTTP-only CLI imports it and
+        # must never import jax, which core/ does
+        from nomad_tpu.core.telemetry import stamp_thread_cpu
 
         class Handler(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
@@ -1842,6 +1846,16 @@ class HTTPAPIServer:
 
             def log_message(self, *a):      # quiet
                 pass
+
+            def setup(self) -> None:
+                # socketserver names a connection's thread `Thread-N
+                # (process_request_thread)`; under this prefix it reads
+                # as the `http` role (core/profiling.py role_of) to the
+                # sampler and to the thread-CPU table alike
+                thread = threading.current_thread()
+                if not thread.name.startswith("http-api"):
+                    thread.name = "http-api-" + thread.name
+                super().setup()
 
             def _respond(self, status: int, payload: Any,
                          index: Optional[int] = None) -> None:
@@ -1878,6 +1892,13 @@ class HTTPAPIServer:
                 self.wfile.write(data)
 
             def _handle(self, method: str) -> None:
+                try:
+                    self._route(method)
+                finally:
+                    # this thread's CPU so far (core/telemetry.py)
+                    stamp_thread_cpu()
+
+            def _route(self, method: str) -> None:
                 parsed = urllib.parse.urlparse(self.path)
                 qs = urllib.parse.parse_qs(parsed.query)
                 if parsed.path in ("/", "/ui", "/ui/") and method == "GET":
@@ -1936,12 +1957,14 @@ class HTTPAPIServer:
                         self._respond(
                             500, {"Error": f"{type(e).__name__}: {e}"})
 
-            def _chunked_loop(self, pull, cleanup) -> None:
+            def _chunked_loop(self, pull, cleanup, send=None) -> None:
                 """Shared chunked-streaming scaffold for the event and
-                monitor streams.  `pull(timeout) -> (line_bytes|None,
-                ended)`; 10s idle heartbeats detect dead clients; a
-                graceful end terminates the chunked body; `cleanup` always
-                runs (including on pre-body write failures).  Heartbeat
+                monitor streams.  `pull(timeout) -> (item|None, ended)`;
+                an item is the line's bytes, or whatever `send(item,
+                chunk)` encodes and hands to `chunk` itself; 10s idle
+                heartbeats detect dead clients; a graceful end
+                terminates the chunked body; `cleanup` always runs
+                (including on pre-body write failures).  Heartbeat
                 pacing is an interval measurement on a real TCP
                 connection — perf_counter, the sanctioned raw
                 primitive, not the injected timebase."""
@@ -1965,7 +1988,10 @@ class HTTPAPIServer:
                             self.wfile.flush()
                             break
                         if line is not None:
-                            chunk(line)
+                            if send is None:
+                                chunk(line)
+                            else:
+                                send(line, chunk)
                             last_write = _time.perf_counter()
                         elif _time.perf_counter() - last_write > 10:
                             chunk(b"{}\n")   # idle: detect disconnects
@@ -1988,18 +2014,30 @@ class HTTPAPIServer:
                 sub = router.server.events.subscribe(
                     topics or None, from_index=from_index)
 
+                timers = getattr(router.server, "stage_timers", None)
+
                 def pull(timeout):
                     if sub.closed:
                         return None, True
                     ev = sub.next(timeout=timeout)
-                    if ev is None:
-                        return (None, sub.closed)
-                    return (json.dumps(
-                        {"Index": ev.index,
-                         "Events": [ev.wire()]}).encode() + b"\n", False)
+                    return ev, (ev is None and sub.closed)
+
+                def send(ev, chunk):
+                    # the "stream_send" stage (core/wavepipe.py): ONE
+                    # delivered event encoded and written, the wait for
+                    # it left out; the tail of every drain, on this
+                    # handler's thread and under the interpreter lock
+                    # the worker and the applier share
+                    with (timers.time("stream_send") if timers is not None
+                          else contextlib.nullcontext()):
+                        chunk(json.dumps(
+                            {"Index": ev.index,
+                             "Events": [ev.wire()]}).encode() + b"\n")
+                    stamp_thread_cpu()
 
                 self._chunked_loop(
-                    pull, lambda: router.server.events.unsubscribe(sub))
+                    pull, lambda: router.server.events.unsubscribe(sub),
+                    send)
 
             def _monitor(self, qs: Dict[str, List[str]]) -> None:
                 """Stream the structured log ring (reference: the

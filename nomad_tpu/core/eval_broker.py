@@ -14,6 +14,7 @@ server's tick loop supplies wall-clock time.
 
 from __future__ import annotations
 
+import contextlib
 import heapq
 import itertools
 import threading
@@ -131,10 +132,15 @@ class EvalBroker:
     # ------------------------------------------------------------ dequeue
 
     def dequeue(self, schedulers: List[str], now: float,
-                timeout: Optional[float] = None,
+                timeout: Optional[float] = None, stage=None,
                 ) -> Tuple[Optional[Evaluation], str]:
         """Pop the highest-priority ready eval for any of `schedulers`.
-        Returns (eval, token) or (None, "") on timeout/disabled."""
+        Returns (eval, token) or (None, "") on timeout/disabled.
+
+        `stage` (a worker's `dequeue` stage, core/wavepipe.py) is a
+        callable giving a context manager, entered only once an eval is
+        in hand: a span cannot be withdrawn, and a poll that times out
+        empty must leave none, so the wait itself stays outside it."""
         deadline = None if timeout is None else now + timeout
         with self._cv:
             while True:
@@ -143,7 +149,8 @@ class EvalBroker:
                 self._tick_locked(now)
                 ev = self._pop_ready_locked(schedulers)
                 if ev is not None:
-                    return ev, self._issue_locked(ev, now)
+                    with stage() if stage else contextlib.nullcontext():
+                        return ev, self._issue_locked(ev, now)
                 if timeout == 0.0 or (deadline is not None and now >= deadline):
                     return None, ""
                 if not self._cv.wait(timeout=0.05):
@@ -152,20 +159,27 @@ class EvalBroker:
                     now += 0.001
 
     def dequeue_batch(self, schedulers: List[str], max_n: int, now: float,
-                      timeout: Optional[float] = None,
+                      timeout: Optional[float] = None, stage=None,
                       ) -> List[Tuple[Evaluation, str]]:
         """Pop up to `max_n` ready evals (each with its own token) for a
         single batched worker pass.  Blocks like dequeue() for the FIRST
         eval; the rest are taken only if immediately ready — a batch
         never waits for stragglers.  Per-job serialization holds across
-        the batch (distinct jobs by construction)."""
+        the batch (distinct jobs by construction).  `stage` as in
+        dequeue(): entered with the first eval in hand, round the rest."""
         out: List[Tuple[Evaluation, str]] = []
         ev, token = self.dequeue(schedulers, now, timeout)
         if ev is None:
             return out
         out.append((ev, token))
+        with stage() if stage else contextlib.nullcontext():
+            self._fill_batch(out, schedulers, max_n, now)
+        return out
+
+    def _fill_batch(self, out: List[Tuple[Evaluation, str]],
+                    schedulers: List[str], max_n: int, now: float) -> None:
         part = self.partition_of
-        want_key = part(ev) if part is not None else None
+        want_key = part(out[0][0]) if part is not None else None
         with self._cv:
             self._tick_locked(now)     # expired redeliveries join the batch
             skipped: List[Evaluation] = []
@@ -183,7 +197,6 @@ class EvalBroker:
                 heapq.heappush(heap, (-ev2.priority, next(self._seq), ev2))
             if skipped:
                 self._cv.notify()
-        return out
 
     def token_valid(self, eval_id: str, token: str) -> bool:
         """Is `token` the CURRENT delivery of `eval_id`?  The plan

@@ -29,6 +29,7 @@ from nomad_tpu.core.telemetry import (
     TRACER,
     StatCounters,
     span_id,
+    stamp_thread_cpu,
 )
 from nomad_tpu.state import StateStore
 from nomad_tpu.structs import (
@@ -300,6 +301,9 @@ class PlanApplier:
                           error=type(pending.error).__name__
                           if pending.error is not None else "",
                           refuted=refuted)
+        # this thread's CPU so far, for the worker's `nomad.cpu` markers
+        # and /v1/metrics (core/telemetry.py): one clock read a plan
+        stamp_thread_cpu()
 
     def _stage(self, name: str):
         """One per-plan stage interval (no wave), where timers are wired."""

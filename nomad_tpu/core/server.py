@@ -87,6 +87,8 @@ class Server:
         # agents of one simulated cluster share a clock already, so
         # last-write-wins is benign.
         obsbus.OBSBUS.configure(self.clock)
+        # the collector as a span and on /v1/metrics; once a process
+        telemetry.install_gc_hook()
         # cluster-scope metric federation (core/federation.py): the
         # Agent wires a FederationPuller here in cluster mode; the tick
         # loop drives it as a leader duty (None on standalone servers

@@ -26,7 +26,9 @@ would break that determinism.
 from __future__ import annotations
 
 import bisect
+import gc
 import threading
+import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
@@ -45,9 +47,144 @@ LabelKey = Tuple[str, Tuple[Tuple[str, str], ...]]
 
 def _module_counters() -> Dict[LabelKey, float]:
     """Counters kept as plain module ints by code that may not import
-    core/ (structs/ sits below it): read here, at scrape time."""
+    core/ (structs/ sits below it), and the process runtime's below
+    (thread CPU, the collector), which nothing on a hot path may lock:
+    read here, at scrape time."""
     from nomad_tpu.structs.structs import ID_STATS
-    return {(f"nomad.ids.{k}", ()): v for k, v in ID_STATS.items()}
+    out: Dict[LabelKey, float] = {
+        (f"nomad.ids.{k}", ()): v for k, v in ID_STATS.items()}
+    for role, cpu_s in thread_cpu_by_role().items():
+        out[("nomad.runtime.thread_cpu_s", (("role", role),))] = cpu_s
+    for gen in range(3):
+        label = (("generation", str(gen)),)
+        out[("nomad.runtime.gc_pause_s", label)] = _gc_pause_s[gen]
+        out[("nomad.runtime.gc_collections", label)] = _gc_collections[gen]
+    return out
+
+
+# ------------------------------------------------- the process runtime
+#
+# Which thread fills the interpreter.  Under one interpreter lock a
+# stage's wall holds other threads' turns; the CPU seconds of each
+# thread do not.  Every thread stamps its OWN `time.thread_time()` here
+# at a boundary it already passes (the worker at the two `nomad.cpu`
+# markers of a pass, core/wavepipe.py; the applier after a plan; an HTTP
+# handler after a request or a streamed event): a plain write to the
+# thread's own slot, no lock.  Nobody reads another thread's clock
+# (`pthread_getcpuclockid` on the ident of a thread that has exited is
+# undefined behaviour).  Readers sum the slots by role; a thread that
+# has exited leaves its last reading in its role's remainder, so the
+# table stays as small as the live threads and a role's total never
+# falls (worker and applier are new threads every scheduling round).
+
+_cpu_lock = threading.Lock()           # opens, folds, sums; never a stamp
+# [thread, role, cpu ns, not read again before (perf_counter s)], one a
+# thread.  Whole nanoseconds: sums of floats in another order (a slot
+# moves to the remainder when its thread exits) differ in the last digit,
+# and a role's total must never fall
+_cpu_slots: List[list] = []
+_retired_cpu: Dict[str, int] = {}      # role -> cpu ns of exited threads
+_cpu_slot = threading.local()          # .own: the calling thread's slot
+
+# A thread reads its CPU clock at most once in this many seconds of
+# wall: the chip host's thread clock ticks at 10 ms and ONE READ OF IT
+# COSTS 6 us there (a system call; `perf_counter` 0.07 us: my chip run,
+# PR 34), so a read a plan and a read a streamed event, 160 a pass, were
+# 1 ms of every pass for readings that move once in 10 ms.  A reading is
+# then at most a tick old, which is what the clock resolves anyway.
+_CPU_READ_EVERY_S = 0.010
+
+
+def stamp_thread_cpu(fresh: bool = False) -> None:
+    """Record the calling thread's CPU seconds so far under its role
+    (core/profiling.py role_of).  `fresh` reads the clock whatever the
+    last reading's age: the worker's markers, two a pass, so a pass
+    shorter than a tick is not read as nothing."""
+    try:
+        slot = _cpu_slot.own
+    except AttributeError:
+        slot = _open_cpu_slot()
+    now = time.perf_counter()
+    if fresh or now >= slot[3]:
+        slot[2] = time.thread_time_ns()
+        slot[3] = now + _CPU_READ_EVERY_S
+
+
+def _open_cpu_slot() -> list:
+    """A thread's first stamp: its slot, found again through the
+    thread's own storage (an ident would be handed on to a later
+    thread)."""
+    from nomad_tpu.core.profiling import role_of
+    me = threading.current_thread()
+    slot = _cpu_slot.own = [me, role_of(me.name), 0, 0.0]
+    with _cpu_lock:
+        _cpu_slots.append(slot)
+    return slot
+
+
+def thread_cpu_by_role() -> Dict[str, float]:
+    """Cumulative CPU seconds of the process's Python threads by role,
+    as each last stamped itself, exited threads included."""
+    with _cpu_lock:
+        total = dict(_retired_cpu)
+        live = []
+        for slot in _cpu_slots:
+            alive = slot[0].is_alive()     # before the reading: if not,
+            role, cpu_ns = slot[1], slot[2]  # the reading is its last
+            total[role] = total.get(role, 0) + cpu_ns
+            if alive:
+                live.append(slot)
+            else:
+                _retired_cpu[role] = _retired_cpu.get(role, 0) + cpu_ns
+        _cpu_slots[:] = live
+    return {role: cpu_ns * 1e-9 for role, cpu_ns in total.items()}
+
+
+# The collector, from inside the program (upstream publishes
+# nomad.runtime.gc_pause_ns).  A collection can begin at ANY allocation,
+# also while this very thread holds the registry's or the stage timers'
+# non-reentrant lock, so the hook takes no lock and calls nothing that
+# does: plain adds on these slots (collections do not nest, and both
+# phases run on the thread that triggered the collection), folded into
+# the registry's output at scrape time, and the profiler's annotation
+# directly.  A collection of generation 1 or 2 is a `nomad.gc` span on
+# that thread's line of a profiler trace, inside whatever stage it
+# stretched; the youngest generation's are microseconds each, tens
+# a pass, and get no span.
+
+_gc_pause_s = [0.0, 0.0, 0.0]          # by generation
+_gc_collections = [0, 0, 0]
+_gc_open: list = [0.0, None]           # start stamp, open span
+_gc_annotation = None                  # jax's TraceAnnotation, once hooked
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    gen = info["generation"]
+    if phase == "start":
+        if gen:
+            span = _gc_open[1] = _gc_annotation("nomad.gc", generation=gen)
+            span.__enter__()
+        _gc_open[0] = time.perf_counter()
+        return
+    pause = time.perf_counter() - _gc_open[0]
+    span = _gc_open[1]
+    if span is not None:
+        _gc_open[1] = None
+        span.__exit__(None, None, None)
+    _gc_pause_s[gen] += pause
+    _gc_collections[gen] += 1
+
+
+def install_gc_hook() -> None:
+    """Hook the collector, once a process (every Server calls this).
+    jax is imported here and not with this module, and never from
+    inside a collection."""
+    global _gc_annotation
+    if _on_gc in gc.callbacks:
+        return
+    from jax.profiler import TraceAnnotation
+    _gc_annotation = TraceAnnotation
+    gc.callbacks.append(_on_gc)
 
 
 def _key(name: str, labels: Dict[str, str]) -> LabelKey:

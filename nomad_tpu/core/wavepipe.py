@@ -51,12 +51,19 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 from nomad_tpu.core import profiling
 from nomad_tpu.core.flightrec import FLIGHT
-from nomad_tpu.core.telemetry import REGISTRY
+from nomad_tpu.core.telemetry import (
+    REGISTRY,
+    stamp_thread_cpu,
+    thread_cpu_by_role,
+)
 
 # stage names, in pipeline order.  Every stage but "device" is the wall
 # of work (or of a wait) on ONE thread, recorded through
 # `StageTimers.time`, which also emits it to the profiler as
 # `nomad.<stage>`:
+#   dequeue      worker: the wait in the broker's dequeue that returned
+#                work, BEFORE a pass and outside it (the head of every
+#                drain; an empty poll records nothing)
 #   pass         worker: one batch, from the batch in hand to return
 #                (encloses the prefetched successor's prepare+dispatch);
 #                the parent of every worker stage below
@@ -84,21 +91,37 @@ from nomad_tpu.core.telemetry import REGISTRY
 #                for one eval's picks (scheduler/device.py carve_block);
 #                lies INSIDE that eval's materialize
 #   plan_wait    worker: blocked on the applier's verdict for one plan
+#   finalize     worker: a wave's eval from the verdict in hand to done
+#                (GenericScheduler.finalize_batched after its wait: the
+#                carve ledger's settle, the full-commit check, the repair
+#                or retry branch, the eval's completion)
+#   batch_admin  worker: a batch's bookkeeping that no other stage covers
+#                (Worker._finish_batch: delivery deadlines restarted,
+#                the chain's state, the prefetch's dequeue, the chain
+#                parked); two or three a pass
 #   eval_update  worker: the eval status write
 #   ack          worker: per-eval records + broker ack/nack (Worker.
 #                _settle); one per eval on every path
 #   commit       applier: evaluate + state-store upsert of one plan
 #   store_upsert applier: the upsert alone (inside commit)
+#   stream_send  an HTTP handler: one event of /v1/event/stream encoded
+#                and written to its follower (api/http_server.py), the
+#                wait for the event left out
 # Worker stages other than "pass" do not nest in one another, but for
 # device_carve (and the solo device path's redo), each inside a
 # materialize, and spread_lower, inside a dispatch: the unnamed part of a
 # pass is its wall minus their UNION (benchmark/host_spans.py
 # View.named), which a nested span leaves as it was; a per-stage sum must
 # leave device_carve and spread_lower out or count them twice.
-STAGES = ("pass", "prepare", "dispatch", "spread_lower", "device",
+#
+# Two more names reach the profiler's trace and are no stage (nothing
+# records them here): `nomad.gc`, a collection of generation 1 or 2 on
+# the line of the thread it struck, inside whatever stage it stretched
+# (core/telemetry.py), and `nomad.cpu`, the marker below.
+STAGES = ("dequeue", "pass", "prepare", "dispatch", "spread_lower", "device",
           "device_wait", "d2h", "solo_place", "system_place", "materialize",
-          "device_carve", "plan_wait", "eval_update", "ack", "commit",
-          "store_upsert")
+          "device_carve", "plan_wait", "finalize", "batch_admin",
+          "eval_update", "ack", "commit", "store_upsert", "stream_send")
 
 _SERIES = {s: f"nomad.wavepipe.{s}_s" for s in STAGES}
 
@@ -112,18 +135,40 @@ _RING = 4096
 _WAVE_SEQ = itertools.count(1)
 
 
-_trace_annotation = None
+_trace_annotation = None     # jax.profiler.TraceAnnotation, at its first use
 
 
-def _annotation(stage: str, wave: int):
-    """The profiler span of one stage interval.  jax is imported at the
-    first use, not with this module; with no profiler session open an
-    annotation costs under a microsecond."""
+def _profiler():
+    """jax's TraceAnnotation; jax is imported at the first use, not with
+    this module."""
     global _trace_annotation
     if _trace_annotation is None:
         from jax.profiler import TraceAnnotation
         _trace_annotation = TraceAnnotation
-    return _trace_annotation("nomad." + stage, wave=wave)
+    return _trace_annotation
+
+
+def mark_cpu(wave: int = -1) -> None:
+    """A `nomad.cpu` marker on the calling thread's line of the trace:
+    the cumulative CPU microseconds of the process's Python threads by
+    role (core/telemetry.py: each thread's own stamps, summed here) and
+    of the whole process (`process_us`: every thread, XLA's and the
+    profiler's too; no sum of roles can pass it).  The worker leaves one
+    immediately before a pass opens and one immediately after it closes,
+    so a pass's interpreter budget is the difference of its two markers:
+    of its wall so much the worker's own CPU, so much the applier's, so
+    much the HTTP handlers'.  Where they sum to less than the wall the
+    rest is time in which no Python thread ran (the device, a socket, a
+    condition wait); where to more, threads ran beside one another
+    outside the interpreter lock (system calls, native code)."""
+    stamp_thread_cpu(fresh=True)
+    cpu = thread_cpu_by_role()
+    named = {role + "_us": int(cpu.pop(role, 0.0) * 1e6)
+             for role in ("worker", "applier", "http")}
+    with _profiler()("nomad.cpu", wave=wave,
+                     other_us=int(sum(cpu.values()) * 1e6),
+                     process_us=int(time.process_time() * 1e6), **named):
+        pass
 
 
 def _merged(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -149,12 +194,21 @@ class _Timed:
         self._timers, self._stage, self._wave = timers, stage, wave
 
     def __enter__(self) -> None:
-        self._span = _annotation(self._stage, self._wave)
-        self._t0 = time.perf_counter()
-        self._span.__enter__()
+        # the profiler span of the interval.  With no profiler session
+        # open (every run but a traced one) none is built: the test
+        # costs a twentieth of the annotation it saves
+        profiler = _trace_annotation or _profiler()
+        if profiler.is_enabled():
+            self._span = profiler("nomad." + self._stage, wave=self._wave)
+            self._t0 = time.perf_counter()
+            self._span.__enter__()
+        else:
+            self._span = None
+            self._t0 = time.perf_counter()
 
     def __exit__(self, *exc) -> None:
-        self._span.__exit__(*exc)
+        if self._span is not None:
+            self._span.__exit__(*exc)
         self._timers.record(self._stage, self._t0, time.perf_counter(),
                             self._wave)
 
@@ -172,20 +226,19 @@ class StageTimers:
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self._acc: Dict[str, float] = {}
-        self._cnt: Dict[str, int] = {}
-        # stage -> deque of (wave, t0, t1) in perf_counter seconds
-        self._ring: Dict[str, deque] = {}
+        # stage -> [seconds, count, deque of (wave, t0, t1) in
+        # perf_counter seconds]: one lookup an interval
+        self._slots: Dict[str, list] = {}
 
     def record(self, stage: str, t0: float, t1: float,
                wave: int = -1) -> None:
         with self._lock:
-            self._acc[stage] = self._acc.get(stage, 0.0) + (t1 - t0)
-            self._cnt[stage] = self._cnt.get(stage, 0) + 1
-            ring = self._ring.get(stage)
-            if ring is None:
-                self._ring[stage] = ring = deque(maxlen=_RING)
-            ring.append((wave, t0, t1))
+            slot = self._slots.get(stage)
+            if slot is None:
+                slot = self._slots[stage] = [0.0, 0, deque(maxlen=_RING)]
+            slot[0] += t1 - t0
+            slot[1] += 1
+            slot[2].append((wave, t0, t1))
         # an interval that carries no wave is a per-plan or per-eval
         # stage (plan_wait, ack, store_upsert: 64 of each a wave) or
         # the solo path's: its total and count above reach /v1/metrics
@@ -215,22 +268,21 @@ class StageTimers:
 
     def totals(self) -> Dict[str, float]:
         with self._lock:
-            return dict(self._acc)
+            return {stage: slot[0] for stage, slot in self._slots.items()}
 
     def counts(self) -> Dict[str, int]:
         with self._lock:
-            return dict(self._cnt)
+            return {stage: slot[1] for stage, slot in self._slots.items()}
 
     def intervals(self, stage: str) -> List[Tuple[int, float, float]]:
         with self._lock:
-            return list(self._ring.get(stage, ()))
+            slot = self._slots.get(stage)
+            return list(slot[2]) if slot is not None else []
 
     def overlap(self, a: str, b: str) -> float:
         """Seconds stages `a` and `b` were simultaneously in flight."""
-        with self._lock:
-            ia = [(t0, t1) for _, t0, t1 in self._ring.get(a, ())]
-            ib = [(t0, t1) for _, t0, t1 in self._ring.get(b, ())]
-        ma, mb = _merged(ia), _merged(ib)
+        ma, mb = (_merged([(t0, t1) for _, t0, t1 in self.intervals(s)])
+                  for s in (a, b))
         total = 0.0
         i = j = 0
         while i < len(ma) and j < len(mb):
@@ -258,9 +310,7 @@ class StageTimers:
 
     def reset(self) -> None:
         with self._lock:
-            self._acc.clear()
-            self._cnt.clear()
-            self._ring.clear()
+            self._slots.clear()
 
 
 @dataclass

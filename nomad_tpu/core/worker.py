@@ -20,7 +20,7 @@ from nomad_tpu.core.telemetry import (
     StatCounters,
     span_id,
 )
-from nomad_tpu.core.wavepipe import WavePipeline
+from nomad_tpu.core.wavepipe import WavePipeline, mark_cpu
 from nomad_tpu.ops import PlacementEngine
 from nomad_tpu.scheduler import new_scheduler
 from nomad_tpu.structs import Evaluation, Plan, PlanResult, new_id
@@ -150,20 +150,25 @@ class Worker:
         # time (a busy dequeue returns in microseconds; its share of
         # samples is negligible)
         with profiling.activity("idle"):
-            evaluation, token = broker.dequeue(self.served, now=t,
-                                               timeout=timeout)
+            evaluation, token = broker.dequeue(
+                self.served, now=t, timeout=timeout,
+                stage=self._dequeue_stage)
         if evaluation is None:
             return 0
         self._eval_token = token
         self._batch_tokens = {evaluation.id: token}
         self._batch_trace = {evaluation.id: evaluation.trace_id}
         self._sched_t0[evaluation.id] = TRACER.clock.monotonic()
+        # the pass's interpreter budget: the threads' CPU by role at
+        # both its ends (core/wavepipe.py mark_cpu)
+        mark_cpu()
         with self.stage("pass"):
             try:
                 err = self._invoke(evaluation, t)
             except Exception as e:  # noqa: BLE001 - a scheduler bug must
                 err = e             # nack, not kill the worker thread
             self._settle(evaluation, token, err, t)
+        mark_cpu()
         return 1
 
     def stage(self, name: str, wave: int = -1):
@@ -172,6 +177,12 @@ class Worker:
         StageTimers, emitted to the profiler).  Schedulers reach it
         through the Planner seam for the work they do on this thread."""
         return self.pipeline.timers.time(name, wave)
+
+    def _dequeue_stage(self):
+        """The "dequeue" stage, handed to the broker's dequeue BEFORE a
+        pass: the broker enters it once work is in hand, so an empty
+        poll records nothing."""
+        return self.stage("dequeue")
 
     def _settle(self, evaluation: Evaluation, token: str,
                 err: Optional[Exception], t: float) -> None:
@@ -240,32 +251,43 @@ class Worker:
         self._prefetch = None
         if pf is None:
             with profiling.activity("idle"):   # see run_once's marker
-                batch = broker.dequeue_batch(self.served, max_n,
-                                             now=t, timeout=timeout)
+                batch = broker.dequeue_batch(
+                    self.served, max_n, now=t, timeout=timeout,
+                    stage=self._dequeue_stage)
             if not batch:
                 return 0
         else:
             batch = pf["batch"]
         settled: set = set()
-        # "pass": the wall of one batch, the dequeue's wait left out; it
-        # encloses the prefetched successor's prepare + dispatch
-        with self.stage("pass"):
-            try:
-                if pf is None:
-                    pf = self._start_batch(batch, t)
-                return self._finish_batch(pf, t, settled, max_n)
-            except Exception as e:  # noqa: BLE001 - the solo path nacks
-                # on any failure; the batched path must give every
-                # dequeued eval the same guarantee or a single bad
-                # snapshot kills the worker thread with the whole
-                # batch's tokens outstanding
-                log("worker", "error",
-                    "batch pass failed; nacking remainder",
-                    worker=self.id, error=repr(e))
-                for ev, token in batch:
-                    if ev.id not in settled:
-                        self._settle(ev, token, e, t)
-                return len(batch)
+        # the pass's interpreter budget (see run_once); a prefetched
+        # batch knows its wave before the pass opens
+        wave = (pf["pending"].wave
+                if pf is not None and pf["pending"] is not None else -1)
+        mark_cpu(wave)
+        try:
+            # "pass": the wall of one batch, the dequeue left out; it
+            # encloses the prefetched successor's prepare + dispatch
+            with self.stage("pass"):
+                try:
+                    if pf is None:
+                        pf = self._start_batch(batch, t)
+                        if pf["pending"] is not None:
+                            wave = pf["pending"].wave
+                    return self._finish_batch(pf, t, settled, max_n)
+                except Exception as e:  # noqa: BLE001 - the solo path
+                    # nacks on any failure; the batched path must give
+                    # every dequeued eval the same guarantee or a single
+                    # bad snapshot kills the worker thread with the whole
+                    # batch's tokens outstanding
+                    log("worker", "error",
+                        "batch pass failed; nacking remainder",
+                        worker=self.id, error=repr(e))
+                    for ev, token in batch:
+                        if ev.id not in settled:
+                            self._settle(ev, token, e, t)
+                    return len(batch)
+        finally:
+            mark_cpu(wave)
 
     def _start_batch(self, batch, t: float, chain=None):
         """Phases 1-2: snapshot, per-eval reconcile, and the (async)
@@ -391,55 +413,68 @@ class Worker:
         work = pf["work"]
         batch_id = pf["batch_id"]
         batch_seq0 = pf["batch_seq0"]
-        self._snapshot = pf["snapshot"]
-        self._snapshot_seq = batch_seq0
-        # a prefetched batch's schedulers were built with the PREVIOUS
-        # call's clock; eval updates (and their delayed follow-ups) must
-        # use that same clock, not this call's
-        self._now = pf["t"]
-        # the prefetched evals sat out the previous batch's host phase;
-        # restart their delivery deadlines so a long phase cannot expire
-        # them into redelivery while this worker is mid-processing
-        self.server.eval_broker.extend_outstanding(
-            [(ev.id, token) for ev, token in pf["batch"]], now=t)
-        self._batch_tokens = {ev.id: token for ev, token in pf["batch"]}
-        self._batch_trace = {ev.id: ev.trace_id for ev, _ in pf["batch"]}
+        pending = pf["pending"]
+        wave = pending.wave if pending is not None else -1
+        broker = self.server.eval_broker
+        # "batch_admin": the batch's bookkeeping that no other stage
+        # covers, here, round the prefetch's dequeue and at the end
+        with self.stage("batch_admin", wave):
+            self._snapshot = pf["snapshot"]
+            self._snapshot_seq = batch_seq0
+            # a prefetched batch's schedulers were built with the
+            # PREVIOUS call's clock; eval updates (and their delayed
+            # follow-ups) must use that same clock, not this call's
+            self._now = pf["t"]
+            # the prefetched evals sat out the previous batch's host
+            # phase; restart their delivery deadlines so a long phase
+            # cannot expire them into redelivery while this worker is
+            # mid-processing
+            broker.extend_outstanding(
+                [(ev.id, token) for ev, token in pf["batch"]], now=t)
+            self._batch_tokens = {ev.id: token for ev, token in pf["batch"]}
+            self._batch_trace = {ev.id: ev.trace_id
+                                 for ev, _ in pf["batch"]}
+        decisions = (self.pipeline.collect(pending)
+                     if pending is not None else None)
         bds = {}
-        if pf["pending"] is not None:
-            decisions = self.pipeline.collect(pf["pending"])
-            # the collect may have sat in a first-time device compile for
-            # longer than the redelivery deadline: restart the batch's
-            # deadlines so the HOST phase doesn't run superseded (plans
-            # from a superseded delivery are rejected at the applier)
-            self.server.eval_broker.extend_outstanding(
-                [(ev.id, token) for ev, token in pf["batch"]],
-                now=self.server.clock.time())
-            bds = {i: d for i, d in zip(pf["prepared_idx"], decisions)}
+        nxt = None
+        with self.stage("batch_admin", wave):
+            if pending is not None:
+                # the collect may have sat in a first-time device compile
+                # for longer than the redelivery deadline: restart the
+                # batch's deadlines so the HOST phase doesn't run
+                # superseded (plans from a superseded delivery are
+                # rejected at the applier)
+                broker.extend_outstanding(
+                    [(ev.id, token) for ev, token in pf["batch"]],
+                    now=self.server.clock.time())
+                bds = {i: d for i, d in zip(pf["prepared_idx"], decisions)}
 
-        # cross-batch prefetch: with this batch fully coupled and more
-        # evals ready, dispatch the next launch NOW so the device works
-        # through it while this thread runs phase 3.  Chained decisions
-        # start from this batch's proposed usage — a superset of what
-        # will commit, so they can under-pack but never oversubscribe.
-        chain_used = self.pipeline.chain_state(pf["pending"])
-        chain_ok = (chain_used is not None and bds
-                    and len(bds) == len(work))
+            # cross-batch prefetch: with this batch fully coupled and
+            # more evals ready, dispatch the next launch NOW so the
+            # device works through it while this thread runs phase 3.
+            # Chained decisions start from this batch's proposed usage —
+            # a superset of what will commit, so they can under-pack but
+            # never oversubscribe.
+            chain_used = self.pipeline.chain_state(pending)
+            chain_ok = (chain_used is not None and bds
+                        and len(bds) == len(work))
+            if chain_ok and not self._stop.is_set():
+                nxt = broker.dequeue_batch(self.served, max_n, now=t,
+                                           timeout=0.0)
         chain_handed_off = False
-        if chain_ok and not self._stop.is_set():
-            nxt = self.server.eval_broker.dequeue_batch(
-                self.served, max_n, now=t, timeout=0.0)
-            if nxt:
-                # the chain buffer is DONATED to the prefetched launch
-                # (alive or failed) — it must not also be retained below
-                chain_handed_off = True
-                try:
-                    self._prefetch = self._start_batch(
-                        nxt, t, chain=(batch_id, batch_seq0, chain_used))
-                except Exception as e:  # noqa: BLE001 - hand them back
-                    log("worker", "error", "prefetch dispatch failed",
-                        worker=self.id, error=repr(e))
-                    for ev, token in nxt:
-                        self.server.eval_broker.nack(ev.id, token, now=t)
+        if nxt:
+            # the chain buffer is DONATED to the prefetched launch
+            # (alive or failed) — it must not also be retained below
+            chain_handed_off = True
+            try:
+                self._prefetch = self._start_batch(
+                    nxt, t, chain=(batch_id, batch_seq0, chain_used))
+            except Exception as e:  # noqa: BLE001 - hand them back
+                log("worker", "error", "prefetch dispatch failed",
+                    worker=self.id, error=repr(e))
+                for ev, token in nxt:
+                    broker.nack(ev.id, token, now=t)
 
         # phase 3: coupled plans FIRST — a solo eval's commit is a
         # placement write the batch snapshot never saw, which would break
@@ -463,10 +498,10 @@ class Worker:
         # and the resident chain included — instead of demoting to
         # per-alloc materialize.
         shared_net: Dict[str, object] = {}
-
-        wave = pf["pending"].wave if pf["pending"] is not None else -1
+        port_rows = 0       # carved columnar by this batch's materializes
 
         def submit(i):
+            nonlocal port_rows
             ev, token, sched, prep = work[i]
             try:
                 sched.last_port_carve = 0
@@ -477,8 +512,7 @@ class Worker:
                         coupled_batch=(batch_id, batch_seq0),
                         net_index_cache=shared_net,
                         device_ledger=self.pipeline.device_ledger)
-                self.pipeline.note_ports_batched(sched.last_port_carve,
-                                                 wave)
+                port_rows += sched.last_port_carve
             except Exception as e:  # noqa: BLE001 - finalize pass nacks
                 handles[i] = e
 
@@ -543,8 +577,13 @@ class Worker:
         # Only after the coupled plans committed (the finalize passes
         # above waited on the applier) — their commits carry the chain's
         # own origin and must not read as foreign invalidations.
-        if chain_ok and not chain_handed_off:
-            self.pipeline.retain_chain(batch_id, batch_seq0, chain_used)
+        park = chain_ok and not chain_handed_off
+        if park or port_rows:
+            with self.stage("batch_admin", wave):
+                self.pipeline.note_ports_batched(port_rows, wave)
+                if park:
+                    self.pipeline.retain_chain(batch_id, batch_seq0,
+                                               chain_used)
         return len(work)
 
     def _invoke(self, evaluation: Evaluation, now: float) -> Optional[Exception]:

@@ -186,11 +186,14 @@ class _BrokerProxy:
         self._idx = idx
         self._pause_acked = False
 
-    def dequeue(self, schedulers, now, timeout=None):
+    def dequeue(self, schedulers, now, timeout=None, stage=None):
         batch = self.dequeue_batch(schedulers, 1, now, timeout=timeout)
         return (batch[0][0], batch[0][1]) if batch else (None, "")
 
-    def dequeue_batch(self, schedulers, max_n, now, timeout=None):
+    def dequeue_batch(self, schedulers, max_n, now, timeout=None,
+                      stage=None):
+        # `stage` (the thread worker's `dequeue` stage) names nothing
+        # here: the pops are the parent's, on its attendant thread
         if not self._run_evt.is_set():
             # paused (or not yet resumed).  The prefetch dequeue passes
             # timeout=0.0 mid-batch — only the TOP-of-loop dequeue acks,

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import zlib
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from nomad_tpu.chaos.clock import SystemClock
 from nomad_tpu.ops import PlacementEngine, PlacementRequest
@@ -417,10 +417,26 @@ class GenericScheduler(Scheduler):
             wait = getattr(self.planner, "wait_plan", None)
             result, err = wait(pending) if wait else pending.wait()
             refreshed_state = None
+        # the "finalize" stage (core/wavepipe.py): from the verdict in
+        # hand to the eval done, named through the planner as the wait
+        # is.  The retry loop runs outside it: its stages are the solo
+        # path's own and must not nest in this one
+        stage = getattr(self.planner, "stage", None)
+        with stage("finalize") if stage else contextlib.nullcontext():
+            retry, err = self._after_verdict(evaluation, plan, result, err,
+                                             refreshed_state, pipeline)
+        return self.process(evaluation) if retry else err
+
+    def _after_verdict(self, evaluation: Evaluation, plan: Plan, result,
+                       err, refreshed_state, pipeline
+                       ) -> Tuple[bool, Optional[Exception]]:
+        """finalize_batched past its wait.  Returns (retry, error):
+        `retry` asks the caller for the normal retry loop on the state
+        this left in `self.state`."""
         if err is not None:
             self._settle_carve(None)
             self._update_eval_status(evaluation, "failed", str(err))
-            return err
+            return False, err
         refuted = list(result.refuted_nodes) if result is not None else []
         self._settle_carve(result.alloc_index if result is not None
                            else None, refuted)
@@ -434,7 +450,7 @@ class GenericScheduler(Scheduler):
                         and plan.alloc_blocks
                         and not plan.node_allocation
                         and evaluation.triggered_by != TRIGGER_PLAN_REFUTE):
-                    return self._repair_refuted(
+                    return False, self._repair_refuted(
                         evaluation, plan, refuted + list(short_nodes),
                         expected - actual + short, pipeline)
                 # partial commit: some nodes were refuted against newer
@@ -446,9 +462,9 @@ class GenericScheduler(Scheduler):
                     refreshed_state = refresh() if refresh else None
                 if refreshed_state is not None:
                     self.state = refreshed_state
-                return self.process(evaluation)
+                return True, None
         self._finalize(evaluation)
-        return None
+        return False, None
 
     def _settle_carve(self, commit_index, refuted_nodes=()) -> None:
         """Close this eval's record in the carve ledger: committed at

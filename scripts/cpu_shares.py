@@ -12,15 +12,27 @@ whichever function was running.)
 Builds a configuration's fleet and jobs with the benchmark's loaders (as
 scripts/gpu_ids_read.py), wraps each `module:attr.path` given (default: the
 wave's pieces), runs N drain cycles through the served path and prints per
-cycle, for each name: calls, CPU ms, wall ms, CPU us a call.
-`Worker.run_batch` and `PlanApplier.apply_one` are the two threads' totals,
-the rest inclusive parts of them.  A name imported with `from x import f`
-is wrapped where it is LOOKED UP (`nomad_tpu.scheduler.generic:new_ids`).
+cycle, for each name: calls, CPU ms, wall ms, CPU us a call.  A name
+imported with `from x import f` is wrapped where it is LOOKED UP
+(`nomad_tpu.scheduler.generic:new_ids`).
 
     chiprun -- env PYTHONPATH=. python3 scripts/cpu_shares.py csi50k drain384
 
-No cell runs it.  On a CPU host: shares of interpreter work, never a speed.
-The chip host's thread clock ticks at 10 ms: read sums there, not calls."""
+What it is for: the PER-FUNCTION rows, inclusive parts of a thread's work
+that nothing else reads.  The two threads' totals (`Worker.run_batch`,
+`PlanApplier.apply_one`) the program now keeps itself, with nothing
+wrapped from outside: each thread stamps its own `time.thread_time()`
+(nomad_tpu/core/telemetry.py), `/v1/metrics` serves the sums as
+`nomad.runtime.thread_cpu_s{role=...}`, and a traced run of any cell
+reads them per pass from the worker's `nomad.cpu` markers
+(`lock.worker_cpu_ms_per_pass`, `lock.applier_cpu_ms_per_pass`,
+`lock.held_share`: `python3 -m benchmark.span_cells --workload <cell>
+...`).  The two rows stay in DEFAULT as the whole the parts are read
+against.
+
+On a CPU host: shares of interpreter work, never a speed.  The chip
+host's thread clock ticks coarsely (PERF.md section 3): read sums there,
+not calls."""
 import argparse
 import http.client
 import importlib

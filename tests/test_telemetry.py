@@ -432,3 +432,269 @@ class TestCheapScrape:
         assert counts["nodes"] == 1
         assert counts["jobs"] == 1
         assert counts["evals"] >= 1
+
+
+# ------------------------------------------------- the process runtime
+
+
+class TestGcHook:
+    """The collector from inside the program (core/telemetry.py): a
+    span per collection of generation 1 or 2, plain accumulators every
+    generation, no lock anywhere in the hook."""
+
+    def test_two_servers_install_it_once(self):
+        import gc
+        from nomad_tpu.core import telemetry
+        Server(num_workers=1)
+        Server(num_workers=1)
+        assert gc.callbacks.count(telemetry._on_gc) == 1
+
+    def test_forced_collections_counted_by_generation(self):
+        import gc
+        from nomad_tpu.core import telemetry
+        telemetry.install_gc_hook()
+        before = (list(telemetry._gc_collections),
+                  list(telemetry._gc_pause_s))
+        for generation in (0, 1, 2, 2):
+            gc.collect(generation)
+        n = [b - a for a, b in zip(before[0], telemetry._gc_collections)]
+        s = [b - a for a, b in zip(before[1], telemetry._gc_pause_s)]
+        # the forced ones, and whatever an allocation in between (this
+        # thread's or another's) may have set off
+        assert n[2] >= 2 and n[1] >= 1 and n[0] >= 1
+        assert all(x > 0.0 for x in s)
+        assert telemetry._gc_open[1] is None       # no span left open
+        flat = REGISTRY.snapshot()["counters"]
+        for generation in "012":
+            assert flat[f"nomad.runtime.gc_collections{{generation="
+                        f"{generation}}}"] >= 1
+            assert flat[f"nomad.runtime.gc_pause_s{{generation="
+                        f"{generation}}}"] > 0.0
+
+    def test_span_for_generations_1_and_2_none_for_0(self, tmp_path):
+        import gc
+        import jax
+        from benchmark import span_args
+        from benchmark import trace_reduce as tr
+        from nomad_tpu.core import telemetry
+        telemetry.install_gc_hook()
+        gc.collect()                 # so the forced ones below are short
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            was = gc.isenabled()
+            gc.disable()             # the forced collections alone
+            try:
+                for generation in (0, 1, 0, 2, 0):
+                    gc.collect(generation)
+            finally:
+                if was:
+                    gc.enable()
+        finally:
+            jax.profiler.stop_trace()
+        spans = span_args.parse(tr.find_xplane(str(tmp_path)))["gc"]
+        assert [g for _, _, g in spans] == [1, 2]
+        assert all(b > a for a, b, _ in spans)
+
+    def test_no_deadlock_under_the_recorders_locks(self):
+        """A collection can begin at any allocation, also inside
+        `MetricsRegistry.observe` or `StageTimers.record` while this
+        very thread holds that object's non-reentrant lock: a hook that
+        called either there would never return."""
+        import gc
+        from nomad_tpu.core import telemetry
+        from nomad_tpu.core.wavepipe import StageTimers
+        telemetry.install_gc_hook()
+        timers = StageTimers()
+        done = []
+
+        def collect_holding_both():
+            with REGISTRY._lock, timers._lock:
+                gc.collect(1)
+                gc.collect(2)
+            done.append(True)
+
+        t = threading.Thread(target=collect_holding_both, daemon=True)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive() and done == [True]
+
+
+def _burn(seconds: float) -> None:
+    """Spend about `seconds` of this thread's CPU."""
+    until = time.thread_time() + seconds
+    while time.thread_time() < until:
+        sum(range(2000))
+
+
+class TestThreadCpu:
+    """CPU seconds by thread role: each thread's own stamps, summed."""
+
+    def _run(self, name, seconds=0.05):
+        from nomad_tpu.core import telemetry
+
+        def body():
+            _burn(seconds)
+            telemetry.stamp_thread_cpu()
+
+        t = threading.Thread(target=body, name=name, daemon=True)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+
+    def test_exited_threads_keep_their_seconds_in_the_roles_total(self):
+        from nomad_tpu.core import telemetry
+        before = telemetry.thread_cpu_by_role().get("worker", 0.0)
+        readings = []
+        for _ in range(3):           # a new thread every round, one role
+            self._run("worker-7")
+            readings.append(telemetry.thread_cpu_by_role()["worker"])
+        assert readings == sorted(readings)
+        # all three threads' seconds, none twice
+        assert 0.15 <= readings[-1] - before < 0.15 + 0.1
+        # the table holds no slot of an exited thread
+        assert all(slot[0].is_alive() for slot in telemetry._cpu_slots)
+
+    def test_a_reused_ident_does_not_inherit_the_old_reading(self):
+        from nomad_tpu.core import telemetry
+        before = telemetry.thread_cpu_by_role().get("applier", 0.0)
+        # the exited thread's slot is still in the table when the next
+        # thread, very likely under its ident, stamps for the first
+        # time: a slot is the thread's own, not its ident's
+        self._run("plan-applier")
+        self._run("plan-applier")
+        after = telemetry.thread_cpu_by_role()["applier"]
+        assert 0.1 <= after - before < 0.1 + 0.1
+
+    def test_a_thread_reads_its_clock_once_a_tick(self, monkeypatch):
+        # a read costs a system call (6 us on the chip's host) and the
+        # clock moves once in 10 ms there: stamps in between keep the
+        # reading they have
+        from nomad_tpu.core import telemetry
+        reads = []
+        real = time.thread_time_ns
+
+        def counted():
+            if threading.current_thread().name == "probe-x":
+                reads.append(1)          # other threads stamp too
+            return real()
+
+        def body():
+            monkeypatch.setattr(telemetry.time, "thread_time_ns", counted)
+            for _ in range(1000):
+                telemetry.stamp_thread_cpu()
+            first = len(reads)
+            time.sleep(2.5 * telemetry._CPU_READ_EVERY_S)
+            telemetry.stamp_thread_cpu()
+            done.append((first, len(reads)))
+
+        done = []
+        t = threading.Thread(target=body, name="probe-x", daemon=True)
+        t.start()
+        t.join(timeout=30)
+        monkeypatch.undo()
+        assert not t.is_alive()
+        ((first, after),) = done
+        assert 1 <= first <= 3 and after == first + 1
+
+    def test_stamps_race_sums_without_losing_or_doubling(self, monkeypatch):
+        import sys
+        from nomad_tpu.core import telemetry
+        # every stamp a read: the fold is what is under test here
+        monkeypatch.setattr(telemetry, "_CPU_READ_EVERY_S", 0.0)
+        before = telemetry.thread_cpu_by_role().get("client", 0.0)
+        stop = threading.Event()
+        seen, finals = [], []
+
+        def reader():
+            while not stop.is_set():
+                seen.append(telemetry.thread_cpu_by_role().get(
+                    "client", 0.0))
+
+        def stamper():
+            for _ in range(100):
+                sum(range(2000))
+                telemetry.stamp_thread_cpu()
+            finals.append(time.thread_time())    # just past its last stamp
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            watch = threading.Thread(target=reader, daemon=True)
+            watch.start()
+            for _ in range(4):       # rounds of short-lived threads
+                threads = [threading.Thread(target=stamper, daemon=True,
+                                            name=f"client-{i}")
+                           for i in range(8)]
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    t.join(timeout=60)
+                assert not any(t.is_alive() for t in threads)
+            stop.set()
+            watch.join(timeout=30)
+            assert not watch.is_alive()
+        finally:
+            sys.setswitchinterval(interval)
+        assert seen == sorted(seen)              # a role's total never falls
+        # every thread's seconds once: none lost at an exit or at an
+        # ident handed on, none counted twice
+        total = telemetry.thread_cpu_by_role()["client"] - before
+        assert len(finals) == 32
+        assert total <= sum(finals)
+        assert total == pytest.approx(sum(finals), rel=0.05, abs=0.01)
+
+    def test_marker_folds_the_other_roles(self):
+        from nomad_tpu.core import telemetry, wavepipe
+        self._run("raft-x", 0.02)
+        self._run("Thread-9 (anything)", 0.02)
+        cpu = telemetry.thread_cpu_by_role()
+        assert cpu["raft"] >= 0.02 and cpu["other"] >= 0.02
+        wavepipe.mark_cpu()          # sums and emits; nothing to assert
+        assert telemetry.thread_cpu_by_role()["other"] >= cpu["other"]
+
+
+class TestRuntimeOnTheApi:
+    def test_handler_threads_answer_http(self, agent, api):
+        from nomad_tpu.core import profiling, telemetry
+        before = telemetry.thread_cpu_by_role().get("http", 0.0)
+        api.agent.metrics()
+        # the handler stamps itself after the response has left, under
+        # the role its name answers to: socketserver's own name for it
+        # would have read `other`
+        assert _wait(lambda: telemetry.thread_cpu_by_role().get(
+            "http", 0.0) > before, timeout=30)
+        assert profiling.role_of(
+            "http-api-Thread-3 (process_request_thread)") == "http"
+        assert profiling.role_of(
+            "Thread-3 (process_request_thread)") == "other"
+        # and the sampler's tables see the same name
+        import http.client
+        host, port = agent.address[len("http://"):].split(":")
+        conn = http.client.HTTPConnection(host, int(port), timeout=30)
+        try:
+            conn.request("GET", "/v1/status/leader")
+            conn.getresponse().read()       # kept alive: its thread lives
+            names = [t.name for t in threading.enumerate()
+                     if "process_request_thread" in t.name]
+            assert names and all(n.startswith("http-api-") for n in names)
+        finally:
+            conn.close()
+
+    def test_metrics_carry_thread_cpu_and_gc_pause(self, api):
+        import gc
+        gc.collect()
+        m = api.agent.metrics()
+        assert m["nomad.runtime.thread_cpu_s{role=http}"] > 0.0
+        for generation in "012":
+            assert f"nomad.runtime.gc_pause_s{{generation={generation}}}" \
+                in m
+        assert m["nomad.runtime.gc_collections{generation=2}"] >= 1
+        text = api.agent.metrics(format="prometheus")
+        families = assert_valid_exposition(text)
+        assert families["nomad_runtime_thread_cpu_seconds"] == "counter"
+        assert families["nomad_runtime_gc_pause_seconds"] == "counter"
+        assert 'nomad_runtime_thread_cpu_seconds{role="http"}' in text
+        assert 'nomad_runtime_gc_collections{generation="2"}' in text

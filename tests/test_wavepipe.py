@@ -532,10 +532,11 @@ class TestExecutorResidentParity:
 # one another, which is what makes "unnamed = pass - the rest" a
 # subtraction (benchmark/host_spans.py reads the same from a trace)
 WORKER_STAGES = ("prepare", "dispatch", "device_wait", "d2h", "solo_place",
-                 "system_place", "materialize", "plan_wait", "eval_update",
-                 "ack")
+                 "system_place", "materialize", "plan_wait", "finalize",
+                 "batch_admin", "eval_update", "ack")
 NEW_STAGES = ("pass", "prepare", "device_wait", "plan_wait", "eval_update",
-              "ack", "solo_place", "system_place", "store_upsert")
+              "ack", "solo_place", "system_place", "store_upsert",
+              "dequeue", "finalize", "batch_admin", "stream_send")
 
 
 @pytest.fixture(scope="module")
@@ -544,6 +545,9 @@ def small_pass():
     server (tests/stage_pass.py)."""
     from stage_pass import run_small_pass
     return run_small_pass()
+
+
+N_WAVE = 6          # stage_pass.N_BATCHED: the evals of the one wave
 
 
 def _spans(server, stage):
@@ -564,9 +568,12 @@ class TestPassStages:
         # tests/test_spread_batched.py hold them to that
         assert set(small_pass.stage_timers.counts()) | {
             "device_carve", "spread_lower"} == set(STAGES)
+        # dequeue is the worker's, before a pass; stream_send the
+        # stream follower's HTTP handler's (stage_pass.py has one)
         assert set(WORKER_STAGES) | {"pass", "device", "commit",
                                      "store_upsert", "device_carve",
-                                     "spread_lower"} == set(STAGES)
+                                     "spread_lower", "dequeue",
+                                     "stream_send"} == set(STAGES)
 
     @pytest.mark.parametrize("stage", NEW_STAGES)
     def test_new_stage_recorded(self, small_pass, stage):
@@ -602,6 +609,42 @@ class TestPassStages:
         ordered = sorted(mine)
         assert all(x[1] <= y[0] for x, y in zip(ordered, ordered[1:]))
 
+    def test_dequeue_before_a_pass_and_only_with_work(self, small_pass):
+        # one a pass that was not prefetched, closed before the pass
+        # opens; the worker's empty polls between the drains (one every
+        # 0.1 s) leave none
+        passes = sorted(_spans(small_pass, "pass"))
+        dequeues = sorted(_spans(small_pass, "dequeue"))
+        assert len(dequeues) == len(passes) == 3
+        for (a, b), (lo, _) in zip(dequeues, passes):
+            assert a <= b <= lo
+        for (_, hi), (a, _) in zip(passes, dequeues[1:]):
+            assert hi <= a
+
+    def test_one_finalize_per_eval_of_a_wave(self, small_pass):
+        # after its plan_wait, before the next eval's; wave-less, as
+        # every per-eval stage
+        finals = sorted(_spans(small_pass, "finalize"))
+        waits = sorted(_spans(small_pass, "plan_wait"))[:N_WAVE]
+        assert len(finals) == N_WAVE
+        for (_, b), (a, _) in zip(waits, finals):
+            assert b <= a
+        assert {w for w, _, _ in small_pass.stage_timers.intervals(
+            "finalize")} == {-1}
+
+    def test_batch_admin_carries_its_wave(self, small_pass):
+        # three on the pass of a wave (its launch's wave id on each),
+        # two on a pass that launched nothing
+        t = small_pass.stage_timers
+        (wave,) = {w for w, _, _ in t.intervals("dispatch")}
+        assert sorted(w for w, _, _ in t.intervals("batch_admin")) == [
+            -1, -1, -1, -1, wave, wave, wave]
+
+    def test_stream_send_once_an_event_delivered(self, small_pass):
+        # the follower read every eval's updates; each line was one span
+        assert (small_pass.stage_timers.counts()["stream_send"]
+                == len(small_pass.stream_lines) >= 8)
+
     def test_stages_account_for_the_pass(self, small_pass):
         totals = small_pass.stage_timers.totals()
         named = sum(totals[s] for s in WORKER_STAGES)
@@ -617,3 +660,53 @@ class TestPassStages:
         upserts = _spans(small_pass, "store_upsert")
         assert len(upserts) == len(commits) == 8
         assert all(_inside(u, commits) for u in upserts)
+
+
+class TestCpuMarkers:
+    def test_two_a_pass_never_falling_across_a_restart(self, monkeypatch):
+        """The worker marks the threads' CPU by role immediately before
+        and after every pass; `stop_scheduling` / `start_scheduling`
+        brings new worker and applier threads under the same roles, and
+        what the old ones had stays in the totals."""
+        import threading
+
+        from nomad_tpu.core import telemetry
+        from nomad_tpu.core import worker as worker_mod
+        from stage_pass import run_small_pass
+        marks, workers = [], set()
+        real = worker_mod.mark_cpu
+
+        def spy(wave=-1):
+            real(wave)
+            marks.append((wave, telemetry.thread_cpu_by_role()))
+            workers.add(threading.current_thread())
+
+        monkeypatch.setattr(worker_mod, "mark_cpu", spy)
+        server = run_small_pass(rounds=2)
+        passes = server.stage_timers.counts()["pass"]
+        assert passes == 4 and len(marks) == 2 * passes
+        assert len(workers) == 2                 # a thread each round
+        for (_, before), (_, after) in zip(marks, marks[1:]):
+            for role in ("worker", "applier", "http"):
+                assert before.get(role, 0.0) <= after[role], role
+        # the batched passes' closing markers carry their launch's wave
+        waves = {w for w, _, _ in server.stage_timers.intervals("dispatch")}
+        assert {w for w, _ in marks if w >= 0} == waves and len(waves) == 2
+        first, last = marks[0][1], marks[-1][1]
+        assert last["worker"] > first.get("worker", 0.0)
+        assert last["applier"] > first.get("applier", 0.0)
+
+    def test_empty_poll_records_no_dequeue(self):
+        """`dequeue` is entered by the broker once an eval is in hand: a
+        poll that times out empty leaves no interval (and no span)."""
+        from nomad_tpu.core.server import Server
+        s = Server(dev_mode=False, num_workers=1, eval_batch=8, mesh=False)
+        s.establish_leadership()
+        worker = s.workers[0]
+        try:
+            assert worker.run_once(timeout=0.0) == 0
+            assert worker.run_once(timeout=0.06) == 0
+            counts = s.stage_timers.counts()
+            assert "dequeue" not in counts and "pass" not in counts
+        finally:
+            s.shutdown()
