@@ -69,13 +69,23 @@ class DeploymentWatcher:
             self._check_one(snap, dep, t)
 
     def _check_one(self, snap, dep: Deployment, now: float) -> None:
-        allocs = [a for a in snap.allocs_by_job(dep.namespace, dep.job_id)
-                  if a.deployment_id == dep.id]
+        # a live columnar block is counted off its columns: its rows are
+        # as committed (pending, no health reported yet: a client's
+        # first write turns the block into table rows), so building them
+        # here would only move a drain's materialization onto this
+        # thread, under the workers' interpreter lock
+        rows, blocks = snap.rows_and_blocks_by_job(dep.namespace,
+                                                   dep.job_id)
+        allocs = [a for a in rows if a.deployment_id == dep.id]
         updated = dep.copy()
         unhealthy = any((a.deployment_status or {}).get("healthy") is False
                         for a in allocs
                         if a.task_group in updated.task_groups)
         self._recount(updated, allocs)
+        for b in blocks:
+            st = updated.task_groups.get(b.template.task_group)
+            if st is not None and b.template.deployment_id == dep.id:
+                st.placed_allocs += b.count
 
         if unhealthy:
             self._fail(updated, DESC_UNHEALTHY_ALLOCS, now)

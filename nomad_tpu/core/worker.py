@@ -559,6 +559,9 @@ class Worker:
             flush_window()
         finally:
             self._defer_evals = None
+        # plans of this pass committed since the batch's snapshot: the
+        # wave's, then each solo eval's
+        stale = bool(coupled)
         for i in [i for i in range(len(work)) if i not in bds]:
             ev, token, sched, prep = work[i]
             if sched is None:
@@ -566,6 +569,16 @@ class Worker:
                 settled.add(ev.id)
                 continue
             try:
+                if stale:
+                    # a view of its own, as `_invoke` gives an eval that
+                    # runs alone.  The scheduler fence-tags its plan from
+                    # the snapshot it computes against: tagged from the
+                    # batch's, a solo plan after the pass's first commit
+                    # meets the applier with a broken fence and is
+                    # re-fitted node by node (a block's rows built for
+                    # it) against writes the engine's usage already holds
+                    sched.state = self.refreshed_snapshot()
+                stale = True
                 err = sched.process(ev)
             except Exception as e:  # noqa: BLE001 - nack, don't die
                 err = e

@@ -35,6 +35,7 @@ from nomad_tpu.structs import (
     NodeScoreMeta,
     RES_DIMS,
     RES_NAMES,
+    RowMetrics,
     SCHED_ALGO_SPREAD,
     TaskGroup,
 )
@@ -346,7 +347,8 @@ class BulkDecisions:
     AllocMetric per water-fill round instead of per-placement objects.
     Building 100k PlacementDecision + AllocMetric objects cost more than
     the device work; the scheduler materializes allocs straight from
-    `picks`."""
+    `picks`.  The exact scan answers a block of fresh placements in the
+    same form, its per-placement metrics as columns (`rows`)."""
     tg_name: str
     picks: np.ndarray                  # [P] node row or -1
     node_ids: List[str]                # row -> node id (shared, read-only)
@@ -354,6 +356,18 @@ class BulkDecisions:
     metrics: List[AllocMetric]         # one per round, shared by the round
     evictions: Dict[int, List] = field(default_factory=dict)
     nodes_evaluated: int = 0
+    # the exact scan's form (a block of fresh placements that could not
+    # ride the bulk kernel): `round_size` 1, `metrics` empty, and one
+    # metric a PLACEMENT kept as the columns the scan returned
+    rows: Optional[RowMetrics] = None
+    scores: Optional[np.ndarray] = None     # [P] float32, the picks' own
+
+    def metric_at(self, i: int) -> AllocMetric:
+        """Placement i's metric: its own, built here, or its round's."""
+        if self.rows is not None:
+            return self.rows.metric(i)
+        return self.metrics[min(i // self.round_size,
+                                len(self.metrics) - 1)]
 
 
 class PlacementEngine:
@@ -991,8 +1005,10 @@ class PlacementEngine:
         pair describing `count` fresh placements of one task group with
         no per-placement state (reconcile.PlaceBlock).  The bulk kernel
         needs nothing more; if the job shape forces the exact scan
-        (spread/distinct/devices), equivalent per-placement requests are
-        synthesized here.
+        (spread/distinct/devices) its rows are filled in here.  With
+        `bulk_api` either kernel answers a block with a BulkDecisions,
+        the scan's with a metric a placement as columns (`rows`); only a
+        device ask still gets a PlacementDecision a placement.
 
         `stopped_allocs`: allocs the in-flight plan is stopping/evicting —
         their usage (and job-count, for this job) is subtracted before
@@ -1127,10 +1143,6 @@ class PlacementEngine:
             bulk_ok = (p_real >= BULK_THRESHOLD
                        and not has_spread and not has_distinct
                        and dev_mask is None)
-            if not bulk_ok or not bulk_api:
-                # rare fallback: the exact scan / per-placement decision
-                # paths need request rows
-                requests = [PlacementRequest(tg_name=block_tg)] * p_real
         else:
             bulk_ok = (
                 p_real >= BULK_THRESHOLD
@@ -1216,7 +1228,8 @@ class PlacementEngine:
             (picks, scores, topk_rows, topk_scores,
              n_feas, n_filt, n_exh, dim_exh) = _unpack_bulk(
                 self._fetch(buf), round_size, p_real, n)
-            n_filt = n_filt - (npad - n)
+            counts = np.column_stack(
+                [n_filt - (npad - n), n_exh, dim_exh])
             inp = binp      # _preempt_fallback field source
         else:
             sp: SpreadTensors = lower_spreads(self.packer, job, t, snapshot)
@@ -1224,11 +1237,16 @@ class PlacementEngine:
             tg_idx = np.zeros(p_pad, np.int32)
             prev_row = np.full(p_pad, -1, np.int32)
             active = np.zeros(p_pad, bool)
-            for i, r in enumerate(requests):
-                tg_idx[i] = name_to_g[r.tg_name]
-                if r.prev_node_id:
-                    prev_row[i] = t.id_to_row.get(r.prev_node_id, -1)
-                active[i] = True
+            if block is not None:
+                # fresh placements of one group: no request rows exist
+                tg_idx[:p_real] = name_to_g[block_tg]
+                active[:p_real] = True
+            else:
+                for i, r in enumerate(requests):
+                    tg_idx[i] = name_to_g[r.tg_name]
+                    if r.prev_node_id:
+                        prev_row[i] = t.id_to_row.get(r.prev_node_id, -1)
+                    active[i] = True
             inp = PlacementInputs(
                 attrs=dev["attrs"], cap=dev["cap"], used0=used0,
                 elig=dev["elig"],
@@ -1270,9 +1288,9 @@ class PlacementEngine:
             scores = b[:, 1].view(np.float32)
             topk_rows = b[:, 2:5]
             topk_scores = b[:, 5:8].view(np.float32)
-            n_filt = b[:, 9] - (npad - n)
-            n_exh = b[:, 10]
-            dim_exh = b[:, 11:11 + RES_DIMS]
+            # nodes_filtered | nodes_exhausted | dimension_exhausted
+            counts = b[:, 9:11 + RES_DIMS].copy()
+            counts[:, 0] -= npad - n
         elapsed = (time.perf_counter_ns() - t0) // max(p_real, 1)
 
         # ---- preemption fallback for failed placements ----
@@ -1280,55 +1298,47 @@ class PlacementEngine:
             picks, snapshot, job, inp, tg_tensors, tg_idx,
             t, used_dev, job_count_dev, p_real)
 
-        dc_counts = self._dc_counts(t)
-
-        # native-python views once, not one numpy-scalar box per field
-        picks_l = picks.tolist()
-        scores_l = scores.tolist()
-        topk_rows_l = topk_rows.tolist()
-        topk_scores_l = topk_scores.tolist()
-        n_filt_l = n_filt.tolist()
-        n_exh_l = n_exh.tolist()
-        dim_exh_l = dim_exh.tolist()
-        n_in_pool = int(ctx.pool_mask.sum())
-        elapsed = int(elapsed)
+        # fresh placements of one group off the exact scan leave as
+        # arrays, like the bulk kernel's (the scheduler commits them as
+        # ONE AllocBlock).  A device ask keeps decisions: its instances
+        # are assigned per placement (generic._assign_devices)
+        as_block = block is not None and bulk_api and dev_mask is None
+        nodes = t.node_ids
+        if as_block:
+            # the block's metric columns name their candidates in a
+            # table of their own: it outlives the node table, and rides
+            # the wire
+            uniq, inv = np.unique(topk_rows, return_inverse=True)
+            nodes = [nodes[r] if r >= 0 else "" for r in uniq.tolist()]
+            topk_rows = np.where(topk_rows >= 0, inv.reshape(
+                topk_rows.shape), -1).astype(np.int32)
+            topk_scores = topk_scores.copy()
+        # the per-placement metrics stay the columns the kernel returned:
+        # an AllocMetric is built where one is read
+        rows = RowMetrics(
+            nodes_evaluated=n, nodes_in_pool=int(ctx.pool_mask.sum()),
+            nodes_available=self._dc_counts(t),
+            allocation_time_ns=int(elapsed), counts=counts,
+            topk=topk_rows, topk_scores=topk_scores, nodes=nodes)
+        if as_block:
+            return BulkDecisions(
+                tg_name=block_tg, picks=picks, node_ids=t.node_ids,
+                round_size=1, metrics=[], evictions=evictions_by_req,
+                nodes_evaluated=n, rows=rows, scores=scores.copy())
+        if block is not None:
+            # a decision a placement after all (no bulk_api, a device
+            # ask): the block's placements as request rows
+            requests = [PlacementRequest(tg_name=block_tg)] * p_real
         node_ids = t.node_ids
-
-        # score_meta_data repeats within a bulk round: share one list per
-        # distinct top-k (read-only by convention, like the shared job ptr)
-        smd_cache: Dict[tuple, list] = {}
-        decisions: List[PlacementDecision] = []
-        for i, r in enumerate(requests):
-            metric = AllocMetric(
-                nodes_evaluated=n,
-                nodes_filtered=n_filt_l[i],
-                nodes_in_pool=n_in_pool,
-                nodes_available=dc_counts,
-                nodes_exhausted=n_exh_l[i],
-                allocation_time_ns=elapsed,
-            )
-            de = dim_exh_l[i]
-            if any(de):
-                for d in range(RES_DIMS):
-                    if de[d]:
-                        metric.dimension_exhausted[RES_NAMES[d]] = de[d]
-            key = (tuple(topk_rows_l[i]), tuple(topk_scores_l[i]))
-            smd = smd_cache.get(key)
-            if smd is None:
-                smd = [NodeScoreMeta(node_id=node_ids[kr],
-                                     scores={"final": ks},
-                                     norm_score=ks)
-                       for kr, ks in zip(topk_rows_l[i], topk_scores_l[i])
-                       if kr >= 0]
-                smd_cache[key] = smd
-            metric.score_meta_data = smd
-            pick = picks_l[i]
-            node_id = node_ids[pick] if pick >= 0 else None
-            decisions.append(PlacementDecision(
-                tg_name=r.tg_name, node_id=node_id,
-                score=scores_l[i], metric=metric,
-                evictions=evictions_by_req.get(i, [])))
-        return decisions
+        return [
+            PlacementDecision(
+                tg_name=r.tg_name,
+                node_id=node_ids[pick] if pick >= 0 else None,
+                score=score, metric=metric,
+                evictions=evictions_by_req.get(i, []))
+            for i, (r, pick, score, metric) in enumerate(zip(
+                requests, picks.tolist(), scores.tolist(),
+                rows.materialize()))]
 
     # device preemption: the victim tables are COMPACT (candidate nodes x
     # pow2 depth ladder), so the upload is bounded by live victims, not

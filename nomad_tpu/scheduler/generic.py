@@ -695,6 +695,7 @@ class GenericScheduler(Scheduler):
         # the 40-field dataclass constructor per placement.
         alloc_templates: Dict[str, Allocation] = {}
 
+        placed_n = 0
         for i, (p, d) in enumerate(zip(places, decisions)):
             tg = p.tg
             if d.node_id is None:
@@ -774,7 +775,17 @@ class GenericScheduler(Scheduler):
                     append_reschedule_tracker(alloc, p.previous_alloc, self.now)
                     alloc.desired_description = ALLOC_RESCHEDULED
             plan.append_alloc(alloc)
+            placed_n += 1
             self._note_placed(tg.name, d.metric, evictions=d.evictions)
+        self._count_materialized("rows", placed_n)
+
+    @staticmethod
+    def _count_materialized(form: str, n: int) -> None:
+        """`nomad.materialize.placements{form}`: placements materialized
+        by representation, "block" (one columnar AllocBlock) or "rows"
+        (an Allocation a placement); once a materialize call."""
+        from nomad_tpu.core.telemetry import REGISTRY
+        REGISTRY.inc("nomad.materialize.placements", n, form=form)
 
     @staticmethod
     def _net_columnar_labels(ask) -> Optional[List[str]]:
@@ -944,7 +955,8 @@ class GenericScheduler(Scheduler):
                 self._materialize_bulk(plan, job, None, decisions,
                                        evaluation, results, block=block)
                 return
-            # engine fell back (spread/devices/small count): expand and
+            # a device ask (the engine answers it a decision a placement:
+            # `_assign_devices` binds instances one by one): expand and
             # run the general path with the decisions it already computed
             places = [RPlace(tg=block.tg, name=_name(job, block.tg, ix),
                              index=ix) for ix in block.indexes]
@@ -1049,9 +1061,11 @@ class GenericScheduler(Scheduler):
         """Materialize allocations straight from a BulkDecisions array —
         the per-placement twin loop of `_compute_placements`, with every
         per-alloc object cost stripped: template-dict clones, batched ids,
-        a shared per-round AllocMetric, and a shared resources object when
-        the group asks for no ports.  With `block` (compact path) names
-        come straight from the index list — no RPlace objects exist.
+        a shared per-round AllocMetric (or, off the exact scan, a metric
+        a placement kept as columns: `bd.rows`), and a shared resources
+        object when the group asks for no ports.  With `block` (compact
+        path) names come straight from the index list — no RPlace
+        objects exist.
 
         A device-asking group reaches this only off a wave
         (prepare_batch admitted it: ONE request; the solo path's scan
@@ -1085,8 +1099,6 @@ class GenericScheduler(Scheduler):
         count = len(block.indexes) if block is not None else len(places)
         ids = new_ids(count)
         node_ids = bd.node_ids
-        metrics = bd.metrics
-        rs = bd.round_size
         node_alloc = plan.node_allocation
         victim_ids = {v.id for vs in bd.evictions.values() for v in vs}
         # `net_idx` may be the BATCH-SHARED port cache (see prepare_batch:
@@ -1114,7 +1126,6 @@ class GenericScheduler(Scheduler):
                       if has_net and PORT_BATCHED and block is not None
                       else None)
         if (rows_fresh and not bd.evictions
-                and results.deployment is None
                 and (not has_net or net_labels is not None)):
             # hottest shape (the bench/batch pattern): fresh block, no
             # preemptions — stays COLUMNAR end-to-end: the picks array +
@@ -1123,7 +1134,10 @@ class GenericScheduler(Scheduler):
             # materializes them lazily on first read).  Networked groups
             # now ride it too (ISSUE 8): dynamic ports are carved per
             # node in ONE batched pass (bit-for-bit the sequential
-            # result) and land as port COLUMNS on the block.
+            # result) and land as port COLUMNS on the block.  A
+            # deployment does not bar it (ISSUE 35): the template carries
+            # its id, fresh rows hold no canary, and the watcher counts a
+            # block's rows off its columns.
             import numpy as np
 
             from nomad_tpu.structs import AllocBlock
@@ -1181,8 +1195,7 @@ class GenericScheduler(Scheduler):
                     # (the first failing round's), coalesced + queued
                     # counters match the per-pick loop's totals
                     tg_name = tg.name
-                    first_fail = int(np.argmax(picks < 0))
-                    m = metrics[min(first_fail // rs, len(metrics) - 1)]
+                    m = bd.metric_at(int(np.argmax(picks < 0)))
                     self._record_failure_shared(tg_name, m)
                     if n_fail > 1:
                         self.failed_tg_allocs[tg_name].coalesced_failures \
@@ -1199,7 +1212,14 @@ class GenericScheduler(Scheduler):
                 else:
                     ids_ok = ids
                     idx_ok = list(indexes)
-                self._note_placed(tg.name, metrics[0], n=n_ok)
+                # (no numpy call where every pick placed: one more
+                # release of the interpreter lock a plan, with the
+                # applier waiting for it, doubled a wave's materialize)
+                self._note_placed(
+                    tg.name, bd.metric_at(int(np.argmax(ok_mask))
+                                          if n_fail or n_short else 0),
+                    n=n_ok)
+                self._count_materialized("block", n_ok)
                 if ports_arr is not None:
                     self.last_port_carve = n_ok
                     from nomad_tpu.core.telemetry import REGISTRY
@@ -1215,8 +1235,11 @@ class GenericScheduler(Scheduler):
                     indexes=idx_ok,
                     picks=inv.astype(np.int32),
                     node_table=[node_ids[int(r)] for r in uniq],
-                    metrics=list(metrics),
-                    round_size=rs,
+                    metrics=list(bd.metrics),
+                    round_size=bd.round_size,
+                    row_metrics=(bd.rows.take(ok_mask)
+                                 if bd.rows is not None
+                                 and (n_fail or n_short) else bd.rows),
                     port_labels=(list(net_labels)
                                  if ports_arr is not None else []),
                     ports=ports_arr,
@@ -1235,16 +1258,19 @@ class GenericScheduler(Scheduler):
             # than commit device-asking allocations that hold none
             raise RuntimeError(
                 f"{job.id}.{tg.name}: a device-asking group left the "
-                "columnar path (evictions, a deployment, or rows that "
-                "are not fresh)")
+                "columnar path (evictions, or rows that are not fresh)")
         picks_l = bd.picks.tolist()
+        # a metric a placement (the exact scan's): built once, not a row
+        # at a time
+        row_ms = bd.rows.materialize() if bd.rows is not None else None
         placed_n = 0          # decision-record capture, noted ONCE below
+        first_m = None        # the first placed row's metric
         victims_sample: List = []
         victims_n = 0
         for i in range(count):
             p = places[i] if block is None else None
             pick = picks_l[i]
-            m = metrics[i // rs]
+            m = row_ms[i] if row_ms is not None else bd.metric_at(i)
             if pick < 0:
                 self._record_failure_shared(tg.name, m)
                 continue
@@ -1311,9 +1337,12 @@ class GenericScheduler(Scheduler):
                 if last_list is None:
                     node_alloc[nid] = last_list = []
                 last_list.append(alloc)
+            if not placed_n:
+                first_m = m
             placed_n += 1
+        self._count_materialized("rows", placed_n)
         if placed_n:
-            self._note_placed(tg.name, metrics[0], n=placed_n,
+            self._note_placed(tg.name, first_m, n=placed_n,
                               evictions=victims_sample)
             if victims_n > len(victims_sample):
                 self._tg_stats[tg.name]["preempted"] += (

@@ -2263,6 +2263,16 @@ class StateSnapshot:
             out.extend(b.materialize_all())
         return out
 
+    def rows_and_blocks_by_job(self, namespace: str, job_id: str):
+        """A job's allocations with its columnar blocks left as they
+        are: (table rows, live blocks).  A live block's rows are as
+        committed (a write to any member turns the block into table
+        rows first), so a reader of counts needs its template and its
+        `count`, and no row built."""
+        key = (namespace, job_id)
+        return (list(self._allocs_by_job.get(key, {}).values()),
+                self._blocks_by_job.get(key, ()))
+
     def allocs_by_node(self, node_id: str) -> List[Allocation]:
         out = list(self._allocs_by_node.get(node_id, {}).values())
         for b in self._blocks_by_node.get(node_id, ()):

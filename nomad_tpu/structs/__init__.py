@@ -1,7 +1,7 @@
 """Data model (reference: nomad/structs)."""
 
 from .structs import *  # noqa: F401,F403
-from .block import AllocBlock  # noqa: F401
+from .block import AllocBlock, RowMetrics  # noqa: F401
 from .funcs import (  # noqa: F401
     MAX_FIT_SCORE,
     NetworkIndex,

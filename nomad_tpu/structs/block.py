@@ -29,7 +29,81 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from .structs import AllocatedDeviceResource, Allocation, AllocMetric
+from .structs import (RES_NAMES, AllocatedDeviceResource, Allocation,
+                      AllocMetric, NodeScoreMeta)
+
+
+@dataclass
+class RowMetrics:
+    """Per-ROW placement metrics as the columns the exact scan returned
+    (ISSUE 35): one row a placement, where a bulk round shares ONE
+    AllocMetric among its rows.  A row's AllocMetric object (and its
+    NodeScoreMeta list) is built on demand, `metric(i)` or
+    `materialize()`, where it is read; the scheduling path carries
+    arrays only."""
+
+    # shared by every row
+    nodes_evaluated: int = 0
+    nodes_in_pool: int = 0
+    nodes_available: Dict[str, int] = field(default_factory=dict)
+    allocation_time_ns: int = 0
+    # [rows, 2 + RES_DIMS] int32: nodes_filtered | nodes_exhausted |
+    # dimension_exhausted by capacity dimension (RES_NAMES)
+    counts: Optional[np.ndarray] = None
+    # the scan's best candidates a row: topk[i, j] indexes `nodes` (-1:
+    # none), topk_scores[i, j] is its final score (float32)
+    topk: Optional[np.ndarray] = None
+    topk_scores: Optional[np.ndarray] = None
+    nodes: List[str] = field(default_factory=list)
+
+    def take(self, keep) -> "RowMetrics":
+        """The rows a boolean mask (or an index array) selects."""
+        return RowMetrics(
+            nodes_evaluated=self.nodes_evaluated,
+            nodes_in_pool=self.nodes_in_pool,
+            nodes_available=self.nodes_available,
+            allocation_time_ns=self.allocation_time_ns,
+            counts=self.counts[keep], topk=self.topk[keep],
+            topk_scores=self.topk_scores[keep], nodes=self.nodes)
+
+    def metric(self, i: int) -> AllocMetric:
+        return self.take(slice(i, i + 1)).materialize()[0]
+
+    def materialize(self) -> List[AllocMetric]:
+        """One AllocMetric a row.  score_meta_data repeats from row to
+        row: one list per distinct top-k is shared (read-only by
+        convention, like the shared job pointer)."""
+        # native-python views once, not one numpy-scalar box per field
+        counts = self.counts.tolist()
+        topk = self.topk.tolist()
+        scores = self.topk_scores.tolist()
+        nodes = self.nodes
+        n_eval, n_pool = self.nodes_evaluated, self.nodes_in_pool
+        avail, elapsed = self.nodes_available, self.allocation_time_ns
+        smd_cache: Dict[tuple, list] = {}
+        out: List[AllocMetric] = []
+        for row, kr, ks in zip(counts, topk, scores):
+            metric = AllocMetric(
+                nodes_evaluated=n_eval,
+                nodes_filtered=row[0],
+                nodes_in_pool=n_pool,
+                nodes_available=avail,
+                nodes_exhausted=row[1],
+                allocation_time_ns=elapsed,
+            )
+            if any(row[2:]):
+                metric.dimension_exhausted = {
+                    name: c for name, c in zip(RES_NAMES, row[2:]) if c}
+            key = (*kr, *ks)
+            smd = smd_cache.get(key)
+            if smd is None:
+                smd_cache[key] = smd = [
+                    NodeScoreMeta(node_id=nodes[r], scores={"final": s},
+                                  norm_score=s)
+                    for r, s in zip(kr, ks) if r >= 0]
+            metric.score_meta_data = smd
+            out.append(metric)
+        return out
 
 
 @dataclass
@@ -49,6 +123,9 @@ class AllocBlock:
     # one AllocMetric per water-fill round, shared by the round's allocs
     metrics: List[AllocMetric] = field(default_factory=list)
     round_size: int = 1024
+    # OR (the exact scan's blocks, ISSUE 35) one metric a ROW, as columns:
+    # row i of `row_metrics` is row i of the block; `metrics` is empty
+    row_metrics: Optional[RowMetrics] = None
     # COLUMNAR port assignment (ISSUE 8): ports[i, j] is row i's value for
     # dynamic-port label port_labels[j].  None for non-networked blocks.
     # The batched carve in scheduler/generic.py fills these; rows
@@ -154,7 +231,8 @@ class AllocBlock:
         round size; after compaction a row's `i // round_size` metric
         index can shift to a neighboring round's (shared, diagnostic)
         metric — acceptable drift for the rare partial-refute path, the
-        same class of sharing the round metrics already are."""
+        same class of sharing the round metrics already are.  Per-row
+        metric columns are compacted with the rows: no drift."""
         bad_rows = np.array(
             [i for i, nid in enumerate(self.node_table)
              if nid in bad_node_ids], np.int64)
@@ -176,6 +254,8 @@ class AllocBlock:
             node_table=[self.node_table[int(r)] for r in uniq],
             metrics=list(self.metrics),
             round_size=self.round_size,
+            row_metrics=(self.row_metrics.take(keep)
+                         if self.row_metrics is not None else None),
             port_labels=list(self.port_labels),
             ports=self.ports[keep] if self.ports is not None else None,
             device_task=self.device_task,
@@ -206,6 +286,10 @@ class AllocBlock:
             prefix = self.name_prefix
             metrics = self.metrics
             rs = self.round_size
+            if self.row_metrics is not None:
+                # a metric a row: built here, on first read
+                metrics = self.row_metrics.materialize()
+                rs = 1
             tmpl_d = self.template.__dict__
             ci, mi = self.create_index, self.modify_index
             plabels = self.port_labels

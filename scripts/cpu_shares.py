@@ -18,6 +18,10 @@ imported with `from x import f` is wrapped where it is LOOKED UP
 
     chiprun -- env PYTHONPATH=. python3 scripts/cpu_shares.py csi50k drain384
 
+`--metrics nomad.materialize` prints, after each cycle, the `/v1/metrics`
+series of that prefix as the agent serves them (counters run since
+process start: a cycle's share is the difference of two prints).
+
 What it is for: the PER-FUNCTION rows, inclusive parts of a thread's work
 that nothing else reads.  The two threads' totals (`Worker.run_batch`,
 `PlanApplier.apply_one`) the program now keeps itself, with nothing
@@ -61,6 +65,8 @@ ap.add_argument("names", nargs="*", default=DEFAULT)
 ap.add_argument("--seed", type=int, default=2147931031)
 ap.add_argument("--cycles", type=int, default=4, help="first one compiles")
 ap.add_argument("--rehearse", action="store_true", help="tiny, for the CPU")
+ap.add_argument("--metrics", default="", metavar="PREFIX",
+                help="after each cycle, the /v1/metrics series that start so")
 args = ap.parse_args()
 
 cfg, mod = load_json("configs", args.config), load_module("configs", args.config)
@@ -158,4 +164,8 @@ for n in range(args.cycles):
         print(f"  {calls:6d} {cpu * 1e3:9.2f} {wall * 1e3:9.2f} "
               f"{cpu * 1e6 / max(calls, 1):11.1f}  {name.split(':')[1]}",
               flush=True)
+    if args.metrics:
+        for k, v in sorted(call(main_conn, "GET", "/v1/metrics").items()):
+            if k.startswith(args.metrics):
+                print(f"  {k} {v}", flush=True)
 agent.shutdown()

@@ -202,3 +202,222 @@ class TestBlockApplier:
         snap = state.snapshot()
         assert len(snap.allocs_by_node(n1.id)) == 5
         assert len(snap.allocs_by_node(n2.id)) == 0
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 35: the exact scan's blocks carry ONE METRIC A ROW as columns
+# (structs.block.RowMetrics), and a deployment no longer bars a block
+# ---------------------------------------------------------------------------
+
+def run_spread(count=120, n_nodes=30):
+    """A service job with a spread stanza, a rack affinity and mock.job's
+    update stanza (a deployment) through a dev-mode server's solo path:
+    the exact scan places it, one block commits it."""
+    from nomad_tpu.structs import OP_EQ, Affinity, Spread, SpreadTarget
+    s = Server(dev_mode=True)
+    s.establish_leadership()
+    for i in range(n_nodes):
+        n = mock.node()
+        n.datacenter = f"dc{1 + i % 3}"
+        n.meta["rack"] = f"r{i % 5}"
+        s.register_node(n, now=NOW)
+    job = mock.job()
+    job.datacenters = ["dc1", "dc2", "dc3"]
+    tg = job.task_groups[0]
+    tg.count = count
+    tg.tasks[0].resources = Resources(cpu=10, memory_mb=10)
+    job.spreads = [Spread(
+        attribute="${node.datacenter}", weight=50,
+        targets=(SpreadTarget("dc1", 50), SpreadTarget("dc2", 30),
+                 SpreadTarget("dc3", 20)))]
+    job.affinities = [Affinity("${meta.rack}", OP_EQ, "r3", weight=50)]
+    s.register_job(job, now=NOW)
+    s.process_all(now=NOW)
+    return s, job
+
+
+def wire_rows(rows, indexes=True):
+    """Rows in their full wire form, by name (`indexes` False: less the
+    two indexes a commit stamps)."""
+    from nomad_tpu.structs import codec
+    out = {a.name: codec.encode(a) for a in rows}
+    if not indexes:
+        for d in out.values():
+            del d["CreateIndex"], d["ModifyIndex"]
+    return out
+
+
+class TestRowMetricBlocks:
+    def test_scan_block_commits_columnar_with_a_metric_a_row(self):
+        s, job = run_spread()
+        (block,) = s.state._alloc_blocks.values()
+        assert block.count == 120 and not block.metrics
+        rm = block.row_metrics
+        assert rm.counts.shape == (120, 6) and rm.topk.shape == (120, 3)
+        assert rm.topk_scores.dtype == np.float32
+        # the candidates' own table, not the fleet's
+        assert len(rm.nodes) <= 30 and rm.topk.max() < len(rm.nodes)
+        assert not s.state._allocs_by_job.get((job.namespace, job.id))
+        rows = s.state.snapshot().allocs_by_job(job.namespace, job.id)
+        # a row's first candidate is the node it went to, its own score
+        # first: row 0 and row 119 do not share a metric
+        assert all(a.metrics.score_meta_data[0].node_id == a.node_id
+                   for a in rows)
+        assert rows[0].metrics is not rows[-1].metrics
+        assert rows[0].metrics.score_meta_data != \
+            rows[-1].metrics.score_meta_data
+        by_dc = {"dc1": 0, "dc2": 0, "dc3": 0}
+        for a in rows:
+            by_dc[s.state.node_by_id(a.node_id).datacenter] += 1
+        # the stanza's 50 / 30 / 20 of 120, the rack affinity pulling
+        assert all(abs(by_dc[dc] - want) <= 6 for dc, want in
+                   (("dc1", 60), ("dc2", 36), ("dc3", 24))), by_dc
+
+    def test_block_survives_the_wire_codec(self):
+        from nomad_tpu.core import wire
+        s, _ = run_spread()
+        (block,) = s.state._alloc_blocks.values()
+        back = wire.unpackb(wire.packb(block))
+        assert isinstance(back, AllocBlock)
+        assert back.row_metrics.nodes == block.row_metrics.nodes
+        for col in ("counts", "topk", "topk_scores"):
+            got = getattr(back.row_metrics, col)
+            want = getattr(block.row_metrics, col)
+            assert got.dtype == want.dtype and np.array_equal(got, want)
+        assert wire_rows(back.materialize_all()) == \
+            wire_rows(block.materialize_all())
+
+    def test_block_survives_snapshot_persist_and_restore(self):
+        from nomad_tpu.state import StateStore
+        s, job = run_spread()
+        before = wire_rows(s.state.allocs_by_job(job.namespace, job.id))
+        fresh = StateStore()
+        fresh.snapshot_restore(s.state.snapshot_save())
+        assert not fresh._alloc_blocks
+        after = wire_rows(fresh.allocs_by_job(job.namespace, job.id))
+        assert len(after) == 120 and after == before
+
+    def test_without_nodes_keeps_each_rows_own_metric(self):
+        s, _ = run_spread()
+        (block,) = s.state._alloc_blocks.values()
+        rows = block.materialize_all()
+        bad = {rows[0].node_id, rows[7].node_id}
+        kept = block.without_nodes(bad)
+        assert 0 < kept.count < 120
+        assert kept.row_metrics.counts.shape[0] == kept.count
+        assert not bad.intersection(kept.node_table)
+        want = wire_rows((a for a in rows if a.node_id not in bad),
+                         indexes=False)
+        assert wire_rows(kept.materialize_all(), indexes=False) == want
+
+    def test_stop_and_purge_of_a_block_committed_job(self):
+        s, job = run_spread()
+        assert s.state._alloc_blocks
+        s.deregister_job(job.namespace, job.id, purge=True, now=NOW + 1)
+        s.process_all(now=NOW + 1)
+        rows = s.state.allocs_by_job(job.namespace, job.id)
+        assert len(rows) == 120
+        assert all(a.desired_status == "stop" for a in rows)
+        assert all(a.metrics.score_meta_data for a in rows)
+        assert not s.state._alloc_blocks     # a member write: table rows
+        t = s.engine.packer.update(s.state.snapshot())
+        assert int(t.used[:, 0].sum()) == 0
+
+    def test_deployment_watcher_counts_a_blocks_rows(self):
+        from nomad_tpu.structs import DEPLOYMENT_STATUS_RUNNING
+        s, job = run_spread()
+        (block,) = s.state._alloc_blocks.values()
+        dep = s.state.latest_deployment_by_job(job.namespace, job.id)
+        assert dep.status == DEPLOYMENT_STATUS_RUNNING
+        assert block.template.deployment_id == dep.id
+        assert dep.task_groups["web"].placed_allocs == 0
+        s.deployments.tick(now=NOW + 1)
+        dep = s.state.latest_deployment_by_job(job.namespace, job.id)
+        st = dep.task_groups["web"]
+        assert (st.placed_allocs, st.healthy_allocs, st.unhealthy_allocs,
+                st.desired_total) == (120, 0, 0, 120)
+        # counted off the block's columns: the tick built no row
+        assert block._rows is None
+        # a member's first write turns the block into rows; same count
+        a = s.state.allocs_by_job(job.namespace, job.id)[0]
+        upd = a.copy_skip_job()
+        upd.client_status = "running"
+        upd.deployment_status = {"healthy": True, "ts": NOW}
+        s.state.update_allocs_from_client([upd])
+        assert not s.state._alloc_blocks
+        s.deployments.tick(now=NOW + 2)
+        st = s.state.deployment_by_id(dep.id).task_groups["web"]
+        assert (st.placed_allocs, st.healthy_allocs) == (120, 1)
+
+
+def test_materialize_counter_by_form_on_v1_metrics():
+    """`nomad.materialize.placements{form}` through an agent's HTTP API:
+    a spread job of 80 (the scan, one block), a batch job of 70 (the bulk
+    kernel, one block) and a service job of 5 (rows)."""
+    import json
+    import re
+    import time
+    import urllib.request
+
+    from nomad_tpu.agent import Agent
+    from nomad_tpu.core.telemetry import REGISTRY
+    from nomad_tpu.structs import Spread, SpreadTarget
+
+    def forms():
+        got = REGISTRY.counter_labels("nomad.materialize.placements")
+        return got.get("form=block", 0), got.get("form=rows", 0)
+
+    agent = Agent(num_clients=0, heartbeat_ttl=86400.0, num_workers=1,
+                  log_level="warn", mesh=False)
+    agent.start()
+    try:
+        srv = agent.server
+        nodes = []
+        for i in range(30):
+            node = mock.node()
+            node.datacenter = f"dc{1 + i % 3}"
+            nodes.append(node)
+        srv.state.upsert_nodes(nodes)
+        spread = mock.job()
+        spread.task_groups[0].count = 80
+        spread.spreads = [Spread(
+            attribute="${node.datacenter}", weight=100,
+            targets=(SpreadTarget("dc1", 50), SpreadTarget("dc2", 50)))]
+        bulk = mock.batch_job()
+        bulk.task_groups[0].count = 70
+        small = mock.job()
+        small.task_groups[0].count = 5
+        jobs = [spread, bulk, small]
+        for job in jobs:
+            job.datacenters = ["dc1", "dc2", "dc3"]
+            job.task_groups[0].tasks[0].resources = Resources(
+                cpu=10, memory_mb=10)
+        block0, rows0 = forms()
+        for job in jobs:
+            srv.register_job(job)
+        deadline = time.monotonic() + 120
+        placed = 0
+        while placed < 155 and time.monotonic() < deadline:
+            time.sleep(0.05)
+            snap = srv.state.snapshot()
+            placed = sum(b.count for b in snap.alloc_blocks()) \
+                + len(snap.allocs())
+        assert placed == 155
+        block1, rows1 = forms()
+        assert (block1 - block0, rows1 - rows0) == (150, 5)
+        with urllib.request.urlopen(agent.address + "/v1/metrics",
+                                    timeout=60) as r:
+            flat = json.load(r)
+        assert flat["nomad.materialize.placements{form=block}"] == block1
+        assert flat["nomad.materialize.placements{form=rows}"] == rows1
+        with urllib.request.urlopen(
+                agent.address + "/v1/metrics?format=prometheus",
+                timeout=60) as r:
+            text = r.read().decode()
+        assert "# TYPE nomad_materialize_placements counter" in text
+        for form in ("block", "rows"):
+            assert re.search(
+                r'^nomad_materialize_placements\{form="%s"\} \d+$' % form,
+                text, re.M)
+    finally:
+        agent.shutdown()

@@ -264,6 +264,50 @@ class TestBatchedWorkerPath:
         assert REGISTRY.counter_labels("nomad.spread.evals_solo").get(
             "rule=deployment", 0.0) - solo0 == 1
 
+    def test_solo_evals_of_one_pass_each_meet_the_applier_fenced(self):
+        """Four service jobs with a spread stanza and an update stanza
+        (off the wave: rule `deployment`) of 80 in one pass: each takes a
+        view of its own after the pass's first commit, so every plan is
+        fence-tagged from the state it was computed against and commits
+        as ONE block on the applier's fast path, no row built for a
+        re-fit (ISSUE 35: tagged from the batch's snapshot, three of the
+        four were re-fitted node by node)."""
+        from nomad_tpu.structs import Spread, SpreadTarget
+        s = Server(dev_mode=True, eval_batch=64, mesh=False)
+        s.establish_leadership()
+        for i in range(30):
+            n = mock.node()
+            n.datacenter = f"dc{1 + i % 3}"
+            s.register_node(n, now=NOW)
+        jobs = []
+        for _ in range(4):
+            job = mock.job()
+            job.datacenters = ["dc1", "dc2", "dc3"]
+            job.task_groups[0].count = 80
+            job.task_groups[0].tasks[0].resources.cpu = 10
+            job.task_groups[0].tasks[0].resources.memory_mb = 10
+            job.spreads = [Spread(
+                attribute="${node.datacenter}", weight=50,
+                targets=[SpreadTarget("dc1", 50), SpreadTarget("dc2", 30),
+                         SpreadTarget("dc3", 20)])]
+            s.register_job(job, now=NOW)
+            jobs.append(job)
+        stats = s.plan_applier.stats
+        before = {k: stats.get(k, 0) for k in ("fast_path", "full_check")}
+        s.process_all(now=NOW)
+        assert s.workers[0].pipeline.stats["waves"] == 0
+        assert stats.get("fast_path", 0) - before["fast_path"] == 4
+        assert stats.get("full_check", 0) == before["full_check"]
+        blocks = s.state.snapshot().alloc_blocks()
+        assert sorted(b.count for b in blocks) == [80] * 4
+        assert all(b._rows is None for b in blocks)
+        snap = s.state.snapshot()
+        for job in jobs:
+            by_dc = {"dc1": 0, "dc2": 0, "dc3": 0}
+            for a in snap.allocs_by_job(job.namespace, job.id):
+                by_dc[snap.node_by_id(a.node_id).datacenter] += 1
+            assert by_dc == {"dc1": 40, "dc2": 24, "dc3": 16}
+
     def test_spread_job_without_update_stanza_rides_the_batch(self):
         from nomad_tpu.core.telemetry import REGISTRY
         rode0 = REGISTRY.counter_sum("nomad.spread.evals_batched")

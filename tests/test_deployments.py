@@ -359,3 +359,99 @@ class TestProgressDeadline:
         dep = s.state.deployment_by_id(dep.id)
         assert dep.status == DEPLOYMENT_STATUS_FAILED
         assert "progress deadline" in dep.status_description.lower()
+
+
+class TestBlockCommittedDeployment:
+    """ISSUE 35: a deployment no longer bars the columnar commit.  A
+    service job with an update stanza, 80 fresh placements and no spread
+    rides the bulk kernel and commits as ONE AllocBlock whose template
+    carries the deployment's id; the lifecycle runs as it did on rows."""
+
+    def _server(self, n_nodes=12):
+        s = Server(dev_mode=True)
+        s.establish_leadership()
+        for _ in range(n_nodes):
+            s.register_node(mock.node(), now=NOW)
+        return s
+
+    def _job(self, **update_kw):
+        j = _service_job(count=80, **update_kw)
+        j.task_groups[0].tasks[0].resources.cpu = 20
+        j.task_groups[0].tasks[0].resources.memory_mb = 16
+        return j
+
+    def _registered(self, **update_kw):
+        s = self._server()
+        job = self._job(**update_kw)
+        s.register_job(job, now=NOW)
+        s.process_all(now=NOW)
+        return s, job
+
+    def test_commits_as_one_block_with_the_deployments_id(self):
+        s, job = self._registered()
+        (block,) = s.state._alloc_blocks.values()
+        assert block.count == 80 and block.row_metrics is None
+        assert block.metrics, "the bulk kernel's per-round metrics"
+        assert not s.state._allocs_by_job.get((job.namespace, job.id))
+        dep = s.state.latest_deployment_by_job(job.namespace, job.id)
+        assert dep.status == DEPLOYMENT_STATUS_RUNNING
+        assert dep.task_groups["web"].desired_total == 80
+        s.deployments.tick(now=NOW + 1)
+        dep = s.state.deployment_by_id(dep.id)
+        assert dep.task_groups["web"].placed_allocs == 80
+        # the tick counted the block off its columns: no row was built
+        assert block._rows is None and s.state._alloc_blocks
+        rows = _live(s, job)
+        assert len(rows) == 80
+        assert {a.deployment_id for a in rows} == {dep.id}
+
+    def test_completes_and_marks_stable(self):
+        s = self._server()
+        job = self._job()
+        dep = _stable_v0(s, job)
+        assert dep.task_groups["web"].healthy_allocs == 80
+        assert len(_live(s, job)) == 80
+
+    def test_unhealthy_row_fails_the_deployment(self):
+        s, job = self._registered()
+        dep = s.state.latest_deployment_by_job(job.namespace, job.id)
+        rows = _live(s, job)
+        _set_health(s, rows[:1], healthy=False)
+        s.deployments.tick(now=NOW + 1)
+        dep = s.state.deployment_by_id(dep.id)
+        assert dep.status == DEPLOYMENT_STATUS_FAILED
+        assert "unhealthy" in dep.status_description.lower()
+
+    def test_manual_fail_and_pause(self):
+        s, job = self._registered()
+        dep = s.state.latest_deployment_by_job(job.namespace, job.id)
+        assert s.deployments.pause(dep.id, True) is None
+        assert s.state.deployment_by_id(dep.id).status == "paused"
+        assert s.deployments.pause(dep.id, False, now=NOW + 1) is None
+        assert s.deployments.fail(dep.id, now=NOW + 2) is None
+        assert s.state.deployment_by_id(dep.id).status == \
+            DEPLOYMENT_STATUS_FAILED
+
+    def test_canaries_beside_a_block_promote_and_roll(self):
+        s = self._server()
+        job = self._job()
+        _stable_v0(s, job)
+        v1 = _mutate(job)
+        v1.update = UpdateStrategy(max_parallel=40, canary=2,
+                                   progress_deadline_s=600.0)
+        s.register_job(v1, now=NOW + 100)
+        s.process_all(now=NOW + 100)
+        dep = s.state.latest_deployment_by_job(v1.namespace, v1.id)
+        canaries = [a for a in _live(s, v1) if a.job_version == 1]
+        assert len(canaries) == 2
+        assert sorted(dep.task_groups["web"].placed_canaries) == \
+            sorted(a.id for a in canaries)
+        assert s.deployments.promote(dep.id, now=NOW + 101) == \
+            "canaries are not healthy"
+        _set_health(s, canaries, healthy=True)
+        assert s.deployments.promote(dep.id, now=NOW + 102) is None
+        final = _drive_to_completion(s, v1, now=NOW + 110)
+        assert final.status == DEPLOYMENT_STATUS_SUCCESSFUL
+        live = _live(s, v1)
+        assert len(live) == 80
+        assert all(a.job_version == final.job_version for a in live)

@@ -444,3 +444,225 @@ class TestPortExhaustionFallback:
         assert placed[0].allocated_ports == {"http": 8080}
         # host redirection dropped the fence: the applier full-checks
         assert plan.coupled_batch is None and plan.host_redirected
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 35: the exact scan's fresh placements leave the engine as arrays
+# and commit as ONE AllocBlock whose per-row metrics are columns
+# ---------------------------------------------------------------------------
+
+def spread_fleet(h, n_nodes=60, cpu=4000):
+    """`n_nodes` over three datacenters and five racks."""
+    nodes = []
+    for i in range(n_nodes):
+        n = mock.node()
+        n.datacenter = f"dc{1 + i % 3}"
+        n.meta["rack"] = f"r{i % 5}"
+        n.resources.cpu = cpu
+        nodes.append(n)
+    h.state.upsert_nodes(nodes)
+    return nodes
+
+
+def spread_job(count, cpu=10):
+    """spread5k's shape: a service job (mock.job's update stanza: a
+    deployment) with a spread over the datacenters and a rack affinity,
+    so the exact scan places it."""
+    from nomad_tpu.structs import OP_EQ, Affinity, Spread, SpreadTarget
+    job = mock.job()
+    job.datacenters = ["dc1", "dc2", "dc3"]
+    tg = job.task_groups[0]
+    tg.count = count
+    tg.tasks[0].resources = Resources(cpu=cpu, memory_mb=10)
+    job.spreads = [Spread(
+        attribute="${node.datacenter}", weight=50,
+        targets=(SpreadTarget("dc1", 50), SpreadTarget("dc2", 30),
+                 SpreadTarget("dc3", 20)))]
+    job.affinities = [Affinity("${meta.rack}", OP_EQ, "r3", weight=50)]
+    return job
+
+
+def plan_rows(plan):
+    return [a for allocs in plan.node_allocation.values() for a in allocs]
+
+
+def process_beside_decisions(h, job):
+    """Run `job`'s eval through the Harness and, from the SAME inputs of
+    its one `engine.place` call (same snapshot, same seed), the rows the
+    per-decision path builds: (plan, scheduler's eval update, reference
+    rows by name, reference scheduler)."""
+    from nomad_tpu.scheduler import new_scheduler
+    from nomad_tpu.scheduler.reconcile import (PlaceRequest,
+                                               ReconcileResults, _name)
+    from nomad_tpu.ops import PlacementRequest
+    from nomad_tpu.structs import Plan
+
+    e = register_and_eval(h, job)
+    real = h.engine.place
+    seen = []
+
+    def place(*args, **kw):
+        # the reference first: neither call writes state
+        seen.append(real(*args, **dict(kw, bulk_api=False)))
+        return real(*args, **kw)
+
+    h.engine.place = place
+    try:
+        assert h.process("service", e, now=NOW) is None
+    finally:
+        h.engine.place = real
+    assert len(seen) == 1
+    plan = h.plans[-1]
+    job = plan.job               # the store's copy, as the scheduler read it
+    tg = job.task_groups[0]
+    ref = new_scheduler("service", h.snapshot(), h, engine=h.engine, now=NOW)
+    ref.queued_allocs = {tg.name: 0}
+    places = [PlaceRequest(tg=tg, name=_name(job, tg, ix), index=ix)
+              for ix in range(tg.count)]
+    ref_plan = Plan(eval_id=e.id, job=job)
+    ref._materialize_decisions(
+        ref_plan, job, places,
+        [PlacementRequest(tg_name=tg.name)] * tg.count, seen[0], e,
+        ReconcileResults(deployment=plan.deployment), [])
+    return plan, h.evals[-1], {a.name: a for a in plan_rows(ref_plan)}, ref
+
+
+def assert_rows_equal(got, want):
+    """Field for field but the id (and the two indexes the commit
+    stamps, and the wall-clock reading of the place call)."""
+    skip = {"id", "create_index", "modify_index", "metrics"}
+    g = {k: v for k, v in got.__dict__.items() if k not in skip}
+    w = {k: v for k, v in want.__dict__.items() if k not in skip}
+    assert g == w
+    gm, wm = dict(got.metrics.__dict__), dict(want.metrics.__dict__)
+    gm.pop("allocation_time_ns"), wm.pop("allocation_time_ns")
+    assert gm == wm
+
+
+class TestScanBlock:
+    @pytest.mark.parametrize("count, cpu", [(100, 10), (300, 700)])
+    def test_one_block_whose_rows_are_the_decision_paths(self, count, cpu):
+        """300 x 700 MHz fills nodes as it goes (five a node), so late
+        rows carry nodes_exhausted and dimension_exhausted."""
+        h = Harness()
+        spread_fleet(h, 60)
+        job = spread_job(count, cpu=cpu)
+        plan, _, want, _ = process_beside_decisions(h, job)
+        assert len(plan.alloc_blocks) == 1 and not plan.node_allocation
+        block = plan.alloc_blocks[0]
+        assert block.count == count == len(want)
+        assert block.row_metrics is not None and not block.metrics
+        rows = block.materialize_all()
+        assert plan.deployment is not None
+        assert {a.deployment_id for a in rows} == {plan.deployment.id}
+        for a in rows:
+            assert_rows_equal(a, want[a.name])
+        # three candidates a row until fewer than three nodes are left
+        assert len(rows[0].metrics.score_meta_data) == 3
+        assert all(1 <= len(a.metrics.score_meta_data) <= 3 for a in rows)
+        if cpu == 700:
+            assert any(a.metrics.dimension_exhausted.get("cpu")
+                       for a in rows)
+            assert any(a.metrics.nodes_exhausted for a in rows)
+        # the store serves the same rows, per job and per node
+        snap = h.snapshot()
+        assert {a.id for a in snap.allocs_by_job(job.namespace, job.id)} \
+            == set(block.ids)
+        assert sum(len(snap.allocs_by_node(nid))
+                   for nid in block.node_table) == count
+
+    def test_failed_picks_count_as_the_decision_loop_counts(self):
+        """180 fit (60 nodes x 3 of 1,300 MHz), 120 fail."""
+        h = Harness()
+        spread_fleet(h, 60)
+        job = spread_job(300, cpu=1300)
+        plan, update, want, ref = process_beside_decisions(h, job)
+        block = plan.alloc_blocks[0]
+        assert block.count == 180 == len(want) and not plan.node_allocation
+        for a in block.materialize_all():
+            assert_rows_equal(a, want[a.name])
+        assert update.queued_allocations == ref.queued_allocs == {"web": 120}
+        got, ref_m = update.failed_tg_allocs["web"], \
+            ref.failed_tg_allocs["web"]
+        assert got.coalesced_failures == ref_m.coalesced_failures == 119
+        gm, wm = dict(got.__dict__), dict(ref_m.__dict__)
+        gm.pop("allocation_time_ns"), wm.pop("allocation_time_ns")
+        assert gm == wm and got.dimension_exhausted.get("cpu")
+        assert h.create_evals[-1].status == "blocked"
+
+    @pytest.mark.parametrize("case", ["evictions", "canary",
+                                      "previous_alloc", "device_ask",
+                                      "under_64"])
+    def test_per_allocation_by_nature_keeps_rows(self, case):
+        from nomad_tpu.structs import (PreemptionConfig, RequestedDevice,
+                                       NodeDeviceResource,
+                                       SchedulerConfiguration,
+                                       UpdateStrategy)
+        h = Harness()
+        nodes = spread_fleet(h, 30)
+        job = spread_job(100)
+        if case == "under_64":
+            job.task_groups[0].count = 63
+        elif case == "device_ask":
+            for n in nodes:
+                n.resources.devices = [NodeDeviceResource(
+                    vendor="nvidia", type="gpu", name="t4",
+                    instance_ids=[f"{n.id[:8]}-{i}" for i in range(4)])]
+            h.state.upsert_nodes(nodes)
+            job.task_groups[0].tasks[0].resources.devices = [
+                RequestedDevice(name="nvidia/gpu", count=1)]
+        elif case == "evictions":
+            h.state.set_scheduler_config(SchedulerConfiguration(
+                preemption_config=PreemptionConfig(
+                    service_scheduler_enabled=True)))
+            low = mock.batch_job(priority=20)
+            low.datacenters = job.datacenters
+            low.task_groups[0].count = 30 * 3
+            low.task_groups[0].tasks[0].resources = Resources(
+                cpu=1300, memory_mb=64)
+            le = register_and_eval(h, low)
+            assert h.process("batch", le, now=NOW) is None
+            job.priority = 100
+            job.task_groups[0].count = 80      # 90 fit, an eviction each
+            job.task_groups[0].tasks[0].resources = Resources(
+                cpu=1000, memory_mb=64)
+        elif case in ("canary", "previous_alloc"):
+            # v0 first: 100 fresh placements, one block
+            e0 = register_and_eval(h, job)
+            assert h.process("service", e0, now=NOW) is None
+            assert h.plans[-1].alloc_blocks
+            if case == "canary":
+                import copy
+                job = copy.deepcopy(job)
+                job.version = 1
+                job.update = UpdateStrategy(max_parallel=1, canary=2)
+                job.task_groups[0].tasks[0].config = {"command": "/bin/w"}
+            else:
+                held = h.snapshot().allocs_by_job(job.namespace, job.id)
+                h.state.update_node_status(held[0].node_id, "down")
+        if case == "previous_alloc":
+            e = mock.eval(job_id=job.id, type=job.type)    # same version
+            h.state.upsert_evals([e])
+        else:
+            e = register_and_eval(h, job)
+        assert h.process("service", e, now=NOW + 1) is None
+        plan = h.plans[-1]
+        assert not plan.alloc_blocks
+        rows = plan_rows(plan)
+        assert rows and all(a.metrics is not None for a in rows)
+        if case == "under_64":
+            assert len(rows) == 63
+        elif case == "device_ask":
+            assert len(rows) == 100
+            assert all(len(a.allocated_devices[0].device_ids) == 1
+                       for a in rows)
+        elif case == "evictions":
+            assert len(rows) == 80
+            assert plan.node_preemptions
+            assert all(a.preempted_allocations for a in rows)
+        elif case == "canary":
+            assert len(rows) == 2
+            assert sorted(plan.deployment.task_groups["web"]
+                          .placed_canaries) == sorted(a.id for a in rows)
+        else:
+            assert rows and all(a.previous_allocation for a in rows)
