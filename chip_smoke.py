@@ -122,10 +122,12 @@ def run_leg(sizes: dict, seed: int, mesh, platform: str) -> dict:
     from nomad_tpu.agent import Agent
     from nomad_tpu.api.client import APIClient
     from nomad_tpu.core.logging import RING
+    from nomad_tpu.ops.engine import mesh_launches_by_program
     from nomad_tpu.structs import CSIVolume, codec
     from nomad_tpu.structs import structs as structs_mod
 
     failures: list = []
+    sharded0 = mesh_launches_by_program()     # the counter is the process's
     phase_s: dict = {}
     t_leg = time.time()
     ring_level = RING.min_level
@@ -325,14 +327,29 @@ def run_leg(sizes: dict, seed: int, mesh, platform: str) -> dict:
                                    for d in arr.devices()})
         check(tensor_platforms == [platform],
               f"node tensors on {platform} (got {tensor_platforms})")
+        # the node-sharded programs this leg launched, by the name a
+        # trace shows them under (parallel/mesh.py PROGRAM_NAMES)
+        sharded = {name: n - sharded0.get(name, 0)
+                   for name, n in mesh_launches_by_program().items()
+                   if n > sharded0.get(name, 0)}
         if server.engine.mesh is not None:
             check(ex.stats["collective_bytes"] > 0,
                   "mesh launches metered collective bytes")
+            for name in ("place_multi_compact_sharded",
+                         "place_multi_compact_sharded_chained",
+                         "place_sharded_packed", "scatter_add_sharded"):
+                check(sharded.get(name, 0) > 0,
+                      f"{name} launched (got {sharded})")
+        else:
+            check(not sharded,
+                  f"no sharded program launched with the mesh off "
+                  f"(got {sharded})")
 
         return {
             "failures": failures,
             "mesh_devices": (server.engine.n_devices
                              if server.engine.mesh is not None else 0),
+            "sharded_programs": sharded,
             "evals": dict(final),
             "placed": sum(placed.values()),
             "asked": sum(asked.values()),

@@ -74,6 +74,11 @@ from nomad_tpu.core.telemetry import (
 #                (ops/engine.py _lower_wave_spreads: landscapes by node
 #                table version, the jobs' expected and existing counts);
 #                lies INSIDE that wave's dispatch
+#   mesh_launch  worker: the call of a wave's node-sharded program
+#                (ops/engine.py dispatch_batch, only where the engine has
+#                a mesh): the wave's replicated inputs go to every device
+#                and one execution a device is enqueued; lies INSIDE that
+#                wave's dispatch
 #   device       NOT a thread's wall: from the dispatch's return to the
 #                collect's wake-up, so it spans the predecessor's host
 #                phase (recorded through `record`, never emitted)
@@ -109,19 +114,21 @@ from nomad_tpu.core.telemetry import (
 #                wait for the event left out
 # Worker stages other than "pass" do not nest in one another, but for
 # device_carve (and the solo device path's redo), each inside a
-# materialize, and spread_lower, inside a dispatch: the unnamed part of a
-# pass is its wall minus their UNION (benchmark/host_spans.py
-# View.named), which a nested span leaves as it was; a per-stage sum must
-# leave device_carve and spread_lower out or count them twice.
+# materialize, and spread_lower and mesh_launch, inside a dispatch: the
+# unnamed part of a pass is its wall minus their UNION
+# (benchmark/host_spans.py View.named), which a nested span leaves as it
+# was; a per-stage sum must leave device_carve, spread_lower and
+# mesh_launch out or count them twice.
 #
 # Two more names reach the profiler's trace and are no stage (nothing
 # records them here): `nomad.gc`, a collection of generation 1 or 2 on
 # the line of the thread it struck, inside whatever stage it stretched
 # (core/telemetry.py), and `nomad.cpu`, the marker below.
-STAGES = ("dequeue", "pass", "prepare", "dispatch", "spread_lower", "device",
-          "device_wait", "d2h", "solo_place", "system_place", "materialize",
-          "device_carve", "plan_wait", "finalize", "batch_admin",
-          "eval_update", "ack", "commit", "store_upsert", "stream_send")
+STAGES = ("dequeue", "pass", "prepare", "dispatch", "spread_lower",
+          "mesh_launch", "device", "device_wait", "d2h", "solo_place",
+          "system_place", "materialize", "device_carve", "plan_wait",
+          "finalize", "batch_admin", "eval_update", "ack", "commit",
+          "store_upsert", "stream_send")
 
 _SERIES = {s: f"nomad.wavepipe.{s}_s" for s in STAGES}
 
@@ -428,6 +435,8 @@ class WavePipeline:
             fields["rounds"] = int(pending.get("rounds", 0))
             fields["rounds_padded"] = int(pending.get("rounds_padded", 0))
             fields["resident"] = bool(pending.get("chained"))
+            # devices the launch's node axis was sharded over (1: none)
+            fields["mesh_devices"] = int(pending.get("mesh_devices", 1))
             for key in ("collective_bytes", "shard_h2d_bytes"):
                 if pending.get(key):
                     fields[key] = int(pending[key])

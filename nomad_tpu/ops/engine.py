@@ -60,6 +60,12 @@ BULK_ROUND = 1024
 # multi-second device compile the first time each size appeared)
 SCATTER_CHUNK = 16384
 _scatter_add_jit = jax.jit(lambda u, r, v: u.at[r].add(v))
+# the single-device wave programs by `dispatch_batch`'s launch kind (their
+# node-sharded twins: SHARDED_KINDS below)
+_WAVE_JITS = {"multi": place_multi_packed_jit,
+              "multi_chained": place_multi_chained_jit,
+              "multi_compact": place_multi_compact_packed_jit,
+              "multi_compact_chained": place_multi_compact_chained_jit}
 
 
 # Process-wide mesh + sharded-kernel caches.  Critically NOT per-engine:
@@ -101,36 +107,42 @@ def _default_mesh():
     return _MESH_SINGLETON
 
 
+# Every program the node-sharded path launches, by the engine's launch
+# kind: (its builder in parallel/mesh.py, the builder's keyword
+# arguments).  The chained kinds are the donated-chain variants: wave
+# k+1 consumes wave k's dead sharded usage buffer in place.  On an engine
+# with a mesh each launch of one of these counts in
+# `nomad.engine.mesh_launches{kind}` (`PlacementEngine._launch`).
+SHARDED_KINDS = {
+    "scan": ("place_sharded_packed_fn", {}),
+    "bulk": ("place_bulk_sharded_packed_fn", {}),
+    "multi": ("place_multi_sharded_packed_fn", {}),
+    "multi_chained": ("place_multi_sharded_packed_fn", {"chained": True}),
+    "multi_compact": ("place_multi_compact_sharded_fn", {}),
+    "multi_compact_chained": ("place_multi_compact_sharded_fn",
+                              {"chained": True}),
+    "scatter": ("scatter_add_sharded_fn", {}),
+}
+
+
 def _sharded_fn(mesh, kind: str, *shape_args):
     key = (kind, tuple(d.id for d in mesh.devices.flat)) + shape_args
     fn = _SHARDED_FN_CACHE.get(key)
     if fn is None:
-        if kind == "scatter":
-            from jax.sharding import NamedSharding, PartitionSpec
-            fn = jax.jit(
-                lambda u, r, v: u.at[r].add(v),
-                out_shardings=NamedSharding(mesh,
-                                            PartitionSpec("nodes", None)))
-        else:
-            from functools import partial as _p
-
-            from nomad_tpu.parallel import mesh as pmesh
-            builder = {"scan": pmesh.place_sharded_packed_fn,
-                       "bulk": pmesh.place_bulk_sharded_packed_fn,
-                       "multi": pmesh.place_multi_sharded_packed_fn,
-                       "multi_compact":
-                           pmesh.place_multi_compact_sharded_fn,
-                       # donated-chain variants: wave k+1 consumes wave
-                       # k's dead sharded usage buffer in place
-                       "multi_chained":
-                           _p(pmesh.place_multi_sharded_packed_fn,
-                              chained=True),
-                       "multi_compact_chained":
-                           _p(pmesh.place_multi_compact_sharded_fn,
-                              chained=True)}[kind]
-            fn = builder(mesh, *shape_args)
+        from nomad_tpu.parallel import mesh as pmesh
+        builder, kwargs = SHARDED_KINDS[kind]
+        fn = getattr(pmesh, builder)(mesh, *shape_args, **kwargs)
         _SHARDED_FN_CACHE[key] = fn
     return fn
+
+
+def mesh_launches_by_program() -> Dict[str, int]:
+    """{program name as a trace shows it less its `jit_`: launches} of
+    the node-sharded programs this process has launched, from
+    `nomad.engine.mesh_launches{kind}` and the programs built."""
+    names = {key[0]: fn.__name__ for key, fn in _SHARDED_FN_CACHE.items()}
+    return {names[label.partition("=")[2]]: int(n) for label, n in
+            _registry().counter_labels("nomad.engine.mesh_launches").items()}
 
 
 def _pad_rows(a: np.ndarray, n_pad: int, fill=0) -> np.ndarray:
@@ -468,6 +480,8 @@ class PlacementEngine:
         kernel kind + the static shape arguments."""
         site = f"engine.{kind}/" + "x".join(str(s) for s in shape_key)
         key = (kind, shape_key)
+        if self.mesh is not None and kind in SHARDED_KINDS:
+            _registry().inc("nomad.engine.mesh_launches", kind=kind)
         t0 = time.perf_counter()
         out = fn(*args)
         dt = time.perf_counter() - t0
@@ -1597,62 +1611,39 @@ class PlacementEngine:
             return built                 # empty-cluster sentinel
         inp, rs, aux = built["inp"], built["rs"], built
         chained = aux.get("chained", False)
-        fills_full = None
-        fill_k = None
-        coll_bytes = 0
+        compact = aux["cand_rows"] is not None
         skey = (rs, aux["npad"], aux["n_lanes"])
-        if aux["cand_rows"] is not None:
-            cr, cv = aux["cand_dev"]
-            if self.mesh is not None:
-                if chained:
-                    # donated sharded chain: wave k's dead sharded usage
-                    # buffer is reused in place, exactly like the
-                    # single-device place_multi_compact_chained_jit
-                    buf, fills_full, used_out = self._launch(
-                        "multi_compact_chained", skey,
-                        self._sharded("multi_compact_chained", rs,
-                                      aux["n_lanes"]),
-                        inp.used0, inp._replace(used0=None), cr, cv)
-                else:
-                    buf, fills_full, used_out = self._launch(
-                        "multi_compact", skey,
-                        self._sharded("multi_compact", rs,
-                                      aux["n_lanes"]),
-                        inp, cr, cv)
-                coll_bytes = self._note_collective(
-                    int(inp.round_g.shape[0]),
-                    min(rs, int(aux["cand_rows"].shape[-1])))
-            elif chained:
-                buf, fills_full, used_out = self._launch(
-                    "multi_compact_chained", skey,
-                    place_multi_compact_chained_jit,
-                    inp.used0, inp._replace(used0=None), cr, cv,
-                    rs, aux["n_lanes"])
-            else:
-                buf, fills_full, used_out = self._launch(
-                    "multi_compact", skey,
-                    place_multi_compact_packed_jit,
-                    inp, cr, cv, rs, aux["n_lanes"])
-            fill_k = min(FILL_K, rs)
-        elif self.mesh is not None:
-            if chained:
-                buf, used_out, _ = self._launch(
-                    "multi_chained", skey,
-                    self._sharded("multi_chained", rs),
-                    inp.used0, inp._replace(used0=None))
-            else:
-                buf, used_out, _ = self._launch(
-                    "multi", skey, self._sharded("multi", rs), inp)
+        # one of four programs, on either deployment: the compact laned
+        # kernel or the flat one, fresh or chained.  A chained launch goes
+        # through the DONATED-usage variant: the previous wave's usage
+        # buffer is dead once consumed, so XLA reuses it in place
+        kind = (("multi_compact" if compact else "multi")
+                + ("_chained" if chained else ""))
+        statics = (rs, aux["n_lanes"]) if compact else (rs,)
+        args = (inp.used0, inp._replace(used0=None)) if chained else (inp,)
+        if compact:
+            args += tuple(aux["cand_dev"])
+        coll_bytes = 0
+        if self.mesh is not None:
+            fn = self._sharded(kind, *statics)
+            # the host's side of a sharded launch: the wave's replicated
+            # inputs go to every device and one execution a device is
+            # enqueued (inside the wave's `dispatch`, as `spread_lower`)
+            with (self.timers.time("mesh_launch") if self.timers is not None
+                  else contextlib.nullcontext()):
+                out = self._launch(kind, skey, fn, *args)
             coll_bytes = self._note_collective(
                 int(inp.round_g.shape[0]),
-                min(rs, aux["npad"] // self._ndev))
-        elif chained:
-            buf, used_out, _ = self._launch(
-                "multi_chained", skey, place_multi_chained_jit,
-                inp.used0, inp._replace(used0=None), rs)
+                min(rs, int(aux["cand_rows"].shape[-1]) if compact
+                    else aux["npad"] // self._ndev))
         else:
-            buf, used_out, _ = self._launch(
-                "multi", skey, place_multi_packed_jit, inp, rs)
+            out = self._launch(kind, skey, _WAVE_JITS[kind], *args, *statics)
+        fills_full = fill_k = None
+        if compact:
+            buf, fills_full, used_out = out
+            fill_k = min(FILL_K, rs)
+        else:
+            buf, used_out, _ = out
         # start the device->host copy of the result buffer NOW: queued
         # behind the compute, a prefetched batch's transfer rides out
         # the PREVIOUS batch's host phase instead of blocking collect
@@ -1669,6 +1660,7 @@ class PlacementEngine:
                 "perm": aux["perm"], "fills_full": fills_full,
                 "fill_k": fill_k, "chained": chained,
                 "collective_bytes": coll_bytes,
+                "mesh_devices": self._ndev,
                 "shard_h2d_bytes": self.shard_h2d_bytes - shard_b0,
                 "padded_fraction":
                     (aux["npad"] - aux["n"]) / aux["npad"],

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 from jax.lax import pcast
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from nomad_tpu.ops.feasibility import constraint_mask
 from nomad_tpu.ops.scoring import affinity_score
@@ -50,6 +50,22 @@ from nomad_tpu.ops.select import (
 )
 
 AXIS = "nodes"
+
+# The jitted programs this module builds, by the name each carries in a
+# profiler trace (`jit_<name>`) and in `jax.jit(...).__name__`.  A
+# placement kernel's name starts with `place_`, as the single-device
+# programs' do (ops/select.py), and says `sharded`, so a trace tells the
+# two deployments apart; `ops/engine.py SHARDED_KINDS` maps the engine's
+# launch kinds onto the builders.
+PROGRAM_NAMES = (
+    "place_sharded_packed",
+    "place_bulk_sharded_packed",
+    "place_multi_sharded_packed",
+    "place_multi_sharded_chained",
+    "place_multi_compact_sharded",
+    "place_multi_compact_sharded_chained",
+    "scatter_add_sharded",
+)
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -221,10 +237,10 @@ def place_sharded_packed_fn(mesh: Mesh):
                           in_specs=(in_specs,), out_specs=out_specs,
                           check_vma=False)
 
-    def f(inp):
+    def place_sharded_packed(inp):
         return pack_outputs(inner(inp))
 
-    return jax.jit(f)
+    return jax.jit(place_sharded_packed)
 
 
 # ------------------------------------------------------------ bulk kernel
@@ -457,7 +473,7 @@ def place_multi_sharded_packed_fn(mesh: Mesh, round_size: int,
         mesh=mesh, in_specs=(in_specs,), out_specs=out_specs,
         check_vma=False)
 
-    def f(inp: MultiEvalInputs):
+    def place_multi_sharded_packed(inp: MultiEvalInputs):
         n = inp.attrs.shape[0]
         assert n < (1 << 20), "packed fill rows support < 2^20 nodes"
         assert round_size <= 1024, "packed fill counts support rounds <= 1024"
@@ -470,12 +486,12 @@ def place_multi_sharded_packed_fn(mesh: Mesh, round_size: int,
         return buf, used, jc
 
     if not chained:
-        return jax.jit(f)
+        return jax.jit(place_multi_sharded_packed)
 
-    def f_chained(used0, inp: MultiEvalInputs):
-        return f(inp._replace(used0=used0))
+    def place_multi_sharded_chained(used0, inp: MultiEvalInputs):
+        return place_multi_sharded_packed(inp._replace(used0=used0))
 
-    return jax.jit(f_chained, donate_argnums=(0,))
+    return jax.jit(place_multi_sharded_chained, donate_argnums=(0,))
 
 
 def _multi_compact_local(inp: MultiEvalInputs, cand_rows, cand_valid,
@@ -593,7 +609,8 @@ def place_multi_compact_sharded_fn(mesh: Mesh, round_size: int,
         out_specs=out_specs, check_vma=False)
     fill_k = min(FILL_K, round_size)
 
-    def f(inp: MultiEvalInputs, cand_rows, cand_valid):
+    def place_multi_compact_sharded(inp: MultiEvalInputs, cand_rows,
+                                    cand_valid):
         n = inp.attrs.shape[0]
         assert n < (1 << 20), "packed fill rows support < 2^20 nodes"
         assert round_size <= 1024, "packed fill counts support rounds <= 1024"
@@ -612,12 +629,15 @@ def place_multi_compact_sharded_fn(mesh: Mesh, round_size: int,
         return buf_small, fills, used
 
     if not chained:
-        return jax.jit(f)
+        return jax.jit(place_multi_compact_sharded)
 
-    def f_chained(used0, inp: MultiEvalInputs, cand_rows, cand_valid):
-        return f(inp._replace(used0=used0), cand_rows, cand_valid)
+    def place_multi_compact_sharded_chained(used0, inp: MultiEvalInputs,
+                                            cand_rows, cand_valid):
+        return place_multi_compact_sharded(inp._replace(used0=used0),
+                                           cand_rows, cand_valid)
 
-    return jax.jit(f_chained, donate_argnums=(0,))
+    return jax.jit(place_multi_compact_sharded_chained,
+                   donate_argnums=(0,))
 
 
 def place_bulk_sharded_packed_fn(mesh: Mesh, round_size: int,
@@ -645,7 +665,7 @@ def place_bulk_sharded_packed_fn(mesh: Mesh, round_size: int,
         mesh=mesh, in_specs=(in_specs,), out_specs=out_specs,
         check_vma=False)
 
-    def f(inp: BulkInputs):
+    def place_bulk_sharded_packed(inp: BulkInputs):
         # same guards as the single-device place_bulk_packed: the fill
         # encoding (row*2048+count) needs n < 2^20 and counts < 2048, and
         # n < 2^20 also keeps the float32 row/count transit through
@@ -661,4 +681,16 @@ def place_bulk_sharded_packed_fn(mesh: Mesh, round_size: int,
         buf = jnp.concatenate([fills, meta], axis=1)
         return buf, used, job_count
 
-    return jax.jit(f)
+    return jax.jit(place_bulk_sharded_packed)
+
+
+def scatter_add_sharded_fn(mesh: Mesh):
+    """The usage deltas' replay on a node-sharded `used` (rows, values
+    replicated; the result keeps the node sharding): what the engine's
+    single-device `_scatter_add_jit` is to one chip."""
+
+    def scatter_add_sharded(used, rows, vals):
+        return used.at[rows].add(vals)
+
+    return jax.jit(scatter_add_sharded,
+                   out_shardings=NamedSharding(mesh, P(AXIS, None)))

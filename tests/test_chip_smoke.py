@@ -35,6 +35,11 @@ def test_cpu_dry_run_passes_and_reports(capsys):
     leg = rep["legs"]["served"]
     # conftest's 8 virtual devices: the default path is the sharded one
     assert leg["mesh_devices"] == jax.device_count()
+    from nomad_tpu.parallel.mesh import PROGRAM_NAMES
+    assert {"place_multi_compact_sharded",
+            "place_multi_compact_sharded_chained", "place_sharded_packed",
+            "scatter_add_sharded"} <= set(leg["sharded_programs"]) <= set(
+                PROGRAM_NAMES)
     n_batch = chip_smoke.TINY["jobs"] + 2 * chip_smoke.ZONES
     assert leg["evals"] == {"complete": n_batch + 1}
     assert leg["placed"] == leg["asked"] == (
