@@ -1295,6 +1295,12 @@ class PlacementEngine:
                     p_pad, min(TOP_K, npad // self._ndev),
                     width=2, extra=128)
             else:
+                # what select.place_packed's trip count will meet: the
+                # steps it runs, and the padding it passes by
+                for kind, steps in (("run", p_real),
+                                    ("padded", p_pad - p_real)):
+                    _registry().inc("nomad.engine.scan_steps", steps,
+                                    kind=kind)
                 buf, used_dev, job_count_dev = self._launch(
                     "scan", (npad, p_pad), place_packed_jit, inp)
             b = self._fetch(buf)[:p_real]

@@ -4,14 +4,19 @@ Replaces the reference's per-placement iterator walk + LimitIterator(2) +
 MaxScoreIterator (scheduler/select.go) with full-cluster scoring and an
 exact argmax — stock Nomad scores a 2-node random subset per placement
 (power-of-two-choices); we score *every* feasible node, so placement quality
-strictly dominates stock while still being faster.
+strictly dominates stock while still being faster.  In the exact scan
+(`place_packed`) the argmax is one: a reduce over the nodes that returns
+the maximum, its lowest row and the score there, and the reported
+runners-up with it (`_top_max`); the rounds-based kernels below take the
+top `round_size` by a sort, or the same reduce where a round wants one.
 
 The subtle part (SURVEY.md §4.3): placements within one plan see each other —
 capacity, job anti-affinity counts, spread counts, distinct_hosts all update
 as the plan grows.  That sequential dependence is preserved exactly with a
-`lax.scan` over the placement axis; everything inside one step is vectorized
-over all N nodes (and the static feasibility/affinity tensors are computed
-once for all G task groups before the scan).
+loop over the placement axis, one dependent step a placement, which ends
+after the last real one; everything inside one step is vectorized over all
+N nodes (and the static feasibility/affinity tensors are computed once for
+all G task groups before the loop).
 
 Outputs per placement: chosen node row (-1 = no node), final score, top-k
 candidate rows/scores (feeds AllocMetric.score_meta_data), and filter/exhaust
@@ -196,27 +201,134 @@ def step_scores(inp: PlacementInputs, st: StepStatics, carry, g, prev):
     return feas, final, stat_g, fit, dh_ok
 
 
-def place(inp: PlacementInputs) -> PlacementOutputs:
+def _top_max(x, rows, k, *payload):
+    """The `k` largest of x in ONE reduce over the nodes: a list, best
+    first, of (value, its row, each payload's value there); among equal
+    values the lower row first, which is the order `lax.top_k` gives and
+    where `argmax` starts.  The reduce's state is the ranked list itself,
+    two of which merge into the first `k` of both, so the runners-up cost
+    no pass of their own and no masking of the rows already taken.  The
+    payloads ride the reduce so that no scalar is gathered afterwards: on
+    the TPU every such gather is an op of its own, as dear as the reduce.
+    `k` is at most the length of x."""
+    width = 2 + len(payload)
+
+    def ahead(a, b):
+        return (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+
+    def either(take_a, a, b):
+        return tuple(jnp.where(take_a, u, v) for u, v in zip(a, b))
+
+    def ranked(flat):
+        return [tuple(flat[j * width:(j + 1) * width]) for j in range(k)]
+
+    def merge(a, b):
+        # k times the better of the two heads; the list it came from
+        # moves up one, the other drops a tail that can no longer place
+        a, b = ranked(a), ranked(b)
+        out = ()
+        for _ in range(k):
+            take_a = ahead(a[0], b[0])
+            out += either(take_a, a[0], b[0])
+            a, b = ([either(take_a, u, v) for u, v in zip(a[1:], a)],
+                    [either(take_a, u, v) for u, v in zip(b, b[1:])])
+        return out
+
+    none = (jnp.array(-jnp.inf, x.dtype),
+            jnp.array(jnp.iinfo(rows.dtype).max, rows.dtype),
+            *(jnp.zeros((), p.dtype) for p in payload))
+    # a node enters as a list of one: itself, then `none` k - 1 times
+    lists = (x, rows, *payload)
+    for _ in range(k - 1):
+        lists += tuple(jnp.full(x.shape, v) for v in none)
+    return ranked(jax.lax.reduce(lists, none * k, merge, (0,)))
+
+
+def pack_row(pick, score, top_rows, top_sc, counts):
+    """ONE placement's outputs as a row of `11 + RES_DIMS` int32 words
+    (floats bitcast), the layout of the one buffer the host fetches:
+    0 pick | 1 score | 2-4 topk_rows | 5-7 topk_scores | then `counts`:
+    8 n_feasible | 9 n_filtered | 10 n_exhausted | 11.. dim_exhausted
+    (one column a capacity dimension, structs.RES_NAMES).  A fleet of
+    fewer than TOP_K nodes leaves the rows it lacks at -1 and their
+    scores at zero.  Each scalar is put on its lane by a select, not
+    concatenated: one fused op a row where the concatenation of fifteen
+    one-word operands cost the step two."""
+    f2i = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
+    k = len(top_rows)
+    head = [pick, f2i(score),
+            *(top_rows[j] if j < k else -1 for j in range(TOP_K)),
+            *(f2i(top_sc[j]) if j < k else 0 for j in range(TOP_K))]
+    row = jnp.pad(counts, (len(head), 0))
+    lane = jnp.arange(row.shape[0])
+    for j, word in enumerate(head):
+        row = jnp.where(lane == j, word, row)
+    return row
+
+
+def pack_outputs(out: PlacementOutputs):
+    """Pack per-placement outputs into ONE int32 buffer `[P, 11 +
+    RES_DIMS]`, a `pack_row` a placement, so the host pays a single
+    device→host round trip instead of one per array (the engine used to
+    fetch ten arrays per batch, and the fixed cost per fetch dominated
+    eval latency).  The single-device scan writes its rows itself
+    (`place_packed`); this is for a scan that stacks its outputs, the
+    node-sharded one (parallel/mesh.place_sharded_packed_fn).
+    Returns (buf, used, job_count); used/job_count are fetched lazily by
+    the engine only on the preemption fallback path.
+    """
+    counts = jnp.concatenate([
+        out.n_feasible[:, None], out.n_filtered[:, None],
+        out.n_exhausted[:, None], out.dim_exhausted], axis=1)
+    buf = jax.vmap(pack_row)(out.picks, out.scores, out.topk_rows,
+                             out.topk_scores, counts)
+    return buf, out.used, out.job_count
+
+
+def place_packed(inp: PlacementInputs):
+    """The exact scan on one device: one dependent step a placement,
+    every step scoring all N nodes, its outputs written as the step's
+    `pack_row` into ONE `[P, 11 + RES_DIMS]` buffer.  Returns (buf, used,
+    job_count).
+
+    A step does what it can use.  The loop's trip count is one past the
+    last ACTIVE step (the engine pads a batch to a power of two, so the
+    padding is the tail and runs nothing); a step inside the trip count
+    whose `active` is false scores and reports its counts but places
+    nothing and changes no state.  Rows past the trip count read pick -1,
+    top rows -1 and zero elsewhere.  The pick and the TOP_K reported rows
+    are the arg-maxes of the masked, jittered scores from one reduce
+    (`_top_max`): the order `lax.top_k` gives, equal values by lower row,
+    with no sort; the reported score and the picked node's spread and
+    distinct_property values ride that reduce, so nothing is gathered
+    after it.  The step's counts are one stacked sum."""
     n = inp.attrs.shape[0]
+    p_pad = inp.tg_idx.shape[0]
     top_k = min(TOP_K, n)
     st = scan_statics(inp, jnp.arange(n))
-    static, noise = st.static, st.noise
+    static, noise, rows = st.static, st.noise, st.rows
+    n_sp = inp.sp_nodeval.shape[0]
 
-    def step(carry, xs):
+    def step(carry, g, prev, act):
         used, job_count, sp_counts, pd_counts = carry
-        g, prev, act = xs
         req_g = inp.req[g]
         stat_g = static[g]
         feas, final, _, fit, dh_ok = step_scores(inp, st, carry, g, prev)
-        rows = st.rows
+
+        # ---- metrics: n_feasible | n_filtered | n_exhausted |
+        # dim_exhausted, all of the state the step met ----
+        over = (used + req_g[None, :]) > inp.cap
+        counts = jnp.sum(jnp.stack([
+            feas, ~stat_g, stat_g & (~fit | ~dh_ok),
+            *((stat_g & ~fit)[:, None] & over).T]).astype(jnp.int32), axis=1)
 
         # selection order gets the tie-break noise; reported scores do not
-        masked = jnp.where(feas, final, NEG_INF)
-        nsc, top_rows = jax.lax.top_k(masked + noise, top_k)
-        top_sc = jnp.where(nsc > NEG_INF / 2, final[top_rows], NEG_INF)
-        pick = top_rows[0]
+        tops = _top_max(jnp.where(feas, final, NEG_INF) + noise, rows, top_k,
+                        final, *inp.sp_nodeval, *inp.pd_nodeval)
+        top_rows = [t[1] for t in tops]
+        top_sc = [jnp.where(t[0] > NEG_INF / 2, t[2], NEG_INF) for t in tops]
         ok = act & (top_sc[0] > NEG_INF / 2)
-        pick = jnp.where(ok, pick, -1)
+        pick = jnp.where(ok, top_rows[0], -1)
 
         # ---- state update (no-op when not placed) ----
         onehot = (rows == pick) & ok
@@ -224,7 +336,7 @@ def place(inp: PlacementInputs) -> PlacementOutputs:
         job_count = job_count + onehot.astype(jnp.int32)
         # spread counts: bump (s, value[s, pick]) for real values
         val_p = jnp.where(pick >= 0,
-                          inp.sp_nodeval[:, jnp.maximum(pick, 0)],
+                          jnp.array(tops[0][3:3 + n_sp], jnp.int32),
                           -1)                               # [S]
         k = sp_counts.shape[1]
         sp_hot = (jax.nn.one_hot(jnp.clip(val_p, 0, k - 1), k)
@@ -233,76 +345,61 @@ def place(inp: PlacementInputs) -> PlacementOutputs:
         # distinct_property counts bump only for rows applying to this TG
         kd = pd_counts.shape[1]
         pd_val_p = jnp.where(pick >= 0,
-                             inp.pd_nodeval[:, jnp.maximum(pick, 0)],
+                             jnp.array(tops[0][3 + n_sp:], jnp.int32),
                              -1)                            # [D]
         pd_hot = (jax.nn.one_hot(jnp.clip(pd_val_p, 0, kd - 1), kd,
                                  dtype=pd_counts.dtype)
                   * ((pd_val_p >= 0) & inp.pd_apply[g] & ok)[..., None])
         pd_counts = pd_counts + pd_hot
 
-        # ---- metrics ----
-        n_filtered = jnp.sum(~stat_g)
-        exhausted = stat_g & (~fit | ~dh_ok)
-        n_exhausted = jnp.sum(exhausted)
-        over = (used - onehot[:, None].astype(jnp.int32) * req_g[None, :]
-                + req_g[None, :]) > inp.cap                # pre-update usage
-        dim_ex = jnp.sum((stat_g & ~fit)[:, None] & over, axis=0)
+        row = pack_row(pick, jnp.where(ok, top_sc[0], 0.0),
+                       [jnp.where(ok, r, -1) for r in top_rows],
+                       [jnp.where(ok, sc, 0.0) for sc in top_sc], counts)
+        return (used, job_count, sp_counts, pd_counts), row
 
-        out = (pick,
-               jnp.where(ok, top_sc[0], 0.0),
-               jnp.where(ok, top_rows, -1),
-               jnp.where(ok, top_sc, 0.0),
-               jnp.sum(feas).astype(jnp.int32),
-               n_filtered.astype(jnp.int32),
-               n_exhausted.astype(jnp.int32),
-               dim_ex.astype(jnp.int32))
-        return (used, job_count, sp_counts, pd_counts), out
+    # a trip-count loop over a buffer made before it, not a `lax.cond` a
+    # step: the branch costs every step, and an [N, RES_DIMS] value
+    # computed inside one takes the padded row-major layout on the TPU
+    idle = pack_row(jnp.int32(-1), jnp.float32(0.0), [jnp.int32(-1)] * top_k,
+                    [jnp.float32(0.0)] * top_k,
+                    jnp.zeros(3 + inp.cap.shape[1], jnp.int32))
+    buf0 = jnp.broadcast_to(idle, (p_pad,) + idle.shape)
+    n_run = jnp.max(jnp.where(inp.active, jnp.arange(p_pad) + 1, 0),
+                    initial=0)
+    # a step's three inputs as one row: one slice a step, not three
+    steps = jnp.stack([inp.tg_idx, inp.prev_row,
+                       inp.active.astype(jnp.int32)], axis=1)
+
+    def body(i, state):
+        carry, buf = state
+        g, prev, act = jax.lax.dynamic_index_in_dim(steps, i, 0, False)
+        carry, row = step(carry, g, prev, act != 0)
+        return carry, jax.lax.dynamic_update_index_in_dim(buf, row, i, 0)
 
     carry0 = (inp.used0, inp.job_count0, inp.sp_counts0, inp.pd_counts0)
-    (used, job_count, _, _), outs = jax.lax.scan(
-        step, carry0, (inp.tg_idx, inp.prev_row, inp.active))
-    return PlacementOutputs(
-        picks=outs[0], scores=outs[1], topk_rows=outs[2], topk_scores=outs[3],
-        n_feasible=outs[4], n_filtered=outs[5], n_exhausted=outs[6],
-        dim_exhausted=outs[7], used=used, job_count=job_count)
-
-
-place_jit = jax.jit(place)
-
-
-def pack_outputs(out: PlacementOutputs):
-    """Pack per-placement outputs into ONE int32 buffer `[P, 11 +
-    RES_DIMS]` (floats
-    bitcast) so the host pays a single device→host round trip instead of
-    one per array (the engine used to fetch ten arrays per batch, and
-    the fixed cost per fetch dominated eval latency).
-
-    Column layout: 0 pick | 1 score | 2-4 topk_rows | 5-7 topk_scores |
-    8 n_feasible | 9 n_filtered | 10 n_exhausted | 11.. dim_exhausted
-    (one column a capacity dimension, structs.RES_NAMES).
-    Returns (buf, used, job_count); used/job_count are fetched lazily by
-    the engine only on the preemption fallback path.
-    """
-    f2i = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
-    p, top_k = out.topk_rows.shape
-    pad_k = jnp.full((p, 3 - top_k), -1, jnp.int32)
-    buf = jnp.concatenate([
-        out.picks[:, None], f2i(out.scores)[:, None],
-        jnp.concatenate([out.topk_rows, pad_k], axis=1),
-        jnp.concatenate([f2i(out.topk_scores),
-                         jnp.zeros((p, 3 - top_k), jnp.int32)], axis=1),
-        out.n_feasible[:, None], out.n_filtered[:, None],
-        out.n_exhausted[:, None], out.dim_exhausted,
-    ], axis=1)
-    return buf, out.used, out.job_count
-
-
-def place_packed(inp: PlacementInputs):
-    """`place` + pack_outputs (see there for the layout)."""
-    return pack_outputs(place(inp))
+    (used, job_count, _, _), buf = jax.lax.fori_loop(
+        0, n_run, body, (carry0, buf0))
+    return buf, used, job_count
 
 
 place_packed_jit = jax.jit(place_packed)
+
+
+def place(inp: PlacementInputs) -> PlacementOutputs:
+    """`place_packed` with the buffer's columns as arrays (tests, and
+    every caller that reads the outputs by name)."""
+    buf, used, job_count = place_packed(inp)
+    top_k = min(TOP_K, inp.attrs.shape[0])
+    i2f = lambda x: jax.lax.bitcast_convert_type(x, jnp.float32)
+    return PlacementOutputs(
+        picks=buf[:, 0], scores=i2f(buf[:, 1]),
+        topk_rows=buf[:, 2:2 + top_k],
+        topk_scores=i2f(buf[:, 2 + TOP_K:2 + TOP_K + top_k]),
+        n_feasible=buf[:, 8], n_filtered=buf[:, 9], n_exhausted=buf[:, 10],
+        dim_exhausted=buf[:, 11:], used=used, job_count=job_count)
+
+
+place_jit = jax.jit(place)
 
 
 class BulkInputs(NamedTuple):
@@ -470,33 +567,19 @@ def waterfill_round(k_i, score, noise, want, spread_algo, round_size: int):
     return rows_p, cnt_p, sc_p, c_i, placed_total, k_round
 
 
-def _first_max(x, rows, *payload):
-    """(the maximum of x, its row, each payload's value there) in ONE
-    reduce over the nodes; among equal maxima the lowest row, which is
-    what `argmax` returns and where `lax.top_k` starts.  The payloads
-    ride the reduce so that no scalar is gathered afterwards: on the TPU
-    every such gather is an op of its own, as dear as the reduce."""
-    def first(a, b):
-        take_a = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
-        return tuple(jnp.where(take_a, u, v) for u, v in zip(a, b))
-
-    init = (jnp.array(-jnp.inf, x.dtype),
-            jnp.array(jnp.iinfo(rows.dtype).max, rows.dtype),
-            *(jnp.zeros((), p.dtype) for p in payload))
-    return jax.lax.reduce((x, rows, *payload), init, first, (0,))
-
-
 def pick_one_round(k_i, score, noise, want, spread_algo, round_size: int):
     """waterfill_round for a round whose `want` is at most 1, without the
     top-k sort: one node takes one allocation, so the selection is the
     arg-max of the same masked, jittered scores, and the TOP_K reported
-    rows are the next arg-maxes of the same vector.  `lax.top_k` orders
-    equal values by lower index and _first_max returns the first
-    maximum, so the order is the sort's.  Same signature and return
-    tuple; every value that reaches the packed buffer (the fills, the
-    first TOP_K rows and scores, c_i, placed_total, k_round) is
-    waterfill_round's bit for bit.  Slots past TOP_K of the prefix, which
-    hold zero counts there too, are row 0 with NEG_INF."""
+    rows are the next arg-maxes of the same vector, each by the reduce
+    the exact scan's step picks with (`_top_max`, above `place_packed`)
+    at a `k` of one.  `lax.top_k` orders equal values by lower index and
+    the reduce returns the first maximum, so the order is the sort's.
+    Same signature and return tuple; every value that reaches the packed
+    buffer (the fills, the first TOP_K rows and scores, c_i,
+    placed_total, k_round) is waterfill_round's bit for bit.  Slots past
+    TOP_K of the prefix, which hold zero counts there too, are row 0 with
+    NEG_INF."""
     n = k_i.shape[0]
     big = jnp.int32(round_size)
     # the spread algorithm's cap, want // viable + 1: with `want` at most
@@ -510,7 +593,7 @@ def pick_one_round(k_i, score, noise, want, spread_algo, round_size: int):
     left = jnp.where(k_round > 0, score, NEG_INF) + noise
     tops = []
     for _ in range(min(TOP_K, n, round_size)):
-        best, row, sc, k_row = _first_max(left, rows_all, score, k_round)
+        (best, row, sc, k_row), = _top_max(left, rows_all, 1, score, k_round)
         tops.append((row, jnp.where(best > NEG_INF / 2, sc, NEG_INF), k_row))
         left = jnp.where(rows_all == row, -jnp.inf, left)
     row, sc, k_row = tops[0]

@@ -111,6 +111,41 @@ class TestShardedPlacement:
         np.testing.assert_array_equal(np.asarray(single.used),
                                       np.asarray(sharded.used))
 
+    def test_padded_spread_batch_matches_on_the_real_rows(self):
+        """A batch padded as the engine pads it (12 of 16 steps active),
+        under a spread stanza and a distinct_property: the single-device
+        loop stops at its trip count, the sharded scan runs its padding
+        masked, and the real rows agree node for node and metric for
+        metric, as does the final state."""
+        mesh = make_mesh(8)
+        h, t, inp = build_inputs(n_nodes=16, count=12,
+                                 pad_to=pad_nodes(16, 8))
+        inp = inp._replace(
+            tg_idx=jnp.zeros(16, jnp.int32),
+            prev_row=jnp.full(16, -1, jnp.int32),
+            active=jnp.arange(16) < 12)
+        single = place_jit(inp)
+        sharded = place_sharded_fn(mesh)(inp)
+        assert (np.asarray(single.picks)[:12] >= 0).all()
+        for name in ("picks", "topk_rows", "n_feasible", "n_filtered",
+                     "n_exhausted", "dim_exhausted"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(single, name))[:12],
+                np.asarray(getattr(sharded, name))[:12], err_msg=name)
+        for name in ("scores", "topk_scores"):
+            np.testing.assert_allclose(
+                np.asarray(getattr(single, name))[:12],
+                np.asarray(getattr(sharded, name))[:12], atol=1e-5,
+                err_msg=name)
+        for name in ("used", "job_count"):
+            np.testing.assert_array_equal(np.asarray(getattr(single, name)),
+                                          np.asarray(getattr(sharded, name)))
+        # past the real rows neither placed; only the sharded scan ran them
+        assert (np.asarray(single.picks)[12:] == -1).all()
+        assert (np.asarray(sharded.picks)[12:] == -1).all()
+        assert not np.asarray(single.n_feasible)[12:].any()
+        assert np.asarray(sharded.n_feasible)[12:].all()
+
     def test_sharded_spread_distribution(self):
         mesh = make_mesh(8)
         n_pad = pad_nodes(12, 8)
