@@ -95,6 +95,13 @@ from nomad_tpu.core.telemetry import (
 #   device_carve worker: the batched device path's carve of instance ids
 #                for one eval's picks (scheduler/device.py carve_block);
 #                lies INSIDE that eval's materialize
+#   port_assign  worker: one eval's port work (scheduler/generic.py
+#                _materialize_bulk): the NetworkIndex build of every node
+#                its picks touch and the assignment, columnar
+#                (_carve_ports_batch) or, where the carve does not take
+#                the eval, the per-allocation loop it falls to, whose
+#                row construction the span then holds too; lies INSIDE
+#                that eval's materialize
 #   plan_wait    worker: blocked on the applier's verdict for one plan
 #   finalize     worker: a wave's eval from the verdict in hand to done
 #                (GenericScheduler.finalize_batched after its wait: the
@@ -113,12 +120,12 @@ from nomad_tpu.core.telemetry import (
 #                and written to its follower (api/http_server.py), the
 #                wait for the event left out
 # Worker stages other than "pass" do not nest in one another, but for
-# device_carve (and the solo device path's redo), each inside a
-# materialize, and spread_lower and mesh_launch, inside a dispatch: the
-# unnamed part of a pass is its wall minus their UNION
+# device_carve and port_assign (and the solo device path's redo), each
+# inside a materialize, and spread_lower and mesh_launch, inside a
+# dispatch: the unnamed part of a pass is its wall minus their UNION
 # (benchmark/host_spans.py View.named), which a nested span leaves as it
-# was; a per-stage sum must leave device_carve, spread_lower and
-# mesh_launch out or count them twice.
+# was; a per-stage sum must leave device_carve, port_assign, spread_lower
+# and mesh_launch out or count them twice.
 #
 # Two more names reach the profiler's trace and are no stage (nothing
 # records them here): `nomad.gc`, a collection of generation 1 or 2 on
@@ -126,7 +133,8 @@ from nomad_tpu.core.telemetry import (
 # (core/telemetry.py), and `nomad.cpu`, the marker below.
 STAGES = ("dequeue", "pass", "prepare", "dispatch", "spread_lower",
           "mesh_launch", "device", "device_wait", "d2h", "solo_place",
-          "system_place", "materialize", "device_carve", "plan_wait",
+          "system_place", "materialize", "device_carve", "port_assign",
+          "plan_wait",
           "finalize", "batch_admin", "eval_update", "ack", "commit",
           "store_upsert", "stream_send")
 

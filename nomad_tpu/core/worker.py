@@ -485,6 +485,7 @@ class Worker:
         # without letting plans pool in the queue (queue-wait is the
         # north star's p99 plan-queue latency — an unbounded submit-all
         # pass inflated it ~60x for zero wall-time gain).
+        from nomad_tpu.scheduler.generic import asks_ports
         coupled = [i for i in range(len(work)) if i in bds]
         handles: Dict[int, object] = {}
         window = 2
@@ -498,20 +499,31 @@ class Worker:
         # and the resident chain included — instead of demoting to
         # per-alloc materialize.
         shared_net: Dict[str, object] = {}
+        # ... and ONE view of the store to build that cache from, taken
+        # now and not with the batch's snapshot: a prefetched batch's
+        # snapshot predates the commits of the wave before it, whose
+        # ports on the same warm nodes it would hand out again (the
+        # applier's re-check refutes them, a repair eval each).  By now
+        # that wave is committed whole.  Taken by the first mate that
+        # asks ports; a wave without one takes none
+        port_view = None
         port_rows = 0       # carved columnar by this batch's materializes
 
         def submit(i):
-            nonlocal port_rows
+            nonlocal port_rows, port_view
             ev, token, sched, prep = work[i]
             try:
                 sched.last_port_carve = 0
+                if port_view is None and asks_ports(prep.tg):
+                    port_view = self.server.state.snapshot()
                 with trace_scope(ev.trace_id), \
                         self.pipeline.materialize(wave):
                     handles[i] = sched.submit_batched(
                         ev, prep, bds[i],
                         coupled_batch=(batch_id, batch_seq0),
                         net_index_cache=shared_net,
-                        device_ledger=self.pipeline.device_ledger)
+                        device_ledger=self.pipeline.device_ledger,
+                        port_view=port_view)
                 port_rows += sched.last_port_carve
             except Exception as e:  # noqa: BLE001 - finalize pass nacks
                 handles[i] = e

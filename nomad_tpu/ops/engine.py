@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from nomad_tpu.pack.interner import UNSET
-from nomad_tpu.pack.packer import ClusterPacker, JobContext, NodeTensors, TGTensors
+from nomad_tpu.pack.packer import (ClusterPacker, JobContext, NodeTensors,
+                                   TGTensors, tg_static_ports)
 from nomad_tpu.pack.spread import (
     SpreadTensors,
     job_spreads,
@@ -54,6 +55,24 @@ from .select import (
 # whole rounds between state refreshes).
 BULK_THRESHOLD = 64
 BULK_ROUND = 1024
+
+# Static ports as a feasibility rule (ISSUE 38): the single-device scan
+# and the flat multi-eval kernel carry, per static port value a launch's
+# groups ask, the nodes that hold it (select.port_state), so a static
+# ask passes by a node whose port is taken, by a live allocation, by a
+# wave-mate or by its own job earlier in the launch, as upstream's
+# BinPackIterator does.  What benchmark/configs/ports50k.py asks the
+# program for by name.
+STATIC_PORT_FEASIBILITY = True
+# the fewest slots of a launch's port state ([Kp, N], Kp on the
+# power-of-two ladder from here): a wave of services asks a handful of
+# well-known values, and one bucket for one to four of them keeps a
+# drain's waves on one compiled shape
+PORT_SLOTS_MIN = 4
+# the most rows a chain of waves hands on for static values its latest
+# launch did not ask; past it the oldest are dropped (their next ask reads
+# the state again, and the applier's re-check holds what was in flight)
+PORT_ROWS_CARRIED = 64
 
 # fixed-size chunks so the delta-replay scatter compiles ONCE, not once
 # per power-of-two delta size (a 100k-alloc plan's replay was paying a
@@ -191,6 +210,15 @@ class PlacementDecision:
     metric: AllocMetric
     # allocs to evict to make this placement possible (preemption)
     evictions: List = field(default_factory=list)
+
+
+def port_collision_dimension(asks) -> str:
+    """The exhausted dimension of a node lost to static-port state, as
+    upstream's NetworkIndex names it; with the value where the launch's
+    groups ask one value between them."""
+    values = {v for a in asks for v in a}
+    return "network: reserved port collision" + (
+        f" {next(iter(values))}" if len(values) == 1 else "")
 
 
 def _pad_pow2(x: int, lo: int = 8) -> int:
@@ -338,6 +366,9 @@ def _unpack_bulk_compact(buf: np.ndarray, round_size: int, p_real: int,
 # the meta block's per-dimension exhaustion columns, in RES_NAMES' order
 # (select.pack_round_buffer: three before `placed` at 12, the rest after)
 _META_DIM_EX = [9, 10, 11] + list(range(13, 10 + RES_DIMS))
+# the column after them: nodes a round lost to static-port state (the
+# flat multi-eval kernel on a wave that carries it; zero elsewhere)
+_META_PORT_EX = 10 + RES_DIMS
 
 
 def _unpack_bulk(buf: np.ndarray, round_size: int, p_real: int, n: int):
@@ -430,6 +461,10 @@ class PlacementEngine:
         # spread stanzas' value landscapes (_spread_landscapes), by node
         # table version and spread signature; a handful, oldest out
         self._spread_cache: Dict[tuple, tuple] = {}
+        # static port values' holder masks (static_port_mask), by value:
+        # (node table version, padded n, the value's holder version, the
+        # [npad] bool on the device); a few dozen, oldest out
+        self._port_mask_cache: Dict[int, tuple] = {}
         self._single_group: Optional[Tuple[int, bool]] = None
         self._dc_cache: Optional[Tuple[int, Dict[str, int]]] = None
         # host->device sync meter (ops/executor.py installs it): called
@@ -928,6 +963,71 @@ class PlacementEngine:
                     "sp_counts0": jnp.asarray(counts0),
                     "by_item": by_item}
 
+    def static_port_mask(self, t: NodeTensors, npad: int, value: int):
+        """The nodes that HOLD static port `value` in the state, as a
+        device [npad] bool: a live allocation that asked it, or the
+        node's own reservation (pack/packer.py static_port_holders, kept
+        by the alloc and block events that keep `used`).  Built once per
+        (value, its holders' version, node-table version) and reused
+        after: `device_static_mask`'s pattern."""
+        version, rows = self.packer.static_port_rows(t, value)
+        key = (t.version, npad, version)
+        with self.packer.lock:
+            hit = self._port_mask_cache.pop(value, None)
+            if hit is not None and hit[0] == key:
+                self._port_mask_cache[value] = hit
+        if hit is not None and hit[0] == key:
+            _registry().inc("nomad.engine.port_masks_reused")
+            return hit[1]
+        mask = np.zeros(npad, bool)
+        mask[rows] = True
+        dev = jnp.asarray(mask)
+        _registry().inc("nomad.engine.port_masks_built")
+        with self.packer.lock:
+            if len(self._port_mask_cache) >= 64:
+                self._port_mask_cache.pop(next(iter(self._port_mask_cache)))
+            self._port_mask_cache[value] = (key, dev)
+        return dev
+
+    def _lower_ports(self, t: NodeTensors, npad: int, asks, g_pad: int,
+                     carried=None):
+        """The static-port fields of a launch (select.port_state), or
+        None where no group asks a static port.  `asks[g]` are the
+        values group g asks; the launch's slots are the values asked in
+        it, in order, padded to the ladder, so a round pays for what its
+        own launch asks and for nothing a chain has seen before.
+        `carried` is the port state of the wave this launch is chained
+        on, (values, their [Kp, npad] holders, {value: [npad] holders}
+        of the values earlier waves of the chain asked and that one did
+        not): a carried row stands for the state AND the chain's
+        placements since, so it is taken as it is; any other value's
+        holders are read from the state.  Returns (values, pt_taken0,
+        pt_ask, rest): `rest` the carried rows this launch does not
+        take, to hand on."""
+        asked = tuple(sorted({v for a in asks for v in a}))
+        if not asked:
+            return None
+        c_values, c_taken, rest = carried or ((), None, {})
+        k_pad = _pad_pow2(len(asked), lo=PORT_SLOTS_MIN)
+        slot = {v: k for k, v in enumerate(asked)}
+        pt_ask = np.zeros((g_pad, k_pad), bool)
+        for g, a in enumerate(asks):
+            for v in a:
+                pt_ask[g, slot[v]] = True
+        if asked == tuple(c_values) and c_taken.shape == (k_pad, npad):
+            return asked, c_taken, jnp.asarray(pt_ask), rest
+        # another set of values than the wave before: its rows join the
+        # ones handed on, and this launch's are taken from among them
+        rest = dict(rest)
+        rest.update((v, c_taken[k]) for k, v in enumerate(c_values))
+        rows = [rest.pop(v) if v in rest
+                else self.static_port_mask(t, npad, v) for v in asked]
+        while len(rest) > PORT_ROWS_CARRIED:
+            rest.pop(next(iter(rest)))
+        zrow = self._dev_const(("zrow", npad), lambda: np.zeros(npad, bool))
+        rows.extend([zrow] * (k_pad - len(asked)))
+        return asked, jnp.stack(rows), jnp.asarray(pt_ask), rest
+
     def single_group_fleet(self, t: NodeTensors) -> bool:
         """No node advertises more than one device group: then "instances
         in use on the node" is one number whatever a request's name, and
@@ -1148,6 +1248,27 @@ class PlacementEngine:
         # matching is host work — scheduler/device.py)
         dev_mask = self._device_mask(
             tgs, t, snapshot, {a.id for a in stopped_allocs}, device_in_use)
+        # static port asks (ISSUE 38): the scan carries their holders
+        # (select.port_state); the bulk kernel has no such state, so a
+        # group that asks one keeps the scan.  The sharded scan has none
+        # either: there the nodes that hold a value in the STATE leave
+        # through the mask, and the eval's own placements keep to one a
+        # node by the distinct_hosts limit (which counts the JOB's
+        # allocations on the node: a second group of the job that asks no
+        # port is held off it too)
+        has_dev_ask = dev_mask is not None
+        port_asks = [tg_static_ports(tg) for tg in tgs]
+        has_static = any(port_asks)
+        port_lost = None
+        if has_static and self.mesh is not None:
+            free = np.ones((len(tgs), n), bool)
+            for g, values in enumerate(port_asks):
+                for v in values:
+                    free[g, self.packer.static_port_rows(t, v)[1]] = False
+                if values and not tg_tensors.dh_limit[g]:
+                    tg_tensors.dh_limit[g] = 1
+            port_lost = (~free).sum(axis=1)
+            dev_mask = free if dev_mask is None else dev_mask & free
         extra_mask = (None if dev_mask is None
                       else jnp.asarray(_pad_cols(dev_mask, npad, False)))
 
@@ -1156,16 +1277,17 @@ class PlacementEngine:
         if block is not None:
             bulk_ok = (p_real >= BULK_THRESHOLD
                        and not has_spread and not has_distinct
-                       and dev_mask is None)
+                       and not has_dev_ask and not has_static)
         else:
             bulk_ok = (
                 p_real >= BULK_THRESHOLD
                 and len({r.tg_name for r in requests}) == 1
                 and not has_spread and not has_distinct
+                and not has_static
                 # device asks cap per-node intake by discrete instance
                 # counts, which the water-fill rounds can't see — exact
                 # scan only
-                and dev_mask is None
+                and not has_dev_ask
                 and all(not r.prev_node_id for r in requests))
         # the sharded bulk kernel has no with_scores variant; the
         # expanded-API bulk path needs per-placement scores, so on a mesh
@@ -1288,6 +1410,10 @@ class PlacementEngine:
                 seed=jnp.asarray(seed & 0xFFFFFFFF, jnp.uint32),
                 extra_mask=extra_mask,
             )
+            if has_static and self.mesh is None:
+                _, taken0, pt_ask, _ = self._lower_ports(
+                    t, npad, port_asks, len(tgs))
+                inp = inp._replace(pt_taken0=taken0, pt_ask=pt_ask)
             if self.mesh is not None:
                 buf, used_dev, job_count_dev = self._launch(
                     "scan", (npad, p_pad), self._sharded("scan"), inp)
@@ -1308,9 +1434,16 @@ class PlacementEngine:
             scores = b[:, 1].view(np.float32)
             topk_rows = b[:, 2:5]
             topk_scores = b[:, 5:8].view(np.float32)
-            # nodes_filtered | nodes_exhausted | dimension_exhausted
-            counts = b[:, 9:11 + RES_DIMS].copy()
+            # nodes_filtered | nodes_exhausted | dimension_exhausted (and,
+            # from a scan with static-port state, the nodes lost to it)
+            counts = b[:, 9:].copy()
             counts[:, 0] -= npad - n
+            if port_lost is not None:
+                # the mask's nodes read as filtered: say what they are
+                lost = port_lost[tg_idx[:p_real]].astype(counts.dtype)
+                counts[:, 0] -= lost
+                counts[:, 1] += lost
+                counts = np.column_stack([counts, lost])
         elapsed = (time.perf_counter_ns() - t0) // max(p_real, 1)
 
         # ---- preemption fallback for failed placements ----
@@ -1322,7 +1455,7 @@ class PlacementEngine:
         # arrays, like the bulk kernel's (the scheduler commits them as
         # ONE AllocBlock).  A device ask keeps decisions: its instances
         # are assigned per placement (generic._assign_devices)
-        as_block = block is not None and bulk_api and dev_mask is None
+        as_block = block is not None and bulk_api and not has_dev_ask
         nodes = t.node_ids
         if as_block:
             # the block's metric columns name their candidates in a
@@ -1339,7 +1472,10 @@ class PlacementEngine:
             nodes_evaluated=n, nodes_in_pool=int(ctx.pool_mask.sum()),
             nodes_available=self._dc_counts(t),
             allocation_time_ns=int(elapsed), counts=counts,
-            topk=topk_rows, topk_scores=topk_scores, nodes=nodes)
+            topk=topk_rows, topk_scores=topk_scores, nodes=nodes,
+            dim_names=RES_NAMES + ((port_collision_dimension(port_asks),)
+                                   if counts.shape[1] > 2 + RES_DIMS
+                                   else ()))
         if as_block:
             return BulkDecisions(
                 tg_name=block_tg, picks=picks, node_ids=t.node_ids,
@@ -1530,9 +1666,12 @@ class PlacementEngine:
 
     @staticmethod
     def _metrics_from_meta(meta, n, n_in_pool, dc_counts, node_ids,
-                           elapsed) -> List[AllocMetric]:
+                           elapsed, port_dim: str = "") -> List[AllocMetric]:
         """Per-round AllocMetric objects from the bulk kernels' compact
-        meta block (shared by the single-eval bulk path and place_batch)."""
+        meta block (shared by the single-eval bulk path and place_batch).
+        `port_dim`: for an item that asks a static port off a wave with
+        port state, the dimension that names the nodes its rounds lost
+        to it (the meta block's column 14)."""
         tsc = meta[:, 3:6].view(np.float32).tolist()
         metrics: List[AllocMetric] = []
         for r, row in enumerate(meta.tolist()):
@@ -1547,6 +1686,8 @@ class PlacementEngine:
             for d, col in enumerate(_META_DIM_EX):
                 if row[col]:
                     metric.dimension_exhausted[RES_NAMES[d]] = row[col]
+            if port_dim and row[_META_PORT_EX]:
+                metric.dimension_exhausted[port_dim] = row[_META_PORT_EX]
             metric.score_meta_data = [
                 NodeScoreMeta(node_id=node_ids[kr],
                               scores={"final": ks}, norm_score=ks)
@@ -1649,7 +1790,12 @@ class PlacementEngine:
             buf, fills_full, used_out = out
             fill_k = min(FILL_K, rs)
         else:
-            buf, used_out, _ = out
+            buf, used_out, _, *taken_out = out
+        # the static-port state a wave chained on this one starts from:
+        # what this launch left, or what it was handed and did not touch
+        port_state = ((aux["ports"][0], taken_out[0], aux["ports"][1])
+                      if aux["ports"] is not None
+                      else aux["carried_ports"])
         # start the device->host copy of the result buffer NOW: queued
         # behind the compute, a prefetched batch's transfer rides out
         # the PREVIOUS batch's host phase instead of blocking collect
@@ -1658,6 +1804,7 @@ class PlacementEngine:
         # while the PREVIOUS batch's host phase runs — that gap is not
         # scheduling time and must not inflate AllocMetric latency
         return {"buf": buf, "used": used_out, "items": list(items),
+                "ports": port_state, "port_asks": aux["port_asks"],
                 "spans": aux["spans"], "counts": aux["counts"], "rs": rs,
                 "item_rs": aux["item_rs"], "rounds": aux["rounds"],
                 "rounds_padded": aux["rounds_padded"],
@@ -1695,10 +1842,14 @@ class PlacementEngine:
         npad = self._padded_n(n)
         dev = self._node_arrays(t)
         used0 = None
+        carried_ports = None
         if used0_dev is not None:
-            arr, chain_ver, chain_npad = used0_dev
+            # (usage, node version, padded n) and, from a wave that had
+            # or carried static-port state, that state
+            arr, chain_ver, chain_npad, *chain_ports = used0_dev
             if chain_ver == t.version and chain_npad == npad:
                 used0 = arr
+                carried_ports = chain_ports[0] if chain_ports else None
         chained = used0 is not None
         if used0 is None:
             used0 = self._used_device(t)
@@ -1836,6 +1987,11 @@ class PlacementEngine:
         # (select.pick_one_round).
         counts = [max(it.count, 0) for it in items]
         spread = self._lower_wave_spreads(t, npad, snapshot, items, g_pad)
+        port_asks = [tg_static_ports(it.tg) for it in items]
+        if any(port_asks) and self.mesh is not None:
+            raise ValueError("the sharded wave kernels carry no static-port "
+                             "state: such an eval takes the solo path")
+        ports = self._lower_ports(t, npad, port_asks, g_pad, carried_ports)
         biggest = max(counts) if counts else 0
         for rs in (64, 256, 512, 1024):
             if biggest <= rs:
@@ -1874,9 +2030,10 @@ class PlacementEngine:
         perm = None
         cand_rows = cand_valid = cand_dev = None
         luts = tgts[-1].luts      # the most complete LUT matrix
-        # (a wave that holds a spread item keeps the flat schedule: the
-        # laned kernel carries no per-lane spread state)
-        if n_real > 1 and len(static_con) > 1 and spread is None:
+        # (a wave that holds a spread item or a static port ask keeps the
+        # flat schedule: the laned kernel carries neither state)
+        if (n_real > 1 and len(static_con) > 1 and spread is None
+                and ports is None):
             weights = [0] * len(static_con)
             for r_idx in range(n_real):
                 weights[int(g_static[round_g[r_idx]])] += 1
@@ -1997,7 +2154,14 @@ class PlacementEngine:
             inp = inp._replace(**{k: spread[k] for k in (
                 "sp_nodeval", "g_spread", "sp_weight", "sp_expected",
                 "sp_counts0")})
+        if ports is not None:
+            inp = inp._replace(pt_taken0=ports[1], pt_ask=ports[2])
         return {"inp": inp, "rs": rs, "spans": spans, "counts": counts,
+                # the launch's static port values in slot order with the
+                # rows it hands on untouched, or, on a wave that asks
+                # none, the chain's state to hand on as is
+                "ports": ports and (ports[0], ports[3]),
+                "port_asks": port_asks, "carried_ports": carried_ports,
                 "item_rs": item_rs, "rounds": n_real, "rounds_padded": pad_r,
                 "t": t, "ctxs": ctxs, "n": n, "npad": npad, "t0": t0,
                 "n_lanes": n_lanes, "perm": perm, "chained": chained,
@@ -2092,6 +2256,7 @@ class PlacementEngine:
                              pending["rs"])
         t, ctxs, n, npad = (pending["t"], pending["ctxs"],
                             pending["n"], pending["npad"])
+        port_asks = pending.get("port_asks") or [()] * len(items)
         t1 = time.perf_counter_ns()
         buf_np = self._fetch(pending["buf"])
         if pending.get("perm") is not None:
@@ -2132,7 +2297,9 @@ class PlacementEngine:
                 meta[:, 7] -= npad - n
             metrics = self._metrics_from_meta(
                 meta, n, int(ctxs[gi].pool_mask.sum()), dc_counts,
-                t.node_ids, int(elapsed))
+                t.node_ids, int(elapsed),
+                port_dim=(port_collision_dimension([port_asks[gi]])
+                          if port_asks[gi] else ""))
             decisions.append(BulkDecisions(
                 tg_name=it.tg.name, picks=picks, node_ids=t.node_ids,
                 round_size=irs, metrics=metrics, nodes_evaluated=n))

@@ -127,10 +127,16 @@ class DeviceExecutor:
 
     def chain_state(self, pending):
         """The (usage, node version, padded n) triple a successor wave
-        chains on, or None when `pending` cannot seed a chain."""
+        chains on, or None when `pending` cannot seed a chain; a wave
+        with static-port state adds it as a fourth (ops/engine.py
+        _lower_ports: the holders it left, for the successor to start
+        from)."""
         if not isinstance(pending, dict):
             return None
-        return (pending["used"], pending["node_version"], pending["npad"])
+        chain = (pending["used"], pending["node_version"], pending["npad"])
+        if pending.get("ports") is not None:
+            chain += (pending["ports"],)
+        return chain
 
     def _note_dispatch(self, pending, wanted_chain: bool) -> None:
         if not isinstance(pending, dict):

@@ -111,6 +111,32 @@ class PlacementInputs(NamedTuple):
     # traced graph; a [G, N] bool (or broadcastable) array is ANDed into
     # the static feasibility mask.
     extra_mask: jnp.ndarray = None       # [G, N] bool | None
+    # static-port state, or None x 2 (`port_state`, below): which nodes
+    # hold each static value some group of the eval asks, and which
+    # values each group asks.  Single-device scan only: the sharded twin
+    # takes the values' holders through `extra_mask`
+    pt_taken0: jnp.ndarray = None        # [Kp, N] bool | None
+    pt_ask: jnp.ndarray = None           # [G, Kp] bool | None
+
+
+def port_state(taken, ask, static):
+    """Static ports as a feasibility rule.  `taken` [Kp, N] says which
+    nodes hold each static port value of the launch (a live allocation
+    or the node's own reservation at the start, and every placement made
+    since that asked it), `ask` [Kp] which of them this placement's
+    group asks.  A node that holds a value asked is no candidate: what
+    upstream's BinPackIterator finds when its NetworkIndex refuses the
+    node, and names "reserved port collision".  Returns (the mask
+    without those nodes, the nodes of it they were: exhausted, not
+    filtered)."""
+    held = jnp.any(taken & ask[:, None], axis=0)
+    return static & ~held, static & held
+
+
+def port_commit(taken, ask, placed):
+    """`taken` after a placement on the nodes of `placed` [N] bool: they
+    now hold every value the group asks."""
+    return taken | (ask[:, None] & placed[None, :])
 
 
 class PlacementOutputs(NamedTuple):
@@ -288,8 +314,9 @@ def pack_outputs(out: PlacementOutputs):
 def place_packed(inp: PlacementInputs):
     """The exact scan on one device: one dependent step a placement,
     every step scoring all N nodes, its outputs written as the step's
-    `pack_row` into ONE `[P, 11 + RES_DIMS]` buffer.  Returns (buf, used,
-    job_count).
+    `pack_row` into ONE `[P, 11 + RES_DIMS]` buffer (one column more
+    where the eval carries static-port state: the nodes a step lost to
+    it).  Returns (buf, used, job_count).
 
     A step does what it can use.  The loop's trip count is one past the
     last ACTIVE step (the engine pads a batch to a power of two, so the
@@ -308,19 +335,28 @@ def place_packed(inp: PlacementInputs):
     st = scan_statics(inp, jnp.arange(n))
     static, noise, rows = st.static, st.noise, st.rows
     n_sp = inp.sp_nodeval.shape[0]
+    has_ports = inp.pt_taken0 is not None
 
     def step(carry, g, prev, act):
-        used, job_count, sp_counts, pd_counts = carry
+        used, job_count, sp_counts, pd_counts = carry[:4]
         req_g = inp.req[g]
         stat_g = static[g]
-        feas, final, _, fit, dh_ok = step_scores(inp, st, carry, g, prev)
+        feas, final, _, fit, dh_ok = step_scores(inp, st, carry[:4], g, prev)
+        port_rows = ()
+        port_hit = False
+        if has_ports:
+            # a node that holds a static value the group asks: exhausted,
+            # and counted in a column of its own after the dimensions
+            feas, port_hit = port_state(carry[4], inp.pt_ask[g], feas)
+            port_rows = (port_hit,)
 
         # ---- metrics: n_feasible | n_filtered | n_exhausted |
         # dim_exhausted, all of the state the step met ----
         over = (used + req_g[None, :]) > inp.cap
         counts = jnp.sum(jnp.stack([
-            feas, ~stat_g, stat_g & (~fit | ~dh_ok),
-            *((stat_g & ~fit)[:, None] & over).T]).astype(jnp.int32), axis=1)
+            feas, ~stat_g, (stat_g & (~fit | ~dh_ok)) | port_hit,
+            *((stat_g & ~fit)[:, None] & over).T,
+            *port_rows]).astype(jnp.int32), axis=1)
 
         # selection order gets the tie-break noise; reported scores do not
         tops = _top_max(jnp.where(feas, final, NEG_INF) + noise, rows, top_k,
@@ -355,14 +391,17 @@ def place_packed(inp: PlacementInputs):
         row = pack_row(pick, jnp.where(ok, top_sc[0], 0.0),
                        [jnp.where(ok, r, -1) for r in top_rows],
                        [jnp.where(ok, sc, 0.0) for sc in top_sc], counts)
-        return (used, job_count, sp_counts, pd_counts), row
+        carry_out = (used, job_count, sp_counts, pd_counts)
+        if has_ports:
+            carry_out += (port_commit(carry[4], inp.pt_ask[g], onehot),)
+        return carry_out, row
 
     # a trip-count loop over a buffer made before it, not a `lax.cond` a
     # step: the branch costs every step, and an [N, RES_DIMS] value
     # computed inside one takes the padded row-major layout on the TPU
     idle = pack_row(jnp.int32(-1), jnp.float32(0.0), [jnp.int32(-1)] * top_k,
                     [jnp.float32(0.0)] * top_k,
-                    jnp.zeros(3 + inp.cap.shape[1], jnp.int32))
+                    jnp.zeros(3 + inp.cap.shape[1] + has_ports, jnp.int32))
     buf0 = jnp.broadcast_to(idle, (p_pad,) + idle.shape)
     n_run = jnp.max(jnp.where(inp.active, jnp.arange(p_pad) + 1, 0),
                     initial=0)
@@ -377,7 +416,9 @@ def place_packed(inp: PlacementInputs):
         return carry, jax.lax.dynamic_update_index_in_dim(buf, row, i, 0)
 
     carry0 = (inp.used0, inp.job_count0, inp.sp_counts0, inp.pd_counts0)
-    (used, job_count, _, _), buf = jax.lax.fori_loop(
+    if has_ports:
+        carry0 += (inp.pt_taken0,)
+    (used, job_count, *_), buf = jax.lax.fori_loop(
         0, n_run, body, (carry0, buf0))
     return buf, used, job_count
 
@@ -396,7 +437,8 @@ def place(inp: PlacementInputs) -> PlacementOutputs:
         topk_rows=buf[:, 2:2 + top_k],
         topk_scores=i2f(buf[:, 2 + TOP_K:2 + TOP_K + top_k]),
         n_feasible=buf[:, 8], n_filtered=buf[:, 9], n_exhausted=buf[:, 10],
-        dim_exhausted=buf[:, 11:], used=used, job_count=job_count)
+        dim_exhausted=buf[:, 11:11 + inp.cap.shape[1]], used=used,
+        job_count=job_count)
 
 
 place_jit = jax.jit(place)
@@ -897,6 +939,16 @@ class MultiEvalInputs(NamedTuple):
     sp_weight: jnp.ndarray = None    # [G, S] float32 (0 = padding / none)
     sp_expected: jnp.ndarray = None  # [G, S, K] float32
     sp_counts0: jnp.ndarray = None   # [G, S, K] float32 (existing allocs)
+    # static-port state, or None x 2 (`port_state`): the nodes that hold
+    # each static value some item of the wave asks (from the state, or
+    # from the wave this one is chained on), and the values each item
+    # asks.  The round scan carries the holders as it carries `used`, so
+    # a wave-mate asking the same value, and the item's own next round,
+    # pass by the nodes taken earlier in the launch; an item that asks
+    # any takes one allocation a node.  None on a wave that holds no
+    # static ask, and the program is the one it was
+    pt_taken0: jnp.ndarray = None    # [Kp, N] bool
+    pt_ask: jnp.ndarray = None       # [G, Kp] bool
 
 
 def round_seeds(seed, rg):
@@ -924,7 +976,10 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
     nodes and one spread value, not a scatter of the prefix's 64 slots.
     Any other `want`: waterfill_round's top-k and its scattered commits.
     Real rounds' rows and `used` are the same bits whichever branch ran.
-    Returns (buf, used, last REAL round's job count row [N])."""
+    Returns (buf, used, last REAL round's job count row [N]), and on a
+    wave with static-port state the holders it left, [Kp, N], for the
+    wave chained on this one; the meta block's column 14 then counts the
+    nodes a round lost to that state."""
     n = inp.attrs.shape[0]
     assert n < (1 << 20), "packed fill rows support < 2^20 nodes"
     assert round_size <= 1024, "packed fill counts support rounds <= 1024"
@@ -968,17 +1023,26 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
         xs_r += (inp.g_spread[rg], inp.sp_weight[rg], inp.sp_expected[rg],
                  inp.sp_counts0[rg])
         carry0 += (inp.sp_counts0[0],)
+    has_ports = inp.pt_taken0 is not None
+    if has_ports:
+        # the item's asked values ride as scan xs, the holders in the
+        # carry: always the last of each
+        xs_r += (inp.pt_ask[rg],)
+        carry0 += (inp.pt_taken0,)
 
     def round_step(carry, xs):
         used, cur_count = carry[:2]
         (u, a, jc0_row, req, desired, dh_limit, want, same, sd) = xs[:9]
-        static = static_u[u]          # [N]; U is tiny — cheap gather
+        static = static_all = static_u[u]   # [N]; U is tiny — cheap gather
+        if has_ports:
+            taken_in = carry[-1]
+            static, _ = port_state(taken_in, xs[-1], static_all)
         aff_sc = aff_u[a]
         aff_any = aff_any_u[a]
         job_count = jnp.where(same, cur_count, jc0_row)
         spread = None
         if has_spread:
-            us, sp_w, sp_exp, sp_c0 = xs[9:]
+            us, sp_w, sp_exp, sp_c0 = xs[9:13]
             sp_nv = inp.sp_nodeval[us]                  # [S, N]
             sp_counts = jnp.where(same, carry[2], sp_c0)
             spread = (spread_boost(sp_nv, sp_w, sp_exp, sp_counts),
@@ -987,6 +1051,11 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
             inp.cap, req, desired, dh_limit, static,
             aff_sc, aff_any, used, job_count,
             inp.spread_algo, round_size, spread=spread)
+        if has_ports:
+            # a static ask is one allocation a node: the fill's top
+            # `want` nodes are then the picks one placement after another
+            # would make, each passing by the nodes before it
+            k_i = jnp.where(jnp.any(xs[-1]), jnp.minimum(k_i, 1), k_i)
 
         def select(select_round):
             # per-item noise (elementwise hash — no [R, N] pre-gather):
@@ -1035,9 +1104,21 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
         top_rows = jnp.where(top_sc > NEG_INF / 2, rows_p[:top_k], -1)
         top_sc = jnp.where(top_sc > NEG_INF / 2, top_sc, 0.0)
         n_feas = jnp.sum(k_round > 0).astype(jnp.int32)
-        n_filt = jnp.sum(~static).astype(jnp.int32)
+        n_filt = jnp.sum(~static_all).astype(jnp.int32)
+        if has_ports:
+            # the round's metrics are of the state it LEFT, the port
+            # state too: the nodes that hold a value asked, this round's
+            # own among them, are exhausted, and counted in a column of
+            # their own after the dimensions
+            taken = port_commit(taken_in, xs[-1], c_i > 0)
+            carry += (taken,)
+            static, port_hit = port_state(taken, xs[-1], static_all)
         n_exh, dim_ex = round_metrics_g(
             inp.cap, req, dh_limit, static, used, job_count)
+        if has_ports:
+            n_port = jnp.sum(port_hit)
+            n_exh = n_exh + n_port
+            dim_ex = jnp.concatenate([dim_ex, n_port[None]])
         out = (rows_p, cnt_p, top_rows, top_sc,
                n_feas, n_filt, n_exh.astype(jnp.int32),
                dim_ex.astype(jnp.int32), placed_total.astype(jnp.int32))
@@ -1062,12 +1143,15 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
             lambda o, v: jax.lax.dynamic_update_index_in_dim(o, v, i, 0),
             outs, out)
 
-    (used, jc, *_), outs = jax.lax.fori_loop(0, n_run, body, (carry0, outs0))
+    (used, jc, *rest), outs = jax.lax.fori_loop(
+        0, n_run, body, (carry0, outs0))
     (rows_p, cnt_p, top_rows, top_sc,
      n_feas, n_filt, n_exh, dim_ex, placed) = outs
     fills, meta = pack_round_buffer(rows_p, cnt_p, top_rows, top_sc,
                                     n_feas, n_filt, n_exh, dim_ex, placed)
     buf = jnp.concatenate([fills, meta], axis=1)
+    if has_ports:
+        return buf, used, jc, rest[-1]
     return buf, used, jc
 
 

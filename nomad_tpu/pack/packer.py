@@ -89,6 +89,27 @@ def resolve_target_key(target: str) -> str:
     return "attr." + t
 
 
+def static_port_values(resources) -> Tuple[int, ...]:
+    """The static port values a resource ask names (`port "lb" { static
+    = 8080 }`: its networks' reserved ports), in the order asked.  What
+    an allocation of it HOLDS on its node while it lives, and what the
+    kernels' port state is keyed by."""
+    return tuple(p.value for net in resources.networks
+                 for p in net.reserved_ports if p.value)
+
+
+def tg_static_ports(tg: TaskGroup) -> Tuple[int, ...]:
+    """`static_port_values` of a task group's combined ask (the group's
+    networks and its tasks'), each value once; () for the common group
+    that asks none, without building the combined ask."""
+    if not tg.networks and not any(t.resources.networks for t in tg.tasks):
+        return ()
+    return tuple(dict.fromkeys(
+        p.value for nets in ([t.resources.networks for t in tg.tasks]
+                             + [tg.networks])
+        for net in nets for p in net.reserved_ports if p.value))
+
+
 def node_property_map(node: Node) -> Dict[str, str]:
     """All scheduling-relevant string properties of a node, keyed by column
     key.  This is the single place node state is flattened for the device."""
@@ -218,6 +239,18 @@ class ClusterPacker:
         # re-uploaded (used_sync_rows_since)
         self._used_sentinel_rows: Dict[int, Optional[np.ndarray]] = {}
         self.lut_epoch = 0
+        # static-port ledger (ISSUE 38): which nodes HOLD a static port
+        # value, kept by the same alloc and block events as `used`, so
+        # the kernels can mask them (ops/engine.py static_port_mask).
+        # value -> {node id: live holders}; an alloc's entry is (node id,
+        # values), a block unit's (values, its node table); a node's own
+        # reservation counts as one holder; a value's version moves with
+        # every change to its holders
+        self._port_holders: Dict[int, Dict[str, int]] = {}
+        self._alloc_ports: Dict[str, Tuple[str, Tuple[int, ...]]] = {}
+        self._block_ports: Dict[str, Tuple[Tuple[int, ...], List[str]]] = {}
+        self._node_ports: Dict[str, Tuple[int, ...]] = {}
+        self._port_versions: Dict[int, int] = {}
 
     # ------------------------------------------------------------ columns
 
@@ -232,6 +265,57 @@ class ClusterPacker:
                     [t.attrs, np.full((t.attrs.shape[0], 1), UNSET, np.int32)],
                     axis=1)
         return col
+
+    # -------------------------------------------------- static-port ledger
+
+    def _port_hold(self, values, node_id: str, by: int) -> None:
+        """`by` more (or fewer) holders of each of `values` on a node
+        (the ledger's writers all run under `self.lock`, as `_fill_row`
+        does)."""
+        for v in values:
+            held = self._port_holders.setdefault(v, {})
+            left = held.get(node_id, 0) + by
+            if left > 0:
+                held[node_id] = left
+            else:
+                held.pop(node_id, None)
+            self._port_versions[v] = self._port_versions.get(v, 0) + 1
+
+    def _hold_alloc_ports(self, alloc_id: str, node_id: str,
+                                 resources) -> None:
+        """A live allocation on a node: it holds the static values its
+        resources ask, if any."""
+        if resources.networks:
+            static = static_port_values(resources)
+            if static:
+                self._alloc_ports[alloc_id] = (node_id, static)
+                self._port_hold(static, node_id, 1)
+
+    def _forget_ports(self) -> None:
+        """A rebuild re-reads every holder from the snapshot."""
+        for v in self._port_holders:
+            self._port_versions[v] = self._port_versions.get(v, 0) + 1
+        self._port_holders.clear()
+        self._alloc_ports.clear()
+        self._block_ports.clear()
+        self._node_ports.clear()
+
+    def static_port_holders(self, value: int) -> Tuple[int, List[str]]:
+        """(version, node ids) of the nodes where a live allocation, or
+        the node's own reservation, holds static port `value`: what a
+        static ask of it must avoid.  The version moves with every
+        change to the holders, so a mask built from them is good for as
+        long as it and the node table's version stand."""
+        with self.lock:
+            return (self._port_versions.get(value, 0),
+                    list(self._port_holders.get(value, ())))
+
+    def static_port_rows(self, t: "NodeTensors", value: int
+                         ) -> Tuple[int, List[int]]:
+        """`static_port_holders` as rows of `t`."""
+        version, holders = self.static_port_holders(value)
+        return version, [t.id_to_row[nid] for nid in holders
+                         if nid in t.id_to_row]
 
     # ------------------------------------------------------- store attach
 
@@ -262,6 +346,7 @@ class ClusterPacker:
                     self._counted.clear()
                     self._alloc_node.clear()
                     self._block_counted.clear()
+                    self._forget_ports()
 
         store.subscribe(on_event)
 
@@ -280,6 +365,7 @@ class ClusterPacker:
         # bulk plans share ONE resources object across a whole round:
         # build its usage tuple once, not per alloc
         res_cache: Dict[int, Tuple[int, ...]] = {}
+        alloc_ports = self._alloc_ports
         for a in allocs:
             aid = a.id
             old_node = alloc_node.get(aid)
@@ -291,9 +377,13 @@ class ClusterPacker:
                     if row is not None:
                         rows.append(row)
                         vals.append(tuple(-v for v in res))
+                held = alloc_ports.pop(aid, None)
+                if held is not None:
+                    self._port_hold(held[1], held[0], -1)
             nid = a.node_id
             if nid and not a.terminal_status():
                 r = a.resources
+                self._hold_alloc_ports(aid, nid, r)
                 if a.allocated_devices:
                     res = a.usage()     # instances are the alloc's own
                 else:
@@ -323,6 +413,14 @@ class ClusterPacker:
         its unique nodes (the block path's whole point — no per-alloc
         python work), tracked as a unit in _block_counted."""
         self._block_counted[block.id] = block
+        if block.template.resources.networks:
+            static = static_port_values(block.template.resources)
+            if static:
+                # (every node of a block's table holds a row of it)
+                self._block_ports[block.id] = (static, block.node_table)
+                for nid, c in zip(block.node_table,
+                                  block.node_counts().tolist()):
+                    self._port_hold(static, nid, c)
         t = self._tensors
         if t is None:
             return
@@ -349,6 +447,7 @@ class ClusterPacker:
         res = block.resources_tuple()
         alloc_node = self._alloc_node
         counted = self._counted
+        static = self._block_ports.pop(block.id, (None,))[0]
         for a in block.materialize_all():
             aid = a.id
             if aid in alloc_node:
@@ -359,6 +458,9 @@ class ClusterPacker:
                 counted[nid] = c = {}
             c[aid] = res
             alloc_node[aid] = nid
+            if static:
+                # the unit's holders become the rows' own, one for one
+                self._alloc_ports[aid] = (nid, static)
 
     def _log_delta(self, rows, vals, refreshed_rows=None) -> int:
         """Append one used-version bump to the replay log.  `rows is None`
@@ -524,6 +626,7 @@ class ClusterPacker:
         self._alloc_node.clear()
         self._counted.clear()
         self._block_counted.clear()
+        self._forget_ports()
         for i, nd in enumerate(nodes):
             self._fill_row(t, i, nd, snapshot, prop_maps[i])
         self._seq += 1
@@ -637,6 +740,15 @@ class ClusterPacker:
         block units' share of it handed in); None: a full rescan."""
         t.cap[i] = nd.capacity()
         t.dev_groups[i] = len(nd.resources.devices)
+        own = tuple(nd.reserved.reserved_ports) + tuple(
+            p.value for net in nd.resources.networks
+            for p in net.reserved_ports)
+        was = self._node_ports.pop(nd.id, ())
+        if own != was:
+            self._port_hold(was, nd.id, -1)
+            self._port_hold(own, nd.id, 1)
+        if own:
+            self._node_ports[nd.id] = own
         if unit_used is not None:
             # dirty-row refill while attached: the counted/_alloc_node
             # ledger is advanced synchronously by Allocations events and
@@ -668,6 +780,7 @@ class ClusterPacker:
                 for d in range(RES_DIMS):
                     used[d] += res[d]
                 self._alloc_node[alc.id] = nd.id
+                self._hold_alloc_ports(alc.id, nd.id, alc.resources)
             self._counted[nd.id] = counted
             t.used[i] = used
         t.elig[i] = nd.ready()

@@ -49,12 +49,20 @@ _WALL = SystemClock()
 MAX_SERVICE_ATTEMPTS = 5
 MAX_BATCH_ATTEMPTS = 2
 
-# Batched port assignment (ISSUE 8): when True, networked fresh blocks
-# ride the columnar path with a per-node bulk port carve; False forces
-# the sequential per-alloc NetworkIndex loop — the PARITY ORACLE the
-# bench gate and tests compare against (bit-for-bit (node, port)
-# equality is the promotion contract, like PR 7's sharded-vs-single).
+# Batched port assignment (ISSUE 8): when True, a networked eval's fresh
+# rows ride the columnar path with a per-node bulk port carve, whatever
+# their count (ISSUE 38: a small eval's rows too, and a static port
+# beside the dynamic ones); False forces the sequential per-alloc
+# NetworkIndex loop — the PARITY ORACLE the bench gate and tests compare
+# against (bit-for-bit (node, port) equality is the promotion contract,
+# like PR 7's sharded-vs-single).
 PORT_BATCHED = True
+
+# The most static port values ONE group may ask and still ride the wave:
+# each is a slot of the launch's port state (ops/engine.py _lower_ports),
+# whose ladder of shapes a group of dozens would climb alone.  A size the
+# mechanism needs, not a switch: nothing sets it.
+PORT_WAVE_MAX_STATIC = 8
 
 # Batched device path (ISSUE 30): when True, a group whose ONE device
 # request has no affinities, that asks for no ports, on a fleet whose
@@ -83,6 +91,11 @@ SPREAD_WAVE_MAX = 64
 # rows would serve the other stale state (ADVICE r2 #4 pattern).  Bounded
 # LRU-ish: old stores' engines are dropped, not leaked.
 _engines: Dict[str, PlacementEngine] = {}
+
+
+def asks_ports(tg) -> bool:
+    """The group carries a `network` block: its own or a task's."""
+    return bool(tg.networks) or any(t.resources.networks for t in tg.tasks)
 
 
 def _engine(explicit: Optional[PlacementEngine],
@@ -132,6 +145,9 @@ class GenericScheduler(Scheduler):
         self._device_solo_rule = ""
         self._carve = None            # (ledger, record)
         self._carve_short = (0, ())   # (rows dropped, their node ids)
+        # the state `_net_index` reads a node's ports from where the
+        # caller has a fresher one than `self.state` (submit_batched)
+        self._port_view = None
 
     # ------------------------------------------------------------- process
 
@@ -229,10 +245,12 @@ class GenericScheduler(Scheduler):
         reschedules, deployment activity) and no distinct_property (the
         exact scan kernel's per-placement state).  A device ask rides
         under `_device_batch_refusal`'s rules, a spread stanza under
-        `_spread_batch_refusal`'s.  Returns a BatchPrep or None (caller
+        `_spread_batch_refusal`'s, a port ask under
+        `_port_batch_refusal`'s.  Returns a BatchPrep or None (caller
         processes the eval through the normal path); a job with a spread
         stanza that is refused counts itself, by rule, in
-        `nomad.spread.evals_solo`."""
+        `nomad.spread.evals_solo`, one that asks ports in
+        `nomad.ports.evals_solo`."""
         if evaluation.annotate_plan:
             return None          # dry-run diffs ride the normal path
         state = self.state
@@ -240,9 +258,12 @@ class GenericScheduler(Scheduler):
         if job is None or job.stopped():
             return None
         prep, rule = self._prepare_batch(evaluation, job)
-        if prep is None and job_spreads(job):
+        if prep is None:
             from nomad_tpu.core.telemetry import REGISTRY
-            REGISTRY.inc("nomad.spread.evals_solo", rule=rule)
+            if job_spreads(job):
+                REGISTRY.inc("nomad.spread.evals_solo", rule=rule)
+            if any(asks_ports(tg) for tg in job.task_groups):
+                REGISTRY.inc("nomad.ports.evals_solo", rule=rule)
         return prep
 
     def _prepare_batch(self, evaluation: Evaluation, job: Job):
@@ -298,15 +319,35 @@ class GenericScheduler(Scheduler):
         # since ISSUE 8 they ride the COLUMNAR block path too: the
         # worker threads ONE NetworkIndex cache through every batch
         # mate's materialize pass (materialization is sequential in the
-        # worker thread), and each mate's dynamic ports are carved in a
+        # worker thread), and each mate's ports are carved in a
         # single batched per-node pass (_carve_ports_batch) that lands
         # as port columns on the AllocBlock — batch-mates landing on one
         # node commit disjoint ports without per-alloc index round
-        # trips.  Safety net: port-carrying plans are demoted from the
-        # applier's skip-fit to the full re-check, which audits block
-        # ports per node (plan_apply._carries_host_assigned /
-        # _eval_blocks).
+        # trips.  A STATIC port is a feasibility rule of the wave's
+        # kernel (ISSUE 38, ops/engine.py STATIC_PORT_FEASIBILITY): a
+        # mate asking the value a mate took passes the node by.  Safety
+        # net: port-carrying plans are demoted from the applier's
+        # skip-fit to the full re-check, which audits block ports per
+        # node (plan_apply._carries_host_assigned / _eval_blocks).
+        rule = self._port_batch_refusal(tg)
+        if rule:
+            return None, rule
         return self.BatchPrep(job, tg, count, block, places, results), ""
+
+    def _port_batch_refusal(self, tg) -> str:
+        """The admission rule that keeps a group with a STATIC port ask
+        off the wave, or "" when it rides (dynamic ports alone always
+        do): at most PORT_WAVE_MAX_STATIC values, and an engine that is
+        not sharded (the mesh's wave kernel carries no port state)."""
+        from nomad_tpu.pack.packer import tg_static_ports
+        static = tg_static_ports(tg)
+        if not static:
+            return ""
+        if len(static) > PORT_WAVE_MAX_STATIC:
+            return "static_count"
+        if self.engine.mesh is not None:
+            return "mesh"
+        return ""
 
     def _spread_batch_refusal(self, job: Job, count: int) -> str:
         """The admission rule that keeps an eval with a spread stanza
@@ -343,7 +384,7 @@ class GenericScheduler(Scheduler):
             return "requests"
         if dev_reqs[0][1].affinities:
             return "affinity"
-        if tg.networks or any(t.resources.networks for t in tg.tasks):
+        if asks_ports(tg):
             return "ports"
         engine = self.engine
         if not engine.single_group_fleet(engine.packer.update(self.state)):
@@ -352,14 +393,18 @@ class GenericScheduler(Scheduler):
 
     def submit_batched(self, evaluation: Evaluation, prep, bd,
                        coupled_batch=None, net_index_cache=None,
-                       device_ledger=None):
+                       device_ledger=None, port_view=None):
         """Phase 2a of the batched path: materialize + ENQUEUE the plan
         without waiting for the applier — the worker submits a whole
         coupled chain first, so plan apply overlaps the next plan's
         materialization.  Returns an opaque handle for finalize_batched,
         or None when the eval needs the solo path (no decisions, or
         preemption could still place failed picks — the batch kernel
-        never preempts)."""
+        never preempts).  `port_view`: the state the shared
+        `net_index_cache` is built from where it is fresher than this
+        scheduler's own snapshot (the worker's, taken as the wave's
+        materialize begins)."""
+        self._port_view = port_view
         from nomad_tpu.ops.preempt import preemption_enabled
         job, results = prep.job, prep.results
         if bd is None:
@@ -381,6 +426,9 @@ class GenericScheduler(Scheduler):
         except BaseException:
             self._settle_carve(None)
             raise
+        if asks_ports(prep.tg):
+            from nomad_tpu.core.telemetry import REGISTRY
+            REGISTRY.inc("nomad.ports.evals_batched")
         if plan.is_no_op():
             self._settle_carve(None)
             short, nodes = self._carve_short
@@ -788,51 +836,68 @@ class GenericScheduler(Scheduler):
         REGISTRY.inc("nomad.materialize.placements", n, form=form)
 
     @staticmethod
-    def _net_columnar_labels(ask) -> Optional[List[str]]:
-        """The batched-carve-eligible network shape: ONE host network,
-        no static (reserved) ports, uniquely-labeled dynamic ports.
-        Anything else — static asks, multi-network, unlabeled or
-        duplicate labels — rides the sequential per-alloc path, which
+    def _net_columnar_labels(ask):
+        """The batched-carve-eligible network shape: ONE network whose
+        ports, static first and then dynamic as `assign_ports` takes
+        them, all carry a label of their own.  Returns (labels, the
+        static ones' values) or None: several networks, unlabeled or
+        duplicate labels ride the sequential per-alloc path, which
         doubles as the parity oracle (ISSUE 8)."""
         if len(ask.networks) != 1:
             return None
         net = ask.networks[0]
-        if net.reserved_ports or not net.dynamic_ports:
+        labels = [p.label for p in net.reserved_ports + net.dynamic_ports]
+        if (not labels or not all(labels)
+                or len(set(labels)) != len(labels)
+                or not all(p.value for p in net.reserved_ports)):
             return None
-        labels = [p.label for p in net.dynamic_ports]
-        if not all(labels) or len(set(labels)) != len(labels):
-            return None
-        return labels
+        return labels, [p.value for p in net.reserved_ports]
 
-    def _carve_ports_batch(self, picks_ok, node_ids, n_labels: int,
+    def _carve_ports_batch(self, picks_ok, node_ids, labels, static,
                            net_idx, victim_ids):
         """Vectorized per-node offset scheme (ISSUE 8): group the wave's
-        placements by node, pre-check every node's free dynamic pool
-        against its cumulative demand, then carve each node's ports in
-        ONE cursor pass and scatter them back to rows in row order.
+        placements by node, pre-check every node against its cumulative
+        demand (its free dynamic pool, and each static value free and
+        asked by one row), then carve each node's ports in ONE cursor
+        pass and scatter them back to rows in row order.
         Bit-for-bit the sequential per-alloc result — mates landing on
         one node take ascending first-fit ports in row order, exactly as
         N ordered assign_ports calls would — without the N sequential
-        index round-trips.  Returns an [n_ok, n_labels] int32 array, or
-        None when any node is short (NOTHING committed — the feasibility
+        index round-trips.  `static` are the values of the first
+        len(static) labels; the rest are dynamic.  Returns an [n_ok,
+        len(labels)] int32 array, or None when any node is short or
+        holds a static value (NOTHING committed — the feasibility
         pass runs before any claim, so a mid-wave shortfall cannot leak
         partial claims into the batch-shared index)."""
         import numpy as np
+
+        from nomad_tpu.structs import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT
+        n_dyn = len(labels) - len(static)
+        # (a static value inside the dynamic range takes a port of the
+        # pool with it)
+        in_pool = sum(MIN_DYNAMIC_PORT <= v <= MAX_DYNAMIC_PORT
+                      for v in static)
         uniq, inv = np.unique(picks_ok, return_inverse=True)
         counts = np.bincount(inv, minlength=len(uniq)).tolist()
         indexes = []
         for r, k in zip(uniq.tolist(), counts):
             ni = self._net_index(node_ids[int(r)], net_idx, victim_ids)
-            if ni.dyn_free_count() < k * n_labels:
+            if static and (k > 1 or not ni.used_ports.isdisjoint(static)):
+                return None
+            if ni.dyn_free_count() < k * n_dyn + in_pool:
                 return None
             indexes.append(ni)
-        out = np.empty((len(picks_ok), n_labels), np.int32)
+        out = np.empty((len(picks_ok), len(labels)), np.int32)
+        out[:, :len(static)] = static
+        held = dict(zip(labels, static))
         order = np.argsort(inv, kind="stable")
         pos = 0
         for ni, k in zip(indexes, counts):
-            got = ni.claim_dynamic_block(k * n_labels)
-            out[order[pos:pos + k]] = np.asarray(
-                got, np.int32).reshape(k, n_labels)
+            if static:
+                ni.commit(held)
+            got = ni.claim_dynamic_block(k * n_dyn)
+            out[order[pos:pos + k], len(static):] = np.asarray(
+                got, np.int32).reshape(k, n_dyn)
             pos += k
         return out
 
@@ -843,10 +908,11 @@ class GenericScheduler(Scheduler):
         ni = cache.get(node_id)
         if ni is None:
             ni = NetworkIndex()
-            node = self.state.node_by_id(node_id)
+            state = self._port_view or self.state
+            node = state.node_by_id(node_id)
             if node is not None:
                 ni.set_node(node)
-            ni.add_allocs(a for a in self.state.allocs_by_node(node_id)
+            ni.add_allocs(a for a in state.allocs_by_node(node_id)
                           if a.id not in victim_ids)
             cache[node_id] = ni
         return ni
@@ -899,6 +965,8 @@ class GenericScheduler(Scheduler):
             # capacity view (the flag also blocks the fence-tag step)
             plan.coupled_batch = None
             plan.host_redirected = True
+            from nomad_tpu.core.telemetry import REGISTRY
+            REGISTRY.inc("nomad.ports.runner_up_redirects")
             return ports, alt
         return None, None
 
@@ -1099,7 +1167,6 @@ class GenericScheduler(Scheduler):
         count = len(block.indexes) if block is not None else len(places)
         ids = new_ids(count)
         node_ids = bd.node_ids
-        node_alloc = plan.node_allocation
         victim_ids = {v.id for vs in bd.evictions.values() for v in vs}
         # `net_idx` may be the BATCH-SHARED port cache (see prepare_batch:
         # batch mates materialize sequentially and must see each other's
@@ -1108,23 +1175,21 @@ class GenericScheduler(Scheduler):
         # shared and the per-plan victim semantics cannot diverge
         if net_idx is None:
             net_idx = {}
-        last_nid = None
-        last_list = None
         # fresh rows named from an index list: a PlaceBlock's, or (device
-        # groups, whose small evals must not fall to per-alloc objects)
-        # the fresh PlaceRequests prepare_batch admitted
+        # and networked groups, whose small evals must not fall to
+        # per-alloc objects) the fresh PlaceRequests of a small eval
+        net_cols = (self._net_columnar_labels(ask)
+                    if has_net and PORT_BATCHED else None)
         rows_fresh = block is not None or (
-            dev_req is not None
+            (dev_req is not None or net_cols is not None)
             and all(p.previous_alloc is None and not p.canary
                     for p in places))
         if rows_fresh:
             prefix = f"{job.id}.{tg.name}["     # matches reconcile._name
             indexes = (block.indexes if block is not None
                        else [p.index for p in places])
-
-        net_labels = (self._net_columnar_labels(ask)
-                      if has_net and PORT_BATCHED and block is not None
-                      else None)
+        net_labels, net_static = net_cols if net_cols is not None \
+            else (None, ())
         if (rows_fresh and not bd.evictions
                 and (not has_net or net_labels is not None)):
             # hottest shape (the bench/batch pattern): fresh block, no
@@ -1152,9 +1217,10 @@ class GenericScheduler(Scheduler):
                 # carve BEFORE any failure accounting: a short node
                 # falls the whole eval back to the sequential per-alloc
                 # oracle below, which keeps its own failure counters
-                ports_arr = self._carve_ports_batch(
-                    picks_ok, node_ids, len(net_labels), net_idx,
-                    victim_ids)
+                with self._port_assign_stage():
+                    ports_arr = self._carve_ports_batch(
+                        picks_ok, node_ids, net_labels, net_static,
+                        net_idx, victim_ids)
             dev_ids = dev_groups = None
             n_short = 0
             if dev_req is not None and n_ok:
@@ -1249,9 +1315,10 @@ class GenericScheduler(Scheduler):
                     device_ids=dev_ids,
                 ))
                 return
-            # a node's dynamic pool was short of the wave's demand:
-            # sequential per-alloc oracle below (runner-up redirects,
-            # per-port exhaustion dimensions)
+            # a node's dynamic pool was short of the wave's demand, or
+            # a node held a static value (a launch without the kernels'
+            # port state): sequential per-alloc oracle below (runner-up
+            # redirects, per-port exhaustion dimensions)
 
         if dev_req is not None:
             # the loop below assigns no instance: rather nack the eval
@@ -1259,6 +1326,33 @@ class GenericScheduler(Scheduler):
             raise RuntimeError(
                 f"{job.id}.{tg.name}: a device-asking group left the "
                 "columnar path (evictions, or rows that are not fresh)")
+        with (self._port_assign_stage() if has_net
+              else contextlib.nullcontext()):
+            self._materialize_rows(plan, job, places, bd, results, block,
+                                   tg, ask, tmpl_d, ids, net_idx,
+                                   victim_ids,
+                                   prefix if rows_fresh else "",
+                                   indexes if rows_fresh else ())
+
+    def _port_assign_stage(self):
+        """The "port_assign" stage (core/wavepipe.py): an eval's port
+        work inside its materialize, timed through the planner where it
+        offers stages."""
+        stage = getattr(self.planner, "stage", None)
+        return stage("port_assign") if stage else contextlib.nullcontext()
+
+    def _materialize_rows(self, plan, job, places, bd, results, block, tg,
+                          ask, tmpl_d, ids, net_idx, victim_ids,
+                          prefix, indexes) -> None:
+        """`_materialize_bulk`'s per-allocation loop: rows that are not
+        fresh, preemptions, and the ports the columnar carve leaves (a
+        shape it does not take, a short node, PORT_BATCHED off: the
+        sequential NetworkIndex oracle, with its runner-up redirects)."""
+        has_net = bool(ask.networks)
+        node_ids = bd.node_ids
+        node_alloc = plan.node_allocation
+        last_nid = None
+        last_list = None
         picks_l = bd.picks.tolist()
         # a metric a placement (the exact scan's): built once, not a row
         # at a time
@@ -1267,7 +1361,7 @@ class GenericScheduler(Scheduler):
         first_m = None        # the first placed row's metric
         victims_sample: List = []
         victims_n = 0
-        for i in range(count):
+        for i in range(len(ids)):
             p = places[i] if block is None else None
             pick = picks_l[i]
             m = row_ms[i] if row_ms is not None else bd.metric_at(i)

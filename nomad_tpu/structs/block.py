@@ -47,9 +47,12 @@ class RowMetrics:
     nodes_in_pool: int = 0
     nodes_available: Dict[str, int] = field(default_factory=dict)
     allocation_time_ns: int = 0
-    # [rows, 2 + RES_DIMS] int32: nodes_filtered | nodes_exhausted |
-    # dimension_exhausted by capacity dimension (RES_NAMES)
+    # [rows, 2 + len(dim_names)] int32: nodes_filtered | nodes_exhausted
+    # | dimension_exhausted by `dim_names`: the capacity dimensions
+    # (RES_NAMES) and, off a scan with static-port state, the port
+    # collision's
     counts: Optional[np.ndarray] = None
+    dim_names: tuple = RES_NAMES
     # the scan's best candidates a row: topk[i, j] indexes `nodes` (-1:
     # none), topk_scores[i, j] is its final score (float32)
     topk: Optional[np.ndarray] = None
@@ -64,7 +67,8 @@ class RowMetrics:
             nodes_available=self.nodes_available,
             allocation_time_ns=self.allocation_time_ns,
             counts=self.counts[keep], topk=self.topk[keep],
-            topk_scores=self.topk_scores[keep], nodes=self.nodes)
+            topk_scores=self.topk_scores[keep], nodes=self.nodes,
+            dim_names=self.dim_names)
 
     def metric(self, i: int) -> AllocMetric:
         return self.take(slice(i, i + 1)).materialize()[0]
@@ -80,6 +84,7 @@ class RowMetrics:
         nodes = self.nodes
         n_eval, n_pool = self.nodes_evaluated, self.nodes_in_pool
         avail, elapsed = self.nodes_available, self.allocation_time_ns
+        dim_names = self.dim_names
         smd_cache: Dict[tuple, list] = {}
         out: List[AllocMetric] = []
         for row, kr, ks in zip(counts, topk, scores):
@@ -93,7 +98,7 @@ class RowMetrics:
             )
             if any(row[2:]):
                 metric.dimension_exhausted = {
-                    name: c for name, c in zip(RES_NAMES, row[2:]) if c}
+                    name: c for name, c in zip(dim_names, row[2:]) if c}
             key = (*kr, *ks)
             smd = smd_cache.get(key)
             if smd is None:

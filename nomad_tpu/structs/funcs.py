@@ -115,9 +115,14 @@ def score_fit(node: Node, used: Resources, algorithm: str) -> float:
 
 @dataclass
 class NetworkIndex:
-    """Tracks port usage on one node.  Simplified to a single host network
-    (the packed-tensor plane models ports as one bitmap per node, which is
-    also what the kernels consume).
+    """Tracks port usage on one node.  Simplified to a single host network:
+    a port on any host network of the node takes the value for all.  The
+    host builds one of these a touched node when it assigns ports
+    (scheduler/generic.py) and when the applier re-checks them
+    (core/plan_apply.py).  What the kernels consume is not this index but
+    the holders of each STATIC value a launch asks, one [N] row a value
+    (ops/select.py port_state, built from pack/packer.py's port ledger):
+    dynamic ports never reach the device.
 
     Dynamic picks run off a FREE CURSOR: `_vcursor` maintains the
     invariant that every port before it IN SCAN ORDER is in

@@ -561,18 +561,21 @@ def _inside(span, outers):
 class TestPassStages:
     def test_stage_list_is_the_recorders(self, small_pass):
         from nomad_tpu.core.wavepipe import STAGES
-        # device_carve, spread_lower and mesh_launch are the stages this
-        # pass does not take (no eval in it asks for a device or carries
-        # a spread stanza, and its engine has no mesh) and the ones that
-        # nest, in their eval's materialize and their wave's dispatch:
-        # tests/test_device_batched.py, tests/test_spread_batched.py and
+        # device_carve, port_assign, spread_lower and mesh_launch are the
+        # stages this pass does not take (no eval in it asks for a device
+        # or a port or carries a spread stanza, and its engine has no
+        # mesh) and the ones that nest, in their eval's materialize and
+        # their wave's dispatch: tests/test_device_batched.py,
+        # tests/test_ports_wave.py, tests/test_spread_batched.py and
         # tests/test_mesh_served.py hold them to that
         assert set(small_pass.stage_timers.counts()) | {
-            "device_carve", "spread_lower", "mesh_launch"} == set(STAGES)
+            "device_carve", "port_assign", "spread_lower",
+            "mesh_launch"} == set(STAGES)
         # dequeue is the worker's, before a pass; stream_send the
         # stream follower's HTTP handler's (stage_pass.py has one)
         assert set(WORKER_STAGES) | {"pass", "device", "commit",
                                      "store_upsert", "device_carve",
+                                     "port_assign",
                                      "spread_lower", "mesh_launch",
                                      "dequeue",
                                      "stream_send"} == set(STAGES)
