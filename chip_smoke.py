@@ -3,6 +3,7 @@
 
     python chip_smoke.py                  # TPU only; fails anywhere else
     python chip_smoke.py --mesh-legs      # multi-chip host: mesh auto vs off
+    python chip_smoke.py --scan-fused     # spread5k's eval: XLA vs fused scan
     python chip_smoke.py --cpu-dry-run    # tiny size on the CPU backend
 
 Drives `job register` on the HTTP API -> broker -> worker -> WavePipeline
@@ -99,6 +100,141 @@ def spread_job(count: int):
     job.id = "smoke-spread"
     job.task_groups[0].count = count
     return job
+
+
+# --scan-fused: spread5k's eval (benchmark/configs/spread5k.json) through
+# both single-device scans; the dry run is a tiny one, the kernel in
+# Pallas' interpreter
+SCAN_FULL = {"nodes": 5_000, "steps": 3_000, "p_pad": 4_096}
+SCAN_TINY = {"nodes": 300, "steps": 30, "p_pad": 64}
+SCAN_LAUNCHES = 5
+
+
+def scan_leg_inputs(sizes: dict, seed: int):
+    """One spread5k eval's scan inputs, lowered as the engine lowers a
+    solo eval: `steps` active placements of the job's group, padded to
+    `p_pad`, on the empty fleet, the tie-break seed live."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.configs import spread5k
+    from nomad_tpu.ops.select import PlacementInputs
+    from nomad_tpu.pack import ClusterPacker, lower_spreads
+    from nomad_tpu.scheduler import Harness
+    from nomad_tpu.structs import Job, codec
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs", "spread5k.json")) as f:
+        cfg = dict(json.load(f), nodes=sizes["nodes"],
+                   count_per_job=sizes["steps"])
+    nodes, _ = spread5k.build_fleet(cfg, seed)
+    job = codec.decode(Job, spread5k.make_job(cfg, 0))
+    h = Harness()
+    h.state.upsert_nodes(nodes)
+    h.state.upsert_job(job)
+    snap = h.snapshot()
+    packer = ClusterPacker()
+    t = packer.build(snap)
+    tgs = job.task_groups
+    tgt = packer.lower_task_groups(job, tgs)
+    ctx = packer.job_context(job, snap, t)
+    sp = lower_spreads(packer, job, t, snap)
+    pd = packer.lower_distinct(job, tgs, tgt, t, snap)
+    p_pad = sizes["p_pad"]
+    return PlacementInputs(
+        attrs=jnp.asarray(t.attrs), cap=jnp.asarray(t.cap),
+        used0=jnp.asarray(t.used), elig=jnp.asarray(t.elig.astype(bool)),
+        dc_mask=jnp.asarray(ctx.dc_mask),
+        pool_mask=jnp.asarray(ctx.pool_mask), luts=jnp.asarray(tgt.luts),
+        con=jnp.asarray(tgt.con), aff=jnp.asarray(tgt.aff),
+        req=jnp.asarray(tgt.req),
+        desired=jnp.asarray(np.array([tg.count for tg in tgs], np.int32)),
+        dh_limit=jnp.asarray(tgt.dh_limit),
+        sp_nodeval=jnp.asarray(sp.sp_nodeval),
+        sp_weight=jnp.asarray(sp.sp_weight),
+        sp_expected=jnp.asarray(sp.sp_expected),
+        sp_counts0=jnp.asarray(sp.sp_counts0),
+        pd_nodeval=jnp.asarray(pd.pd_nodeval),
+        pd_limit=jnp.asarray(pd.pd_limit), pd_apply=jnp.asarray(pd.pd_apply),
+        pd_counts0=jnp.asarray(pd.pd_counts0),
+        tg_idx=jnp.zeros(p_pad, jnp.int32),
+        prev_row=jnp.full(p_pad, -1, jnp.int32),
+        active=jnp.asarray(np.arange(p_pad) < sizes["steps"]),
+        job_count0=jnp.asarray(ctx.job_count),
+        spread_algo=jnp.asarray(False),
+        seed=jnp.asarray(seed & 0xFFFFFFFF, jnp.uint32))
+
+
+def run_scan_leg(sizes: dict, seed: int, interpret: bool) -> dict:
+    """Both single-device scans on one eval's inputs: the rows they
+    agree on and differ in (for a differing pick, the two picks' score
+    gap in ulps), and each one's ms a launch, on the device's clock from
+    a profiler trace and on the host's around a blocked launch."""
+    import tempfile
+
+    import jax
+    import numpy as np
+
+    from benchmark.trace_reduce import find_xplane, reduce_trace
+    from nomad_tpu.ops import scan_fused, select
+
+    def place_packed_fused(inp):
+        return scan_fused.place_packed_fused(inp, interpret=interpret)
+
+    inp = scan_leg_inputs(sizes, seed)
+    # programs `jit_place_packed_xla` and `jit_place_packed_fused`
+    fns = {"xla": jax.jit(select.place_packed_xla),
+           "fused": jax.jit(place_packed_fused)}
+    outs = {k: [np.asarray(x) for x in f(inp)] for k, f in fns.items()}
+    host_ms = {}
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for k, f in fns.items():
+                t0 = time.perf_counter()
+                for _ in range(SCAN_LAUNCHES):
+                    jax.block_until_ready(f(inp))
+                host_ms[k] = (time.perf_counter() - t0) * 1e3 / SCAN_LAUNCHES
+        path = find_xplane(trace_dir)
+        chips = reduce_trace(path, with_ops=False)["chips"] if path else {}
+    device_ms = {}
+    for chip in chips.values():
+        for name, (launches, secs) in chip["programs"].items():
+            for k in fns:
+                if name.startswith("jit_") and name.endswith(k) and launches:
+                    device_ms[k] = secs * 1e3 / launches
+    (xbuf, xused, xjc), (fbuf, fused_used, fjc) = outs["xla"], outs["fused"]
+    differ = np.flatnonzero((xbuf != fbuf).any(axis=1))
+    pick_differ = np.flatnonzero(xbuf[:, 0] != fbuf[:, 0])
+    gaps = []
+    for i in pick_differ[:8].tolist():
+        a, b = xbuf[i, 1].astype(np.int64), fbuf[i, 1].astype(np.int64)
+        gaps.append({"row": i, "picks": [int(xbuf[i, 0]), int(fbuf[i, 0])],
+                     "score_gap_ulps": int(abs(a - b))})
+    # each column's differing rows, and its widest gap in ulps where it
+    # holds a float's bits (the reported scores: 1 and 5-7)
+    columns = {}
+    for c in np.flatnonzero((xbuf != fbuf).any(axis=0)).tolist():
+        rows = xbuf[:, c] != fbuf[:, c]
+        gap = np.abs(xbuf[rows, c].astype(np.int64)
+                     - fbuf[rows, c].astype(np.int64))
+        columns[str(c)] = {"rows": int(rows.sum()),
+                           "max_gap": int(gap.max())}
+    steps = sizes["steps"]
+    return {
+        "sizes": sizes,
+        "rows_equal": int(len(xbuf) - len(differ)),
+        "rows_differing": int(len(differ)),
+        "picks_differing": int(len(pick_differ)),
+        "first_differing_picks": gaps,
+        "differing_columns": columns,
+        "final_state_equal": bool((xused == fused_used).all()
+                                  and (xjc == fjc).all()),
+        "placed": int((fbuf[:steps, 0] >= 0).sum()),
+        "device_ms_per_launch": device_ms,
+        "device_us_per_step": {k: v * 1e3 / steps
+                               for k, v in device_ms.items()},
+        "host_ms_per_blocked_launch": host_ms,
+    }
 
 
 def cache_entries(path: str) -> int:
@@ -392,6 +528,10 @@ def main(argv=None) -> int:
                     help="multi-device host: run with the node axis "
                          "sharded over every device, then single-device, "
                          "and require identical placements")
+    ap.add_argument("--scan-fused", action="store_true",
+                    help="spread5k's eval through the XLA scan and the "
+                         "fused Pallas scan: rows equal and differing, "
+                         "each one's ms a launch")
     args = ap.parse_args(argv)
 
     import jax
@@ -421,6 +561,20 @@ def main(argv=None) -> int:
     cache_before = cache_entries(cache_dir)
     sizes = TINY if args.cpu_dry_run else FULL
     t0 = time.perf_counter()
+    if args.scan_fused:
+        leg = run_scan_leg(SCAN_TINY if args.cpu_dry_run else SCAN_FULL,
+                           args.seed, interpret=args.cpu_dry_run)
+        failures = ([] if leg["placed"] == leg["sizes"]["steps"] else
+                    [f"scan_fused: {leg['placed']} placed of "
+                     f"{leg['sizes']['steps']}"])
+        report = {"ok": not failures, "device": device,
+                  "jax": jax.__version__, "seed": args.seed,
+                  "scan_fused": leg}
+        if failures:
+            report["failures"] = failures
+        print(json.dumps(report), flush=True)
+        print(json.dumps({"ok": report["ok"], "device": device}), flush=True)
+        return 0 if not failures else 1
     if args.mesh_legs:
         legs = {"mesh_auto": run_leg(sizes, args.seed, None,
                                      want_platform),

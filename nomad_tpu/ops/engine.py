@@ -44,6 +44,7 @@ from nomad_tpu.structs import (
 from .feasibility import (constraint_mask, feasible_mask_jit,
                           place_system_jit)
 from .preempt import Preemptor, preemption_enabled
+from .scan_fused import scan_gate
 from .select import (
     BulkInputs, FILL_K, MultiEvalInputs, PlacementInputs, TOP_K,
     place_bulk_packed_jit, place_multi_chained_jit,
@@ -1427,6 +1428,10 @@ class PlacementEngine:
                                     ("padded", p_pad - p_real)):
                     _registry().inc("nomad.engine.scan_steps", steps,
                                     kind=kind)
+                # which scan `place_packed` takes at this shape, and why
+                impl, why = scan_gate(inp)
+                _registry().inc("nomad.engine.scan_launches", 1,
+                                impl=impl, why=why)
                 buf, used_dev, job_count_dev = self._launch(
                     "scan", (npad, p_pad), place_packed_jit, inp)
             b = self._fetch(buf)[:p_real]

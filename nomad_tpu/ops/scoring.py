@@ -36,13 +36,21 @@ def binpack_score(cap: jnp.ndarray,          # [N, 3] float32
     utilization; only cpu (0) and memory (1) dims contribute to the score,
     matching the reference."""
     total_used = used + req
-    safe_cap = jnp.maximum(cap, 1.0)
-    free = 1.0 - jnp.minimum(total_used / safe_cap, 1.0)
-    total = jnp.power(10.0, free[..., 0]) + jnp.power(10.0, free[..., 1])
+    return fit_score(cap[..., 0], cap[..., 1], total_used[..., 0],
+                     total_used[..., 1], spread_algo)
+
+
+def fit_score(cap_cpu, cap_mem, total_cpu, total_mem, spread_algo=False):
+    """`binpack_score` on its two dimensions given apart, each any shape
+    (float32): the form the fused scan (ops/scan_fused.py) calls on its
+    node planes, so both scans rank with one body."""
+    free = [1.0 - jnp.minimum(total / jnp.maximum(cap, 1.0), 1.0)
+            for cap, total in ((cap_cpu, total_cpu), (cap_mem, total_mem))]
+    total = jnp.power(10.0, free[0]) + jnp.power(10.0, free[1])
     score = jnp.where(spread_algo, total - 2.0, 20.0 - total)
     score = jnp.clip(score, 0.0, MAX_FIT_SCORE)
     # zero-capacity nodes score 0
-    ok = (cap[..., 0] > 0) & (cap[..., 1] > 0)
+    ok = (cap_cpu > 0) & (cap_mem > 0)
     return jnp.where(ok, score, 0.0)
 
 
@@ -121,13 +129,26 @@ def spread_boost(sp_nodeval: jnp.ndarray,    # [S, N] int32 local value idx, -1 
     val = jnp.clip(sp_nodeval, 0, k - 1)
     exp_n = jnp.take_along_axis(sp_expected, val, axis=1)     # [S, N]
     cnt_n = jnp.take_along_axis(sp_counts, val, axis=1)       # [S, N]
-    boost = (exp_n - (cnt_n + 1.0)) / jnp.maximum(exp_n, 1.0)
-    boost = jnp.clip(boost, -1.0, 1.0)
+    boost = value_boost(exp_n, cnt_n)
     # nodes whose value is not a spread target get no boost
     boost = jnp.where(sp_nodeval >= 0, boost, 0.0)
-    w = sp_weight / 100.0
-    n_active = jnp.maximum(jnp.sum(sp_weight > 0), 1.0)
+    w, n_active = spread_weights(sp_weight)
     return jnp.sum(boost * w[:, None], axis=0) / n_active
+
+
+def spread_weights(sp_weight):
+    """(each spread's weight as a share [S], how many spreads count [])
+    of `spread_boost`'s weighted mean."""
+    return sp_weight / 100.0, jnp.maximum(jnp.sum(sp_weight > 0), 1.0)
+
+
+def value_boost(expected, counts):
+    """One spread value's boost at its expected and current counts
+    (`spread_boost`), elementwise: the fused scan calls it on the [S, K]
+    table of values where the XLA scan calls it on the values gathered
+    a node, so both compute each node's boost with one body."""
+    return jnp.clip((expected - (counts + 1.0)) / jnp.maximum(expected, 1.0),
+                    -1.0, 1.0)
 
 
 def normalize_scores(components: jnp.ndarray,   # [Ncomp, ...] stacked

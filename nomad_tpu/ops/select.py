@@ -312,11 +312,24 @@ def pack_outputs(out: PlacementOutputs):
 
 
 def place_packed(inp: PlacementInputs):
-    """The exact scan on one device: one dependent step a placement,
-    every step scoring all N nodes, its outputs written as the step's
-    `pack_row` into ONE `[P, 11 + RES_DIMS]` buffer (one column more
-    where the eval carries static-port state: the nodes a step lost to
-    it).  Returns (buf, used, job_count).
+    """The exact scan on one device, (buf, used, job_count): ONE Pallas
+    kernel where `scan_fused.scan_gate` says it fits (the TPU, node
+    state within its VMEM budget, value tables within its `where`
+    chains), else `place_packed_xla`.  Decided at trace time from the
+    backend and the shapes, so one program `jit_place_packed` a shape."""
+    from .scan_fused import place_packed_fused, scan_gate  # imports this
+    if scan_gate(inp)[0] == "fused":
+        return place_packed_fused(inp)
+    return place_packed_xla(inp)
+
+
+def place_packed_xla(inp: PlacementInputs):
+    """The exact scan on one device as XLA ops: one dependent step a
+    placement, every step scoring all N nodes, its outputs written as
+    the step's `pack_row` into ONE `[P, 11 + RES_DIMS]` buffer (one
+    column more where the eval carries static-port state: the nodes a
+    step lost to it).  Returns (buf, used, job_count).  The fused
+    kernel's oracle, and the scan wherever that kernel does not fit.
 
     A step does what it can use.  The loop's trip count is one past the
     last ACTIVE step (the engine pads a batch to a power of two, so the

@@ -61,6 +61,24 @@ def test_cpu_dry_run_passes_and_reports(capsys):
                                          "entries_after"}
 
 
+def test_scan_fused_leg_dry_run_agrees_and_reports_no_device_time(capsys):
+    """`--scan-fused --cpu-dry-run`: spread5k's eval, tiny, through both
+    scans (the kernel in Pallas' interpreter): every row equal, every
+    step placed, and no device time from a CPU run."""
+    assert chip_smoke.main(["--scan-fused", "--cpu-dry-run",
+                            "--seed", "2147939001"]) == 0
+    rep, verdict = _report_and_verdict(capsys.readouterr().out)
+    assert verdict == {"ok": True, "device": rep["device"]}
+    leg = rep["scan_fused"]
+    assert leg["sizes"] == chip_smoke.SCAN_TINY
+    assert (leg["rows_differing"], leg["picks_differing"]) == (0, 0)
+    assert leg["rows_equal"] == chip_smoke.SCAN_TINY["p_pad"]
+    assert leg["final_state_equal"] and leg["differing_columns"] == {}
+    assert leg["placed"] == chip_smoke.SCAN_TINY["steps"]
+    assert leg["device_ms_per_launch"] == leg["device_us_per_step"] == {}
+    assert set(leg["host_ms_per_blocked_launch"]) == {"xla", "fused"}
+
+
 def test_default_refuses_a_cpu_backend(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr().out
