@@ -46,8 +46,8 @@ from .feasibility import (constraint_mask, feasible_mask_jit,
 from .preempt import Preemptor, preemption_enabled
 from .scan_fused import scan_gate
 from .select import (
-    BulkInputs, FILL_K, MultiEvalInputs, PlacementInputs, TOP_K,
-    place_bulk_packed_jit, place_multi_chained_jit,
+    BulkInputs, FILL_K, MultiEvalInputs, PlacementInputs, SCAN_PACKED_FIELDS,
+    TOP_K, pack_scan_inputs, place_bulk_packed_jit, place_multi_chained_jit,
     place_multi_compact_chained_jit, place_multi_compact_packed_jit,
     place_multi_packed_jit, place_packed_jit)
 
@@ -80,6 +80,17 @@ PORT_ROWS_CARRIED = 64
 # multi-second device compile the first time each size appeared)
 SCATTER_CHUNK = 16384
 _scatter_add_jit = jax.jit(lambda u, r, v: u.at[r].add(v))
+
+
+def _fold_rows(npad: int) -> int:
+    """The rows of usage replay a single-device scan launch carries in
+    its input buffer (`select.pack_scan_inputs`): fixed for a fleet, so
+    the program keeps one shape; room for a replay that touches every
+    node twice (a plan's placements and the stops before it), within the
+    scatter ladder's bounds.  More goes by `_used_device`'s scatter."""
+    return min(SCATTER_CHUNK, _pad_pow2(2 * npad, lo=512))
+
+
 # the single-device wave programs by `dispatch_batch`'s launch kind (their
 # node-sharded twins: SHARDED_KINDS below)
 _WAVE_JITS = {"multi": place_multi_packed_jit,
@@ -227,6 +238,19 @@ def _pad_pow2(x: int, lo: int = 8) -> int:
     while p < x:
         p *= 2
     return p
+
+
+def _port_slots(asks, g_pad: int):
+    """(the static port values a launch's groups ask, in slot order;
+    `[g_pad, Kp]` bool, the slots each group asks), Kp on the
+    power-of-two ladder from PORT_SLOTS_MIN."""
+    asked = tuple(sorted({v for a in asks for v in a}))
+    slot = {v: k for k, v in enumerate(asked)}
+    pt_ask = np.zeros((g_pad, _pad_pow2(len(asked), lo=PORT_SLOTS_MIN)), bool)
+    for g, a in enumerate(asks):
+        for v in a:
+            pt_ask[g, slot[v]] = True
+    return asked, pt_ask
 
 
 # lane-parallel scheduling cap: lanes beyond this stop paying (each step's
@@ -757,6 +781,9 @@ class PlacementEngine:
             if deltas is not None:
                 rows = np.concatenate([d[0] for d in deltas])
                 vals = np.concatenate([d[1] for d in deltas])
+                if len(rows):
+                    _registry().inc("nomad.engine.used_replay_rows",
+                                    len(rows), how="scatter")
                 # aggregate per row first: a 100k-alloc plan touches far
                 # fewer distinct rows; the upload shrinks with it
                 if len(rows) > SCATTER_CHUNK:
@@ -813,6 +840,51 @@ class PlacementEngine:
                            "invalidation-replay" if deltas is not None
                            else "initial-upload")
             return self._used_dev
+
+    def _used_to_fold(self, t: NodeTensors, cap: int):
+        """What a launch needs to replay the usage deltas itself, in place
+        of `_used_device`'s scatter: (the resident copy, its version, the
+        version it reaches, the deltas between), read under the packer
+        lock as `_used_device` reads them.  None where there is no
+        resident copy, a rescan intervened, or the deltas pass `cap`
+        rows: `_used_device` then does what it always did."""
+        with self.packer.lock:
+            base, base_ver = self._used_dev, self._used_version
+            if base is None:
+                return None
+            ver = t.used_version
+            deltas = ([] if base_ver == ver
+                      else self.packer.used_deltas_since(base_ver))
+            if deltas is None or sum(len(r) for r, _ in deltas) > cap:
+                return None
+            return base, base_ver, ver, deltas
+
+    def _adopt_used(self, fold, used_next, seconds: float) -> None:
+        """Take the launch's replayed `used` as the resident copy, at the
+        version `_used_to_fold` read.  Not where another caller moved the
+        copy meanwhile (a replay of its own, a full upload): the one it
+        left is as new or newer.  The launch runs outside the packer
+        lock, so the applier's commits never wait on it."""
+        base, base_ver, ver, deltas = fold
+        rows = sum(len(r) for r, _ in deltas)
+        if rows:
+            _registry().inc("nomad.engine.used_replay_rows", rows,
+                            how="folded")
+        with self.packer.lock:
+            if ver != base_ver and self._used_dev is base:
+                self._used_dev, self._used_version = used_next, ver
+        self._note_h2d(rows * (1 + RES_DIMS) * 4, seconds,
+                       "invalidation-replay")
+
+    def _scan_ports(self, t: NodeTensors, npad: int, asks) -> dict:
+        """The single-device scan's static-port fields as host arrays, to
+        go up in its input buffer: `_lower_ports`' slots and holders for
+        a launch with no chain behind it."""
+        asked, ask = _port_slots(asks, len(asks))
+        taken = np.zeros((ask.shape[1], npad), bool)
+        for k, v in enumerate(asked):
+            taken[k, self.packer.static_port_rows(t, v)[1]] = True
+        return {"pt_taken0": taken, "pt_ask": ask}
 
     def _dev_const(self, key, builder):
         """Small per-eval tensors that repeat across evals (empty spread
@@ -1005,16 +1077,11 @@ class PlacementEngine:
         holders are read from the state.  Returns (values, pt_taken0,
         pt_ask, rest): `rest` the carried rows this launch does not
         take, to hand on."""
-        asked = tuple(sorted({v for a in asks for v in a}))
+        asked, pt_ask = _port_slots(asks, g_pad)
         if not asked:
             return None
         c_values, c_taken, rest = carried or ((), None, {})
-        k_pad = _pad_pow2(len(asked), lo=PORT_SLOTS_MIN)
-        slot = {v: k for k, v in enumerate(asked)}
-        pt_ask = np.zeros((g_pad, k_pad), bool)
-        for g, a in enumerate(asks):
-            for v in a:
-                pt_ask[g, slot[v]] = True
+        k_pad = pt_ask.shape[1]
         if asked == tuple(c_values) and c_taken.shape == (k_pad, npad):
             return asked, c_taken, jnp.asarray(pt_ask), rest
         # another set of values than the wave before: its rows join the
@@ -1213,19 +1280,24 @@ class PlacementEngine:
         desired = np.array([tg.count for tg in tgs], np.int32)
         algo = snapshot.scheduler_config().scheduler_algorithm
         dev = self._node_arrays(t)
-        used0 = self._used_device(t)
         job_count = ctx.job_count
+        stop_delta = None
         if stopped_allocs:
-            delta = np.zeros((npad, RES_DIMS), np.int32)
+            stop_delta = np.zeros((npad, RES_DIMS), np.int32)
             job_count = job_count.copy()
             for a in stopped_allocs:
                 row = t.id_to_row.get(a.node_id)
                 if row is None:
                     continue
-                delta[row] -= a.usage()
+                stop_delta[row] -= a.usage()
                 if a.job_id == job.id and job_count[row] > 0:
                     job_count[row] -= 1
-            used0 = used0 + jnp.asarray(delta)
+
+        def used_on_device():
+            used = self._used_device(t)
+            if stop_delta is not None:
+                used = used + jnp.asarray(stop_delta)
+            return used
 
         # cached per-eval device constants: every [N]-sized upload that
         # repeats across evals is cached
@@ -1307,7 +1379,7 @@ class PlacementEngine:
             round_size = min(BULK_ROUND, p_pad)
             n_rounds = p_pad // round_size
             binp = BulkInputs(
-                attrs=dev["attrs"], cap=dev["cap"], used0=used0,
+                attrs=dev["attrs"], cap=dev["cap"], used0=used_on_device(),
                 elig=dev["elig"], dc_mask=dcm, pool_mask=pm, luts=luts_dev,
                 con=jnp.asarray(tg_tensors.con),
                 aff=jnp.asarray(tg_tensors.aff),
@@ -1384,38 +1456,43 @@ class PlacementEngine:
                     if r.prev_node_id:
                         prev_row[i] = t.id_to_row.get(r.prev_node_id, -1)
                     active[i] = True
+            # the per-eval fields as host arrays: on one device they go up
+            # as ONE buffer (`select.pack_scan_inputs`), on a mesh each
+            # as an array of its own
             inp = PlacementInputs(
-                attrs=dev["attrs"], cap=dev["cap"], used0=used0,
+                attrs=dev["attrs"], cap=dev["cap"], used0=None,
                 elig=dev["elig"],
                 dc_mask=dcm,
                 pool_mask=pm,
                 luts=luts_dev,
-                con=jnp.asarray(tg_tensors.con),
-                aff=jnp.asarray(tg_tensors.aff),
-                req=jnp.asarray(tg_tensors.req),
-                desired=jnp.asarray(desired),
-                dh_limit=jnp.asarray(tg_tensors.dh_limit),
-                sp_nodeval=jnp.asarray(_pad_cols(sp.sp_nodeval, npad, -1)),
-                sp_weight=jnp.asarray(sp.sp_weight),
-                sp_expected=jnp.asarray(sp.sp_expected),
-                sp_counts0=jnp.asarray(sp.sp_counts0),
-                pd_nodeval=jnp.asarray(_pad_cols(pd.pd_nodeval, npad, -1)),
-                pd_limit=jnp.asarray(pd.pd_limit),
-                pd_apply=jnp.asarray(pd.pd_apply),
-                pd_counts0=jnp.asarray(pd.pd_counts0),
-                tg_idx=jnp.asarray(tg_idx),
-                prev_row=jnp.asarray(prev_row),
-                active=jnp.asarray(active),
+                con=tg_tensors.con,
+                aff=tg_tensors.aff,
+                req=tg_tensors.req,
+                desired=desired,
+                dh_limit=tg_tensors.dh_limit,
+                sp_nodeval=_pad_cols(sp.sp_nodeval, npad, -1),
+                sp_weight=sp.sp_weight,
+                sp_expected=sp.sp_expected,
+                sp_counts0=sp.sp_counts0,
+                pd_nodeval=_pad_cols(pd.pd_nodeval, npad, -1),
+                pd_limit=pd.pd_limit,
+                pd_apply=pd.pd_apply,
+                pd_counts0=pd.pd_counts0,
+                tg_idx=tg_idx,
+                prev_row=prev_row,
+                active=active,
                 job_count0=jc_dev,
-                spread_algo=jnp.asarray(algo == SCHED_ALGO_SPREAD),
-                seed=jnp.asarray(seed & 0xFFFFFFFF, jnp.uint32),
+                spread_algo=np.bool_(algo == SCHED_ALGO_SPREAD),
+                seed=np.uint32(seed & 0xFFFFFFFF),
                 extra_mask=extra_mask,
             )
             if has_static and self.mesh is None:
-                _, taken0, pt_ask, _ = self._lower_ports(
-                    t, npad, port_asks, len(tgs))
-                inp = inp._replace(pt_taken0=taken0, pt_ask=pt_ask)
+                inp = inp._replace(**self._scan_ports(t, npad, port_asks))
             if self.mesh is not None:
+                _registry().inc("nomad.engine.scan_inputs", 1, form="fields")
+                inp = inp._replace(used0=used_on_device(), **{
+                    f: jnp.asarray(getattr(inp, f)) for f in
+                    SCAN_PACKED_FIELDS if getattr(inp, f) is not None})
                 buf, used_dev, job_count_dev = self._launch(
                     "scan", (npad, p_pad), self._sharded("scan"), inp)
                 self._note_collective(
@@ -1432,8 +1509,27 @@ class PlacementEngine:
                 impl, why = scan_gate(inp)
                 _registry().inc("nomad.engine.scan_launches", 1,
                                 impl=impl, why=why)
-                buf, used_dev, job_count_dev = self._launch(
-                    "scan", (npad, p_pad), place_packed_jit, inp)
+                _registry().inc("nomad.engine.scan_inputs", 1, form="packed")
+                # the usage replay rides the launch where the resident
+                # copy has one to take and no stop is added to it
+                fold = (None if stop_delta is not None
+                        else self._used_to_fold(t, _fold_rows(npad)))
+                if fold is None:
+                    inp = inp._replace(used0=used_on_device())
+                    deltas = ()
+                else:
+                    inp = inp._replace(used0=fold[0])
+                    deltas = fold[3]
+                t0h = time.perf_counter()
+                layout, packed = pack_scan_inputs(inp, deltas,
+                                                  _fold_rows(npad))
+                pack_s = time.perf_counter() - t0h
+                buf, used_dev, job_count_dev, used_next = self._launch(
+                    "scan", (npad, p_pad), place_packed_jit,
+                    inp._replace(**dict.fromkeys(SCAN_PACKED_FIELDS)),
+                    packed, layout)
+                if fold is not None:
+                    self._adopt_used(fold, used_next, pack_s)
             b = self._fetch(buf)[:p_real]
             picks = b[:, 0].copy()
             scores = b[:, 1].view(np.float32)

@@ -25,11 +25,13 @@ counts (feeds nodes_filtered / nodes_exhausted / dimension_exhausted).
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from nomad_tpu.structs import RES_DIMS
 
@@ -311,16 +313,105 @@ def pack_outputs(out: PlacementOutputs):
     return buf, out.used, out.job_count
 
 
-def place_packed(inp: PlacementInputs):
+# The single-device scan's per-eval inputs, in the order they lie in its
+# ONE input buffer (`pack_scan_inputs`); the node tensors, `used0`, the
+# masks, the LUTs, `job_count0` and `extra_mask` stay arguments of their
+# own: they are resident or cached on the device.  The static-port pair
+# is there where the eval carries it.
+SCAN_PACKED_FIELDS = ("con", "aff", "req", "desired", "dh_limit",
+                      "sp_nodeval", "sp_weight", "sp_expected", "sp_counts0",
+                      "pd_nodeval", "pd_limit", "pd_apply", "pd_counts0",
+                      "tg_idx", "prev_row", "active", "spread_algo", "seed",
+                      "pt_taken0", "pt_ask")
+_BOOL, _INT32 = np.dtype(bool), np.dtype(np.int32)
+
+
+def scan_layout(inp: PlacementInputs, replay_rows: int) -> tuple:
+    """The input buffer's layout: (name, shape, dtype) of each field of
+    SCAN_PACKED_FIELDS that `inp` carries, as JAX would take it in, then
+    the usage replay's section, `replay_rows` rows and their values
+    `[replay_rows, RES_DIMS]`.  A function of the shapes alone: the
+    program's static argument, so one compiled program a set of shapes."""
+    layout = []
+    for name in SCAN_PACKED_FIELDS:
+        x = getattr(inp, name)
+        if x is None:
+            continue
+        dtype = jax.dtypes.canonicalize_dtype(np.result_type(x))
+        if dtype != _BOOL and dtype.itemsize != 4:
+            raise ValueError(f"{name}: {dtype} does not fit an int32 word")
+        layout.append((name, np.shape(x), dtype))
+    return tuple(layout) + (("replay_rows", (replay_rows,), _INT32),
+                            ("replay_vals", (replay_rows, RES_DIMS), _INT32))
+
+
+def pack_scan_inputs(inp: PlacementInputs, deltas, replay_rows: int):
+    """(layout, buffer): the per-eval fields of `inp` (host arrays) as ONE
+    int32 buffer at the offsets `scan_layout` gives, floats bitcast and
+    bools one word each, and the usage `deltas` ((rows, values) pairs,
+    `replay_rows` rows in all at most) in its replay section, the rows
+    past them zero: they add nothing to row 0."""
+    layout = scan_layout(inp, replay_rows)
+    sizes = [math.prod(shape) for _, shape, _ in layout]
+    buf = np.zeros(sum(sizes), np.int32)
+    off = 0
+    for (name, _, dtype), size in zip(layout[:-2], sizes):
+        x = np.asarray(getattr(inp, name)).reshape(-1)
+        if dtype != _BOOL:
+            x = x.astype(dtype, copy=False).view(np.int32)
+        buf[off:off + size] = x
+        off += size
+    rows = buf[off:off + replay_rows]
+    vals = buf[off + replay_rows:].reshape(replay_rows, RES_DIMS)
+    lo = 0
+    for r, v in deltas:
+        rows[lo:lo + len(r)] = r
+        vals[lo:lo + len(r)] = v
+        lo += len(r)
+    return layout, buf
+
+
+def unpack_scan_inputs(inp: PlacementInputs, packed, layout):
+    """Inside the program: the fields of `layout` out of `packed` by
+    static slices and bitcasts, each the dtype and the bits it went in
+    with, and the replay added to `inp.used0`.  Returns (the inputs the
+    scan takes, `used0` after the replay)."""
+    fields, off = {}, 0
+    for name, shape, dtype in layout:
+        size = math.prod(shape)
+        words = packed[off:off + size]
+        off += size
+        if dtype == _BOOL:
+            words = words != 0
+        elif dtype != _INT32:
+            words = jax.lax.bitcast_convert_type(words, dtype)
+        fields[name] = words.reshape(shape)
+    rows, vals = fields.pop("replay_rows"), fields.pop("replay_vals")
+    used0 = inp.used0.at[rows].add(vals)
+    return inp._replace(used0=used0, **fields), used0
+
+
+def place_packed(inp: PlacementInputs, packed=None, layout=()):
     """The exact scan on one device, (buf, used, job_count): ONE Pallas
     kernel where `scan_fused.scan_gate` says it fits (the TPU, node
     state within its VMEM budget, value tables within its `where`
     chains), else `place_packed_xla`.  Decided at trace time from the
-    backend and the shapes, so one program `jit_place_packed` a shape."""
+    backend and the shapes, so one program `jit_place_packed` a shape.
+
+    With `packed` (the engine's launch), `inp` holds only the resident
+    arguments and the per-eval fields come out of that ONE buffer, laid
+    out by `layout` (`pack_scan_inputs`); the usage replay it carries is
+    added to `inp.used0` ahead of the scan, and the sum is a fourth
+    output: the engine's resident `used` from then on."""
     from .scan_fused import place_packed_fused, scan_gate  # imports this
+    used0 = None
+    if packed is not None:
+        inp, used0 = unpack_scan_inputs(inp, packed, layout)
     if scan_gate(inp)[0] == "fused":
-        return place_packed_fused(inp)
-    return place_packed_xla(inp)
+        out = place_packed_fused(inp)
+    else:
+        out = place_packed_xla(inp)
+    return out if used0 is None else (*out, used0)
 
 
 def place_packed_xla(inp: PlacementInputs):
@@ -436,7 +527,7 @@ def place_packed_xla(inp: PlacementInputs):
     return buf, used, job_count
 
 
-place_packed_jit = jax.jit(place_packed)
+place_packed_jit = jax.jit(place_packed, static_argnames="layout")
 
 
 def place(inp: PlacementInputs) -> PlacementOutputs:
