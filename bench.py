@@ -1003,7 +1003,7 @@ def run_config_5(args):
 
     # continuity metric (rounds 1-2 reported this): ONE giant eval — a
     # single job wanting the full 100k placements — through the same
-    # pipeline; its placements/sec shows the bulk kernel's raw rate when
+    # pipeline; its placements/sec shows the water-fill's raw rate when
     # an eval is big enough to amortize every per-eval cost
     def run_giant(cpu, mem):
         giant = make_job(n_place, cpu=cpu, mem=mem, zone=0)
@@ -1392,7 +1392,7 @@ def run_config_5(args):
             "port_collisions": net_collisions,
             "networked_port_batched_rows": net_batched_rows,
             # one 100k-placement eval end-to-end (the rounds-1/2 metric):
-            # the bulk kernel's rate once an eval amortizes per-eval costs
+            # the water-fill's rate once an eval amortizes per-eval costs
             "single_eval_placements_per_sec": round(giant_rate, 1),
             "single_eval_placed": giant_placed,
             "single_eval_vs_flat_upper_bound": round(

@@ -132,7 +132,10 @@ class MemLedger:
         # a wall throttle loses nothing canonical — it just keeps the
         # ledger inside its 0.1%-of-soak-wall budget (PERF.md §21)
         self.min_wall_s = min_wall_s
-        self._last_wall = 0.0
+        # the first sample is always due: perf_counter() counts from an
+        # arbitrary origin (boot, on Linux), so on a fresh host it reads
+        # under min_wall_s
+        self._last_wall = float("-inf")
         self._sizers: Dict[str, Callable[[], Dict]] = {}
         self._last: Dict[str, Dict] = {}      # plane -> last sizer doc
         self._last_rss: Dict[str, int] = {"rss_bytes": 0,

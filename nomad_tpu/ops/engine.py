@@ -46,16 +46,16 @@ from .feasibility import (constraint_mask, feasible_mask_jit,
 from .preempt import Preemptor, preemption_enabled
 from .scan_fused import scan_gate
 from .select import (
-    BulkInputs, FILL_K, MultiEvalInputs, PlacementInputs, SCAN_PACKED_FIELDS,
-    TOP_K, pack_scan_inputs, place_bulk_packed_jit, place_multi_chained_jit,
+    FILL_K, MultiEvalInputs, PlacementInputs, SCAN_PACKED_FIELDS, TOP_K,
+    pack_scan_inputs, place_multi_chained_jit,
     place_multi_compact_chained_jit, place_multi_compact_packed_jit,
     place_multi_packed_jit, place_packed_jit)
 
-# Minimum homogeneous batch size before the rounds-based bulk kernel beats
-# the per-placement scan (scan is exact sequential semantics; bulk commits
-# whole rounds between state refreshes).
+# Minimum homogeneous batch size before a solo eval's water-fill rounds
+# (the flat multi-eval kernel, as a wave of one item) beat the
+# per-placement scan (scan is exact sequential semantics; a water-fill
+# commits whole rounds between state refreshes).
 BULK_THRESHOLD = 64
-BULK_ROUND = 1024
 
 # Static ports as a feasibility rule (ISSUE 38): the single-device scan
 # and the flat multi-eval kernel carry, per static port value a launch's
@@ -146,7 +146,6 @@ def _default_mesh():
 # `nomad.engine.mesh_launches{kind}` (`PlacementEngine._launch`).
 SHARDED_KINDS = {
     "scan": ("place_sharded_packed_fn", {}),
-    "bulk": ("place_bulk_sharded_packed_fn", {}),
     "multi": ("place_multi_sharded_packed_fn", {}),
     "multi_chained": ("place_multi_sharded_packed_fn", {"chained": True}),
     "multi_compact": ("place_multi_compact_sharded_fn", {}),
@@ -338,12 +337,11 @@ def _disjoint_cliques(sig_rows, luts, weights):
 
 
 def _resolve_compact_fills(buf_np: np.ndarray, fills_full, slot_k: int):
-    """The compact-output overflow protocol, shared by the single-eval
-    bulk path and collect_batch: the small buffer's fill prefix is
-    complete iff the per-round prefix counts sum to the placed-total
-    meta column; otherwise fetch the device-resident full fills and
-    rebuild the full-layout buffer.  Returns (buf, slot_k) where
-    slot_k == 0 means full layout."""
+    """The compact laned kernel's overflow protocol: the small buffer's
+    fill prefix is complete iff the per-round prefix counts sum to the
+    placed-total meta column; otherwise fetch the device-resident full
+    fills and rebuild the full-layout buffer.  Returns (buf, slot_k)
+    where slot_k == 0 means full layout."""
     if not slot_k:
         return buf_np, 0
     cnt_small = buf_np[:, :slot_k] & 2047
@@ -353,11 +351,11 @@ def _resolve_compact_fills(buf_np: np.ndarray, fills_full, slot_k: int):
     return np.concatenate([full, buf_np[:, slot_k:]], axis=1), 0
 
 
-def _unpack_bulk_compact(buf: np.ndarray, round_size: int, p_real: int,
-                         with_scores: bool = False, slot_k: int = 0):
-    """Expand the bulk kernel's compact per-round buffer (see
-    select.place_bulk_packed for the layout) into per-placement picks plus
-    the per-round metric block.  Placements within a round are
+def _unpack_rounds(buf: np.ndarray, round_size: int, p_real: int,
+                   slot_k: int = 0):
+    """Expand the water-fill kernels' per-round buffer (see
+    select.pack_round_buffer for the layout) into per-placement picks
+    plus the per-round metric block.  Placements within a round are
     interchangeable, so per-node fill counts expand with np.repeat.
 
     `slot_k`: fill slots per buffer row when they differ from the round
@@ -366,16 +364,12 @@ def _unpack_bulk_compact(buf: np.ndarray, round_size: int, p_real: int,
     n_rounds = buf.shape[0]
     slot_k = slot_k or round_size
     fills = buf[:, :slot_k]
-    off = 2 * slot_k if with_scores else slot_k
-    sc_r = buf[:, slot_k:off].view(np.float32) if with_scores else None
-    meta = buf[:, off:]
+    meta = buf[:, slot_k:]
     rows_r = fills >> 11
     cnt_r = fills & 2047
     placed_r = meta[:, 12]
 
-    p_pad = n_rounds * round_size
-    picks = np.full(p_pad, -1, np.int32)
-    scores = np.zeros(p_pad, np.float32)
+    picks = np.full(n_rounds * round_size, -1, np.int32)
     for r in range(n_rounds):
         lo = r * round_size
         k = int(placed_r[r])
@@ -383,9 +377,7 @@ def _unpack_bulk_compact(buf: np.ndarray, round_size: int, p_real: int,
             continue
         nz = cnt_r[r].nonzero()[0]
         picks[lo:lo + k] = np.repeat(rows_r[r, nz], cnt_r[r, nz])[:k]
-        if with_scores:
-            scores[lo:lo + k] = np.repeat(sc_r[r, nz], cnt_r[r, nz])[:k]
-    return picks[:p_real], scores[:p_real], meta
+    return picks[:p_real], meta
 
 
 # the meta block's per-dimension exhaustion columns, in RES_NAMES' order
@@ -396,16 +388,21 @@ _META_DIM_EX = [9, 10, 11] + list(range(13, 10 + RES_DIMS))
 _META_PORT_EX = 10 + RES_DIMS
 
 
-def _unpack_bulk(buf: np.ndarray, round_size: int, p_real: int, n: int):
-    """Per-placement expansion of the compact buffer (exact-API path)."""
-    picks, scores, meta = _unpack_bulk_compact(
-        buf, round_size, p_real, with_scores=True)
-    n_rounds = buf.shape[0]
-    rep = np.repeat(np.arange(n_rounds), round_size)[:p_real]
-    m = meta[rep]
-    return (picks, scores,
-            m[:, 0:3], m[:, 3:6].view(np.float32),
-            m[:, 6], m[:, 7], m[:, 8], m[:, _META_DIM_EX])
+def _stop_delta(t: NodeTensors, npad: int, given_back):
+    """A plan's stopped allocations on `t`'s rows.  `given_back` is
+    {node id: (the usage they give back, how many of them are the
+    job's)}; returns the delta to add to `used` ([npad, RES_DIMS],
+    negative) and the job's count given back a node ([n]).  By node id,
+    not row: a launch reads the node table again, and rows move when it
+    is rebuilt."""
+    delta = np.zeros((npad, RES_DIMS), np.int32)
+    jc_back = np.zeros(t.n, np.int32)
+    for nid, (usage, own) in given_back.items():
+        row = t.id_to_row.get(nid)
+        if row is not None:
+            delta[row] -= usage
+            jc_back[row] += own
+    return delta, jc_back
 
 
 
@@ -425,7 +422,7 @@ class BulkDecisions:
     evictions: Dict[int, List] = field(default_factory=dict)
     nodes_evaluated: int = 0
     # the exact scan's form (a block of fresh placements that could not
-    # ride the bulk kernel): `round_size` 1, `metrics` empty, and one
+    # ride the water-fill): `round_size` 1, `metrics` empty, and one
     # metric a PLACEMENT kept as the columns the scan returned
     rows: Optional[RowMetrics] = None
     scores: Optional[np.ndarray] = None     # [P] float32, the picks' own
@@ -1175,22 +1172,23 @@ class PlacementEngine:
               requests: Sequence[PlacementRequest],
               tensors: Optional[NodeTensors] = None,
               stopped_allocs: Sequence = (),
-              bulk_api: bool = False,
               seed: int = 0,
               device_in_use=None,
               block=None,
               ):
         """Score + select nodes for `requests` (placements of `tgs`).
-        Returns one decision per request, in order.
 
         `block`: compact alternative to `requests` — a (tg_name, count)
         pair describing `count` fresh placements of one task group with
-        no per-placement state (reconcile.PlaceBlock).  The bulk kernel
+        no per-placement state (reconcile.PlaceBlock).  The water-fill
         needs nothing more; if the job shape forces the exact scan
-        (spread/distinct/devices) its rows are filled in here.  With
-        `bulk_api` either kernel answers a block with a BulkDecisions,
-        the scan's with a metric a placement as columns (`rows`); only a
-        device ask still gets a PlacementDecision a placement.
+        (spread/distinct/devices) its rows are filled in here.
+
+        Returns, for a water-fill (BULK_THRESHOLD or more placements of
+        one group, nothing that asks the exact scan), one BulkDecisions;
+        for a block off the exact scan, one BulkDecisions with a metric a
+        placement as columns (`rows`), but where the group asks for
+        devices; otherwise one PlacementDecision a request, in order.
 
         `stopped_allocs`: allocs the in-flight plan is stopping/evicting —
         their usage (and job-count, for this job) is subtracted before
@@ -1206,8 +1204,25 @@ class PlacementEngine:
         with (self.timers.time("solo_place") if self.timers is not None
               else contextlib.nullcontext()):
             return self._place(snapshot, job, tgs, requests, tensors,
-                               stopped_allocs, bulk_api, seed,
-                               device_in_use, block)
+                               stopped_allocs, seed, device_in_use, block)
+
+    def _feasibility_fields(self, t: NodeTensors, npad: int, job: Job,
+                            ctx: JobContext, tg_tensors: TGTensors):
+        """What a job's static feasibility mask is computed from
+        (feasibility.feasible_mask): (attrs, elig, dc_mask, pool_mask,
+        con, luts), every one that repeats across evals cached on the
+        device."""
+        dev = self._node_arrays(t)
+        return (
+            dev["attrs"], dev["elig"],
+            self._dev_const(("dc", t.version, npad, tuple(job.datacenters)),
+                            lambda: _pad_rows(ctx.dc_mask, npad, False)),
+            self._dev_const(("pool", t.version, npad, job.node_pool),
+                            lambda: _pad_rows(ctx.pool_mask, npad, False)),
+            tg_tensors.con,
+            self._dev_const(
+                ("luts", self.packer.lut_epoch, tg_tensors.luts.shape),
+                lambda: tg_tensors.luts))
 
     def place_system(self, snapshot, job: Job, tgs: Sequence[TaskGroup],
                      node_ids: Sequence[str]):
@@ -1236,24 +1251,17 @@ class PlacementEngine:
             domain[rows[rows >= 0]] = True
             dev = self._node_arrays(t)
             used = self._used_device(t)
-            dcm = self._dev_const(
-                ("dc", t.version, npad, tuple(job.datacenters)),
-                lambda: _pad_rows(ctx.dc_mask, npad, False))
-            pm = self._dev_const(
-                ("pool", t.version, npad, job.node_pool),
-                lambda: _pad_rows(ctx.pool_mask, npad, False))
-            luts = self._dev_const(
-                ("luts", self.packer.lut_epoch, tg_tensors.luts.shape),
-                lambda: tg_tensors.luts)
+            attrs, elig, dcm, pm, con, luts = self._feasibility_fields(
+                t, npad, job, ctx, tg_tensors)
             verdicts = self._launch(
-                "system", (len(tgs), tg_tensors.con.shape[1], npad),
-                place_system_jit, dev["attrs"], dev["elig"], dcm, pm,
-                jnp.asarray(tg_tensors.con), luts, dev["cap"], used,
-                jnp.asarray(tg_tensors.req), jnp.asarray(domain))
+                "system", (len(tgs), con.shape[1], npad),
+                place_system_jit, attrs, elig, dcm, pm, jnp.asarray(con),
+                luts, dev["cap"], used, jnp.asarray(tg_tensors.req),
+                jnp.asarray(domain))
             return t, rows, self._fetch(verdicts)[:, :t.n]
 
     def _place(self, snapshot, job, tgs, requests, tensors, stopped_allocs,
-               bulk_api, seed, device_in_use, block):
+               seed, device_in_use, block):
         if block is not None:
             block_tg, block_count = block
             if block_count <= 0:
@@ -1270,81 +1278,23 @@ class PlacementEngine:
 
         tg_tensors: TGTensors = self.packer.lower_task_groups(
             job, tgs, snapshot=snapshot)
-        ctx: JobContext = self.packer.job_context(job, snapshot, t)
-
         name_to_g = {name: i for i, name in enumerate(tg_tensors.names)}
         p_real = block_count if block is not None else len(requests)
-        p_pad = _pad_pow2(p_real)
-        npad = self._padded_n(n)
-
-        desired = np.array([tg.count for tg in tgs], np.int32)
-        algo = snapshot.scheduler_config().scheduler_algorithm
-        dev = self._node_arrays(t)
-        job_count = ctx.job_count
-        stop_delta = None
-        if stopped_allocs:
-            stop_delta = np.zeros((npad, RES_DIMS), np.int32)
-            job_count = job_count.copy()
-            for a in stopped_allocs:
-                row = t.id_to_row.get(a.node_id)
-                if row is None:
-                    continue
-                stop_delta[row] -= a.usage()
-                if a.job_id == job.id and job_count[row] > 0:
-                    job_count[row] -= 1
-
-        def used_on_device():
-            used = self._used_device(t)
-            if stop_delta is not None:
-                used = used + jnp.asarray(stop_delta)
-            return used
-
-        # cached per-eval device constants: every [N]-sized upload that
-        # repeats across evals is cached
-        dcm = self._dev_const(
-            ("dc", t.version, npad, tuple(job.datacenters)),
-            lambda: _pad_rows(ctx.dc_mask, npad, False))
-        pm = self._dev_const(
-            ("pool", t.version, npad, job.node_pool),
-            lambda: _pad_rows(ctx.pool_mask, npad, False))
-        luts_dev = self._dev_const(
-            ("luts", self.packer.lut_epoch, tg_tensors.luts.shape),
-            lambda: tg_tensors.luts)
-        if job_count.any():
-            jc_dev = jnp.asarray(_pad_rows(job_count, npad))
-        else:
-            jc_dev = self._dev_const(("zjc", npad),
-                                     lambda: np.zeros(npad, np.int32))
+        # what the plan's stopped allocations give back, by node
+        given_back: Dict[str, tuple] = {}
+        for a in stopped_allocs:
+            usage, own = given_back.get(a.node_id, (0, 0))
+            given_back[a.node_id] = (np.add(usage, a.usage()),
+                                     own + (a.job_id == job.id))
 
         # device (GPU/...) feasibility: host-computed per-TG node mask
         # (kernel capacity dims stay cpu/mem/disk; discrete instance
         # matching is host work — scheduler/device.py)
         dev_mask = self._device_mask(
             tgs, t, snapshot, {a.id for a in stopped_allocs}, device_in_use)
-        # static port asks (ISSUE 38): the scan carries their holders
-        # (select.port_state); the bulk kernel has no such state, so a
-        # group that asks one keeps the scan.  The sharded scan has none
-        # either: there the nodes that hold a value in the STATE leave
-        # through the mask, and the eval's own placements keep to one a
-        # node by the distinct_hosts limit (which counts the JOB's
-        # allocations on the node: a second group of the job that asks no
-        # port is held off it too)
         has_dev_ask = dev_mask is not None
         port_asks = [tg_static_ports(tg) for tg in tgs]
         has_static = any(port_asks)
-        port_lost = None
-        if has_static and self.mesh is not None:
-            free = np.ones((len(tgs), n), bool)
-            for g, values in enumerate(port_asks):
-                for v in values:
-                    free[g, self.packer.static_port_rows(t, v)[1]] = False
-                if values and not tg_tensors.dh_limit[g]:
-                    tg_tensors.dh_limit[g] = 1
-            port_lost = (~free).sum(axis=1)
-            dev_mask = free if dev_mask is None else dev_mask & free
-        extra_mask = (None if dev_mask is None
-                      else jnp.asarray(_pad_cols(dev_mask, npad, False)))
-
         has_spread = bool(job.spreads) or any(tg.spreads for tg in tgs)
         has_distinct = any(tg_tensors.distinct)
         if block is not None:
@@ -1362,201 +1312,179 @@ class PlacementEngine:
                 # scan only
                 and not has_dev_ask
                 and all(not r.prev_node_id for r in requests))
-        # the sharded bulk kernel has no with_scores variant; the
-        # expanded-API bulk path needs per-placement scores, so on a mesh
-        # it routes through the exact scan instead (tests/diagnostics only
-        # — production callers use bulk_api)
-        if self.mesh is not None and not bulk_api:
-            bulk_ok = False
+        if bulk_ok:
+            return self._place_waterfill(
+                snapshot, job, tgs, tg_tensors,
+                name_to_g[block_tg if block is not None
+                          else requests[0].tg_name],
+                p_real, given_back, seed)
+
+        ctx: JobContext = self.packer.job_context(job, snapshot, t)
+        p_pad = _pad_pow2(p_real)
+        npad = self._padded_n(n)
+        desired = np.array([tg.count for tg in tgs], np.int32)
+        algo = snapshot.scheduler_config().scheduler_algorithm
+        dev = self._node_arrays(t)
+        job_count = ctx.job_count
+        stop_delta = None
+        if given_back:
+            stop_delta, jc_back = _stop_delta(t, npad, given_back)
+            job_count = np.maximum(job_count - jc_back, 0)
+
+        def used_on_device():
+            used = self._used_device(t)
+            if stop_delta is not None:
+                used = used + jnp.asarray(stop_delta)
+            return used
+
+        # cached per-eval device constants: every [N]-sized upload that
+        # repeats across evals is cached
+        feas = self._feasibility_fields(t, npad, job, ctx, tg_tensors)
+        if job_count.any():
+            jc_dev = jnp.asarray(_pad_rows(job_count, npad))
+        else:
+            jc_dev = self._dev_const(("zjc", npad),
+                                     lambda: np.zeros(npad, np.int32))
+
+        # static port asks (ISSUE 38): the scan carries their holders
+        # (select.port_state).  The sharded scan has none: there the
+        # nodes that hold a value in the STATE leave through the mask,
+        # and the eval's own placements keep to one a node by the
+        # distinct_hosts limit (which counts the JOB's allocations on the
+        # node: a second group of the job that asks no port is held off
+        # it too)
+        port_lost = None
+        if has_static and self.mesh is not None:
+            free = np.ones((len(tgs), n), bool)
+            for g, values in enumerate(port_asks):
+                for v in values:
+                    free[g, self.packer.static_port_rows(t, v)[1]] = False
+                if values and not tg_tensors.dh_limit[g]:
+                    tg_tensors.dh_limit[g] = 1
+            port_lost = (~free).sum(axis=1)
+            dev_mask = free if dev_mask is None else dev_mask & free
+        extra_mask = (None if dev_mask is None
+                      else jnp.asarray(_pad_cols(dev_mask, npad, False)))
 
         # ONE packed device->host transfer: the chip sits behind a network
         # transport with a large fixed cost per array fetch, so the kernels
         # bitcast every output into a single int32 buffer.  used/job_count
         # stay on device, fetched only on the preemption fallback path.
-        if bulk_ok:
-            g_idx = name_to_g[block_tg if block is not None
-                              else requests[0].tg_name]
-            round_size = min(BULK_ROUND, p_pad)
-            n_rounds = p_pad // round_size
-            binp = BulkInputs(
-                attrs=dev["attrs"], cap=dev["cap"], used0=used_on_device(),
-                elig=dev["elig"], dc_mask=dcm, pool_mask=pm, luts=luts_dev,
-                con=jnp.asarray(tg_tensors.con),
-                aff=jnp.asarray(tg_tensors.aff),
-                req=jnp.asarray(tg_tensors.req),
-                desired=jnp.asarray(desired),
-                dh_limit=jnp.asarray(tg_tensors.dh_limit),
-                job_count0=jc_dev,
-                spread_algo=jnp.asarray(algo == SCHED_ALGO_SPREAD),
-                g=jnp.asarray(g_idx, jnp.int32),
-                p_real=jnp.asarray(p_real, jnp.int32),
-                seed=jnp.asarray(seed & 0xFFFFFFFF, jnp.uint32),
-                extra_mask=extra_mask,
-            )
-            fills_full = None
-            slot_k = 0
-            if self.mesh is not None:
-                buf, used_dev, job_count_dev = self._launch(
-                    "bulk", (round_size, n_rounds, npad),
-                    self._sharded("bulk", round_size, n_rounds), binp)
-                self._note_collective(
-                    n_rounds, min(round_size, npad // self._ndev))
-            elif bulk_api and algo != SCHED_ALGO_SPREAD:
-                # compact output: FILL_K slots always fetched; full
-                # fills stay device-resident for the rare overflow.
-                # The SPREAD algorithm fans every round over ~want
-                # distinct nodes, so its rounds would overflow the
-                # prefix every time and pay two fetches — it keeps the
-                # full layout (code-review r5).
-                slot_k = min(FILL_K, round_size)
-                buf, fills_full, used_dev, job_count_dev = self._launch(
-                    "bulk_compact", (round_size, n_rounds, npad, slot_k),
-                    place_bulk_packed_jit, binp, round_size, n_rounds,
-                    False, slot_k)
-            else:
-                buf, used_dev, job_count_dev = self._launch(
-                    "bulk", (round_size, n_rounds, npad, bulk_api),
-                    place_bulk_packed_jit, binp, round_size, n_rounds,
-                    not bulk_api)
-            tg_idx = np.full(p_real, g_idx, np.int32)
-            if bulk_api:
-                buf_np, slot_k = _resolve_compact_fills(
-                    self._fetch(buf), fills_full, slot_k)
-                picks, _, meta = _unpack_bulk_compact(
-                    buf_np, round_size, p_real, slot_k=slot_k)
-                if npad != n:
-                    # mesh padding rows are statically infeasible; they
-                    # must not read as real filtered nodes
-                    meta = meta.copy()
-                    meta[:, 7] -= npad - n
-                return self._bulk_decisions(
-                    block_tg if block is not None else requests[0].tg_name,
-                    picks, meta, round_size, t, ctx,
-                    snapshot, job, binp, tg_tensors, tg_idx, used_dev,
-                    job_count_dev, p_real, n, t0)
-            (picks, scores, topk_rows, topk_scores,
-             n_feas, n_filt, n_exh, dim_exh) = _unpack_bulk(
-                self._fetch(buf), round_size, p_real, n)
-            counts = np.column_stack(
-                [n_filt - (npad - n), n_exh, dim_exh])
-            inp = binp      # _preempt_fallback field source
+        sp: SpreadTensors = lower_spreads(self.packer, job, t, snapshot)
+        pd = self.packer.lower_distinct(job, tgs, tg_tensors, t, snapshot)
+        tg_idx = np.zeros(p_pad, np.int32)
+        prev_row = np.full(p_pad, -1, np.int32)
+        active = np.zeros(p_pad, bool)
+        if block is not None:
+            # fresh placements of one group: no request rows exist
+            tg_idx[:p_real] = name_to_g[block_tg]
+            active[:p_real] = True
         else:
-            sp: SpreadTensors = lower_spreads(self.packer, job, t, snapshot)
-            pd = self.packer.lower_distinct(job, tgs, tg_tensors, t, snapshot)
-            tg_idx = np.zeros(p_pad, np.int32)
-            prev_row = np.full(p_pad, -1, np.int32)
-            active = np.zeros(p_pad, bool)
-            if block is not None:
-                # fresh placements of one group: no request rows exist
-                tg_idx[:p_real] = name_to_g[block_tg]
-                active[:p_real] = True
+            for i, r in enumerate(requests):
+                tg_idx[i] = name_to_g[r.tg_name]
+                if r.prev_node_id:
+                    prev_row[i] = t.id_to_row.get(r.prev_node_id, -1)
+                active[i] = True
+        # the per-eval fields as host arrays: on one device they go up
+        # as ONE buffer (`select.pack_scan_inputs`), on a mesh each
+        # as an array of its own
+        inp = PlacementInputs(
+            attrs=feas[0], cap=dev["cap"], used0=None, elig=feas[1],
+            dc_mask=feas[2],
+            pool_mask=feas[3],
+            luts=feas[5],
+            con=tg_tensors.con,
+            aff=tg_tensors.aff,
+            req=tg_tensors.req,
+            desired=desired,
+            dh_limit=tg_tensors.dh_limit,
+            sp_nodeval=_pad_cols(sp.sp_nodeval, npad, -1),
+            sp_weight=sp.sp_weight,
+            sp_expected=sp.sp_expected,
+            sp_counts0=sp.sp_counts0,
+            pd_nodeval=_pad_cols(pd.pd_nodeval, npad, -1),
+            pd_limit=pd.pd_limit,
+            pd_apply=pd.pd_apply,
+            pd_counts0=pd.pd_counts0,
+            tg_idx=tg_idx,
+            prev_row=prev_row,
+            active=active,
+            job_count0=jc_dev,
+            spread_algo=np.bool_(algo == SCHED_ALGO_SPREAD),
+            seed=np.uint32(seed & 0xFFFFFFFF),
+            extra_mask=extra_mask,
+        )
+        if has_static and self.mesh is None:
+            inp = inp._replace(**self._scan_ports(t, npad, port_asks))
+        if self.mesh is not None:
+            _registry().inc("nomad.engine.scan_inputs", 1, form="fields")
+            inp = inp._replace(used0=used_on_device(), **{
+                f: jnp.asarray(getattr(inp, f)) for f in
+                SCAN_PACKED_FIELDS if getattr(inp, f) is not None})
+            buf, used_dev, job_count_dev = self._launch(
+                "scan", (npad, p_pad), self._sharded("scan"), inp)
+            self._note_collective(
+                p_pad, min(TOP_K, npad // self._ndev),
+                width=2, extra=128)
+        else:
+            # what select.place_packed's trip count will meet: the
+            # steps it runs, and the padding it passes by
+            for kind, steps in (("run", p_real),
+                                ("padded", p_pad - p_real)):
+                _registry().inc("nomad.engine.scan_steps", steps,
+                                kind=kind)
+            # which scan `place_packed` takes at this shape, and why
+            impl, why = scan_gate(inp)
+            _registry().inc("nomad.engine.scan_launches", 1,
+                            impl=impl, why=why)
+            _registry().inc("nomad.engine.scan_inputs", 1, form="packed")
+            # the usage replay rides the launch where the resident
+            # copy has one to take and no stop is added to it
+            fold = (None if stop_delta is not None
+                    else self._used_to_fold(t, _fold_rows(npad)))
+            if fold is None:
+                inp = inp._replace(used0=used_on_device())
+                deltas = ()
             else:
-                for i, r in enumerate(requests):
-                    tg_idx[i] = name_to_g[r.tg_name]
-                    if r.prev_node_id:
-                        prev_row[i] = t.id_to_row.get(r.prev_node_id, -1)
-                    active[i] = True
-            # the per-eval fields as host arrays: on one device they go up
-            # as ONE buffer (`select.pack_scan_inputs`), on a mesh each
-            # as an array of its own
-            inp = PlacementInputs(
-                attrs=dev["attrs"], cap=dev["cap"], used0=None,
-                elig=dev["elig"],
-                dc_mask=dcm,
-                pool_mask=pm,
-                luts=luts_dev,
-                con=tg_tensors.con,
-                aff=tg_tensors.aff,
-                req=tg_tensors.req,
-                desired=desired,
-                dh_limit=tg_tensors.dh_limit,
-                sp_nodeval=_pad_cols(sp.sp_nodeval, npad, -1),
-                sp_weight=sp.sp_weight,
-                sp_expected=sp.sp_expected,
-                sp_counts0=sp.sp_counts0,
-                pd_nodeval=_pad_cols(pd.pd_nodeval, npad, -1),
-                pd_limit=pd.pd_limit,
-                pd_apply=pd.pd_apply,
-                pd_counts0=pd.pd_counts0,
-                tg_idx=tg_idx,
-                prev_row=prev_row,
-                active=active,
-                job_count0=jc_dev,
-                spread_algo=np.bool_(algo == SCHED_ALGO_SPREAD),
-                seed=np.uint32(seed & 0xFFFFFFFF),
-                extra_mask=extra_mask,
-            )
-            if has_static and self.mesh is None:
-                inp = inp._replace(**self._scan_ports(t, npad, port_asks))
-            if self.mesh is not None:
-                _registry().inc("nomad.engine.scan_inputs", 1, form="fields")
-                inp = inp._replace(used0=used_on_device(), **{
-                    f: jnp.asarray(getattr(inp, f)) for f in
-                    SCAN_PACKED_FIELDS if getattr(inp, f) is not None})
-                buf, used_dev, job_count_dev = self._launch(
-                    "scan", (npad, p_pad), self._sharded("scan"), inp)
-                self._note_collective(
-                    p_pad, min(TOP_K, npad // self._ndev),
-                    width=2, extra=128)
-            else:
-                # what select.place_packed's trip count will meet: the
-                # steps it runs, and the padding it passes by
-                for kind, steps in (("run", p_real),
-                                    ("padded", p_pad - p_real)):
-                    _registry().inc("nomad.engine.scan_steps", steps,
-                                    kind=kind)
-                # which scan `place_packed` takes at this shape, and why
-                impl, why = scan_gate(inp)
-                _registry().inc("nomad.engine.scan_launches", 1,
-                                impl=impl, why=why)
-                _registry().inc("nomad.engine.scan_inputs", 1, form="packed")
-                # the usage replay rides the launch where the resident
-                # copy has one to take and no stop is added to it
-                fold = (None if stop_delta is not None
-                        else self._used_to_fold(t, _fold_rows(npad)))
-                if fold is None:
-                    inp = inp._replace(used0=used_on_device())
-                    deltas = ()
-                else:
-                    inp = inp._replace(used0=fold[0])
-                    deltas = fold[3]
-                t0h = time.perf_counter()
-                layout, packed = pack_scan_inputs(inp, deltas,
-                                                  _fold_rows(npad))
-                pack_s = time.perf_counter() - t0h
-                buf, used_dev, job_count_dev, used_next = self._launch(
-                    "scan", (npad, p_pad), place_packed_jit,
-                    inp._replace(**dict.fromkeys(SCAN_PACKED_FIELDS)),
-                    packed, layout)
-                if fold is not None:
-                    self._adopt_used(fold, used_next, pack_s)
-            b = self._fetch(buf)[:p_real]
-            picks = b[:, 0].copy()
-            scores = b[:, 1].view(np.float32)
-            topk_rows = b[:, 2:5]
-            topk_scores = b[:, 5:8].view(np.float32)
-            # nodes_filtered | nodes_exhausted | dimension_exhausted (and,
-            # from a scan with static-port state, the nodes lost to it)
-            counts = b[:, 9:].copy()
-            counts[:, 0] -= npad - n
-            if port_lost is not None:
-                # the mask's nodes read as filtered: say what they are
-                lost = port_lost[tg_idx[:p_real]].astype(counts.dtype)
-                counts[:, 0] -= lost
-                counts[:, 1] += lost
-                counts = np.column_stack([counts, lost])
+                inp = inp._replace(used0=fold[0])
+                deltas = fold[3]
+            t0h = time.perf_counter()
+            layout, packed = pack_scan_inputs(inp, deltas,
+                                              _fold_rows(npad))
+            pack_s = time.perf_counter() - t0h
+            buf, used_dev, job_count_dev, used_next = self._launch(
+                "scan", (npad, p_pad), place_packed_jit,
+                inp._replace(**dict.fromkeys(SCAN_PACKED_FIELDS)),
+                packed, layout)
+            if fold is not None:
+                self._adopt_used(fold, used_next, pack_s)
+        b = self._fetch(buf)[:p_real]
+        picks = b[:, 0].copy()
+        scores = b[:, 1].view(np.float32)
+        topk_rows = b[:, 2:5]
+        topk_scores = b[:, 5:8].view(np.float32)
+        # nodes_filtered | nodes_exhausted | dimension_exhausted (and,
+        # from a scan with static-port state, the nodes lost to it)
+        counts = b[:, 9:].copy()
+        counts[:, 0] -= npad - n
+        if port_lost is not None:
+            # the mask's nodes read as filtered: say what they are
+            lost = port_lost[tg_idx[:p_real]].astype(counts.dtype)
+            counts[:, 0] -= lost
+            counts[:, 1] += lost
+            counts = np.column_stack([counts, lost])
         elapsed = (time.perf_counter_ns() - t0) // max(p_real, 1)
 
         # ---- preemption fallback for failed placements ----
         evictions_by_req = self._preempt_fallback(
-            picks, snapshot, job, inp, tg_tensors, tg_idx,
+            picks, snapshot, job, feas, tg_tensors, tg_idx,
             t, used_dev, job_count_dev, p_real)
 
         # fresh placements of one group off the exact scan leave as
-        # arrays, like the bulk kernel's (the scheduler commits them as
+        # arrays, like the water-fill's (the scheduler commits them as
         # ONE AllocBlock).  A device ask keeps decisions: its instances
         # are assigned per placement (generic._assign_devices)
-        as_block = block is not None and bulk_api and not has_dev_ask
+        as_block = block is not None and not has_dev_ask
         nodes = t.node_ids
         if as_block:
             # the block's metric columns name their candidates in a
@@ -1583,8 +1511,8 @@ class PlacementEngine:
                 round_size=1, metrics=[], evictions=evictions_by_req,
                 nodes_evaluated=n, rows=rows, scores=scores.copy())
         if block is not None:
-            # a decision a placement after all (no bulk_api, a device
-            # ask): the block's placements as request rows
+            # a decision a placement after all (a device ask): the
+            # block's placements as request rows
             requests = [PlacementRequest(tg_name=block_tg)] * p_real
         node_ids = t.node_ids
         return [
@@ -1610,12 +1538,13 @@ class PlacementEngine:
     # attached chip
     PREEMPT_DEVICE_MAX_TABLE = 256 * 1024
 
-    def _preempt_fallback(self, picks, snapshot, job, inp, tg_tensors,
+    def _preempt_fallback(self, picks, snapshot, job, feas, tg_tensors,
                           tg_idx, t, used_dev, job_count_dev, p_real
                           ) -> Dict[int, List]:
         """Preemption for placements the kernel could not fit (reference:
         BinPackIterator drives the Preemptor when Fit fails and preemption
-        is enabled for the scheduler type).  Mutates `picks`."""
+        is enabled for the scheduler type).  `feas`: the static mask's
+        fields (`_feasibility_fields`).  Mutates `picks`."""
         evictions_by_req: Dict[int, List] = {}
         if (not np.any(picks < 0)
                 or not preemption_enabled(snapshot.scheduler_config(),
@@ -1623,9 +1552,7 @@ class PlacementEngine:
             return evictions_by_req
         # slice off mesh padding rows: the preemptor works host-side over
         # the REAL node rows
-        static = np.asarray(feasible_mask_jit(
-            inp.attrs, inp.elig, inp.dc_mask, inp.pool_mask,
-            inp.con, inp.luts))[:, :t.n]
+        static = np.asarray(feasible_mask_jit(*feas))[:, :t.n]
         used = np.asarray(used_dev)[:t.n]
         job_count = np.asarray(job_count_dev)[:t.n]
         pre_evicted: set = set()
@@ -1750,26 +1677,32 @@ class PlacementEngine:
         self._dc_cache = (t.version, counts)
         return counts
 
-    def _bulk_decisions(self, tg_name, picks, meta, round_size, t, ctx,
-                        snapshot, job, inp, tg_tensors, tg_idx, used_dev,
-                        job_count_dev, p_real, n, t0) -> BulkDecisions:
-        evictions = self._preempt_fallback(
-            picks, snapshot, job, inp, tg_tensors, tg_idx,
-            t, used_dev, job_count_dev, p_real)
-        elapsed = int(time.perf_counter_ns() - t0) // max(p_real, 1)
-        metrics = self._metrics_from_meta(
-            meta, n, int(ctx.pool_mask.sum()), self._dc_counts(t),
-            t.node_ids, elapsed)
-        return BulkDecisions(
-            tg_name=tg_name, picks=picks, node_ids=t.node_ids,
-            round_size=round_size, metrics=metrics, evictions=evictions,
-            nodes_evaluated=n)
+    def _place_waterfill(self, snapshot, job: Job, tgs, tg_tensors, g: int,
+                         p_real: int, given_back, seed: int) -> BulkDecisions:
+        """A solo eval's water-fill: its `p_real` fresh placements of
+        group `g` as a wave of ONE item on the flat multi-eval kernel (or
+        its sharded twin on a mesh), the allocations its plan stops gone
+        from the state the launch sees, then the preemption fallback for
+        the placements the kernel could not fit."""
+        tg = next(x for x in tgs if x.name == tg_tensors.names[g])
+        pending = self.dispatch_batch(
+            snapshot, [BatchItem(job=job, tg=tg, count=p_real)], seed=seed,
+            stopped=given_back)
+        (bd,) = self.collect_batch(pending)
+        t = pending["t"]
+        bd.evictions = self._preempt_fallback(
+            bd.picks, snapshot, job,
+            self._feasibility_fields(t, pending["npad"], job,
+                                     pending["ctxs"][0], tg_tensors),
+            tg_tensors, np.full(p_real, g, np.int32), t, pending["used"],
+            pending["job_count"], p_real)
+        return bd
 
     @staticmethod
     def _metrics_from_meta(meta, n, n_in_pool, dc_counts, node_ids,
                            elapsed, port_dim: str = "") -> List[AllocMetric]:
-        """Per-round AllocMetric objects from the bulk kernels' compact
-        meta block (shared by the single-eval bulk path and place_batch).
+        """Per-round AllocMetric objects from the water-fill kernels'
+        compact meta block (collect_batch).
         `port_dim`: for an item that asks a static port off a wave with
         port state, the dimension that names the nodes its rounds lost
         to it (the meta block's column 14)."""
@@ -1819,7 +1752,7 @@ class PlacementEngine:
 
     def dispatch_batch(self, snapshot, items: Sequence[BatchItem],
                        seed: int = 0, used0_dev=None,
-                       masked_node_ids=None):
+                       masked_node_ids=None, stopped=None):
         """Asynchronous half of place_batch: pack + LAUNCH the kernel and
         return a pending handle (kernel dispatch does not block; the
         device computes while the host does other work — collect_batch
@@ -1844,7 +1777,10 @@ class PlacementEngine:
         eligibility — the wave pipeline's refute-repair input
         (core/wavepipe.py): a chained launch's usage buffer predates the
         foreign write that refuted these nodes, so masking is the only
-        way the kernel can avoid re-picking them."""
+        way the kernel can avoid re-picking them.
+
+        `stopped`: see build_multi_inputs (the solo path's one-item
+        launch)."""
         if not items:
             return None
         # per-dispatch dirty-shard upload meter: build_multi_inputs pays
@@ -1854,7 +1790,8 @@ class PlacementEngine:
         shard_b0 = self.shard_h2d_bytes
         built = self.build_multi_inputs(snapshot, items, seed=seed,
                                         used0_dev=used0_dev,
-                                        masked_node_ids=masked_node_ids)
+                                        masked_node_ids=masked_node_ids,
+                                        stopped=stopped)
         if isinstance(built, tuple):
             return built                 # empty-cluster sentinel
         inp, rs, aux = built["inp"], built["rs"], built
@@ -1886,12 +1823,12 @@ class PlacementEngine:
                     else aux["npad"] // self._ndev))
         else:
             out = self._launch(kind, skey, _WAVE_JITS[kind], *args, *statics)
-        fills_full = fill_k = None
+        fills_full = fill_k = jc_out = None
         if compact:
             buf, fills_full, used_out = out
             fill_k = min(FILL_K, rs)
         else:
-            buf, used_out, _, *taken_out = out
+            buf, used_out, jc_out, *taken_out = out
         # the static-port state a wave chained on this one starts from:
         # what this launch left, or what it was handed and did not touch
         port_state = ((aux["ports"][0], taken_out[0], aux["ports"][1])
@@ -1904,7 +1841,10 @@ class PlacementEngine:
         # prep_ns, not a wall t0: a prefetched batch may sit dispatched
         # while the PREVIOUS batch's host phase runs — that gap is not
         # scheduling time and must not inflate AllocMetric latency
-        return {"buf": buf, "used": used_out, "items": list(items),
+        # `job_count`: the flat kernel's last real round's count row (the
+        # solo path's preemption fallback reads it)
+        return {"buf": buf, "used": used_out, "job_count": jc_out,
+                "items": list(items),
                 "ports": port_state, "port_asks": aux["port_asks"],
                 "spans": aux["spans"], "counts": aux["counts"], "rs": rs,
                 "item_rs": aux["item_rs"], "rounds": aux["rounds"],
@@ -1922,7 +1862,7 @@ class PlacementEngine:
 
     def build_multi_inputs(self, snapshot, items: Sequence[BatchItem],
                            seed: int = 0, used0_dev=None,
-                           masked_node_ids=None):
+                           masked_node_ids=None, stopped=None):
         """Host half of dispatch_batch: pack + lower a multi-eval batch
         into MultiEvalInputs WITHOUT launching (bench.py --kernel times
         the production kernel on exactly these inputs).  Returns a dict
@@ -1933,7 +1873,12 @@ class PlacementEngine:
         dropped from the launch's eligibility — ANDed into the device
         elig tensor for the flat/sharded kernels and into the host-side
         signature masks the compact candidate frames are built from, so
-        both kernel layouts honor the mask identically."""
+        both kernel layouts honor the mask identically.
+
+        `stopped` (the solo path's one-item launch, never chained): the
+        allocations its plan stops, as `_stop_delta` takes them — their
+        usage leaves the launch's `used` and the job's own leave its
+        count row, as the exact scan sees them.  None adds nothing."""
         from nomad_tpu.scheduler.device import request_signature
         t = self.packer.update(snapshot)
         n = t.n
@@ -1954,6 +1899,10 @@ class PlacementEngine:
         chained = used0 is not None
         if used0 is None:
             used0 = self._used_device(t)
+        jc_back = None
+        if stopped:
+            stop_delta, jc_back = _stop_delta(t, npad, stopped)
+            used0 = used0 + jnp.asarray(stop_delta)
         # refuted-node mask: host bool overlay ANDed into eligibility
         # (one tiny upload; the node tensor caches stay untouched)
         elig_dev = dev["elig"]
@@ -2055,9 +2004,11 @@ class PlacementEngine:
                 aff_keys[akey] = ai
                 aff_rows.append(aff_row)
             g_aff[gi] = ai
-            if ctx.job_count.any():
+            jc = (ctx.job_count if jc_back is None
+                  else np.maximum(ctx.job_count - jc_back, 0))
+            if jc.any():
                 jc_nz_idx.append(gi)
-                jc_nz_rows.append(ctx.job_count)
+                jc_nz_rows.append(jc)
         m_pad = _pad_pow2(len(mask_rows), lo=1)
         zrow = self._dev_const(("zrow", npad),
                                lambda: np.zeros(npad, bool))
@@ -2390,7 +2341,7 @@ class PlacementEngine:
                 continue
             # a spread item's rounds hold one placement each
             irs = pending["item_rs"][gi]
-            picks, _, meta = _unpack_bulk_compact(
+            picks, meta = _unpack_rounds(
                 buf_np[lo:hi], irs, counts[gi],
                 slot_k=rs_eff if rs_eff != irs else 0)
             if npad != n:

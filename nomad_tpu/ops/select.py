@@ -548,53 +548,15 @@ def place(inp: PlacementInputs) -> PlacementOutputs:
 place_jit = jax.jit(place)
 
 
-class BulkInputs(NamedTuple):
-    """Reduced device inputs for the bulk kernel: no per-placement arrays
-    (the homogeneous batch is described by the scalars `g` and `p_real`)
-    and no spread/distinct state (the engine routes only spread-free
-    batches here).  Uploading [P]-sized index arrays cost more than the
-    kernel at 100k placements — the transport moves ~3MB/s."""
-    attrs: jnp.ndarray       # [N, A] int32
-    cap: jnp.ndarray         # [N, RES_DIMS] int32
-    used0: jnp.ndarray       # [N, RES_DIMS] int32
-    elig: jnp.ndarray        # [N] bool
-    dc_mask: jnp.ndarray     # [N] bool
-    pool_mask: jnp.ndarray   # [N] bool
-    luts: jnp.ndarray        # [L, V] bool
-    con: jnp.ndarray         # [G, C, 3] int32
-    aff: jnp.ndarray         # [G, Af, 4] int32
-    req: jnp.ndarray         # [G, RES_DIMS] int32
-    desired: jnp.ndarray     # [G] int32
-    dh_limit: jnp.ndarray    # [G] int32
-    job_count0: jnp.ndarray  # [N] int32
-    spread_algo: jnp.ndarray  # [] bool
-    g: jnp.ndarray           # [] int32  the task-group row being placed
-    p_real: jnp.ndarray      # [] int32  real placement count (<= R*round)
-    seed: jnp.ndarray = jnp.uint32(0)  # [] per-eval tie-break (see above)
-    extra_mask: jnp.ndarray = None     # [G, N] bool | None (see above)
-
-
-def _to_bulk_inputs(inp: PlacementInputs) -> BulkInputs:
-    return BulkInputs(
-        attrs=inp.attrs, cap=inp.cap, used0=inp.used0, elig=inp.elig,
-        dc_mask=inp.dc_mask, pool_mask=inp.pool_mask, luts=inp.luts,
-        con=inp.con, aff=inp.aff, req=inp.req, desired=inp.desired,
-        dh_limit=inp.dh_limit, job_count0=inp.job_count0,
-        spread_algo=inp.spread_algo, g=inp.tg_idx[0],
-        p_real=jnp.sum(inp.active).astype(jnp.int32),
-        seed=inp.seed, extra_mask=inp.extra_mask)
-
-
 def round_scores_g(cap, req, desired, dh_limit, static, aff_sc, aff_any,
                    used, job_count, spread_algo, round_size: int,
                    spread=None):
     """Per-node intake capacity (k_i) and rank-chain score for one
     water-fill round at the current proposed state, parameterized on the
-    round's task group values — THE shared scoring core of every bulk
-    deployment: the single-device bulk kernel (fixed g via
-    bulk_round_scores), the sharded variant (parallel/mesh._bulk_local),
-    and the multi-eval batch kernel (dynamic g per round), so none of
-    the three can drift.
+    round's task group values — THE shared scoring core of every
+    water-fill kernel: the flat multi-eval kernel, its sharded twin
+    (parallel/mesh._multi_local) and the laned compact kernels, so none
+    of them can drift.
 
     `spread`: None, or the round's (spread_boost [N], whether its item
     has a stanza at all) — one more component of the mean, as the scan's
@@ -635,17 +597,6 @@ def round_scores_g(cap, req, desired, dh_limit, static, aff_sc, aff_any,
     return k_i, score
 
 
-def bulk_round_scores(inp: BulkInputs, static_t, used, job_count,
-                      round_size: int):
-    """round_scores_g at the bulk kernel's fixed task group `inp.g`
-    (shared verbatim with parallel/mesh._bulk_local)."""
-    g = inp.g
-    static, aff_sc, aff_any, _ = static_t
-    return round_scores_g(inp.cap, inp.req[g], inp.desired[g],
-                          inp.dh_limit[g], static, aff_sc, aff_any,
-                          used, job_count, inp.spread_algo, round_size)
-
-
 def round_metrics_g(cap, req, dh_limit, static, used, job_count):
     """Post-commit exhaustion metrics for one water-fill round,
     parameterized on the round's task group values (shared core, see
@@ -660,19 +611,13 @@ def round_metrics_g(cap, req, dh_limit, static, used, job_count):
     return n_exh, dim_ex
 
 
-def bulk_round_metrics(inp: BulkInputs, static, used, job_count):
-    """round_metrics_g at the bulk kernel's fixed task group `inp.g`."""
-    return round_metrics_g(inp.cap, inp.req[inp.g], inp.dh_limit[inp.g],
-                           static, used, job_count)
-
-
 def waterfill_round(k_i, score, noise, want, spread_algo, round_size: int):
     """Water-fill one round: pick the top-scored nodes and fill each up
     to its intake k_i until `want` placements are assigned.  Returns the
     compact fill prefix (rows/counts/scores, padded to round_size), the
     per-node committed counts c_i, and the total placed — shared by the
-    single-device bulk kernel and the multi-eval batch kernel (the
-    sharded kernel's two-stage variant lives in parallel/mesh)."""
+    flat and compact multi-eval kernels (the sharded kernels' two-stage
+    variant lives in parallel/mesh)."""
     n = k_i.shape[0]
     big = jnp.int32(round_size)
     # spread algorithm: cap per-node intake so a round fans out
@@ -757,86 +702,24 @@ def pick_one_round(k_i, score, noise, want, spread_algo, round_size: int):
     return rows_p, cnt_p, sc_p, c_i, placed_total, k_round
 
 
-def _bulk_step(inp: BulkInputs, round_size: int, top_k: int, static_t,
-               carry, want):
-    """One water-fill round of the bulk kernel.  Returns compact per-round
-    outputs: the sorted fill prefix (node rows + per-node fill counts +
-    scores, length `round_size`) and shared round metrics — everything the
-    host needs, at O(round_size) not O(N) per round.
-
-    `static_t` is the loop-invariant (feasibility mask, affinity scores)
-    triple, computed once in _bulk_scan and closed over — recomputing it
-    per round would multiply the gather/reduce chain by the round count.
-    """
-    g = inp.g
-    req = inp.req[g]
-    static, aff_sc, aff_any, noise = static_t
-
-    used, job_count = carry
-    k_i, score = bulk_round_scores(inp, static_t, used, job_count,
-                                   round_size)
-    rows_p, cnt_p, sc_p, c_i, placed_total, k_round = waterfill_round(
-        k_i, score, noise, want, inp.spread_algo, round_size)
-
-    # commit the round
-    used = used + c_i[:, None] * req[None, :]
-    job_count = job_count + c_i
-
-    # round metrics (shared by every placement of the round)
-    top_sc = sc_p[:top_k]
-    top_rows = jnp.where(top_sc > NEG_INF / 2, rows_p[:top_k], -1)
-    top_sc = jnp.where(top_sc > NEG_INF / 2, top_sc, 0.0)
-    n_feas = jnp.sum(k_round > 0).astype(jnp.int32)
-    n_filt = jnp.sum(~static).astype(jnp.int32)
-    # exhaustion is reported POST-commit: a placement that failed inside
-    # this round failed against capacity already consumed by the round's
-    # earlier fills (sequential semantics), and for successful rounds the
-    # stock metric likewise counts nodes filled by earlier placements
-    n_exh, dim_ex = bulk_round_metrics(inp, static, used, job_count)
-    n_exh = n_exh.astype(jnp.int32)
-    dim_ex = dim_ex.astype(jnp.int32)
-
-    out = (rows_p, cnt_p, sc_p, top_rows, top_sc,
-           n_feas, n_filt, n_exh, dim_ex,
-           placed_total.astype(jnp.int32))
-    return (used, job_count), out
-
-
-def _bulk_static(inp: BulkInputs, g):
-    full = feasible_mask(inp.attrs, inp.elig, inp.dc_mask, inp.pool_mask,
-                         inp.con, inp.luts)                  # [G, N]
-    if inp.extra_mask is not None:
-        full = full & inp.extra_mask
-    static = full[g]                                         # [N]
-    aff_sc = affinity_score(inp.attrs, inp.aff, inp.luts)[g]  # [N]
-    aff_any = jnp.any(inp.aff[..., 3] != 0, axis=1)[g]
-    noise = tiebreak_noise(inp.seed, jnp.arange(inp.attrs.shape[0]))
-    return static, aff_sc, aff_any, noise
-
-
-def _bulk_scan(inp: BulkInputs, round_size: int, n_rounds: int, top_k: int):
-    # placements are a contiguous prefix of the padded batch, so each
-    # round's demand derives from the p_real scalar — no [P] active array
-    want_r = jnp.clip(
-        inp.p_real - jnp.arange(n_rounds, dtype=jnp.int32) * round_size,
-        0, round_size)
-    carry0 = (inp.used0, inp.job_count0)
-    static_t = _bulk_static(inp, inp.g)
-    return jax.lax.scan(
-        partial(_bulk_step, inp, round_size, top_k, static_t),
-        carry0, want_r)
-
-
-
 def pack_round_buffer(rows_p, cnt_p, top_rows, top_sc, n_feas, n_filt,
                       n_exh, dim_ex, placed):
     """Shared per-round output assembly for every rounds-based kernel
-    (single-eval bulk, multi-eval flat/compact, and the sharded
-    variants): the packed fill slots (row*2048 + count) and the 16-word
-    meta block — layout documented on place_bulk_packed.  `dim_ex` has
-    one column a capacity dimension: the first three sit before
-    `placed`, where they always have, the rest after it.  Returns
-    (fills, meta)."""
+    (multi-eval flat/compact and their sharded twins): the packed fill
+    slots and the 16-word meta block.  Row layout per round r:
+      [0 : round_size)     fill prefix, row*2048 + count packed
+                           (count <= round_size <= 1024 < 2048; asserts
+                           n < 2^20 nodes)
+      [round_size : +16)   topk_rows(3) | bitcast topk_scores(3) |
+                           n_feasible | n_filtered | n_exhausted |
+                           dim_exhausted(cpu, memory, disk) |
+                           placed_total | dim_exhausted(devices) | pad(2)
+    `dim_ex` has one column a capacity dimension: the first three sit
+    before `placed`, where they always have, the rest after it.  The
+    host expands fills to per-placement picks with np.repeat: placements
+    within a round are interchangeable (same task group, no
+    per-placement state), so fill order IS the placement order.
+    Returns (fills, meta)."""
     f2i = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
     fills = jnp.where(cnt_p > 0, rows_p * 2048 + cnt_p, 0)
     r = top_rows.shape[0]
@@ -851,128 +734,6 @@ def pack_round_buffer(rows_p, cnt_p, top_rows, top_sc, n_feas, n_filt,
         jnp.zeros((r, 6 - dim_ex.shape[1]), jnp.int32),
     ], axis=1)
     return fills, meta
-
-
-def place_bulk_packed(inp: BulkInputs, round_size: int, n_rounds: int,
-                      with_scores: bool = False, fill_k: int = 0):
-    """Bulk kernel with compact per-round outputs packed into ONE int32
-    buffer `[R, round_size + 16]` — a single device→host transfer whose
-    size scales with rounds, not placements or nodes.
-
-    Row layout per round r:
-      [0 : round_size)               fill prefix, row*2048 + count packed
-                                     (count <= round_size <= 1024 < 2048;
-                                     asserts n < 2^20 nodes)
-      [round_size : +16)             topk_rows(3) | bitcast topk_scores(3) |
-                                     n_feasible | n_filtered | n_exhausted |
-                                     dim_exhausted(cpu, memory, disk) |
-                                     placed_total | dim_exhausted(devices)
-                                     | pad(2)
-
-    With `with_scores=True` a bitcast per-slot score block is inserted
-    between fills and meta (buffer `[R, 2*round_size + 16]`) so the host
-    can expand real per-placement scores; the default drops it because the
-    hot BulkDecisions path never reads per-placement scores and the
-    transfer cost scales with buffer bytes.
-
-    The host expands fills to per-placement picks with np.repeat — placements
-    within a round are interchangeable (same task group, no per-placement
-    state), so fill order IS the placement order.
-
-    `fill_k > 0` (compact output, mutually exclusive with with_scores):
-    the always-fetched buffer carries only the first `fill_k` fill slots
-    per round (water-fill commits in sorted order, so the nonzero fills
-    are a prefix; a binpack round fills a handful of nodes) and the FULL
-    fills come back as a separate device-resident array the host fetches
-    only when a round overflows — the giant-eval transfer shrinks ~30×.
-    Returns (buf_small, fills_full, used, job_count) in that mode.
-
-    Returns (buf, used, job_count).
-    """
-    n = inp.attrs.shape[0]
-    assert n < (1 << 20), "packed fill rows support < 2^20 nodes"
-    assert round_size <= 1024, "packed fill counts support rounds <= 1024"
-    assert not (with_scores and fill_k), "scores need the full slot layout"
-    top_k = min(TOP_K, n)
-    (used, job_count), outs = _bulk_scan(inp, round_size, n_rounds, top_k)
-    (rows_p, cnt_p, sc_p, top_rows, top_sc,
-     n_feas, n_filt, n_exh, dim_ex, placed) = outs
-    fills, meta = pack_round_buffer(rows_p, cnt_p, top_rows, top_sc,
-                                    n_feas, n_filt, n_exh, dim_ex, placed)
-    if fill_k:
-        buf_small = jnp.concatenate(
-            [fills[:, :min(fill_k, round_size)], meta], axis=1)
-        return buf_small, fills, used, job_count
-    if with_scores:
-        f2i = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
-        parts = [fills, f2i(sc_p), meta]
-    else:
-        parts = [fills, meta]
-    buf = jnp.concatenate(parts, axis=1)
-    return buf, used, job_count
-
-
-place_bulk_packed_jit = jax.jit(place_bulk_packed,
-                               static_argnums=(1, 2, 3, 4))
-
-
-def place_bulk(inp: PlacementInputs, round_size: int) -> PlacementOutputs:
-    """Fast path for homogeneous placement batches: one task group, no
-    spread stanza, no distinct_property, no reschedule penalties (the
-    engine routes only such batches here).
-
-    Instead of a scan step per placement, placements are assigned in
-    rounds of `round_size`: score every node once per round at the current
-    proposed state, then water-fill the sorted nodes up to their remaining
-    multi-alloc capacity (SURVEY.md §7 P3's "greedy conflict-resolution
-    rounds" alternative to the per-placement scan).  Capacity,
-    distinct_hosts and job anti-affinity are re-evaluated between rounds;
-    within a round a node absorbs as many allocs as fit (binpack wants to
-    fill the best node anyway; for the spread algorithm the per-round
-    per-node intake is capped to spread the wave).
-
-    Device cost: O(P/R) scan steps of O(N log N) each, vs O(P) steps for
-    `place` — ~R× fewer sequential launches.  (The engine uses the
-    `place_bulk_packed` variant below; this expanded-output form is the
-    reference API for tests and the sharded mesh path.)
-    """
-    n = inp.attrs.shape[0]
-    p_pad = inp.tg_idx.shape[0]
-    assert p_pad % round_size == 0
-    top_k = min(TOP_K, n)
-    (used, job_count), outs = _bulk_scan(
-        _to_bulk_inputs(inp), round_size, p_pad // round_size, top_k)
-    (rows_p, cnt_p, sc_p, top_rows, top_sc,
-     n_feas, n_filt, n_exh, dim_ex, placed) = outs
-
-    # expand per-round fill prefixes to per-placement picks
-    def expand(rows_r, cnt_r, sc_r, placed_r):
-        fill_edges = jnp.cumsum(cnt_r)
-        p_idx = jnp.arange(round_size)
-        slot = jnp.searchsorted(fill_edges, p_idx, side="right")
-        slot = jnp.clip(slot, 0, rows_r.shape[0] - 1)
-        pick = jnp.where(p_idx < placed_r, rows_r[slot], -1)
-        pick_score = jnp.where(pick >= 0, sc_r[slot], 0.0)
-        return pick, pick_score
-
-    picks_r, scores_r = jax.vmap(expand)(rows_p, cnt_p, sc_p, placed)
-
-    def flat(x):
-        return x.reshape((p_pad,) + x.shape[2:])
-
-    def rep(x):
-        return flat(jnp.broadcast_to(
-            x[:, None], (x.shape[0], round_size) + x.shape[1:]))
-
-    return PlacementOutputs(
-        picks=flat(picks_r), scores=flat(scores_r),
-        topk_rows=rep(top_rows), topk_scores=rep(top_sc),
-        n_feasible=rep(n_feas), n_filtered=rep(n_filt),
-        n_exhausted=rep(n_exh), dim_exhausted=rep(dim_ex),
-        used=used, job_count=job_count)
-
-
-place_bulk_jit = jax.jit(place_bulk, static_argnums=1)
 
 
 class MultiEvalInputs(NamedTuple):
@@ -1065,12 +826,18 @@ def round_seeds(seed, rg):
 
 
 def place_multi_packed(inp: MultiEvalInputs, round_size: int):
-    """Batched multi-eval placement: every round's intake/score math is
-    the same round_scores_g / waterfill_round / round_metrics_g core the
-    single-eval bulk kernel runs — only the task group (and its job's
-    count row) varies per round.  Output is the compact per-round packed
-    buffer of place_bulk_packed, `[R, round_size + 16]`, one device→host
-    transfer for the WHOLE batch; the host slices rows per eval.
+    """Batched multi-eval placement in water-fill rounds (SURVEY.md §7
+    P3's "greedy conflict-resolution rounds" alternative to the
+    per-placement scan): each round scores every node once at the
+    current proposed state (round_scores_g), then fills the best nodes
+    up to their remaining multi-alloc capacity until the round's `want`
+    is met (waterfill_round); capacity, distinct_hosts and job
+    anti-affinity are re-evaluated between rounds (round_metrics_g).
+    Only the task group (and its job's count row) varies per round.  A
+    solo eval of many fresh placements is a wave of one item.  Output
+    is the compact per-round packed buffer (pack_round_buffer),
+    `[R, round_size + 16]`, one device→host transfer for the WHOLE
+    batch; the host slices rows per eval.
 
     A round does what its own `want` can use.  `want` 0 (the schedule's
     padding to a power of two, always its tail): the round loop ends
@@ -1163,8 +930,8 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
 
         def select(select_round):
             # per-item noise (elementwise hash — no [R, N] pre-gather):
-            # the round draws its EVAL's tie-break stream, matching what
-            # the solo bulk kernel computes for the same eval id
+            # the round draws its EVAL's tie-break stream, as the exact
+            # scan does for the same eval id
             return select_round(k_i, score, tiebreak_noise(sd, rows_all),
                                 want, inp.spread_algo, round_size)
 

@@ -69,7 +69,7 @@ DOP_LUT = 5         # set(col) and luts[arg, attrs[col]]
 
 _TARGET_RE = re.compile(r"^\$\{(.+)\}$")
 
-# hard ceiling on the node-table height: the bulk kernels' packed fill
+# hard ceiling on the node-table height: the water-fill kernels' packed fill
 # rows encode (node row, count) in one int32 as `row << 11 | count`
 # (ops/select.py pack_round_buffer), leaving 20 usable row bits.  The
 # kernels assert this deep in a launch; validating HERE, at table-build

@@ -32,13 +32,9 @@ from nomad_tpu.structs import RES_DIMS
 from nomad_tpu.ops.select import (
     NEG_INF,
     TOP_K,
-    BulkInputs,
     MultiEvalInputs,
     PlacementInputs,
     PlacementOutputs,
-    _bulk_static,
-    bulk_round_metrics,
-    bulk_round_scores,
     pack_outputs,
     pack_round_buffer,
     round_metrics_g,
@@ -59,7 +55,6 @@ AXIS = "nodes"
 # launch kinds onto the builders.
 PROGRAM_NAMES = (
     "place_sharded_packed",
-    "place_bulk_sharded_packed",
     "place_multi_sharded_packed",
     "place_multi_sharded_chained",
     "place_multi_compact_sharded",
@@ -243,7 +238,7 @@ def place_sharded_packed_fn(mesh: Mesh):
     return jax.jit(place_sharded_packed)
 
 
-# ------------------------------------------------------------ bulk kernel
+# ------------------------------------------------------ water-fill kernels
 
 
 def _sharded_waterfill(k_i, score, noise, static, want, spread_algo,
@@ -251,9 +246,8 @@ def _sharded_waterfill(k_i, score, noise, static, want, spread_algo,
                        global_rows, frame_commit: bool = False):
     """One sharded water-fill round: local candidates -> two-stage top-k
     over ICI -> replicated fill math -> owner-shard commit counts.
-    Shared by the sharded bulk kernel (fixed task group), the sharded
-    multi-eval kernel (task group per round), and — with
-    `frame_commit=True` — the sharded COMPACT laned kernel, where the
+    Shared by the sharded multi-eval kernel (task group per round) and —
+    with `frame_commit=True` — the sharded COMPACT laned kernel, where the
     local axis is a per-signature candidate FRAME rather than the node
     shard: commits then scatter back to frame slots (ownership decided
     by each winner's packed frame index + the global-row range test).
@@ -337,50 +331,6 @@ def _sharded_waterfill(k_i, score, noise, static, want, spread_algo,
             c_i, placed_total.astype(jnp.int32))
 
 
-def _bulk_local(inp: BulkInputs, round_size: int, n_rounds: int,
-                top_k: int):
-    """Per-shard body of the sharded bulk (water-fill rounds) kernel.
-    The round's intake/score math is ops.select.bulk_round_scores — the
-    same function the single-device kernel runs — on the local node
-    shard; the fill is decided globally via _sharded_waterfill."""
-    n_loc = inp.attrs.shape[0]
-    offset = jax.lax.axis_index(AXIS) * n_loc
-    global_rows = offset + jnp.arange(n_loc)
-
-    static, aff_sc, aff_any, _ = _bulk_static(inp, inp.g)
-    noise = tiebreak_noise(inp.seed, global_rows)
-    static_t = (static, aff_sc, aff_any, noise)
-
-    def round_step(carry, want):
-        used, job_count = carry
-        k_i, score = bulk_round_scores(inp, static_t, used, job_count,
-                                       round_size)
-        (rows_p, cnt_p, sc_p, top_rows, top_sc, n_feas, n_filt,
-         c_i, placed) = _sharded_waterfill(
-            k_i, score, noise, static, want, inp.spread_algo, round_size,
-            top_k, n_loc, offset, global_rows)
-        req = inp.req[inp.g]
-        used = used + c_i[:, None] * req[None, :]
-        job_count = job_count + c_i
-
-        # round metrics (global, same classification as the single-device
-        # kernel: POST-commit exhaustion)
-        n_exh_l, dim_ex_l = bulk_round_metrics(inp, static, used, job_count)
-        n_exh = jax.lax.psum(n_exh_l, AXIS).astype(jnp.int32)
-        dim_ex = jax.lax.psum(dim_ex_l, AXIS).astype(jnp.int32)
-
-        out = (rows_p, cnt_p, sc_p, top_rows, top_sc,
-               n_feas, n_filt, n_exh, dim_ex, placed)
-        return (used, job_count), out
-
-    want_r = jnp.clip(
-        inp.p_real - jnp.arange(n_rounds, dtype=jnp.int32) * round_size,
-        0, round_size)
-    carry0 = (inp.used0, inp.job_count0)
-    (used, job_count), outs = jax.lax.scan(round_step, carry0, want_r)
-    return outs + (used, job_count)
-
-
 def _multi_local(inp: MultiEvalInputs, round_size: int, top_k: int):
     """Per-shard body of the sharded multi-eval batch kernel: the same
     round_scores_g / round_metrics_g core as ops.select.place_multi_packed
@@ -415,8 +365,8 @@ def _multi_local(inp: MultiEvalInputs, round_size: int, top_k: int):
         (u, a, jc0_row, req, desired, dh_limit, want, same, sd) = xs
         static = static_u[u]
         # per-item noise over GLOBAL rows: identical for a given row on
-        # every shard AND identical to the solo bulk launch for the same
-        # eval id (wavepipe serial/pipelined parity)
+        # every shard AND to the single-device launch for the same eval
+        # id (wavepipe serial/pipelined parity)
         noise = tiebreak_noise(sd, global_rows)
         job_count = jnp.where(same, cur_count, jc0_row)
         k_i, score = round_scores_g(
@@ -638,50 +588,6 @@ def place_multi_compact_sharded_fn(mesh: Mesh, round_size: int,
 
     return jax.jit(place_multi_compact_sharded_chained,
                    donate_argnums=(0,))
-
-
-def place_bulk_sharded_packed_fn(mesh: Mesh, round_size: int,
-                                 n_rounds: int):
-    """Sharded bulk kernel with the same compact packed buffer layout as
-    ops.select.place_bulk_packed (with_scores variant included via the
-    `with_scores` call arg being fixed False — the engine's BulkDecisions
-    path never reads per-placement scores)."""
-    import jax.numpy as jnp  # noqa: F811 (local clarity)
-
-    spec_n = P(AXIS)
-    in_specs = BulkInputs(
-        attrs=spec_n, cap=spec_n, used0=spec_n, elig=spec_n,
-        dc_mask=spec_n, pool_mask=spec_n, luts=P(),
-        con=P(), aff=P(), req=P(), desired=P(), dh_limit=P(),
-        job_count0=spec_n, spread_algo=P(), g=P(), p_real=P(), seed=P(),
-        extra_mask=P(None, AXIS),
-    )
-    out_specs = (P(), P(), P(), P(), P(), P(), P(), P(), P(), P(),
-                 spec_n, spec_n)
-    top_k = TOP_K
-    inner = shard_map(
-        partial(_bulk_local, round_size=round_size, n_rounds=n_rounds,
-                top_k=top_k),
-        mesh=mesh, in_specs=(in_specs,), out_specs=out_specs,
-        check_vma=False)
-
-    def place_bulk_sharded_packed(inp: BulkInputs):
-        # same guards as the single-device place_bulk_packed: the fill
-        # encoding (row*2048+count) needs n < 2^20 and counts < 2048, and
-        # n < 2^20 also keeps the float32 row/count transit through
-        # _bulk_local's all_gather exact (float32 is exact below 2^24)
-        n = inp.attrs.shape[0]
-        assert n < (1 << 20), "packed fill rows support < 2^20 nodes"
-        assert round_size <= 1024, "packed fill counts support rounds <= 1024"
-        (rows_p, cnt_p, sc_p, top_rows, top_sc,
-         n_feas, n_filt, n_exh, dim_ex, placed, used, job_count) = inner(inp)
-        fills, meta = pack_round_buffer(
-            rows_p, cnt_p, top_rows, top_sc, n_feas, n_filt, n_exh,
-            dim_ex, placed)
-        buf = jnp.concatenate([fills, meta], axis=1)
-        return buf, used, job_count
-
-    return jax.jit(place_bulk_sharded_packed)
 
 
 def scatter_add_sharded_fn(mesh: Mesh):

@@ -692,7 +692,7 @@ class GenericScheduler(Scheduler):
         # allocs this plan is stopping free their capacity for placement
         stopped = [a for allocs in plan.node_update.values() for a in allocs]
         decisions = self.engine.place(self.state, job, tgs, reqs,
-                                      stopped_allocs=stopped, bulk_api=True,
+                                      stopped_allocs=stopped,
                                       seed=getattr(self, "_seed", 0))
         with self._materialize_stage():
             if isinstance(decisions, BulkDecisions):
@@ -1015,8 +1015,7 @@ class GenericScheduler(Scheduler):
         stopped = [a for allocs in plan.node_update.values() for a in allocs]
         decisions = self.engine.place(
             self.state, job, job.task_groups, None,
-            stopped_allocs=stopped, bulk_api=True,
-            seed=getattr(self, "_seed", 0),
+            stopped_allocs=stopped, seed=getattr(self, "_seed", 0),
             block=(block.tg.name, len(block.indexes)))
         with self._materialize_stage():
             if isinstance(decisions, BulkDecisions):

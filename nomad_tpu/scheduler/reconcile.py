@@ -70,7 +70,7 @@ class PlaceBlock:
     index list instead of N PlaceRequests.  At bench scale (100k
     placements) the per-request objects and name strings alone cost more
     than the device work, so the common batch-job shape stays compact all
-    the way into the bulk kernel."""
+    the way into the water-fill kernel."""
     tg: TaskGroup
     indexes: List[int]
 

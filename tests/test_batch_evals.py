@@ -4,7 +4,7 @@ The reference processes one eval per worker goroutine (nomad/worker.go);
 here compatible pending evals share ONE device launch
 (ops.select.place_multi_packed via engine.place_batch) and their plans are
 mutually consistent by construction.  These tests pin:
-  - kernel parity: a batch of one == the single-eval bulk kernel
+  - kernel parity: a solo water-fill eval == a batch of one
   - capacity coupling: plans inside one batch never oversubscribe and
     never refute each other at the serialized applier
   - end-to-end: Server.process_all with eval_batch handles a mixed queue
@@ -53,23 +53,117 @@ def batch_jobs(h, counts, cpu=100, mem=64):
     return jobs
 
 
+def metric_key(m):
+    """Everything an AllocMetric says but the wall-clock reading."""
+    return (m.nodes_evaluated, m.nodes_filtered, m.nodes_in_pool,
+            m.nodes_exhausted, dict(m.nodes_available),
+            dict(m.dimension_exhausted),
+            [(s.node_id, s.scores, s.norm_score)
+             for s in m.score_meta_data])
+
+
+def assert_same_answer(solo, wave):
+    """Two BulkDecisions give one answer: the picks in their order on
+    one node table, every round's metric (filtered, exhausted, each
+    dimension, the top rows and their scores) and so every placement's,
+    the evictions."""
+    assert solo.node_ids == wave.node_ids
+    assert np.array_equal(solo.picks, wave.picks)
+    assert solo.round_size == wave.round_size
+    assert len(wave.metrics) == -(-len(wave.picks) // wave.round_size)
+    assert ([metric_key(m) for m in solo.metrics]
+            == [metric_key(m) for m in wave.metrics])
+    assert solo.evictions == wave.evictions == {}
+    assert solo.nodes_evaluated == wave.nodes_evaluated
+
+
 class TestPlaceBatchKernel:
-    def test_single_item_matches_bulk_kernel(self):
-        h, _ = build_cluster(150)
-        (job,) = batch_jobs(h, [200])
+    @pytest.mark.parametrize("devices", [1, 8])
+    @pytest.mark.parametrize("live", ["fresh", "live", "stopping"])
+    @pytest.mark.parametrize("algo", ["binpack", "spread"])
+    @pytest.mark.parametrize("count", [64, 100, 200, 1024, 1500, 2100])
+    def test_single_item_matches_bulk_kernel(self, count, algo, live,
+                                             devices):
+        """A solo water-fill eval answers as a one-item wave: picks in
+        their order and every round's metric.  At 700 MHz an ask the
+        150 nodes hold ~1,900 of, so the largest counts exhaust them.
+        `live`: the job already runs two allocations on every seventh
+        node (its count rows and their usage).  `stopping`: the solo
+        eval's plan also stops one of each pair and another job's
+        allocation on every eleventh node, and the wave runs on the
+        state with those allocations gone."""
+        from nomad_tpu.structs import (Resources, SCHED_ALGO_SPREAD,
+                                       SchedulerConfiguration)
+        h, nodes = build_cluster(150)
+        if algo == "spread":
+            h.state.set_scheduler_config(SchedulerConfiguration(
+                scheduler_algorithm=SCHED_ALGO_SPREAD))
+        (job, other) = batch_jobs(h, [count, 1], cpu=700)
+        tg = job.task_groups[0]
+        stopped = []
+        if live != "fresh":
+            def running(j, n):
+                return mock.alloc(job=j, node_id=n.id,
+                                  task_group=j.task_groups[0].name,
+                                  resources=Resources(cpu=700, memory_mb=64),
+                                  client_status="running")
+            allocs = [running(job, n) for n in nodes[::7] for _ in range(2)]
+            foreign = [running(other, n) for n in nodes[::11]]
+            h.state.upsert_allocs(allocs + foreign)
+            tg.count = count + len(allocs)
+            h.state.upsert_job(job)
+            if live == "stopping":
+                stopped = allocs[::2] + foreign
         snap = h.state.snapshot()
-        eng = PlacementEngine()
-        bd_batch = eng.place_batch(
-            snap, [BatchItem(job=job, tg=job.task_groups[0], count=200)],
+        solo = PlacementEngine(mesh=None if devices > 1 else False).place(
+            snap, job, job.task_groups, None, stopped_allocs=stopped,
+            seed=9, block=(tg.name, count))
+        if stopped:
+            gone = [a.copy() for a in stopped]
+            for a in gone:
+                a.desired_status, a.client_status = "stop", "complete"
+            h.state.upsert_allocs(gone)
+            snap = h.state.snapshot()
+        eng = PlacementEngine(mesh=None if devices > 1 else False)
+        assert eng._ndev == devices
+        wave = eng.place_batch(
+            snap, [BatchItem(job=job, tg=tg, count=count)], seed=9)[0]
+        assert_same_answer(solo, wave)
+
+    @pytest.mark.parametrize("devices", [1, 8])
+    def test_solo_stops_give_their_nodes_back(self, devices):
+        """distinct_hosts makes the job's own count a hard limit: its
+        live allocation on every other node keeps that node out, and the
+        plan's stops on every fourth node give it back to a solo
+        water-fill eval, as the wave on the state without them sees."""
+        from nomad_tpu.structs import Constraint, Resources
+        h, nodes = build_cluster(150)
+        (job,) = batch_jobs(h, [100])
+        job.constraints.append(Constraint("", "distinct_hosts", ""))
+        tg = job.task_groups[0]
+        live = [mock.alloc(job=job, node_id=n.id, task_group=tg.name,
+                           resources=Resources(cpu=100, memory_mb=64),
+                           client_status="running") for n in nodes[::2]]
+        h.state.upsert_allocs(live)
+        tg.count = 100 + len(live)
+        h.state.upsert_job(job)
+        mesh = None if devices > 1 else False
+        solo = PlacementEngine(mesh=mesh).place(
+            h.state.snapshot(), job, job.task_groups, None,
+            stopped_allocs=live[::2], seed=9, block=(tg.name, 100))
+        gone = [a.copy() for a in live[::2]]
+        for a in gone:
+            a.desired_status, a.client_status = "stop", "complete"
+        h.state.upsert_allocs(gone)
+        wave = PlacementEngine(mesh=mesh).place_batch(
+            h.state.snapshot(), [BatchItem(job=job, tg=tg, count=100)],
             seed=9)[0]
-        bd_bulk = eng.place(snap, job, job.task_groups, None, bulk_api=True,
-                            seed=9, block=(job.task_groups[0].name, 200))
-        assert np.array_equal(np.sort(bd_batch.picks),
-                              np.sort(bd_bulk.picks))
-        # metric parity for the first round
-        m_batch, m_bulk = bd_batch.metrics[0], bd_bulk.metrics[0]
-        assert m_batch.nodes_filtered == m_bulk.nodes_filtered
-        assert m_batch.nodes_exhausted == m_bulk.nodes_exhausted
+        assert_same_answer(solo, wave)
+        # 75 nodes hold none of the job, 38 more are given back: all
+        # 100 place, some of them on the nodes the stops free
+        assert (solo.picks >= 0).all()
+        freed = {n.id for n in nodes[::4]}
+        assert freed & {solo.node_ids[p] for p in solo.picks.tolist()}
 
     def test_capacity_coupling_across_items(self):
         """Items in one batch see each other's proposed usage: total
@@ -723,11 +817,9 @@ class TestCompactLanedKernel:
                 assert ma.nodes_exhausted == mb.nodes_exhausted
 
     def test_single_eval_bulk_overflow_fallback(self):
-        """The single-eval bulk kernel's compact output must survive a
-        round filling more distinct nodes than the FILL_K prefix (tiny
-        nodes force ~2 allocs each): the engine refetches the resident
-        full fills and the picks match the full-layout run exactly."""
-        import nomad_tpu.ops.engine as em
+        """A solo water-fill eval whose round fills more distinct nodes
+        than the compact kernel's FILL_K prefix (tiny nodes force ~2
+        allocs each) places every allocation, within capacity."""
         from nomad_tpu.ops.select import FILL_K
 
         h = Harness()
@@ -750,20 +842,11 @@ class TestCompactLanedKernel:
         snap = h.state.snapshot()
 
         bd = PlacementEngine(mesh=False).place(
-            snap, job, job.task_groups, None, bulk_api=True, seed=5,
+            snap, job, job.task_groups, None, seed=5,
             block=(tg.name, count))
-        old = em.FILL_K
-        em.FILL_K = 4096                   # full prefix: no overflow
-        try:
-            bd_full = PlacementEngine(mesh=False).place(
-                snap, job, job.task_groups, None, bulk_api=True, seed=5,
-                block=(tg.name, count))
-        finally:
-            em.FILL_K = old
-        assert np.array_equal(bd.picks, bd_full.picks)
         placed = bd.picks[bd.picks >= 0]
         assert len(placed) == count
-        assert len(np.unique(placed)) > FILL_K     # really overflowed
+        assert len(np.unique(placed)) > FILL_K
         counts = np.bincount(placed)
         assert counts.max() <= 2                   # capacity respected
 

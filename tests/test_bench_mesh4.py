@@ -18,7 +18,7 @@ from benchmark.loader import load_json, load_module  # noqa: E402
 
 CELL = "csi50k-drain-mesh4"
 # the static arguments each kind's builder takes after the mesh
-KIND_STATICS = {"scan": (), "bulk": (64, 4), "multi": (64,),
+KIND_STATICS = {"scan": (), "multi": (64,),
                 "multi_chained": (64,), "multi_compact": (64, 5),
                 "multi_compact_chained": (64, 5), "scatter": ()}
 

@@ -364,7 +364,7 @@ class TestProgressDeadline:
 class TestBlockCommittedDeployment:
     """ISSUE 35: a deployment no longer bars the columnar commit.  A
     service job with an update stanza, 80 fresh placements and no spread
-    rides the bulk kernel and commits as ONE AllocBlock whose template
+    rides the water-fill and commits as ONE AllocBlock whose template
     carries the deployment's id; the lifecycle runs as it did on rows."""
 
     def _server(self, n_nodes=12):
@@ -391,7 +391,7 @@ class TestBlockCommittedDeployment:
         s, job = self._registered()
         (block,) = s.state._alloc_blocks.values()
         assert block.count == 80 and block.row_metrics is None
-        assert block.metrics, "the bulk kernel's per-round metrics"
+        assert block.metrics, "the water-fill's per-round metrics"
         assert not s.state._allocs_by_job.get((job.namespace, job.id))
         dep = s.state.latest_deployment_by_job(job.namespace, job.id)
         assert dep.status == DEPLOYMENT_STATUS_RUNNING

@@ -4,7 +4,8 @@ The conftest forces 8 virtual CPU devices, so PlacementEngine() auto-builds
 a node-axis mesh — THE production multi-device path.  These tests pin that
 the full engine (packing, padding, caches, unpack) produces the same Plans
 sharded as single-device (`mesh=False`) at realistic node counts, for all
-three kernels: exact scan, bulk water-fill, and the multi-eval batch.
+three paths: the exact scan, a solo water-fill eval (the multi-eval
+kernel as a wave of one item) and the multi-eval batch.
 """
 
 import random
@@ -59,10 +60,10 @@ class TestShardedEngineParity:
         sharded, single = engines()
         assert sharded is not None
         bd_s = sharded.place(snap, job, job.task_groups, None,
-                             bulk_api=True, seed=13,
+                             seed=13,
                              block=(tg.name, 2000))
         bd_1 = single.place(snap, job, job.task_groups, None,
-                            bulk_api=True, seed=13,
+                            seed=13,
                             block=(tg.name, 2000))
         assert np.array_equal(np.sort(bd_s.picks), np.sort(bd_1.picks))
         for m_s, m_1 in zip(bd_s.metrics, bd_1.metrics):
@@ -138,9 +139,9 @@ class TestShardedEngineParity:
         snap = h.state.snapshot()
         sharded, single = engines()
         bd_s = sharded.place(snap, job, job.task_groups, None,
-                             bulk_api=True, seed=5, block=(tg.name, 400))
+                             seed=5, block=(tg.name, 400))
         bd_1 = single.place(snap, job, job.task_groups, None,
-                            bulk_api=True, seed=5, block=(tg.name, 400))
+                            seed=5, block=(tg.name, 400))
         picks = bd_s.picks
         placed = picks[picks >= 0]
         assert placed.size > 0
@@ -169,10 +170,10 @@ class TestShardedEngineParity:
             h.state.upsert_job(job)
             snap = h.state.snapshot()
             bd_s = sharded.place(snap, job, job.task_groups, None,
-                                 bulk_api=True, seed=seed,
+                                 seed=seed,
                                  block=(tg.name, count))
             bd_1 = single.place(snap, job, job.task_groups, None,
-                                bulk_api=True, seed=seed,
+                                seed=seed,
                                 block=(tg.name, count))
             return bd_s, bd_1
 
@@ -217,8 +218,8 @@ class TestShardedEngineParity:
             return job, snap
 
         job, snap = place(1)
-        sharded.place(snap, job, job.task_groups, None, bulk_api=True,
-                      seed=1, block=(job.task_groups[0].name, 80))
+        sharded.place(snap, job, job.task_groups, None, seed=1,
+                      block=(job.task_groups[0].name, 80))
         full_bytes = h2d["bytes"]
         assert full_bytes > 0
         shard_b0 = sharded.shard_h2d_bytes
@@ -229,7 +230,7 @@ class TestShardedEngineParity:
         h2d["bytes"] = 0
         job, snap = place(2)
         bd_s = sharded.place(snap, job, job.task_groups, None,
-                             bulk_api=True, seed=2,
+                             seed=2,
                              block=(job.task_groups[0].name, 80))
         assert sharded.shard_h2d_bytes > shard_b0, \
             "dirty-shard patch never engaged"
@@ -239,7 +240,7 @@ class TestShardedEngineParity:
             (h2d["bytes"], full_bytes)
         single = PlacementEngine(mesh=False)
         bd_1 = single.place(snap, job, job.task_groups, None,
-                            bulk_api=True, seed=2,
+                            seed=2,
                             block=(job.task_groups[0].name, 80))
         assert np.array_equal(np.sort(bd_s.picks), np.sort(bd_1.picks))
         # the drained node is gone from both engines' picks

@@ -501,10 +501,13 @@ def process_beside_decisions(h, job):
     real = h.engine.place
     seen = []
 
-    def place(*args, **kw):
-        # the reference first: neither call writes state
-        seen.append(real(*args, **dict(kw, bulk_api=False)))
-        return real(*args, **kw)
+    def place(snap, job, tgs, requests, block, **kw):
+        # the reference first, the block's placements as request rows:
+        # neither call writes state
+        tg_name, count = block
+        seen.append(real(snap, job, tgs,
+                         [PlacementRequest(tg_name=tg_name)] * count, **kw))
+        return real(snap, job, tgs, requests, block=block, **kw)
 
     h.engine.place = place
     try:

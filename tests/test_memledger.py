@@ -9,7 +9,7 @@ import pytest
 from nomad_tpu import mock
 from nomad_tpu.chaos.clock import SystemClock, VirtualClock
 from nomad_tpu.chaos.trace import state_fingerprint
-from nomad_tpu.core import flightrec
+from nomad_tpu.core import flightrec, memledger
 from nomad_tpu.core.fanout import WatchHub, _Shape
 from nomad_tpu.core.memledger import (
     MEMLEDGER,
@@ -85,15 +85,21 @@ def test_sample_throttles_on_injected_clock():
     assert ml.stats()["scrapes"] == 2
 
 
-def test_sample_wall_guard_caps_scrape_rate():
+def test_sample_wall_guard_caps_scrape_rate(monkeypatch):
     # a VirtualClock soak advances hundreds of virtual seconds per wall
     # second; the wall guard must keep that from becoming dozens of
-    # scrapes (values are volatile wall facts — skipping loses nothing)
-    ml = MemLedger(interval_s=5.0, min_wall_s=3600.0)
-    ml.register("p", lambda: {"bytes": 1})
-    assert ml.sample(0.0) is True
-    assert ml.sample(1000.0) is False     # wall guard, not interval
-    assert ml.stats()["scrapes"] == 1
+    # scrapes (values are volatile wall facts — skipping loses nothing).
+    # Second pass: perf_counter() as a host up for 12.5 s reads it, under
+    # min_wall_s; the first sample is taken all the same
+    for wall in (None, 12.5):
+        if wall is not None:
+            monkeypatch.setattr(memledger.time, "perf_counter",
+                                lambda: wall)
+        ml = MemLedger(interval_s=5.0, min_wall_s=3600.0)
+        ml.register("p", lambda: {"bytes": 1})
+        assert ml.sample(0.0) is True
+        assert ml.sample(1000.0) is False     # wall guard, not interval
+        assert ml.stats()["scrapes"] == 1
 
 
 def test_register_is_last_write_wins_and_unregister_drops():

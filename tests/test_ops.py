@@ -418,11 +418,12 @@ class TestReviewRegressions:
     def test_bulk_kernel_rejects_over_capacity_unrequested_dim(self):
         # A node over capacity in a dimension the task group does NOT
         # request (e.g. disk after a shrunk re-registration) must be
-        # infeasible in the bulk rounds kernel, matching capacity_fit's
-        # all-dims check in the exact scan kernel.
+        # infeasible in the water-fill rounds (the flat multi-eval kernel,
+        # one item), matching capacity_fit's all-dims check in the exact
+        # scan kernel.
         import jax.numpy as jnp
-        from nomad_tpu.ops.select import (PlacementInputs, place_bulk_jit,
-                                          place_jit)
+        from nomad_tpu.ops.select import (MultiEvalInputs, PlacementInputs,
+                                          place_jit, place_multi_packed_jit)
 
         n, p = 8, 64
         attrs = np.zeros((n, 4), np.int32)
@@ -453,6 +454,19 @@ class TestReviewRegressions:
             job_count0=jnp.zeros(n, jnp.int32),
             spread_algo=jnp.asarray(False),
         )
-        for picks in (np.asarray(place_jit(inp).picks),
-                      np.asarray(place_bulk_jit(inp, 32).picks)):
-            assert (picks != 0).all(), picks
+        assert (np.asarray(place_jit(inp).picks) != 0).all()
+        # the same inputs as a wave of one item: two rounds of 32
+        multi = MultiEvalInputs(
+            attrs=inp.attrs, cap=inp.cap, used0=inp.used0, elig=inp.elig,
+            luts=inp.luts, base_mask=jnp.ones((1, n), bool), con=inp.con,
+            u_mask=jnp.zeros(1, jnp.int32), aff=inp.aff, req=inp.req,
+            desired=inp.desired, dh_limit=inp.dh_limit,
+            g_static=jnp.zeros(1, jnp.int32), g_aff=jnp.zeros(1, jnp.int32),
+            g_job=jnp.zeros(1, jnp.int32),
+            job_count0=inp.job_count0[None, :], spread_algo=inp.spread_algo,
+            round_g=jnp.zeros(2, jnp.int32),
+            round_want=jnp.full(2, 32, jnp.int32))
+        fills = np.asarray(place_multi_packed_jit(multi, 32)[0])[:, :32]
+        rows, counts = fills >> 11, fills & 2047
+        assert counts.sum() == p          # node 0 refused, 7 nodes hold 64
+        assert (rows[counts > 0] != 0).all(), fills

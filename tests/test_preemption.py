@@ -140,8 +140,7 @@ class TestDevicePreemptParity:
 
     def test_device_matches_host_eviction_sets(self):
         """Force both implementations on the same snapshot and compare."""
-        import numpy as np
-        from nomad_tpu.ops import PlacementEngine
+        from nomad_tpu.ops import PlacementEngine, PlacementRequest
 
         h = self._cluster()
         snap = h.snapshot()
@@ -159,8 +158,9 @@ class TestDevicePreemptParity:
             else:
                 # disable the device path: force the host Preemptor
                 eng.PREEMPT_DEVICE_MIN_FAILED = 10 ** 9
-            ds = eng.place(snap, hi, hi.task_groups, None,
-                           seed=3, block=(hi.task_groups[0].name, 20))
+            ds = eng.place(snap, hi, hi.task_groups,
+                           [PlacementRequest(hi.task_groups[0].name)] * 20,
+                           seed=3)
             picks = [d.node_id for d in ds]
             evs = sorted(v.id for d in ds for v in d.evictions)
             return picks, evs
@@ -177,7 +177,7 @@ class TestDevicePreemptParity:
     def test_device_evictions_minimal_and_lower_priority(self):
         """Heterogeneous bands: the kernel's evictions must still be
         strictly lower priority and exactly sufficient."""
-        from nomad_tpu.ops import PlacementEngine
+        from nomad_tpu.ops import PlacementEngine, PlacementRequest
 
         h = Harness()
         h.state.set_scheduler_config(SchedulerConfiguration(
@@ -207,8 +207,9 @@ class TestDevicePreemptParity:
         snap = h.snapshot()
         eng = PlacementEngine(mesh=False)
         eng.PREEMPT_DEVICE_MIN_NODES = 0             # force the kernel
-        ds = eng.place(snap, hi, hi.task_groups, None,
-                       seed=1, block=(hi.task_groups[0].name, 4))
+        ds = eng.place(snap, hi, hi.task_groups,
+                       [PlacementRequest(hi.task_groups[0].name)] * 4,
+                       seed=1)
         placed = sum(1 for d in ds if d.node_id is not None)
         victims = [v for d in ds for v in d.evictions]
         # every victim strictly lower priority

@@ -280,7 +280,7 @@ class TestPipelinedSerialParity:
             job.id = f"parity-{i}"
             tg = job.task_groups[0]
             tg.count = 80          # >= 64: the solo path runs the same
-            tg.tasks[0].resources.cpu = 100    # waterfill bulk kernel
+            tg.tasks[0].resources.cpu = 100    # the water-fill
             tg.tasks[0].resources.memory_mb = 64
             s.state.upsert_job(job)
             ev = mock.eval(job_id=job.id, type=job.type)
