@@ -333,10 +333,13 @@ class WaveHandle:
     """One dispatched wave: the engine's pending launch plus timing and
     chain metadata.  `pending` is whatever `engine.dispatch_batch`
     returned (a dict for a live launch, a tuple for the empty-cluster
-    sentinel, None for an empty batch)."""
+    sentinel, None for an empty batch).  `spans`: each eval's items
+    where the items name their evals (`collect` then answers an eval a
+    list, one decision a group), else None."""
     wave: int
     pending: object = None
     items: list = field(default_factory=list)
+    spans: Optional[List[Tuple[int, int]]] = None
     # (dispatch start, dispatch end) perf_counter stamps: the device
     # interval starts where the dispatch returned
     t_dispatch: Tuple[float, float] = (0.0, 0.0)
@@ -433,11 +436,19 @@ class WavePipeline:
                     int(pending["collective_bytes"])
         # flight record (core/flightrec.py): the wave's launch shape +
         # the engine/executor gauges the dispatch already computed —
-        # one merge call, nothing new measured on the hot path
-        fields: Dict[str, object] = {"items": len(items),
+        # one merge call, nothing new measured on the hot path.  `items`
+        # counts the wave's evals, `groups` the launch's items (an eval
+        # of a multi-group job is one item a group)
+        from nomad_tpu.ops.engine import eval_spans
+        named = any(it.eval_pos is not None for it in items)
+        spans = eval_spans(items) if named else None
+        fields: Dict[str, object] = {"items": len(spans or items),
+                                     "groups": len(items),
                                      "chained": used0_dev is not None,
                                      "masked_nodes": len(mask or ())}
         if isinstance(pending, dict):
+            # job-count rows of the launch: one an eval
+            fields["job_rows"] = int(pending.get("job_rows", len(items)))
             # real rounds of the launch's schedule, and the flat
             # schedule's padding to its power of two beside them
             fields["rounds"] = int(pending.get("rounds", 0))
@@ -453,16 +464,24 @@ class WavePipeline:
                     float(pending["padded_fraction"]), 6)
         FLIGHT.record_wave(wave, **fields)
         return WaveHandle(wave=wave, pending=pending, items=list(items),
-                          t_dispatch=(t0, t1))
+                          spans=spans, t_dispatch=(t0, t1))
 
     def collect(self, handle: Optional[WaveHandle]):
-        """Block on the wave's result and expand per-item decisions.
+        """Block on the wave's result and expand per-item decisions,
+        folded into one answer an eval where the items name their evals:
+        the list of that eval's decisions, one a group, in item order.
         Records the device interval (dispatch end -> kernel ready), the
         worker's own wait inside it (device_wait) and the d2h interval
         (ready -> decisions expanded) separately, so the split between
         launch in flight, wait and fetch stays visible."""
         if handle is None:
             return []
+        decisions = self._collect(handle)
+        if handle.spans is None:
+            return decisions
+        return [decisions[lo:hi] for lo, hi in handle.spans]
+
+    def _collect(self, handle: WaveHandle):
         handle.collected = True
         pending = handle.pending
         if not isinstance(pending, dict):

@@ -327,14 +327,20 @@ class Worker:
                 batch_id, batch_seq0, used_dev = chain
             else:
                 batch_id, used_dev = new_id(), None
-            items = [BatchItem(job=w[3].job, tg=w[3].tg, count=w[3].count)
-                     for _, w in prepared]
-            # per-item seeds, the SAME formula GenericScheduler.process
-            # uses at attempt 0: an eval drawing identical tie-break
-            # noise on the batched and solo paths is what makes the
-            # wave pipeline's output bit-identical to serial processing
-            seeds = [(zlib.crc32(w[0].id.encode()) & 0xFFFFFFFF) or 1
-                     for _, w in prepared]
+            # one item a group, in eval order, each naming its eval's
+            # position (the groups of one eval share its job-count row)
+            # and carrying its eval's seed: the SAME formula
+            # GenericScheduler.process uses at attempt 0 — an eval
+            # drawing identical tie-break noise on the batched and solo
+            # paths is what makes the wave pipeline's output
+            # bit-identical to serial processing
+            items, seeds = [], []
+            for pos, (_, w) in enumerate(prepared):
+                seed = (zlib.crc32(w[0].id.encode()) & 0xFFFFFFFF) or 1
+                for part in w[3].parts:
+                    items.append(BatchItem(job=w[3].job, tg=part.tg,
+                                           count=part.count, eval_pos=pos))
+                    seeds.append(seed)
             # no solo fallback for a FAILED launch (a compile the device
             # refuses, an HBM limit, a lowering bug): the solo path is
             # for evals that are not batchable, and quietly finishing
@@ -448,6 +454,7 @@ class Worker:
                 broker.extend_outstanding(
                     [(ev.id, token) for ev, token in pf["batch"]],
                     now=self.server.clock.time())
+                # an eval's answer: its groups' decisions (collect folds)
                 bds = {i: d for i, d in zip(pf["prepared_idx"], decisions)}
 
             # cross-batch prefetch: with this batch fully coupled and
@@ -514,7 +521,8 @@ class Worker:
             ev, token, sched, prep = work[i]
             try:
                 sched.last_port_carve = 0
-                if port_view is None and asks_ports(prep.tg):
+                if port_view is None and any(asks_ports(p.tg)
+                                             for p in prep.parts):
                     port_view = self.server.state.snapshot()
                 with trace_scope(ev.trace_id), \
                         self.pipeline.materialize(wave):

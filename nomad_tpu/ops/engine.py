@@ -204,13 +204,32 @@ class PlacementRequest:
 
 @dataclass
 class BatchItem:
-    """One eval's placement block inside a multi-eval batch: `count`
-    fresh placements of `tg` for `job` (the batch-eligible shape the
-    worker's batched path prepares — reconcile produced exactly one
-    PlaceBlock and nothing else)."""
+    """One task group's placement block inside a multi-eval batch:
+    `count` fresh placements of `tg` for `job` (the batch-eligible shape
+    the worker's batched path prepares).  `eval_pos` names the eval the
+    item belongs to: consecutive items of one eval (the groups of a
+    multi-group job) share ONE job-count row of the launch, so each
+    group's anti-affinity counts its sibling groups' picks made earlier
+    in the launch, as the solo scan counts the job's.  None: an eval of
+    its own."""
     job: Job
     tg: TaskGroup
     count: int
+    eval_pos: Optional[int] = None
+
+
+def eval_spans(items) -> List[Tuple[int, int]]:
+    """[lo, hi) of each eval's items in a batch: consecutive items that
+    name one `eval_pos` are the groups of that eval; an item that names
+    none is an eval of its own."""
+    spans: List[Tuple[int, int]] = []
+    for i, it in enumerate(items):
+        if (spans and it.eval_pos is not None
+                and it.eval_pos == items[i - 1].eval_pos):
+            spans[-1] = (spans[-1][0], i + 1)
+        else:
+            spans.append((i, i + 1))
+    return spans
 
 
 @dataclass
@@ -1849,6 +1868,7 @@ class PlacementEngine:
                 "spans": aux["spans"], "counts": aux["counts"], "rs": rs,
                 "item_rs": aux["item_rs"], "rounds": aux["rounds"],
                 "rounds_padded": aux["rounds_padded"],
+                "job_rows": aux["job_rows"],
                 "t": aux["t"], "ctxs": aux["ctxs"], "n": aux["n"],
                 "npad": aux["npad"], "node_version": aux["t"].version,
                 "perm": aux["perm"], "fills_full": fills_full,
@@ -1932,12 +1952,18 @@ class PlacementEngine:
                     f"per-item seeds: got {len(seeds)} for {G} items")
             seed_g = np.zeros(g_pad, np.uint32)
             seed_g[:G] = seeds
+        # job-count rows: the items of one eval share the eval's row and
+        # its job context, built once
+        spans = eval_spans(items)
+        n_jobs = len(spans)
+        job_of = [j for j, (lo, hi) in enumerate(spans) for _ in range(lo, hi)]
         tgts = []
         ctxs = []
-        for it in items:
+        for gi, it in enumerate(items):
             tgts.append(self.packer.lower_task_groups(
                 it.job, [it.tg], snapshot=snapshot))
-            ctxs.append(self.packer.job_context(it.job, snapshot, t))
+            ctxs.append(ctxs[-1] if gi and job_of[gi] == job_of[gi - 1]
+                        else self.packer.job_context(it.job, snapshot, t))
         # pad the constraint/affinity row axes to a pow2 ladder so mixed
         # batches land on a handful of compiled shapes
         c_max = _pad_pow2(max(tt.con.shape[1] for tt in tgts), lo=1)
@@ -2004,6 +2030,8 @@ class PlacementEngine:
                 aff_keys[akey] = ai
                 aff_rows.append(aff_row)
             g_aff[gi] = ai
+            if gi and job_of[gi] == job_of[gi - 1]:
+                continue                # the eval's row is seeded once
             jc = (ctx.job_count if jc_back is None
                   else np.maximum(ctx.job_count - jc_back, 0))
             if jc.any():
@@ -2083,9 +2111,11 @@ class PlacementEngine:
         cand_rows = cand_valid = cand_dev = None
         luts = tgts[-1].luts      # the most complete LUT matrix
         # (a wave that holds a spread item or a static port ask keeps the
-        # flat schedule: the laned kernel carries neither state)
+        # flat schedule: the laned kernel carries neither state; nor
+        # does it carry a count row from one item to the next, so a wave
+        # whose evals hold several groups keeps it too)
         if (n_real > 1 and len(static_con) > 1 and spread is None
-                and ports is None):
+                and ports is None and n_jobs == G):
             weights = [0] * len(static_con)
             for r_idx in range(n_real):
                 weights[int(g_static[round_g[r_idx]])] += 1
@@ -2156,9 +2186,12 @@ class PlacementEngine:
         # per-job alloc-count seeds.  Compact path: a tiny [J', Nc] table
         # (row 0 = zeros shared by every fresh job; one gathered row per
         # job with live allocs) — the kernel gathers L rows per step.
-        # Flat path: device zeros [G, N] + a scatter of only the nonzero
-        # jobs (fresh jobs upload nothing).  The old [G, N] table cost a
-        # 76ms gather of mostly zeros per launch at bench scale.
+        # Flat path: device zeros [J, N], a row an eval (the groups of one
+        # eval share it: the kernel's `same_r` carries the count from
+        # group to group, as their rounds are consecutive), + a scatter of
+        # only the nonzero jobs (fresh jobs upload nothing).  The old
+        # [G, N] table cost a 76ms gather of mostly zeros per launch at
+        # bench scale.
         if cand_rows is not None:
             g_job = np.zeros(g_pad, np.int32)
             jrows = [np.zeros(cand_rows.shape[:-2] + (nc,), np.int32)]
@@ -2177,12 +2210,17 @@ class PlacementEngine:
             jc0 = jnp.asarray(jc0)
             g_job_dev = jnp.asarray(g_job)
         else:
-            jc0 = jnp.zeros((g_pad, npad), jnp.int32)
+            jc0 = jnp.zeros((_pad_pow2(n_jobs, lo=1), npad), jnp.int32)
             if jc_nz_idx:
-                jc0 = jc0.at[
-                    jnp.asarray(np.array(jc_nz_idx, np.int32))].set(
+                jc0 = jc0.at[jnp.asarray(np.array(
+                    [job_of[gi] for gi in jc_nz_idx], np.int32))].set(
                     jnp.asarray(_pad_cols(np.stack(jc_nz_rows), npad)))
-            g_job_dev = jnp.arange(g_pad, dtype=jnp.int32)
+            if n_jobs == G:             # a row an item: the identity
+                g_job_dev = jnp.arange(g_pad, dtype=jnp.int32)
+            else:
+                g_job = np.zeros(g_pad, np.int32)
+                g_job[:G] = job_of
+                g_job_dev = jnp.asarray(g_job)
 
         luts_dev = self._dev_const(
             ("luts", self.packer.lut_epoch, luts.shape), lambda: luts)
@@ -2215,7 +2253,7 @@ class PlacementEngine:
                 "ports": ports and (ports[0], ports[3]),
                 "port_asks": port_asks, "carried_ports": carried_ports,
                 "item_rs": item_rs, "rounds": n_real, "rounds_padded": pad_r,
-                "t": t, "ctxs": ctxs, "n": n, "npad": npad, "t0": t0,
+                "job_rows": n_jobs, "t": t, "ctxs": ctxs, "n": n, "npad": npad, "t0": t0,
                 "n_lanes": n_lanes, "perm": perm, "chained": chained,
                 "cand_rows": cand_rows, "cand_valid": cand_valid,
                 "cand_dev": cand_dev}

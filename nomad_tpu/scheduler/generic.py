@@ -84,6 +84,13 @@ DEVICE_BATCHED = True
 # needs, not a switch: nothing sets it.
 SPREAD_WAVE_MAX = 64
 
+# Several task groups of one eval ride the wave: one launch
+# item a group, the eval's groups sharing its job-count row, one plan an
+# eval.  False sends every eval whose placements name two or more groups
+# down the solo path (rule `off`): the reference tests/test_groups_wave.py
+# compares against.  A module constant that only tests set.
+MULTI_GROUP_WAVE = True
+
 # Shared engines so packed node tensors + jit caches persist across evals
 # of one in-process scheduler session (the worker wires its own).  Keyed
 # by the backing store's identity: two Harness/Server instances in one
@@ -221,19 +228,28 @@ class GenericScheduler(Scheduler):
 
     # ------------------------------------------------------- batched path
 
-    class BatchPrep:
-        """One batch-eligible eval's reconcile output: `count` fresh
+    class BatchPart:
+        """One task group of a batch-eligible eval: `count` fresh
         placements of `tg` — either a compact PlaceBlock (count >= 64)
-        or a list of fresh PlaceRequests (small evals, THE case the
+        or a list of fresh PlaceRequests (small groups, THE case the
         multi-eval launch amortizes)."""
-        __slots__ = ("job", "tg", "count", "block", "places", "results")
+        __slots__ = ("tg", "count", "block", "places")
 
-        def __init__(self, job, tg, count, block, places, results):
-            self.job = job
+        def __init__(self, tg, count, block, places):
             self.tg = tg
             self.count = count
             self.block = block
             self.places = places
+
+    class BatchPrep:
+        """One batch-eligible eval's reconcile output: its fresh
+        placements as one BatchPart a task group, in the job's group
+        order (one item each of the wave's launch)."""
+        __slots__ = ("job", "parts", "results")
+
+        def __init__(self, job, parts, results):
+            self.job = job
+            self.parts = parts
             self.results = results
 
     def prepare_batch(self, evaluation: Evaluation):
@@ -241,16 +257,18 @@ class GenericScheduler(Scheduler):
         nomad/worker.go runs one eval per goroutine; here compatible
         evals share ONE device launch): run the reconcile phase only and
         decide whether this eval is the batchable shape — ONLY fresh
-        placements of one task group and nothing else (no stops, updates,
-        reschedules, deployment activity) and no distinct_property (the
-        exact scan kernel's per-placement state).  A device ask rides
-        under `_device_batch_refusal`'s rules, a spread stanza under
-        `_spread_batch_refusal`'s, a port ask under
-        `_port_batch_refusal`'s.  Returns a BatchPrep or None (caller
-        processes the eval through the normal path); a job with a spread
-        stanza that is refused counts itself, by rule, in
-        `nomad.spread.evals_solo`, one that asks ports in
-        `nomad.ports.evals_solo`."""
+        placements, of one task group or several, and nothing else (no
+        stops, updates, reschedules, deployment activity) and no
+        distinct_property (the exact scan kernel's per-placement state).
+        A device ask rides under `_device_batch_refusal`'s rules, a
+        spread stanza under `_spread_batch_refusal`'s, a port ask under
+        `_port_batch_refusal`'s; an eval of several groups with any of
+        the three stays solo (rule `groups_state`).  Returns a BatchPrep
+        or None (caller processes the eval through the normal path); a
+        job with a spread stanza that is refused counts itself, by rule,
+        in `nomad.spread.evals_solo`, one that asks ports in
+        `nomad.ports.evals_solo`, one of several groups in
+        `nomad.groups.evals_solo`."""
         if evaluation.annotate_plan:
             return None          # dry-run diffs ride the normal path
         state = self.state
@@ -264,6 +282,8 @@ class GenericScheduler(Scheduler):
                 REGISTRY.inc("nomad.spread.evals_solo", rule=rule)
             if any(asks_ports(tg) for tg in job.task_groups):
                 REGISTRY.inc("nomad.ports.evals_solo", rule=rule)
+            if len(job.task_groups) > 1:
+                REGISTRY.inc("nomad.groups.evals_solo", rule=rule)
         return prep
 
     def _prepare_batch(self, evaluation: Evaluation, job: Job):
@@ -280,32 +300,53 @@ class GenericScheduler(Scheduler):
             return None, "reconcile"
         if results.deployment is not None or results.deployment_updates:
             return None, "deployment"
-        block = None
-        places = None
-        if len(results.place_blocks) == 1 and not results.place:
-            block = results.place_blocks[0]
-            tg = block.tg
-            count = len(block.indexes)
-        elif results.place and not results.place_blocks:
-            places = results.place
-            tg = places[0].tg
-            if any(p.tg is not tg or p.previous_alloc is not None
-                   or p.canary for p in places):
+        # one part a task group: its PlaceBlock or its fresh rows
+        # (reconcile gives a group one form or the other), in the job's
+        # group order
+        by_tg: Dict[int, GenericScheduler.BatchPart] = {}
+        for block in results.place_blocks:
+            if id(block.tg) in by_tg:
+                return None, "shape"
+            by_tg[id(block.tg)] = self.BatchPart(
+                block.tg, len(block.indexes), block, None)
+        for p in results.place:
+            if p.previous_alloc is not None or p.canary:
                 return None, "shape"   # reschedules/canaries: exact path
-            count = len(places)
-        else:
+            part = by_tg.get(id(p.tg))
+            if part is None:
+                by_tg[id(p.tg)] = part = self.BatchPart(p.tg, 0, None, [])
+            elif part.block is not None:
+                return None, "shape"
+            part.places.append(p)
+            part.count += 1
+        if not by_tg:
             return None, "shape"
-        if count < 1:
-            return None, "shape"
+        order = {id(tg): i for i, tg in enumerate(job.task_groups)}
+        parts = sorted(by_tg.values(),
+                       key=lambda part: order.get(id(part.tg), len(order)))
         from nomad_tpu.structs import OP_DISTINCT_PROPERTY
-        cons = (list(job.constraints) + list(tg.constraints)
-                + [c for task in tg.tasks for c in task.constraints])
-        if any(c.operand == OP_DISTINCT_PROPERTY for c in cons):
-            return None, "distinct_property"
-        rule = self._spread_batch_refusal(job, count)
+        from .device import tg_device_requests
+        for part in parts:
+            tg = part.tg
+            cons = (list(job.constraints) + list(tg.constraints)
+                    + [c for task in tg.tasks for c in task.constraints])
+            if any(c.operand == OP_DISTINCT_PROPERTY for c in cons):
+                return None, "distinct_property"
+        if len(parts) > 1 and not MULTI_GROUP_WAVE:
+            return None, "off"
+        if len(parts) > 1 and (job_spreads(job) or any(
+                tg_device_requests(p.tg) or asks_ports(p.tg)
+                for p in parts)):
+            # what the wave carries from one round to the next beside
+            # `used` and the job's count row (spread counts, static-port
+            # holders, carved instances) is not carried across the
+            # groups of one eval
+            return None, "groups_state"
+        # from here on one part, or several that carry none of it
+        tg = parts[0].tg
+        rule = self._spread_batch_refusal(job, parts[0].count)
         if rule:
             return None, rule
-        from .device import tg_device_requests
         dev_reqs = tg_device_requests(tg)
         if dev_reqs:
             # a device-asking group rides the wave where the kernel's
@@ -332,7 +373,7 @@ class GenericScheduler(Scheduler):
         rule = self._port_batch_refusal(tg)
         if rule:
             return None, rule
-        return self.BatchPrep(job, tg, count, block, places, results), ""
+        return self.BatchPrep(job, parts, results), ""
 
     def _port_batch_refusal(self, tg) -> str:
         """The admission rule that keeps a group with a STATIC port ask
@@ -391,25 +432,27 @@ class GenericScheduler(Scheduler):
             return "multi_group_node"
         return ""
 
-    def submit_batched(self, evaluation: Evaluation, prep, bd,
+    def submit_batched(self, evaluation: Evaluation, prep, bds,
                        coupled_batch=None, net_index_cache=None,
                        device_ledger=None, port_view=None):
         """Phase 2a of the batched path: materialize + ENQUEUE the plan
         without waiting for the applier — the worker submits a whole
         coupled chain first, so plan apply overlaps the next plan's
-        materialization.  Returns an opaque handle for finalize_batched,
-        or None when the eval needs the solo path (no decisions, or
-        preemption could still place failed picks — the batch kernel
-        never preempts).  `port_view`: the state the shared
-        `net_index_cache` is built from where it is fresher than this
-        scheduler's own snapshot (the worker's, taken as the wave's
-        materialize begins)."""
+        materialization.  `bds`: the wave's decisions for the eval, one
+        a part of `prep`; ONE plan takes every group, each in the form
+        its size gives it (a block at 64 or more, rows below).  Returns
+        an opaque handle for finalize_batched, or None when the eval
+        needs the solo path (no decisions, or preemption could still
+        place failed picks — the batch kernel never preempts).
+        `port_view`: the state the shared `net_index_cache` is built
+        from where it is fresher than this scheduler's own snapshot (the
+        worker's, taken as the wave's materialize begins)."""
         self._port_view = port_view
         from nomad_tpu.ops.preempt import preemption_enabled
         job, results = prep.job, prep.results
-        if bd is None:
+        if any(bd is None for bd in bds):
             return None
-        if ((bd.picks < 0).any()
+        if (any((bd.picks < 0).any() for bd in bds)
                 and preemption_enabled(self.state.scheduler_config(),
                                        job.type)):
             return None
@@ -419,22 +462,27 @@ class GenericScheduler(Scheduler):
         plan = Plan(eval_id=evaluation.id, priority=evaluation.priority,
                     job=job, coupled_batch=coupled_batch)
         try:
-            self._materialize_bulk(plan, job, prep.places, bd, evaluation,
-                                   results, block=prep.block,
-                                   net_idx=net_index_cache,
-                                   device_ledger=device_ledger)
+            for part, bd in zip(prep.parts, bds):
+                self._materialize_bulk(plan, job, part.places, bd,
+                                       evaluation, results, block=part.block,
+                                       net_idx=net_index_cache,
+                                       device_ledger=device_ledger)
         except BaseException:
             self._settle_carve(None)
             raise
-        if asks_ports(prep.tg):
-            from nomad_tpu.core.telemetry import REGISTRY
+        from nomad_tpu.core.telemetry import REGISTRY
+        if asks_ports(prep.parts[0].tg):
             REGISTRY.inc("nomad.ports.evals_batched")
+        if len(job.task_groups) > 1:
+            REGISTRY.inc("nomad.groups.evals_batched")
         if plan.is_no_op():
             self._settle_carve(None)
             short, nodes = self._carve_short
             if short:
                 # every row's node was short of instances at carve time
-                self._repair_refuted(evaluation, plan, nodes, short, None)
+                # (a device ask: one group)
+                self._repair_refuted(evaluation, plan, nodes,
+                                     {prep.parts[0].tg.name: short}, None)
                 return ("done", None)
             self._finalize(evaluation)
             return ("done", None)
@@ -492,15 +540,20 @@ class GenericScheduler(Scheduler):
         # never entered the plan: they re-enter as the refuted rows do
         short, short_nodes = self._carve_short
         if result is not None:
-            full, expected, actual = result.full_commit(plan)
+            full, _, _ = result.full_commit(plan)
             if not full or short:
                 if (pipeline is not None and (refuted or short)
                         and plan.alloc_blocks
                         and not plan.node_allocation
                         and evaluation.triggered_by != TRIGGER_PLAN_REFUTE):
+                    missing = result.missing_by_group(plan)
+                    if short:
+                        # a device ask's rows: one group, its block
+                        tg = plan.alloc_blocks[0].template.task_group
+                        missing[tg] = missing.get(tg, 0) + short
                     return False, self._repair_refuted(
                         evaluation, plan, refuted + list(short_nodes),
-                        expected - actual + short, pipeline)
+                        missing, pipeline)
                 # partial commit: some nodes were refuted against newer
                 # state — re-run the normal retry loop, which reconciles
                 # the committed remainder on a fresh snapshot
@@ -523,24 +576,24 @@ class GenericScheduler(Scheduler):
             ledger.settle(record, commit_index, refuted_nodes)
 
     def _repair_refuted(self, evaluation: Evaluation, plan: Plan,
-                        refuted_nodes, missing: int, pipeline
+                        refuted_nodes, missing: Dict[str, int], pipeline
                         ) -> Optional[Exception]:
         """Refute-repair (core/wavepipe.py): the applier refuted rows of
-        this eval's block against newer state.  Instead of re-running
+        this eval's blocks against newer state.  Instead of re-running
         the whole device launch, (1) the refuted nodes join the
         pipeline's mask so subsequent CHAINED dispatches — whose usage
         buffers predate the refuting write — cannot re-pick them, and
-        (2) a fresh pending eval re-places only the `missing` rows in a
-        later wave (its reconcile counts the committed remainder, so
-        nothing double-commits).  Repair evals that refute AGAIN fall
-        back to the normal retry loop (the TRIGGER_PLAN_REFUTE guard in
+        (2) a fresh pending eval re-places only the `missing` rows
+        ({task group: rows}, queued a group) in a later wave (its
+        reconcile counts the committed remainder, so nothing
+        double-commits).  Repair evals that refute AGAIN fall back to the
+        normal retry loop (the TRIGGER_PLAN_REFUTE guard in
         finalize_batched) — the repair never recurses."""
         if pipeline is not None:
             pipeline.note_refuted(refuted_nodes)
-        tg_name = (plan.alloc_blocks[0].template.task_group
-                   if plan.alloc_blocks else next(iter(self.queued_allocs)))
-        self.queued_allocs[tg_name] = (
-            self.queued_allocs.get(tg_name, 0) + missing)
+        for tg_name, n in missing.items():
+            self.queued_allocs[tg_name] = (
+                self.queued_allocs.get(tg_name, 0) + n)
         follow = Evaluation(
             namespace=evaluation.namespace,
             priority=evaluation.priority,
@@ -552,13 +605,14 @@ class GenericScheduler(Scheduler):
         self.planner.create_eval(follow)
         self._update_eval_status(
             evaluation, EVAL_STATUS_COMPLETE,
-            f"{missing} refuted placement(s) re-queued as {follow.id}")
+            f"{sum(missing.values())} refuted placement(s) re-queued as "
+            f"{follow.id}")
         return None
 
-    def process_batched(self, evaluation: Evaluation, prep, bd,
+    def process_batched(self, evaluation: Evaluation, prep, bds,
                         coupled_batch=None) -> Optional[Exception]:
         """Phase 2, synchronous form: submit + finalize in one call."""
-        handle = self.submit_batched(evaluation, prep, bd,
+        handle = self.submit_batched(evaluation, prep, bds,
                                      coupled_batch=coupled_batch)
         if handle is None:
             return self.process(evaluation)

@@ -1192,6 +1192,23 @@ class PlanResult:
                   + sum(b.count for b in self.alloc_blocks))
         return actual == expected, expected, actual
 
+    def missing_by_group(self, plan: Plan) -> Dict[str, int]:
+        """`full_commit` a task group: {group: placements the plan held
+        and this result does not}, the groups that lost none left out."""
+        def placed(node_allocation, blocks) -> Dict[str, int]:
+            out: Dict[str, int] = {}
+            for allocs in node_allocation.values():
+                for a in allocs:
+                    out[a.task_group] = out.get(a.task_group, 0) + 1
+            for b in blocks:
+                tg = b.template.task_group
+                out[tg] = out.get(tg, 0) + b.count
+            return out
+        got = placed(self.node_allocation, self.alloc_blocks)
+        return {tg: n - got.get(tg, 0) for tg, n in placed(
+            plan.node_allocation, plan.alloc_blocks).items()
+            if n > got.get(tg, 0)}
+
 
 @dataclass
 class DesiredUpdates:

@@ -434,11 +434,13 @@ class TestCells:
         with open(os.path.join(os.path.dirname(hs.__file__), os.pardir,
                                "BENCHMARK.json")) as f:
             cells = {w["name"] for w in json.load(f)["workloads"]}
-        # the four-chip cell and the ports cell came with `model_config`
-        # PRs (36, 38), which may not edit benchmark/span_cells.py: a
-        # `benchmark` PR's to list (PERF.md section 7)
+        # the four-chip cell, the ports cell and the groups cell came
+        # with `model_config` PRs, which may not edit
+        # benchmark/span_cells.py: a `benchmark` PR's to list (PERF.md
+        # section 7)
         assert set(span_cells.EXTRA) == cells - {"csi50k-drain-mesh4",
-                                                 "ports50k-drain"}
+                                                 "ports50k-drain",
+                                                 "groups50k-drain"}
         for cell, extra in span_cells.EXTRA.items():
             # a per-layer metric moves one end-to-end metric: bare names
             # in the batched path's cell alone, the cell's prefix elsewhere

@@ -835,13 +835,15 @@ def test_benchmark_lists_the_cell_and_its_metrics():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
-    assert len(cells) == 7 and sum(
+    assert len(cells) == 8 and sum(
         w["chips"] == 4 for w in cells.values()) == 1
     assert cells[CELL] == {k: load_json("workloads", CELL)[k] for k in (
         "name", "config", "traffic", "chips", "why")}
     solo = next(m for m in bench["end_to_end"]
                 if m["name"] == "solo_placed_per_s")
-    assert solo["workloads"][-1] == CELL and solo["bound"] == 0.2
+    # the cells after it came with later PRs (groups50k-drain)
+    assert solo["workloads"][-2:] == [CELL, "groups50k-drain"]
+    assert solo["bound"] == 0.2
     mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
     assert [m["name"] for m in mine] == load_json(
         "workloads", CELL)["per_layer"]
