@@ -1837,11 +1837,12 @@ class HTTPAPIServer:
 
         class Handler(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
-            # a response leaves as two writes (headers, then body) and a
-            # stream chunk as two more: under Nagle's algorithm the second
-            # waits for the peer's delayed ACK of the first, 40 ms on
-            # every request after a connection's first (measured: a
-            # DELETE over a kept-alive connection 3.4 ms, then 44 ms each)
+            # a response leaves as two writes (headers, then body), and
+            # a stream's chunks follow its headers: under Nagle's
+            # algorithm the second waits for the peer's delayed ACK of
+            # the first, 40 ms on every request after a connection's
+            # first (measured: a DELETE over a kept-alive connection
+            # 3.4 ms, then 44 ms each)
             disable_nagle_algorithm = True
 
             def log_message(self, *a):      # quiet
@@ -1971,8 +1972,9 @@ class HTTPAPIServer:
                 import time as _time
 
                 def chunk(data: bytes) -> None:
-                    self.wfile.write(f"{len(data):x}\r\n".encode())
-                    self.wfile.write(data + b"\r\n")
+                    # size line, data and CRLF as ONE write: the socket
+                    # is unbuffered, so a write is a system call
+                    self.wfile.write(b"%x\r\n%s\r\n" % (len(data), data))
                     self.wfile.flush()
 
                 try:
@@ -2011,33 +2013,35 @@ class HTTPAPIServer:
                     from_index = int((qs.get("index") or ["0"])[0])
                 except ValueError:
                     return self._respond(400, {"Error": "bad index"})
-                sub = router.server.events.subscribe(
-                    topics or None, from_index=from_index)
+                events = router.server.events
+                sub = events.subscribe(topics or None, from_index=from_index)
 
                 timers = getattr(router.server, "stage_timers", None)
 
                 def pull(timeout):
                     if sub.closed:
                         return None, True
-                    ev = sub.next(timeout=timeout)
-                    return ev, (ev is None and sub.closed)
+                    frame = sub.next_frame(timeout=timeout)
+                    return frame or None, (not frame and sub.closed)
 
-                def send(ev, chunk):
+                def send(frame, chunk):
                     # the "stream_send" stage (core/wavepipe.py): ONE
-                    # delivered event encoded and written, the wait for
-                    # it left out; the tail of every drain, on this
-                    # handler's thread and under the interpreter lock
-                    # the worker and the applier share
+                    # frame, the events of one index, encoded and
+                    # written as one line, the wait for it left out; the
+                    # tail of every drain, on this handler's thread and
+                    # under the interpreter lock the worker and the
+                    # applier share
                     with (timers.time("stream_send") if timers is not None
                           else contextlib.nullcontext()):
                         chunk(json.dumps(
-                            {"Index": ev.index,
-                             "Events": [ev.wire()]}).encode() + b"\n")
+                            {"Index": frame[0].index,
+                             "Events": [ev.wire() for ev in frame]}
+                        ).encode() + b"\n")
+                    events.note_sent(frame)
                     stamp_thread_cpu()
 
                 self._chunked_loop(
-                    pull, lambda: router.server.events.unsubscribe(sub),
-                    send)
+                    pull, lambda: events.unsubscribe(sub), send)
 
             def _monitor(self, qs: Dict[str, List[str]]) -> None:
                 """Stream the structured log ring (reference: the

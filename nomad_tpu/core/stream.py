@@ -41,6 +41,7 @@ import threading
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+from nomad_tpu.core import telemetry
 from nomad_tpu.core.fanout import EventRing
 from nomad_tpu.structs import codec
 
@@ -182,9 +183,11 @@ class Subscription:
         """Entries between this cursor and the ring head."""
         return max(self._ring.stats()["next_seq"] - self._seq, 0)
 
-    def _scan(self) -> Optional[Event]:
+    def _scan(self, index: Optional[int] = None) -> Optional[Event]:
         """Advance the cursor to the next matching event without
-        parking; None at the head.  An entry whose topic cannot expand
+        parking; None at the head.  Given `index`, None as well at the
+        first entry of another index, the cursor left on that entry
+        (nothing of it counted yet).  An entry whose topic cannot expand
         to anything this subscription named is stepped over UNEXPANDED
         (an `Evaluation` follower builds no Event for a 260-alloc block
         commit); its `cum_end`, known at append time, keeps the drop
@@ -204,6 +207,8 @@ class Subscription:
             if probe[0] == "head":
                 return None
             entry = probe[1]
+            if index is not None and entry.index != index:
+                return None
             if (self._entry_topics is None
                     or entry.topic in self._entry_topics):
                 evs = entry.expanded
@@ -235,6 +240,21 @@ class Subscription:
             return None
         return self._scan()
 
+    def next_frame(self, timeout: Optional[float] = None) -> List[Event]:
+        """The next matching event and every further matching event of
+        its index ALREADY in the ring, in ring order: what one store
+        commit produced for this subscription, delivered as one frame
+        (a batch of evals written under one index is one entry an eval).
+        Parks as `next()` does, only while it holds nothing; [] on close
+        or timeout."""
+        first = self.next(timeout)
+        if first is None:
+            return []
+        frame = [first]
+        while (ev := self._scan(first.index)) is not None:
+            frame.append(ev)
+        return frame
+
     def __iter__(self):
         while not self.closed:
             ev = self.next(timeout=0.5)
@@ -251,6 +271,10 @@ class EventBroker:
         self._lock = threading.Lock()
         self._ring = EventRing(capacity=buffer_size)
         self._subs: List[Subscription] = []
+        # written to followers: frames (one an index, a line each) and
+        # the events in them; their ratio is events per frame
+        self.frames_sent = 0
+        self.events_sent = 0
 
     # ------------------------------------------------------------- attach
 
@@ -297,6 +321,22 @@ class EventBroker:
         # wake any parked next() so the close is observed promptly
         self._ring.wake()
 
+    def note_sent(self, frame: List[Event]) -> None:
+        """A follower's handler wrote `frame` (`next_frame`) as one
+        line: `nomad.stream.frames_sent` and `events_sent` by event
+        topic (a frame that holds several topics counts once under
+        each)."""
+        topics: Dict[str, int] = {}
+        for ev in frame:
+            topics[ev.topic] = topics.get(ev.topic, 0) + 1
+        with self._lock:
+            self.frames_sent += 1
+            self.events_sent += len(frame)
+        for topic, n in topics.items():
+            telemetry.REGISTRY.inc("nomad.stream.frames_sent", topic=topic)
+            telemetry.REGISTRY.inc("nomad.stream.events_sent", n,
+                                   topic=topic)
+
     def close(self) -> None:
         """Wake and end every subscriber (server shutdown)."""
         with self._lock:
@@ -313,6 +353,7 @@ class EventBroker:
         /v1/operator/debug."""
         with self._lock:
             subs = list(self._subs)
+            frames_sent, events_sent = self.frames_sent, self.events_sent
         ring = self._ring.stats()
         return {
             "Subscribers": len(subs),
@@ -320,6 +361,8 @@ class EventBroker:
             "DroppedTotal": ring["dropped_total"],
             "EntriesSkipped": ring["entries_skipped"],
             "EntriesExpanded": ring["entries_expanded"],
+            "FramesSent": frames_sent,
+            "EventsSent": events_sent,
             "Cursors": [s.stats() for s in subs],
         }
 

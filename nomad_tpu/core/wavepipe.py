@@ -116,9 +116,10 @@ from nomad_tpu.core.telemetry import (
 #                _settle); one per eval on every path
 #   commit       applier: evaluate + state-store upsert of one plan
 #   store_upsert applier: the upsert alone (inside commit)
-#   stream_send  an HTTP handler: one event of /v1/event/stream encoded
-#                and written to its follower (api/http_server.py), the
-#                wait for the event left out
+#   stream_send  an HTTP handler: one frame of /v1/event/stream (the
+#                events of one index) encoded and written to its
+#                follower as one line (api/http_server.py), the wait
+#                for it left out
 # Worker stages other than "pass" do not nest in one another, but for
 # device_carve and port_assign (and the solo device path's redo), each
 # inside a materialize, and spread_lower and mesh_launch, inside a

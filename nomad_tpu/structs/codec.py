@@ -18,6 +18,7 @@ Used by the jobspec JSON path, the HTTP API, and the api SDK.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import typing
 from typing import Any, Dict, Optional, get_args, get_origin, get_type_hints
 
@@ -39,6 +40,7 @@ _FIELD_OVERRIDES = {
 }
 
 
+@functools.lru_cache(maxsize=None)
 def wire_name(py_name: str) -> str:
     if py_name in _FIELD_OVERRIDES:
         return _FIELD_OVERRIDES[py_name]
@@ -51,18 +53,46 @@ def _is_duration(py_name: str) -> bool:
     return py_name.endswith("_s")
 
 
+# Values that encode as themselves; their exact types (no subclass), none
+# of which is a dataclass
+_PLAIN = frozenset((str, int, float, bool, type(None)))
+
+# type -> its field plan, ((attribute, wire name, is duration), ...) in
+# field order, for a dataclass type; None for any other type.  Worked out
+# on a type's first encode: the event stream encodes every delivered
+# event's payload, and the reflection (`dataclasses.fields`, a wire name
+# split and joined) was paid per field of every one
+_PLANS: Dict[type, Optional[tuple]] = {}
+
+
+def _field_plan(tp: type) -> Optional[tuple]:
+    plan = None
+    if dataclasses.is_dataclass(tp):
+        plan = tuple((f.name, wire_name(f.name), _is_duration(f.name))
+                     for f in dataclasses.fields(tp))
+    _PLANS[tp] = plan
+    return plan
+
+
 def encode(obj: Any) -> Any:
     """Dataclass/list/dict/scalar -> JSON-safe wire value."""
-    if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
+    tp = type(obj)
+    if tp in _PLAIN:
+        return obj
+    try:
+        plan = _PLANS[tp]
+    except KeyError:
+        plan = _field_plan(tp)
+    if plan is not None:
         out: Dict[str, Any] = {}
-        for f in dataclasses.fields(obj):
-            v = getattr(obj, f.name)
+        for attr, name, duration in plan:
+            v = getattr(obj, attr)
             if v is None:
                 continue
-            if _is_duration(f.name) and isinstance(v, (int, float)):
-                out[wire_name(f.name)] = int(v * 1e9)
+            if duration and isinstance(v, (int, float)):
+                out[name] = int(v * 1e9)
             else:
-                out[wire_name(f.name)] = encode(v)
+                out[name] = v if type(v) in _PLAIN else encode(v)
         return out
     if isinstance(obj, dict):
         return {k: encode(v) for k, v in obj.items()}

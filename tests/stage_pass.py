@@ -8,6 +8,7 @@ stages.  One follower reads `/v1/event/stream?topic=Evaluation` over
 HTTP throughout, as the benchmark's client does."""
 
 import http.client
+import json
 import threading
 import time
 from types import SimpleNamespace
@@ -15,6 +16,7 @@ from types import SimpleNamespace
 from nomad_tpu import mock
 from nomad_tpu.api.http_server import HTTPAPIServer
 from nomad_tpu.core.server import Server
+from nomad_tpu.core.telemetry import REGISTRY
 from nomad_tpu.structs import Spread, SpreadTarget
 
 N_BATCHED, N_SOLO, N_SYSTEM = 6, 1, 1
@@ -56,6 +58,7 @@ def run_small_pass(between=None, rounds=1):
     api = HTTPAPIServer(SimpleNamespace(server=s))
     api.start()
     s.stream_lines = []
+    sent_before = _evals_sent()
     follower = _follow(api.addr, s.stream_lines)
     now = time.time()
     for i in range(24):
@@ -104,7 +107,19 @@ def run_small_pass(between=None, rounds=1):
         follower.join(timeout=30)
         api.shutdown()
     assert not follower.is_alive()
+    # what the process counted on /v1/metrics while the follower read
+    s.stream_events_sent = _evals_sent() - sent_before
     return s
+
+
+def _evals_sent() -> float:
+    return REGISTRY.counter("nomad.stream.events_sent", topic="Evaluation")
+
+
+def stream_events(lines) -> list:
+    """The events the follower's lines held, in order (a line is one
+    frame: the events of one index)."""
+    return [ev for line in lines for ev in json.loads(line)["Events"]]
 
 
 def _register_batched(server, now: float) -> None:
@@ -121,7 +136,8 @@ def _register_batched(server, now: float) -> None:
 def _drain_stream(server, evals: int, timeout_s: float = 60.0) -> None:
     """Until the follower has read every eval's `complete`."""
     deadline = time.monotonic() + timeout_s
-    while sum(b'"complete"' in ln for ln in server.stream_lines) < evals:
+    while sum((ev["Payload"] or {}).get("Status") == "complete"
+              for ev in stream_events(list(server.stream_lines))) < evals:
         if time.monotonic() > deadline:
             raise AssertionError(
                 f"{len(server.stream_lines)} stream lines, fewer than "

@@ -2,8 +2,10 @@
 watches, the cursor-based event ring, and follower-served reads
 (reference: blockingRPC + nomad/stream/event_buffer.go + stale reads)."""
 
+import json
 import threading
 import time
+import urllib.request
 
 import pytest
 
@@ -18,6 +20,7 @@ from nomad_tpu.core.telemetry import REGISTRY
 from nomad_tpu.state.state_store import StateStore
 from nomad_tpu.structs import (AllocBlock, Deployment, Node, PlanResult,
                                codec)
+from test_codec import reference_encode
 
 
 def _wait(fn, timeout=30, period=0.1):
@@ -409,6 +412,180 @@ def test_event_topics_table_in_lockstep(case):
 
 
 # ---------------------------------------------------------------------------
+# Frames: what one store commit produced, as one delivery (next_frame)
+# ---------------------------------------------------------------------------
+
+
+def _framed_commits():
+    """Commits where several entries share an index, as a batched
+    `upsert_evals` writes them: one entry an eval, under ONE index."""
+    evals = [mock.eval(status="complete", job_id=f"job-{i}")
+             for i in range(4)]
+    return [
+        ("Evaluation", 3, evals[0]),
+        ("Evaluation", 3, _BLOCKED_EVAL),
+        ("AllocBlock", 3, AllocBlock(id="blk", ids=list(_BLOCK_IDS[:5]))),
+        ("Evaluation", 3, evals[1]),
+        ("Evaluation", 4, evals[2]),
+        ("Allocations", 5, list(_ROW_ALLOCS)),
+        ("Evaluation", 5, evals[3]),
+        ("Job", 6, ("default", "job-gone")),
+        ("HealthBreach", 6, _BREACH),
+        ("Evaluation", 7, _COMPLETE_EVAL),
+    ]
+
+
+def _frames(sub):
+    out = []
+    while True:
+        frame = sub.next_frame(timeout=0.05)
+        if not frame:
+            return out
+        out.append([(ev.topic, ev.type, ev.key, ev.index, ev.payload)
+                    for ev in frame])
+
+
+def _reference_frames(events):
+    """The events of one index, in ring order, as one frame."""
+    frames = []
+    for ev in events:
+        if frames and frames[-1][0][3] == ev[3]:
+            frames[-1].append(ev)
+        else:
+            frames.append([ev])
+    return frames
+
+
+class TestNextFrame:
+    @pytest.mark.parametrize("name", sorted(_SUBSCRIPTIONS))
+    def test_a_frame_is_one_index_of_what_next_delivers(self, name):
+        """Key filters and topic skips as `next()` has them, event for
+        event; frames break exactly where the index changes."""
+        topics, _ = _SUBSCRIPTIONS[name]
+        commits = _framed_commits()
+        broker = EventBroker()
+        by_next = broker.subscribe(topics, from_index=0)
+        by_frame = broker.subscribe(topics, from_index=0)
+        _publish(broker, commits)
+        want = _reference_events(commits, topics)
+        assert _drain(by_next) == want
+        frames = _frames(by_frame)
+        assert frames == _reference_frames(want)
+        assert [ev for frame in frames for ev in frame] == want
+        for a, b in zip(frames, frames[1:]):
+            assert a[0][3] != b[0][3]
+        broker.close()
+
+    @pytest.mark.parametrize("name", ["evaluation", "allocation_key",
+                                      "job_and_evaluation", "wildcard"])
+    def test_replay_from_index(self, name):
+        topics, _ = _SUBSCRIPTIONS[name]
+        commits = _framed_commits()
+        broker = EventBroker()
+        _publish(broker, commits)
+        for after in (0, 2, 3, 4, 6):
+            want = _reference_events(commits, topics, after_index=after)
+            assert (_drain(broker.subscribe(topics, from_index=after))
+                    == want)
+            assert (_frames(broker.subscribe(topics, from_index=after))
+                    == _reference_frames(want))
+        broker.close()
+
+    def test_consecutive_indexes_are_separate_frames(self):
+        broker = EventBroker()
+        store = StateStore()
+        broker.attach(store)
+        sub = broker.subscribe({"Evaluation": ["*"]})
+        first = store.upsert_evals([mock.eval(), mock.eval()])
+        second = store.upsert_evals([mock.eval()])
+        frames = _frames(sub)
+        assert [[ev[3] for ev in f] for f in frames] == [
+            [first, first], [second]]
+        broker.close()
+
+    def test_never_waits_for_what_is_not_appended(self):
+        """A frame holds what the ring held when it was taken: it
+        returns at once with what it has, and an event of the same
+        index appended later is the next frame's."""
+        broker = EventBroker()
+        sub = broker.subscribe({"Evaluation": ["*"]})
+        _publish(broker, [("Evaluation", 9, _COMPLETE_EVAL),
+                          ("Evaluation", 9, _BLOCKED_EVAL)])
+        t0 = time.perf_counter()
+        frame = sub.next_frame(timeout=5)
+        assert time.perf_counter() - t0 < 1.0
+        assert [ev.payload for ev in frame] == [_COMPLETE_EVAL,
+                                                _BLOCKED_EVAL]
+        late = mock.eval(status="complete")
+        _publish(broker, [("Evaluation", 9, late)])
+        assert [ev.payload for ev in sub.next_frame(timeout=1)] == [late]
+        # a parked frame wakes with the append that ends its wait
+        got = []
+        t = threading.Thread(
+            target=lambda: got.append(sub.next_frame(timeout=10)),
+            daemon=True)
+        t.start()
+        time.sleep(0.1)
+        _publish(broker, [("Evaluation", 10, _COMPLETE_EVAL)])
+        t.join(timeout=5)
+        assert not t.is_alive()
+        assert [ev.index for ev in got[0]] == [10]
+        assert sub.next_frame(timeout=0.05) == []
+        broker.close()
+
+    def test_drop_ledger_as_next_counts_it(self):
+        """A cursor lagging off a small ring loses, and counts, the same
+        events read by frames as read one at a time."""
+        cap = 4
+        broker = EventBroker(buffer_size=cap)
+        before = REGISTRY.counter("nomad.stream.dropped")
+        by_next = broker.subscribe()
+        by_frame = broker.subscribe()
+        commits = [("Evaluation", 2 + i // 3,
+                    _BLOCKED_EVAL if i % 4 == 1 else _COMPLETE_EVAL)
+                   for i in range(11)]
+        commits.insert(5, ("AllocBlock", 3,
+                           AllocBlock(id="b", ids=list(_BLOCK_IDS))))
+        _publish(broker, commits)
+        events = _drain(by_next)
+        frames = _frames(by_frame)
+        assert [ev for frame in frames for ev in frame] == events
+        assert frames == _reference_frames(events)
+        assert by_next.dropped == by_frame.dropped > 260
+        assert by_frame.stats()["Dropped"] == by_frame.dropped
+        assert (REGISTRY.counter("nomad.stream.dropped") - before
+                == 2 * by_frame.dropped)
+        broker.close()
+
+    def test_note_sent_counts_frames_and_events_by_topic(self):
+        broker = EventBroker()
+        sub = broker.subscribe()
+        _publish(broker, _framed_commits())
+        before = {(name, topic): REGISTRY.counter(
+                      f"nomad.stream.{name}", topic=topic)
+                  for name in ("frames_sent", "events_sent")
+                  for topic in ("Evaluation", "Allocation")}
+        frames = []
+        while frame := sub.next_frame(timeout=0.05):
+            broker.note_sent(frame)
+            frames.append(frame)
+        st = broker.stats()
+        assert st["FramesSent"] == len(frames) == 5
+        assert st["EventsSent"] == sum(map(len, frames))
+
+        def moved(name, topic):
+            return (REGISTRY.counter(f"nomad.stream.{name}", topic=topic)
+                    - before[name, topic])
+        # index 3's frame holds evaluations, a placement failure and five
+        # allocations: it counts once under each topic it holds
+        assert moved("frames_sent", "Evaluation") == 4
+        assert moved("events_sent", "Evaluation") == 6
+        assert moved("frames_sent", "Allocation") == 2
+        assert moved("events_sent", "Allocation") == 5 + len(_ROW_ALLOCS)
+        broker.close()
+
+
+# ---------------------------------------------------------------------------
 # HTTP plane: hub-backed blocking + columnar lists
 # ---------------------------------------------------------------------------
 
@@ -494,6 +671,74 @@ class TestHTTPFanout:
         for i, aid in enumerate(cols["ID"]):
             assert cols["Name"][i] == by_id[aid]["Name"]
             assert cols["JobID"][i] == by_id[aid]["JobID"]
+
+    def test_event_stream_writes_one_line_an_index(self, agent):
+        """A 16-eval `upsert_evals` is one index: a follower reads it as
+        ONE line whose events are, byte for byte, what the one-event
+        lines of the reflective encode held, `Index` included."""
+        state = agent.server.state
+        evals = [mock.eval(status="complete", job_id=f"frame-job-{i}")
+                 for i in range(16)]
+        idx = state.upsert_evals(evals)
+        head = b'{"Index": %d, "Events": [' % idx
+        one_event_lines = [json.dumps(
+            {"Index": idx,
+             "Events": [{"Topic": "Evaluation",
+                         "Type": "EvaluationUpdated", "Key": e.id,
+                         "Index": idx,
+                         "Payload": reference_encode(
+                             state.eval_by_id(e.id))}]}).encode()
+            for e in evals]
+        assert all(ln.startswith(head) and ln.endswith(b"]}")
+                   for ln in one_event_lines)
+        url = (f"{agent.address}/v1/event/stream?topic=Evaluation"
+               f"&index={idx - 1}")
+        with urllib.request.urlopen(url, timeout=30) as resp:
+            line = resp.readline()
+        assert line == head + b", ".join(
+            ln[len(head):-2] for ln in one_event_lines) + b"]}\n"
+
+    def test_event_stream_live_frames_counted(self, agent):
+        """Live: every line one index, the events in commit order, and
+        the frames and events written on /v1/metrics by topic."""
+        state, events = agent.server.state, agent.server.events
+
+        def counted():
+            with urllib.request.urlopen(agent.address + "/v1/metrics",
+                                        timeout=30) as r:
+                flat = json.load(r)
+            return [flat.get(f"nomad.stream.{name}{{topic=Evaluation}}", 0)
+                    for name in ("frames_sent", "events_sent")]
+
+        frames_before, events_before = counted()
+        subs = len(events._subs)
+        url = (f"{agent.address}/v1/event/stream?topic=Evaluation"
+               f"&index={state.latest_index()}")
+        evals = [mock.eval(status="complete", job_id=f"live-job-{i}")
+                 for i in range(16)]
+        mine = {e.id for e in evals}
+        lines = []
+        with urllib.request.urlopen(url, timeout=30) as resp:
+            assert _wait(lambda: len(events._subs) == subs + 1, timeout=5)
+            idx = state.upsert_evals(evals)
+            got = []
+            while len(got) < len(evals):
+                line = resp.readline()
+                frame = json.loads(line)
+                if not frame:
+                    continue                # an idle heartbeat
+                lines.append(frame)
+                got += [ev for ev in frame["Events"] if ev["Key"] in mine]
+        assert [ev["Key"] for ev in got] == [e.id for e in evals]
+        assert {ev["Index"] for ev in got} == {idx}
+        for frame in lines:
+            assert {ev["Index"] for ev in frame["Events"]} == {
+                frame["Index"]}
+        frames_after, events_after = _wait(
+            lambda: (c := counted())[1] - events_before >= 16 and c)
+        assert frames_after - frames_before >= len(lines)
+        assert events_after - events_before >= sum(
+            len(f["Events"]) for f in lines)
 
     def test_debug_bundle_has_fanout_sections(self, api):
         dbg = api.request("GET", "/v1/operator/debug")

@@ -12,6 +12,7 @@ The pipelining contract, proven rather than asserted:
     IDENTICAL final state-store contents for the same eval batch.
 """
 
+import json
 import random
 
 import numpy as np
@@ -649,6 +650,22 @@ class TestPassStages:
         # the follower read every eval's updates; each line was one span
         assert (small_pass.stage_timers.counts()["stream_send"]
                 == len(small_pass.stream_lines) >= 8)
+
+    def test_events_sent_counts_every_event_delivered(self, small_pass):
+        """A line is a frame, the events of one index: the broker counts
+        the frames and the events in them, and `/v1/metrics`'s
+        `nomad.stream.events_sent` the same events."""
+        from stage_pass import stream_events
+        lines = small_pass.stream_lines
+        events = stream_events(lines)
+        stats = small_pass.events.stats()
+        assert stats["FramesSent"] == len(lines)
+        assert (stats["EventsSent"] == small_pass.stream_events_sent
+                == len(events) > len(lines))
+        for line in lines:
+            frame = json.loads(line)
+            assert {ev["Index"] for ev in frame["Events"]} == {
+                frame["Index"]}
 
     def test_stages_account_for_the_pass(self, small_pass):
         totals = small_pass.stage_timers.totals()
