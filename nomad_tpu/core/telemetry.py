@@ -47,12 +47,16 @@ LabelKey = Tuple[str, Tuple[Tuple[str, str], ...]]
 
 def _module_counters() -> Dict[LabelKey, float]:
     """Counters kept as plain module ints by code that may not import
-    core/ (structs/ sits below it), and the process runtime's below
-    (thread CPU, the collector), which nothing on a hot path may lock:
-    read here, at scrape time."""
+    core/ (structs/ sits below it) or counts under the store's lock
+    (state/), and the process runtime's below (thread CPU, the
+    collector), which nothing on a hot path may lock: read here, at
+    scrape time."""
+    from nomad_tpu.state.state_store import SLOT_CHECKS
     from nomad_tpu.structs.structs import ID_STATS
     out: Dict[LabelKey, float] = {
         (f"nomad.ids.{k}", ()): v for k, v in ID_STATS.items()}
+    for how, n in SLOT_CHECKS.items():
+        out[("nomad.store.slot_checks", (("how", how),))] = n
     for role, cpu_s in thread_cpu_by_role().items():
         out[("nomad.runtime.thread_cpu_s", (("role", role),))] = cpu_s
     for gen in range(3):

@@ -68,6 +68,31 @@ def _entry_cost(entry: tuple) -> int:
     return 64 + sys.getsizeof(entry) + sys.getsizeof(entry[2])
 
 
+# nomad.store.slot_checks{how=none_held|intersected}: the name-slot
+# refute's checks, one a block and one a group of rows.  Plain ints
+# written under the store lock and read at scrape time
+# (core/telemetry.py _module_counters): no telemetry work under the lock.
+SLOT_CHECKS = {"none_held": 0, "intersected": 0}
+
+
+def _name_slot(name: str) -> Tuple[str, object]:
+    """An allocation name as (prefix through its last `[`, index): the
+    int between that `[` and the closing `]`, as Allocation.index()
+    reads it, where the name spells that int as str() does (a block
+    row's name is `name_prefix + str(index) + "]"`); else the tail as a
+    string, which matches only a name spelled the same."""
+    cut = name.rfind("[") + 1
+    tail = name[cut:]
+    if tail[-1:] == "]":
+        try:
+            i = int(tail[:-1])
+        except ValueError:
+            return name[:cut], tail
+        if str(i) == tail[:-1]:
+            return name[:cut], i
+    return name[:cut], tail
+
+
 class StateStore:
     """All cluster state.  Thread-safe; single writer at a time."""
 
@@ -1220,50 +1245,80 @@ class StateStore:
         this index, so all see the same live slots.  System-family jobs
         are exempt (their allocs legitimately share name index [0]
         across nodes; their uniqueness key is the node, and the
-        per-node fit re-check covers them)."""
+        per-node fit re-check covers them).
+
+        Slots compare by integer name index, never by formatted name: a
+        job's held slots are {(group, job_version, prefix): {index:
+        holder id}}, a live block's taken whole from its `indexes` and
+        `ids` columns, a live row's name split once (`_name_slot`).  A
+        block of a group and version the job holds no slot of (every
+        fresh job's) is kept with no work a row; otherwise its indexes
+        meet the held ones by one set intersection."""
         touched = set()
         for node_allocs in result.node_update.values():
             touched.update(a.id for a in node_allocs)
         for node_allocs in result.node_preemptions.values():
             touched.update(a.id for a in node_allocs)
 
-        live_cache: Dict[Tuple[str, str], Dict[Tuple, str]] = {}
+        held_cache: Dict[Tuple[str, str], Dict[Tuple, Dict]] = {}
 
-        def live_slots(ns: str, job_id: str) -> Dict[Tuple, str]:
+        def held_slots(ns: str, job_id: str) -> Dict[Tuple, Dict]:
             key = (ns, job_id)
-            slots = live_cache.get(key)
-            if slots is not None:
-                return slots
-            slots = {}
+            held = held_cache.get(key)
+            if held is not None:
+                return held
+            held = {}
             for a in self._allocs_by_job.get(key, {}).values():
                 if (a.id in touched or a.desired_status != "run"
                         or a.client_terminal_status()):
                     continue
-                slots[(a.task_group, a.name, a.job_version)] = a.id
+                prefix, i = _name_slot(a.name)
+                held.setdefault((a.task_group, a.job_version, prefix),
+                                {})[i] = a.id
             for b in self._blocks_by_job.get(key, ()):
                 tmpl = b.template
-                for i, bid in zip(b.indexes, b.ids):
-                    if bid in touched:
-                        continue
-                    slots[(tmpl.task_group, f"{b.name_prefix}{i}]",
-                           tmpl.job_version)] = bid
-            live_cache[key] = slots
-            return slots
+                pairs = zip(b.indexes, b.ids)
+                if touched:
+                    pairs = [(i, bid) for i, bid in pairs
+                             if bid not in touched]
+                if b.name_prefix.endswith("["):
+                    held.setdefault((tmpl.task_group, tmpl.job_version,
+                                     b.name_prefix), {}).update(pairs)
+                    continue
+                for i, bid in pairs:     # a prefix no scheduler builds
+                    prefix, i = _name_slot(f"{b.name_prefix}{i}]")
+                    held.setdefault((tmpl.task_group, tmpl.job_version,
+                                     prefix), {})[i] = bid
+            held_cache[key] = held
+            return held
 
         def system_family(job) -> bool:
             return job is not None and job.type in ("system", "sysbatch")
 
+        # the rows' groups, (job, group, version), and those of them the
+        # job holds a slot of; a fresh job's rows parse no name
+        row_groups, held_groups = set(), set()
         for nid, node_allocs in list(result.node_allocation.items()):
             keep = []
             for a in node_allocs:
                 if not system_family(a.job):
-                    holder = live_slots(a.namespace, a.job_id).get(
-                        (a.task_group, a.name, a.job_version))
-                    if holder is not None and holder != a.id:
-                        continue              # replayed slot — refute
+                    group = (a.namespace, a.job_id, a.task_group,
+                             a.job_version)
+                    row_groups.add(group)
+                    held = held_slots(a.namespace, a.job_id)
+                    if held:
+                        prefix, i = _name_slot(a.name)
+                        entry = held.get((a.task_group, a.job_version,
+                                          prefix))
+                        if entry:
+                            held_groups.add(group)
+                            if entry.get(i) not in (None, a.id):
+                                continue      # replayed slot — refute
                 keep.append(a)
             if len(keep) != len(node_allocs):
                 result.node_allocation[nid] = keep
+        SLOT_CHECKS["intersected"] += len(held_groups)
+        SLOT_CHECKS["none_held"] += len(row_groups) - len(held_groups)
 
         if result.alloc_blocks:
             kept_blocks = []
@@ -1272,13 +1327,29 @@ class StateStore:
                 if system_family(tmpl.job):
                     kept_blocks.append(block)
                     continue
-                slots = live_slots(tmpl.namespace, tmpl.job_id)
-                colliding = {
-                    j for j, (i, bid) in enumerate(
-                        zip(block.indexes, block.ids))
-                    if slots.get((tmpl.task_group,
-                                  f"{block.name_prefix}{i}]",
-                                  tmpl.job_version)) not in (None, bid)}
+                held = held_slots(tmpl.namespace, tmpl.job_id)
+                group, version = tmpl.task_group, tmpl.job_version
+                if block.name_prefix.endswith("["):
+                    entry = held.get((group, version, block.name_prefix))
+                    if not entry:
+                        SLOT_CHECKS["none_held"] += 1
+                        kept_blocks.append(block)
+                        continue
+                    common = entry.keys() & block.indexes
+                    colliding = {
+                        j for j, (i, bid) in enumerate(
+                            zip(block.indexes, block.ids))
+                        if i in common and entry[i] not in (None, bid)
+                    } if common else set()
+                else:                    # a prefix no scheduler builds
+                    colliding = set()
+                    for j, (i, bid) in enumerate(
+                            zip(block.indexes, block.ids)):
+                        prefix, i = _name_slot(f"{block.name_prefix}{i}]")
+                        entry = held.get((group, version, prefix))
+                        if entry and entry.get(i) not in (None, bid):
+                            colliding.add(j)
+                SLOT_CHECKS["intersected"] += 1
                 if not colliding:
                     kept_blocks.append(block)
                     continue

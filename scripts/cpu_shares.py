@@ -14,7 +14,9 @@ scripts/gpu_ids_read.py), wraps each `module:attr.path` given (default: the
 wave's pieces), runs N drain cycles through the served path and prints per
 cycle, for each name: calls, CPU ms, wall ms, CPU us a call.  A name
 imported with `from x import f` is wrapped where it is LOOKED UP
-(`nomad_tpu.scheduler.generic:new_ids`).
+(`nomad_tpu.scheduler.generic:new_ids`).  The agent is imported only
+after the wraps are in place, so nothing its import chain binds at
+import time holds an unwrapped function.
 
     chiprun -- env PYTHONPATH=. python3 scripts/cpu_shares.py csi50k drain384
 
@@ -76,7 +78,6 @@ if args.rehearse:
     traffic.update(traffic["rehearse"])
 
 import jax  # noqa: E402
-from nomad_tpu.agent import Agent  # noqa: E402
 
 acc = {}                               # name -> [calls, cpu s, wall s]
 
@@ -102,6 +103,7 @@ def wrap(name):
 
 
 [wrap(name) for name in args.names]
+from nomad_tpu.agent import Agent  # noqa: E402  (after the wraps: see top)
 print("platform", jax.devices()[0].platform, flush=True)
 nodes, _fleet = mod.build_fleet(cfg, args.seed)
 agent = Agent(num_clients=cfg["server"]["num_clients"],
