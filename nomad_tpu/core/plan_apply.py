@@ -702,22 +702,20 @@ class PlanApplier:
                         _mark((nid,), "in-plan-dup-port")
                     claimed.add(port)
         # per-node DEVICE audit input (ISSUE 30), the same way: the
-        # plan's instance claims per node across device-carrying blocks,
-        # straight off the id columns.  An instance claimed twice within
-        # the plan refutes the node outright.
-        plan_devs: Dict[str, tuple] = {}      # node -> (group, {ids})
+        # plan's instance claims per node and device group across
+        # device-carrying blocks, straight off the id columns.  An
+        # instance claimed twice within the plan refutes the node
+        # outright.
+        plan_devs: Dict[str, Dict[tuple, set]] = {}   # node -> {group: ids}
         for b in columnar:
-            for nid, (group, ids) in b.devices_by_node().items():
-                held = plan_devs.get(nid)
-                if held is None:
-                    plan_devs[nid] = held = (group, set())
-                elif held[0] != group:
-                    _mark((nid,), "in-plan-device-group")
-                    continue
-                before = len(held[1])
-                held[1].update(ids)
-                if len(held[1]) != before + len(ids):
-                    _mark((nid,), "in-plan-dup-device")
+            for nid, by_group in b.devices_by_node().items():
+                held = plan_devs.setdefault(nid, {})
+                for group, ids in by_group.items():
+                    seen = held.setdefault(group, set())
+                    before = len(seen)
+                    seen.update(ids)
+                    if len(seen) != before + len(ids):
+                        _mark((nid,), "in-plan-dup-device")
         # per-node demand aggregated ACROSS blocks (two blocks on one
         # node were fit-checked together on the expanded path)
         total: Dict[str, List[int]] = {}
@@ -739,14 +737,13 @@ class PlanApplier:
                 continue
             claimed_dev = plan_devs.get(nid)
             if claimed_dev is not None:
-                # every claimed instance is one of the group's own (so
+                # every claimed instance is one of its group's own (so
                 # the claims, distinct by the check above, are within
-                # its count)
-                group, dev_ids = claimed_dev
-                inventory = next(
-                    (d.instance_ids for d in node.resources.devices
-                     if (d.vendor, d.type, d.name) == group), ())
-                if not dev_ids.issubset(inventory):
+                # the group's count)
+                inventory = {(d.vendor, d.type, d.name): d.instance_ids
+                             for d in node.resources.devices}
+                if not all(dev_ids.issubset(inventory.get(group, ()))
+                           for group, dev_ids in claimed_dev.items()):
                     _mark((nid,), "device-unknown")
                     continue
             if skip_fit:
@@ -774,8 +771,10 @@ class PlanApplier:
                     used_ports.add_allocs((a,))
                 if claimed_dev is not None and a.allocated_devices:
                     for ad in a.allocated_devices:
-                        if not dev_ids.isdisjoint(ad.device_ids) and (
-                                ad.vendor, ad.type, ad.name) == group:
+                        dev_ids = claimed_dev.get(
+                            (ad.vendor, ad.type, ad.name))
+                        if dev_ids and not dev_ids.isdisjoint(
+                                ad.device_ids):
                             held_dev = True
             if used_ports is not None and not claimed.isdisjoint(
                     used_ports.used_ports):

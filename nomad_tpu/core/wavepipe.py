@@ -74,6 +74,11 @@ from nomad_tpu.core.telemetry import (
 #                (ops/engine.py _lower_wave_spreads: landscapes by node
 #                table version, the jobs' expected and existing counts);
 #                lies INSIDE that wave's dispatch
+#   device_lower worker: the host lowering of a wave's device group slots
+#                (ops/engine.py _lower_wave_slots: accept tables by
+#                request signature and node table version, the slots'
+#                instances and usage); one a wave that holds a device
+#                ask, INSIDE that wave's dispatch
 #   mesh_launch  worker: the call of a wave's node-sharded program
 #                (ops/engine.py dispatch_batch, only where the engine has
 #                a mesh): the wave's replicated inputs go to every device
@@ -122,18 +127,18 @@ from nomad_tpu.core.telemetry import (
 #                for it left out
 # Worker stages other than "pass" do not nest in one another, but for
 # device_carve and port_assign (and the solo device path's redo), each
-# inside a materialize, and spread_lower and mesh_launch, inside a
-# dispatch: the unnamed part of a pass is its wall minus their UNION
-# (benchmark/host_spans.py View.named), which a nested span leaves as it
-# was; a per-stage sum must leave device_carve, port_assign, spread_lower
-# and mesh_launch out or count them twice.
+# inside a materialize, and spread_lower, device_lower and mesh_launch,
+# inside a dispatch: the unnamed part of a pass is its wall minus their
+# UNION (benchmark/host_spans.py View.named), which a nested span leaves
+# as it was; a per-stage sum must leave device_carve, port_assign,
+# spread_lower, device_lower and mesh_launch out or count them twice.
 #
 # Two more names reach the profiler's trace and are no stage (nothing
 # records them here): `nomad.gc`, a collection of generation 1 or 2 on
 # the line of the thread it struck, inside whatever stage it stretched
 # (core/telemetry.py), and `nomad.cpu`, the marker below.
 STAGES = ("dequeue", "pass", "prepare", "dispatch", "spread_lower",
-          "mesh_launch", "device", "device_wait", "d2h", "solo_place",
+          "device_lower", "mesh_launch", "device", "device_wait", "d2h", "solo_place",
           "system_place", "materialize", "device_carve", "port_assign",
           "plan_wait",
           "finalize", "batch_admin", "eval_update", "ack", "commit",

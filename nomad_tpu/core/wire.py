@@ -63,7 +63,7 @@ _EXT_NDARRAY = 3
 # field sets in scripts/analysis/wire_manifest.json and requires this
 # constant to match the manifest's version, so a silent field drift
 # cannot land).  Mixed-version peers reject frames via channel_tag AAD.
-SCHEMA_VERSION = 3
+SCHEMA_VERSION = 4
 
 _NONCE_LEN = 12
 _TS_LEN = 8

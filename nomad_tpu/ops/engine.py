@@ -20,8 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from nomad_tpu.pack.interner import UNSET
-from nomad_tpu.pack.packer import (ClusterPacker, JobContext, NodeTensors,
-                                   TGTensors, tg_static_ports)
+from nomad_tpu.pack.packer import (DEVICE_GROUP_SLOTS, ClusterPacker,
+                                   JobContext, NodeTensors, TGTensors,
+                                   tg_static_ports)
 from nomad_tpu.pack.spread import (
     SpreadTensors,
     job_spreads,
@@ -74,6 +75,24 @@ PORT_SLOTS_MIN = 4
 # launch did not ask; past it the oldest are dropped (their next ask reads
 # the state again, and the applier's re-check holds what was in flight)
 PORT_ROWS_CARRIED = 64
+
+# Device group slots: DEVICE_GROUP_SLOTS, imported above from
+# pack/packer.py, where the node tensors are sized by it (a constant
+# shape): a node's first groups in its own order, one slot each.  A wave
+# with a device ask carries, per slot, the instances and the instances
+# held (select.MultiEvalInputs dev_cap / dev_used0, slot-major [S, N]),
+# and per request signature a table of the slots it accepts: a
+# placement takes all of its instances from ONE accepted group, the
+# first with room, as scheduler/device.py assign_devices picks one.  A
+# fleet of single-group nodes launches the S = 1 form; any other fleet
+# of at most this many groups a node, S = DEVICE_GROUP_SLOTS.  What
+# benchmark/configs/mig50k.py asks the program for by name.
+#
+# The fewest accept tables a launch stacks ([Ud, S, N], Ud on the
+# power-of-two ladder from here): a drain's waves hold a handful of
+# request signatures, and one bucket for up to eight keeps them on one
+# compiled shape.
+DEVICE_SLOT_TABLES_MIN = 8
 
 # fixed-size chunks so the delta-replay scatter compiles ONCE, not once
 # per power-of-two delta size (a 100k-alloc plan's replay was paying a
@@ -371,7 +390,7 @@ def _resolve_compact_fills(buf_np: np.ndarray, fills_full, slot_k: int):
 
 
 def _unpack_rounds(buf: np.ndarray, round_size: int, p_real: int,
-                   slot_k: int = 0):
+                   slot_k: int = 0, takes=None):
     """Expand the water-fill kernels' per-round buffer (see
     select.pack_round_buffer for the layout) into per-placement picks
     plus the per-round metric block.  Placements within a round are
@@ -379,7 +398,13 @@ def _unpack_rounds(buf: np.ndarray, round_size: int, p_real: int,
 
     `slot_k`: fill slots per buffer row when they differ from the round
     size (the compact-output kernel emits a FILL_K-slot prefix while
-    rounds still hold `round_size` placements)."""
+    rounds still hold `round_size` placements).
+
+    `takes`: a device item's `[rounds, fill slots, S]` charges (the flat
+    kernel's slot output: of a fill's placements, how many each device
+    group slot took, the slots in node order); then a third result, the
+    slot each placement was charged to (-1 where none), in the picks'
+    order: a fill's placements take its slots one after another."""
     n_rounds = buf.shape[0]
     slot_k = slot_k or round_size
     fills = buf[:, :slot_k]
@@ -389,6 +414,14 @@ def _unpack_rounds(buf: np.ndarray, round_size: int, p_real: int,
     placed_r = meta[:, 12]
 
     picks = np.full(n_rounds * round_size, -1, np.int32)
+    if takes is not None:
+        # every round's placements in fill order, each fill's slots one
+        # after another (a round's charges sum to what it placed)
+        seq = np.repeat(np.broadcast_to(
+            np.arange(takes.shape[2], dtype=np.int32), takes.shape).ravel(),
+            takes.ravel())
+        slots = np.full_like(picks, -1)
+        off = 0
     for r in range(n_rounds):
         lo = r * round_size
         k = int(placed_r[r])
@@ -396,7 +429,12 @@ def _unpack_rounds(buf: np.ndarray, round_size: int, p_real: int,
             continue
         nz = cnt_r[r].nonzero()[0]
         picks[lo:lo + k] = np.repeat(rows_r[r, nz], cnt_r[r, nz])[:k]
-    return picks[:p_real], meta
+        if takes is not None:
+            slots[lo:lo + k] = seq[off:off + k]
+            off += k
+    if takes is None:
+        return picks[:p_real], meta
+    return picks[:p_real], meta, slots[:p_real]
 
 
 # the meta block's per-dimension exhaustion columns, in RES_NAMES' order
@@ -445,6 +483,10 @@ class BulkDecisions:
     # metric a PLACEMENT kept as the columns the scan returned
     rows: Optional[RowMetrics] = None
     scores: Optional[np.ndarray] = None     # [P] float32, the picks' own
+    # a device item off a wave with slot state: the device group slot
+    # each placement was charged to ([P] int32, -1 where none), the
+    # carve's group (scheduler/device.py carve_block)
+    slots: Optional[np.ndarray] = None
 
     def metric_at(self, i: int) -> AllocMetric:
         """Placement i's metric: its own, built here, or its round's."""
@@ -496,9 +538,13 @@ class PlacementEngine:
         # compact-lane candidate frames (_candidate_frames), by what they
         # were derived from; a handful, oldest out
         self._frame_cache: Dict[tuple, tuple] = {}
-        # device requests' static masks (device_static_mask), by node
-        # table version and request signature; a handful, oldest out
-        self._device_mask_cache: Dict[tuple, np.ndarray] = {}
+        # device requests' static masks and slot accept tables
+        # (device_accept_table), by node table version and request
+        # signature; a handful, oldest out
+        self._device_mask_cache: Dict[tuple, tuple] = {}
+        # the device group slots' usage as last uploaded for a fresh
+        # wave: ((used version, padded n, S), the [S, npad] array)
+        self._slot_used_dev: Optional[tuple] = None
         # spread stanzas' value landscapes (_spread_landscapes), by node
         # table version and spread signature; a handful, oldest out
         self._spread_cache: Dict[tuple, tuple] = {}
@@ -506,7 +552,7 @@ class PlacementEngine:
         # (node table version, padded n, the value's holder version, the
         # [npad] bool on the device); a few dozen, oldest out
         self._port_mask_cache: Dict[int, tuple] = {}
-        self._single_group: Optional[Tuple[int, bool]] = None
+        self._fleet_groups: Optional[Tuple[int, int]] = None
         self._dc_cache: Optional[Tuple[int, Dict[str, int]]] = None
         # host->device sync meter (ops/executor.py installs it): called
         # with (bytes, seconds, cause) for every node-state upload —
@@ -925,16 +971,21 @@ class PlacementEngine:
 
     # -------------------------------------------------------------- solve
 
-    def device_static_mask(self, t: NodeTensors, snapshot, req
-                           ) -> np.ndarray:
+    def device_accept_table(self, t: NodeTensors, snapshot, req,
+                            counter: str = "device_slot_tables"):
         """The STATIC half of the DeviceChecker for one device request:
-        a read-only [n] bool, True where the node carries a device group
-        the request's name and constraints accept (scheduler/device.py
-        group_feasible).  It moves with the node table alone, so it is
-        built once per (request signature, node-table version), over the
-        nodes that advertise a device at all, and reused after:
-        `_candidate_frames`' pattern.  How many instances are FREE is
-        the tensors' device dimension, the kernels' to account."""
+        (a read-only [n] bool, True where the node carries a device group
+        the request's name and constraints accept, scheduler/device.py
+        group_feasible; a read-only [n, DEVICE_GROUP_SLOTS] bool, True at
+        the slots of the node's groups it accepts).  The mask is the
+        table's any over slots, and over a node's groups past its slots.
+        Both move with the node table alone, so they are built once per
+        (request signature, node-table version), over the nodes that
+        advertise a device at all, and reused after: `_candidate_frames`'
+        pattern: a build counts in `nomad.engine.device_masks_built` and
+        `device_slot_tables_built` (one walk makes both), a hit in
+        `nomad.engine.<counter>_reused`.  How many instances are FREE is
+        the tensors', the kernels' to account."""
         from nomad_tpu.scheduler.device import (group_accepts,
                                                 request_signature)
         key = (t.version, request_signature(req))
@@ -943,25 +994,39 @@ class PlacementEngine:
             if hit is not None:
                 self._device_mask_cache[key] = hit
         if hit is not None:
-            _registry().inc("nomad.engine.device_masks_reused")
+            _registry().inc(f"nomad.engine.{counter}_reused")
             return hit
         mask = np.zeros(t.n, bool)
+        table = np.zeros((t.n, DEVICE_GROUP_SLOTS), bool)
         memo: Dict[tuple, bool] = {}
         node_ids = t.node_ids
         for row in np.flatnonzero(t.dev_groups > 0).tolist():
             node = snapshot.node_by_id(node_ids[row])
-            if node is not None and any(
-                    group_accepts(dev, req, memo)
-                    for dev in node.resources.devices):
-                mask[row] = True
+            if node is None:
+                continue
+            for s, dev in enumerate(node.resources.devices):
+                if group_accepts(dev, req, memo):
+                    mask[row] = True
+                    if s < DEVICE_GROUP_SLOTS:
+                        table[row, s] = True
         mask.setflags(write=False)
+        table.setflags(write=False)
         _registry().inc("nomad.engine.device_masks_built")
+        _registry().inc("nomad.engine.device_slot_tables_built")
+        hit = (mask, table)
         with self.packer.lock:
             if len(self._device_mask_cache) >= 8:
                 self._device_mask_cache.pop(
                     next(iter(self._device_mask_cache)))
-            self._device_mask_cache[key] = mask
-        return mask
+            self._device_mask_cache[key] = hit
+        return hit
+
+    def device_static_mask(self, t: NodeTensors, snapshot, req
+                           ) -> np.ndarray:
+        """`device_accept_table`'s mask: the nodes that carry a group the
+        request accepts (counted in `nomad.engine.device_masks_*`)."""
+        return self.device_accept_table(t, snapshot, req,
+                                        counter="device_masks")[0]
 
     def _spread_landscapes(self, t: NodeTensors, sig: tuple,
                            spreads) -> tuple:
@@ -1112,16 +1177,104 @@ class PlacementEngine:
         rows.extend([zrow] * (k_pad - len(asked)))
         return asked, jnp.stack(rows), jnp.asarray(pt_ask), rest
 
+    def fleet_device_groups(self, t: NodeTensors) -> int:
+        """The most device groups one node of the table advertises."""
+        hit = self._fleet_groups
+        if hit is None or hit[0] != t.version:
+            hit = self._fleet_groups = (
+                t.version, int(t.dev_groups.max()) if t.n else 0)
+        return hit[1]
+
     def single_group_fleet(self, t: NodeTensors) -> bool:
         """No node advertises more than one device group: then "instances
         in use on the node" is one number whatever a request's name, and
         the tensors' device dimension says exactly what a single request
-        can take (the batched device path's admission rule)."""
-        hit = self._single_group
-        if hit is None or hit[0] != t.version:
-            hit = self._single_group = (
-                t.version, not bool((t.dev_groups > 1).any()))
-        return hit[1]
+        can take."""
+        return self.fleet_device_groups(t) <= 1
+
+    def kernel_slots(self, t: NodeTensors) -> int:
+        """The device group slots a wave's launch carries: one on a fleet
+        of single-group nodes (its device dimension, a slot), else all
+        of DEVICE_GROUP_SLOTS."""
+        return 1 if self.single_group_fleet(t) else DEVICE_GROUP_SLOTS
+
+    def _slot_used(self, t: NodeTensors, npad: int, s: int):
+        """The device group slots' usage for a launch with no chain to
+        carry it, [s, npad] on the device (slot-major, as the kernel
+        takes it): the packer's, uploaded once a used version.  Read
+        after the launch's `used` (`_used_device`), so it is as new as
+        that or newer."""
+        with self.packer.lock:
+            key = (t.used_version, npad, s)
+            hit = self._slot_used_dev
+            if hit is not None and hit[0] == key:
+                return hit[1]
+            host = _pad_cols(np.ascontiguousarray(t.slot_used[:, :s].T),
+                             npad)
+        t0 = time.perf_counter()
+        dev = jnp.asarray(host)
+        self._slot_used_dev = (key, dev)
+        self._note_h2d(host.nbytes, time.perf_counter() - t0)
+        return dev
+
+    def _lower_wave_slots(self, t: NodeTensors, npad: int, snapshot,
+                          reqs: Sequence, g_pad: int, carried=None):
+        """The device group slot fields of MultiEvalInputs for a wave, or
+        None where no item asks a device (`reqs`: each item's device
+        request, None where it asks none) or the engine has a mesh (the
+        sharded wave kernels carry no slot state: a device ask rides
+        there on single-group fleets alone, the tensors' device
+        dimension its one slot).  The accept tables of the wave's
+        distinct request signatures (`device_accept_table`), slot-major
+        `[S, npad]` as every slot field, go up stacked once a node-table
+        version and set of signatures (`_dev_const`, as the base masks
+        do), at least DEVICE_SLOT_TABLES_MIN; an item's row is its
+        signature's (an item that asks no device reads row 0 and charges
+        nothing).
+        `carried` is the slot usage of the wave this one is chained on,
+        which stands for the state and the chain's placements since."""
+        from nomad_tpu.scheduler.device import request_signature
+        if self.mesh is not None:
+            return None
+        if not any(reqs):
+            return None
+        with (self.timers.time("device_lower") if self.timers is not None
+              else contextlib.nullcontext()):
+            s = self.kernel_slots(t)
+            by_sig = {request_signature(r): r for r in reqs if r}
+            sigs = sorted(by_sig, key=repr)
+            row_of = {sig: k for k, sig in enumerate(sigs)}
+            g_dev = np.zeros(g_pad, np.int32)
+            for gi, r in enumerate(reqs):
+                if r:
+                    g_dev[gi] = row_of[request_signature(r)]
+            tables = [self.device_accept_table(t, snapshot, by_sig[sig])[1]
+                      for sig in sigs]
+
+            def stacked():
+                out = np.zeros((_pad_pow2(len(sigs),
+                                          lo=DEVICE_SLOT_TABLES_MIN),
+                                s, npad), bool)
+                for k, table in enumerate(tables):
+                    out[k, :, :t.n] = table[:, :s].T
+                return out
+
+            def slot_cap():
+                with self.packer.lock:
+                    return _pad_cols(
+                        np.ascontiguousarray(t.slot_cap[:, :s].T), npad)
+            used0 = (carried if carried is not None
+                     and carried.shape == (s, npad)
+                     else self._slot_used(t, npad, s))
+            return {"dev_cap": self._dev_const(
+                        ("slotcap", t.version, npad, s), slot_cap),
+                    "dev_used0": used0,
+                    # a drain's waves ask the same few signatures: the
+                    # stack goes up once a node-table version and set
+                    "dev_accept": self._dev_const(
+                        ("slottables", t.version, npad, s) + tuple(sigs),
+                        stacked),
+                    "g_dev": jnp.asarray(g_dev)}
 
     def _device_mask(self, tgs: Sequence[TaskGroup], t: NodeTensors,
                      snapshot, stopped_ids, device_in_use=None):
@@ -1847,12 +2000,15 @@ class PlacementEngine:
             buf, fills_full, used_out = out
             fill_k = min(FILL_K, rs)
         else:
-            buf, used_out, jc_out, *taken_out = out
-        # the static-port state a wave chained on this one starts from:
-        # what this launch left, or what it was handed and did not touch
-        port_state = ((aux["ports"][0], taken_out[0], aux["ports"][1])
+            buf, used_out, jc_out, *extras = out
+        slots = aux["slots"]
+        # the static-port and device slot state a wave chained on this
+        # one starts from: what this launch left, or what it was handed
+        # and did not touch
+        port_state = ((aux["ports"][0], extras[0], aux["ports"][1])
                       if aux["ports"] is not None
                       else aux["carried_ports"])
+        slot_used = extras[-1] if slots is not None else aux["carried_slots"]
         # start the device->host copy of the result buffer NOW: queued
         # behind the compute, a prefetched batch's transfer rides out
         # the PREVIOUS batch's host phase instead of blocking collect
@@ -1865,6 +2021,8 @@ class PlacementEngine:
         return {"buf": buf, "used": used_out, "job_count": jc_out,
                 "items": list(items),
                 "ports": port_state, "port_asks": aux["port_asks"],
+                "slot_used": slot_used, "slot_takes": slots is not None,
+                "dev_items": aux["dev_items"],
                 "spans": aux["spans"], "counts": aux["counts"], "rs": rs,
                 "item_rs": aux["item_rs"], "rounds": aux["rounds"],
                 "rounds_padded": aux["rounds_padded"],
@@ -1899,7 +2057,8 @@ class PlacementEngine:
         allocations its plan stops, as `_stop_delta` takes them — their
         usage leaves the launch's `used` and the job's own leave its
         count row, as the exact scan sees them.  None adds nothing."""
-        from nomad_tpu.scheduler.device import request_signature
+        from nomad_tpu.scheduler.device import (request_signature,
+                                                tg_device_requests)
         t = self.packer.update(snapshot)
         n = t.n
         if n == 0:
@@ -1908,14 +2067,16 @@ class PlacementEngine:
         npad = self._padded_n(n)
         dev = self._node_arrays(t)
         used0 = None
-        carried_ports = None
+        carried_ports = carried_slots = None
         if used0_dev is not None:
             # (usage, node version, padded n) and, from a wave that had
-            # or carried static-port state, that state
-            arr, chain_ver, chain_npad, *chain_ports = used0_dev
+            # or carried static-port or device slot state, that state
+            arr, chain_ver, chain_npad, *chain_rest = used0_dev
             if chain_ver == t.version and chain_npad == npad:
                 used0 = arr
-                carried_ports = chain_ports[0] if chain_ports else None
+                carried_ports = chain_rest[0] if chain_rest else None
+                carried_slots = (chain_rest[1] if len(chain_rest) > 1
+                                 else None)
         chained = used0 is not None
         if used0 is None:
             used0 = self._used_device(t)
@@ -2072,6 +2233,10 @@ class PlacementEngine:
             raise ValueError("the sharded wave kernels carry no static-port "
                              "state: such an eval takes the solo path")
         ports = self._lower_ports(t, npad, port_asks, g_pad, carried_ports)
+        dev_reqs = [next(iter(tg_device_requests(it.tg)), (None, None))[1]
+                    for it in items]
+        slots = self._lower_wave_slots(t, npad, snapshot, dev_reqs, g_pad,
+                                       carried_slots)
         biggest = max(counts) if counts else 0
         for rs in (64, 256, 512, 1024):
             if biggest <= rs:
@@ -2110,12 +2275,13 @@ class PlacementEngine:
         perm = None
         cand_rows = cand_valid = cand_dev = None
         luts = tgts[-1].luts      # the most complete LUT matrix
-        # (a wave that holds a spread item or a static port ask keeps the
-        # flat schedule: the laned kernel carries neither state; nor
-        # does it carry a count row from one item to the next, so a wave
-        # whose evals hold several groups keeps it too)
+        # (a wave that holds a spread item, a static port ask or a
+        # device ask keeps the flat schedule: the laned kernel carries
+        # none of their state; nor does it carry a count row from one
+        # item to the next, so a wave whose evals hold several groups
+        # keeps it too)
         if (n_real > 1 and len(static_con) > 1 and spread is None
-                and ports is None and n_jobs == G):
+                and ports is None and slots is None and n_jobs == G):
             weights = [0] * len(static_con)
             for r_idx in range(n_real):
                 weights[int(g_static[round_g[r_idx]])] += 1
@@ -2246,7 +2412,14 @@ class PlacementEngine:
                 "sp_counts0")})
         if ports is not None:
             inp = inp._replace(pt_taken0=ports[1], pt_ask=ports[2])
+        if slots is not None:
+            inp = inp._replace(**{k: slots[k] for k in (
+                "dev_cap", "dev_used0", "dev_accept", "g_dev")})
         return {"inp": inp, "rs": rs, "spans": spans, "counts": counts,
+                # the launch's device group slot state, or the chain's to
+                # hand on as is; which items ask a device
+                "slots": slots, "carried_slots": carried_slots,
+                "dev_items": [r is not None for r in dev_reqs],
                 # the launch's static port values in slot order with the
                 # rows it hands on untouched, or, on a wave that asks
                 # none, the chain's state to hand on as is
@@ -2364,6 +2537,14 @@ class PlacementEngine:
         buf_np, slot_eff = _resolve_compact_fills(
             buf_np, _full_fills, fill_k or 0)
         rs_eff = slot_eff or rs
+        # a wave with device slot state: each round's charges by slot
+        # follow its fill slots and meta block (select.place_multi_packed)
+        dev_items = pending.get("dev_items") or [False] * len(items)
+        dev_picks = []             # each device item's picks
+        takes = None
+        if pending.get("slot_takes"):
+            takes = buf_np[:, rs + 16:].reshape(buf_np.shape[0], rs, -1)
+            buf_np = buf_np[:, :rs + 16]
 
         dc_counts = self._dc_counts(t)
         elapsed = ((pending["prep_ns"] + time.perf_counter_ns() - t1)
@@ -2379,9 +2560,13 @@ class PlacementEngine:
                 continue
             # a spread item's rounds hold one placement each
             irs = pending["item_rs"][gi]
-            picks, meta = _unpack_rounds(
+            picks, meta, *slots = _unpack_rounds(
                 buf_np[lo:hi], irs, counts[gi],
-                slot_k=rs_eff if rs_eff != irs else 0)
+                slot_k=rs_eff if rs_eff != irs else 0,
+                takes=(takes[lo:hi] if takes is not None and dev_items[gi]
+                       else None))
+            if dev_items[gi]:
+                dev_picks.append(picks)
             if npad != n:
                 meta = meta.copy()
                 meta[:, 7] -= npad - n
@@ -2392,7 +2577,18 @@ class PlacementEngine:
                           if port_asks[gi] else ""))
             decisions.append(BulkDecisions(
                 tg_name=it.tg.name, picks=picks, node_ids=t.node_ids,
-                round_size=irs, metrics=metrics, nodes_evaluated=n))
+                round_size=irs, metrics=metrics, nodes_evaluated=n,
+                slots=slots[0] if slots else None))
+        if dev_picks:
+            # the wave's placements with a device ask, by whether their
+            # node advertises one device group or several
+            placed = np.concatenate(dev_picks)
+            placed = placed[placed >= 0]
+            multi = int(np.count_nonzero(t.dev_groups[placed] > 1))
+            _registry().inc("nomad.device.picks", len(placed) - multi,
+                            node="single_group")
+            _registry().inc("nomad.device.picks", multi,
+                            node="multi_group")
         return decisions
 
     def _no_nodes_decision(self, r: PlacementRequest, snapshot, job: Job

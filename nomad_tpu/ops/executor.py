@@ -128,14 +128,16 @@ class DeviceExecutor:
     def chain_state(self, pending):
         """The (usage, node version, padded n) triple a successor wave
         chains on, or None when `pending` cannot seed a chain; a wave
-        with static-port state adds it as a fourth (ops/engine.py
-        _lower_ports: the holders it left, for the successor to start
+        with static-port or device slot state adds both as a fourth and
+        fifth, None where it has none (ops/engine.py _lower_ports,
+        _lower_wave_slots: the state it left, for the successor to start
         from)."""
         if not isinstance(pending, dict):
             return None
         chain = (pending["used"], pending["node_version"], pending["npad"])
-        if pending.get("ports") is not None:
-            chain += (pending["ports"],)
+        if (pending.get("ports") is not None
+                or pending.get("slot_used") is not None):
+            chain += (pending.get("ports"), pending.get("slot_used"))
         return chain
 
     def _note_dispatch(self, pending, wanted_chain: bool) -> None:

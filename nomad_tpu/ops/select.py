@@ -597,18 +597,60 @@ def round_scores_g(cap, req, desired, dh_limit, static, aff_sc, aff_any,
     return k_i, score
 
 
-def round_metrics_g(cap, req, dh_limit, static, used, job_count):
+def round_metrics_g(cap, req, dh_limit, static, used, job_count,
+                    dev_fit=None):
     """Post-commit exhaustion metrics for one water-fill round,
     parameterized on the round's task group values (shared core, see
-    round_scores_g; the sharded caller psums the returned local sums)."""
+    round_scores_g; the sharded caller psums the returned local sums).
+    `dev_fit` [N] bool: where a launch carries device group slots, the
+    nodes with an accepted group that still holds the round's ask, which
+    then decides the device dimension in place of its column's sum."""
     free2 = cap - used
-    fit2 = jnp.all(free2 >= req[None, :], axis=1) & jnp.all(
-        free2 >= 0, axis=1)
+    if dev_fit is None:
+        fit2 = jnp.all(free2 >= req[None, :], axis=1)
+    else:
+        fit2 = jnp.all(free2[:, :-1] >= req[None, :-1], axis=1) & dev_fit
+    fit2 = fit2 & jnp.all(free2 >= 0, axis=1)
     dh_ok2 = jnp.where(dh_limit > 0, job_count < dh_limit, True)
     exhausted2 = static & ~(fit2 & dh_ok2)
     n_exh = jnp.sum(exhausted2)
-    dim_ex = jnp.sum(exhausted2[:, None] & (free2 < req[None, :]), axis=0)
+    if dev_fit is None:
+        dim_ex = jnp.sum(exhausted2[:, None] & (free2 < req[None, :]),
+                         axis=0)
+    else:
+        dim_ex = jnp.concatenate([
+            jnp.sum(exhausted2[:, None] & (free2[:, :-1] < req[None, :-1]),
+                    axis=0),
+            jnp.sum(exhausted2 & ~dev_fit)[None]])
     return n_exh, dim_ex
+
+
+def slot_room(dev_cap, dev_used, accept, need, round_size: int):
+    """Per device group slot and node ([S, N], slot-major: the nodes on
+    the TPU's lanes), how many more placements of a `need`-instance ask
+    the slot's group takes: its free instances over `need` where the ask
+    accepts the group, else 0 (an ask of no instance: 0 everywhere)."""
+    free = dev_cap - dev_used
+    room = jnp.where(accept, free // jnp.maximum(need, 1), 0)
+    return jnp.where(need > 0, jnp.clip(room, 0, round_size), 0)
+
+
+def slot_charge(room, c_i, need):
+    """The slots `c_i` [N] placements of a `need`-instance ask take, one
+    placement after another, each from the FIRST accepted slot with room
+    in node order (scheduler/device.py assign_devices' group when the
+    ask has no affinity): slot s takes what the slots before it left, up
+    to its room.  Returns ([S, N] placements a slot, [S, N] instances a
+    slot).  The running sum goes slot by slot over [N] rows: a cumsum
+    over the slot axis makes the TPU lay the slot state out slot-minor,
+    each row of S words padded to 128 lanes."""
+    before = jnp.zeros_like(c_i)
+    takes = []
+    for s in range(room.shape[0]):
+        takes.append(jnp.clip(c_i - before, 0, room[s]))
+        before = before + room[s]
+    take = jnp.stack(takes)
+    return take, take * need
 
 
 def waterfill_round(k_i, score, noise, want, spread_algo, round_size: int):
@@ -814,6 +856,21 @@ class MultiEvalInputs(NamedTuple):
     # static ask, and the program is the one it was
     pt_taken0: jnp.ndarray = None    # [Kp, N] bool
     pt_ask: jnp.ndarray = None       # [G, Kp] bool
+    # device group slot state, or None x 4: per node its first S device
+    # groups in node order (ops/engine.py DEVICE_GROUP_SLOTS), their
+    # instances and the instances held (from the state, or from the wave
+    # this one is chained on), one table a request signature of the
+    # slots it accepts, and each item's table (an item that asks no
+    # device charges nothing).  A node's intake for a device item is its
+    # accepted slots' room summed (`slot_room`), and a round's
+    # placements on it charge the first accepted slot with room
+    # (`slot_charge`); the round scan carries the usage as it carries
+    # `used`.  None on a wave that holds no device ask, and the program
+    # is the one it was
+    dev_cap: jnp.ndarray = None      # [S, N] int32 (slot-major)
+    dev_used0: jnp.ndarray = None    # [S, N] int32
+    dev_accept: jnp.ndarray = None   # [Ud, S, N] bool
+    g_dev: jnp.ndarray = None        # [G] int32 -> dev_accept row
 
 
 def round_seeds(seed, rg):
@@ -849,8 +906,12 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
     Real rounds' rows and `used` are the same bits whichever branch ran.
     Returns (buf, used, last REAL round's job count row [N]), and on a
     wave with static-port state the holders it left, [Kp, N], for the
-    wave chained on this one; the meta block's column 14 then counts the
-    nodes a round lost to that state."""
+    wave chained on this one (the meta block's column 14 then counts the
+    nodes a round lost to that state), and on a wave with device slot
+    state the slot usage it left, [S, N].  A round of such a wave adds
+    its charges by slot after its meta block: `round_size` x S words,
+    for each fill slot how many of its placements each device group slot
+    took."""
     n = inp.attrs.shape[0]
     assert n < (1 << 20), "packed fill rows support < 2^20 nodes"
     assert round_size <= 1024, "packed fill counts support rounds <= 1024"
@@ -895,19 +956,28 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
                  inp.sp_counts0[rg])
         carry0 += (inp.sp_counts0[0],)
     has_ports = inp.pt_taken0 is not None
+    # where the port and slot state ride in the scan xs and the carry
+    xi_port, ci_port = len(xs_r), len(carry0)
     if has_ports:
         # the item's asked values ride as scan xs, the holders in the
-        # carry: always the last of each
+        # carry
         xs_r += (inp.pt_ask[rg],)
         carry0 += (inp.pt_taken0,)
+    has_slots = inp.dev_accept is not None
+    xi_dev, ci_dev = len(xs_r), len(carry0)
+    if has_slots:
+        # the item's accept table row rides as scan xs, the slot usage
+        # in the carry
+        xs_r += (inp.g_dev[rg],)
+        carry0 += (inp.dev_used0,)
 
     def round_step(carry, xs):
         used, cur_count = carry[:2]
         (u, a, jc0_row, req, desired, dh_limit, want, same, sd) = xs[:9]
         static = static_all = static_u[u]   # [N]; U is tiny — cheap gather
         if has_ports:
-            taken_in = carry[-1]
-            static, _ = port_state(taken_in, xs[-1], static_all)
+            taken_in = carry[ci_port]
+            static, _ = port_state(taken_in, xs[xi_port], static_all)
         aff_sc = aff_u[a]
         aff_any = aff_any_u[a]
         job_count = jnp.where(same, cur_count, jc0_row)
@@ -926,7 +996,18 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
             # a static ask is one allocation a node: the fill's top
             # `want` nodes are then the picks one placement after another
             # would make, each passing by the nodes before it
-            k_i = jnp.where(jnp.any(xs[-1]), jnp.minimum(k_i, 1), k_i)
+            k_i = jnp.where(jnp.any(xs[xi_port]), jnp.minimum(k_i, 1), k_i)
+        if has_slots:
+            # a device ask takes all of its instances from ONE group: a
+            # node's intake is its accepted groups' room summed, which
+            # the device column's sum can only exceed
+            dev_used = carry[ci_dev]
+            accept = inp.dev_accept[xs[xi_dev]]          # [S, N]
+            need = req[-1]
+            room = slot_room(inp.dev_cap, dev_used, accept, need,
+                             round_size)
+            k_i = jnp.where(need > 0,
+                            jnp.minimum(k_i, jnp.sum(room, axis=0)), k_i)
 
         def select(select_round):
             # per-item noise (elementwise hash — no [R, N] pre-gather):
@@ -970,6 +1051,21 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
         carry = (used, job_count)
         if has_spread:
             carry += (sp_counts + sp_commits[0],)
+        if has_ports:
+            # the holders this round left (its own picks among them)
+            taken = port_commit(taken_in, xs[xi_port], c_i > 0)
+            carry += (taken,)
+        dev_fit = None
+        if has_slots:
+            took, charged = slot_charge(room, c_i, need)
+            dev_used = dev_used + charged
+            carry += (dev_used,)
+            # each fill slot's charges, by device group slot (a row
+            # gather a slot: the slot state stays slot-major)
+            takes = jnp.where(cnt_p[:, None] > 0, jnp.stack(
+                [took[k][rows_p] for k in range(took.shape[0])], axis=1), 0)
+            dev_fit = (need <= 0) | jnp.any(
+                accept & (inp.dev_cap - dev_used >= need), axis=0)
 
         top_sc = sc_p[:top_k]
         top_rows = jnp.where(top_sc > NEG_INF / 2, rows_p[:top_k], -1)
@@ -981,11 +1077,9 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
             # state too: the nodes that hold a value asked, this round's
             # own among them, are exhausted, and counted in a column of
             # their own after the dimensions
-            taken = port_commit(taken_in, xs[-1], c_i > 0)
-            carry += (taken,)
-            static, port_hit = port_state(taken, xs[-1], static_all)
+            static, port_hit = port_state(taken, xs[xi_port], static_all)
         n_exh, dim_ex = round_metrics_g(
-            inp.cap, req, dh_limit, static, used, job_count)
+            inp.cap, req, dh_limit, static, used, job_count, dev_fit)
         if has_ports:
             n_port = jnp.sum(port_hit)
             n_exh = n_exh + n_port
@@ -993,6 +1087,8 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
         out = (rows_p, cnt_p, top_rows, top_sc,
                n_feas, n_filt, n_exh.astype(jnp.int32),
                dim_ex.astype(jnp.int32), placed_total.astype(jnp.int32))
+        if has_slots:
+            out += (takes.astype(jnp.int32),)
         return carry, out
 
     # the schedule's padding (`want` 0) is its tail, so the loop stops one
@@ -1017,13 +1113,15 @@ def place_multi_packed(inp: MultiEvalInputs, round_size: int):
     (used, jc, *rest), outs = jax.lax.fori_loop(
         0, n_run, body, (carry0, outs0))
     (rows_p, cnt_p, top_rows, top_sc,
-     n_feas, n_filt, n_exh, dim_ex, placed) = outs
+     n_feas, n_filt, n_exh, dim_ex, placed) = outs[:9]
     fills, meta = pack_round_buffer(rows_p, cnt_p, top_rows, top_sc,
                                     n_feas, n_filt, n_exh, dim_ex, placed)
-    buf = jnp.concatenate([fills, meta], axis=1)
-    if has_ports:
-        return buf, used, jc, rest[-1]
-    return buf, used, jc
+    parts = [fills, meta]
+    if has_slots:
+        parts.append(outs[9].reshape(r_pad, -1))
+    buf = jnp.concatenate(parts, axis=1)
+    # the port holders and the slot usage, where the wave carries them
+    return (buf, used, jc, *rest[has_spread:])
 
 
 place_multi_packed_jit = jax.jit(place_multi_packed, static_argnums=(1,))

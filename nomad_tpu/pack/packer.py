@@ -77,6 +77,16 @@ _TARGET_RE = re.compile(r"^\$\{(.+)\}$")
 # error naming the cap.
 PACKED_FILL_CAP = 1 << 20
 
+# The device groups a node's tensors carry one by one: its first
+# DEVICE_GROUP_SLOTS groups in the node's own order, each a SLOT with its
+# instances and the instances live allocations hold of it (a request
+# takes all of its instances from ONE group it accepts, so "instances
+# free on the node" is a number a slot).  A constant shape, not a knob:
+# an A100 node in MIG mixed mode advertises three groups (whole GPUs and
+# two profiles); a node of more goes solo (scheduler/generic.py, rule
+# `slots`).  ops/engine.py re-exports it, the name the wave is read by.
+DEVICE_GROUP_SLOTS = 4
+
 
 def resolve_target_key(target: str) -> str:
     """Normalize a constraint l-target to a column key
@@ -154,9 +164,15 @@ class NodeTensors:
                              # key: plan applies touch used, not attrs)
     # device groups a node advertises, [N] int32.  cap's device column
     # is their instances TOGETHER, which is "the instances a request can
-    # take" only where a node has one group: the batched device path
-    # asks (scheduler/generic.py prepare_batch)
+    # take" only where a node has one group
     dev_groups: Optional[np.ndarray] = None
+    # per device group slot (DEVICE_GROUP_SLOTS), [N, S] int32: the
+    # instances of the node's group in that slot, and the instances its
+    # live allocations hold; a node of no more groups than slots sums
+    # them to cap's and used's device column.  Kept by the events that
+    # keep `used`, so `slot_used` moves with `used_version`
+    slot_cap: Optional[np.ndarray] = None
+    slot_used: Optional[np.ndarray] = None
 
     @property
     def n(self) -> int:
@@ -251,6 +267,14 @@ class ClusterPacker:
         self._block_ports: Dict[str, Tuple[Tuple[int, ...], List[str]]] = {}
         self._node_ports: Dict[str, Tuple[int, ...]] = {}
         self._port_versions: Dict[int, int] = {}
+        # device group ledger, kept by the same events as `used`: node
+        # id -> {group id: instances held}, by group id and not by slot
+        # (a node write may reorder its groups); an alloc's entry is
+        # (node id, ((group id, instances), ...)); a node's slots are
+        # {group id: slot} of its first DEVICE_GROUP_SLOTS groups
+        self._group_held: Dict[str, Dict[str, int]] = {}
+        self._alloc_groups: Dict[str, Tuple[str, tuple]] = {}
+        self._node_slots: Dict[str, Dict[str, int]] = {}
 
     # ------------------------------------------------------------ columns
 
@@ -317,6 +341,41 @@ class ClusterPacker:
         return version, [t.id_to_row[nid] for nid in holders
                          if nid in t.id_to_row]
 
+    # ------------------------------------------------ device group ledger
+
+    def _hold_groups(self, node_id: str, pairs, by: int) -> None:
+        """`by` times `pairs` ((group id, instances), ...) more held on a
+        node: the ledger, and the node's slots in the tensors (the
+        writers all run under `self.lock`)."""
+        held = self._group_held.setdefault(node_id, {})
+        for gid, k in pairs:
+            left = held.get(gid, 0) + by * k
+            if left:
+                held[gid] = left
+            else:
+                held.pop(gid, None)
+        t = self._tensors
+        slots = self._node_slots.get(node_id)
+        if t is None or not slots:
+            return
+        row = t.id_to_row.get(node_id)
+        if row is not None:
+            for gid, k in pairs:
+                s = slots.get(gid)
+                if s is not None:
+                    t.slot_used[row, s] += by * k
+
+    def _forget_groups(self) -> None:
+        """A rebuild re-reads every holder from the snapshot."""
+        self._group_held.clear()
+        self._alloc_groups.clear()
+        self._node_slots.clear()
+
+    @staticmethod
+    def _alloc_group_pairs(alloc) -> tuple:
+        return tuple((ad.group_id(), len(ad.device_ids))
+                     for ad in alloc.allocated_devices)
+
     # ------------------------------------------------------- store attach
 
     def attach(self, store) -> None:
@@ -347,6 +406,7 @@ class ClusterPacker:
                     self._alloc_node.clear()
                     self._block_counted.clear()
                     self._forget_ports()
+                    self._forget_groups()
 
         store.subscribe(on_event)
 
@@ -380,12 +440,18 @@ class ClusterPacker:
                 held = alloc_ports.pop(aid, None)
                 if held is not None:
                     self._port_hold(held[1], held[0], -1)
+                held = self._alloc_groups.pop(aid, None)
+                if held is not None:
+                    self._hold_groups(held[0], held[1], -1)
             nid = a.node_id
             if nid and not a.terminal_status():
                 r = a.resources
                 self._hold_alloc_ports(aid, nid, r)
                 if a.allocated_devices:
                     res = a.usage()     # instances are the alloc's own
+                    pairs = self._alloc_group_pairs(a)
+                    self._alloc_groups[aid] = (nid, pairs)
+                    self._hold_groups(nid, pairs, 1)
                 else:
                     res = res_cache.get(id(r))
                     if res is None:
@@ -421,6 +487,8 @@ class ClusterPacker:
                 for nid, c in zip(block.node_table,
                                   block.node_counts().tolist()):
                     self._port_hold(static, nid, c)
+        for nid, by_group in block.device_use_by_node().items():
+            self._hold_groups(nid, tuple(by_group.items()), 1)
         t = self._tensors
         if t is None:
             return
@@ -461,6 +529,9 @@ class ClusterPacker:
             if static:
                 # the unit's holders become the rows' own, one for one
                 self._alloc_ports[aid] = (nid, static)
+            if a.allocated_devices:
+                # so are the instances it holds of each group
+                self._alloc_groups[aid] = (nid, self._alloc_group_pairs(a))
 
     def _log_delta(self, rows, vals, refreshed_rows=None) -> int:
         """Append one used-version bump to the replay log.  `rows is None`
@@ -622,11 +693,14 @@ class ClusterPacker:
             pool=np.zeros(n, np.int32),
             klass=np.zeros(n, np.int32),
             dev_groups=np.zeros(n, np.int32),
+            slot_cap=np.zeros((n, DEVICE_GROUP_SLOTS), np.int32),
+            slot_used=np.zeros((n, DEVICE_GROUP_SLOTS), np.int32),
         )
         self._alloc_node.clear()
         self._counted.clear()
         self._block_counted.clear()
         self._forget_ports()
+        self._forget_groups()
         for i, nd in enumerate(nodes):
             self._fill_row(t, i, nd, snapshot, prop_maps[i])
         self._seq += 1
@@ -739,7 +813,21 @@ class ClusterPacker:
         """`unit_used`: the dirty-row refill (usage from the ledger, the
         block units' share of it handed in); None: a full rescan."""
         t.cap[i] = nd.capacity()
-        t.dev_groups[i] = len(nd.resources.devices)
+        devs = nd.resources.devices
+        t.dev_groups[i] = len(devs)
+        # a group id names its first slot: an allocation's instances say
+        # vendor/type/name and nothing more (two groups of one name on
+        # one node are one group to the ledger)
+        slots: Dict[str, int] = {}
+        for s, d in enumerate(devs[:DEVICE_GROUP_SLOTS]):
+            slots.setdefault(d.id(), s)
+        if slots:
+            self._node_slots[nd.id] = slots
+        else:
+            self._node_slots.pop(nd.id, None)
+        t.slot_cap[i] = 0
+        t.slot_cap[i, :min(len(devs), DEVICE_GROUP_SLOTS)] = [
+            len(d.instance_ids) for d in devs[:DEVICE_GROUP_SLOTS]]
         own = tuple(nd.reserved.reserved_ports) + tuple(
             p.value for net in nd.resources.networks
             for p in net.reserved_ports)
@@ -773,6 +861,7 @@ class ClusterPacker:
             # caller of a full rescan, cleared them)
             counted: Dict[str, Tuple[int, ...]] = {}
             used = [0] * RES_DIMS
+            held: Dict[str, int] = {}
             for alc in snapshot.allocs_by_node(nd.id):
                 if alc.terminal_status():
                     continue
@@ -781,8 +870,18 @@ class ClusterPacker:
                     used[d] += res[d]
                 self._alloc_node[alc.id] = nd.id
                 self._hold_alloc_ports(alc.id, nd.id, alc.resources)
+                if alc.allocated_devices:
+                    pairs = self._alloc_group_pairs(alc)
+                    self._alloc_groups[alc.id] = (nd.id, pairs)
+                    for gid, k in pairs:
+                        held[gid] = held.get(gid, 0) + k
             self._counted[nd.id] = counted
+            self._group_held[nd.id] = held
             t.used[i] = used
+        held = self._group_held.get(nd.id, {})
+        t.slot_used[i] = 0
+        for gid, s in slots.items():
+            t.slot_used[i, s] = held.get(gid, 0)
         t.elig[i] = nd.ready()
         t.dc[i] = self.interner.intern(nd.datacenter)
         t.pool[i] = self.interner.intern(nd.node_pool)

@@ -20,14 +20,15 @@ string-keyed inventories, so it stays host-side (SURVEY.md §7 P1's
     DeviceChecker: whether a node carries a group a request's name and
     constraints accept.  The engine keeps it as a per-signature mask by
     node-table version (ops/engine.py device_static_mask);
-  * `node_feasible` is the exact DYNAMIC check, which only a group with
-    several requests or a node with several groups still needs;
+  * `node_feasible` is the exact DYNAMIC check of the solo path, for a
+    group with several requests or a fleet with multi-group nodes;
   * `assign_devices` picks concrete instance IDs for a chosen node after
     the kernel has placed (the AllocateDevice analog), with affinity
     scoring across eligible device groups;
   * `carve_block` is its columnar twin for a whole eval's picks (the
-    batched path, scheduler/generic.py), against a `CarveLedger` that
-    the wave's mates and the cycle's earlier waves share.
+    batched path, scheduler/generic.py), each row's ids from the group
+    the wave's kernel charged it to, against a `CarveLedger` that the
+    wave's mates and the cycle's earlier waves share.
 
 Both consult an `InUseIndex` built from live allocations so instances are
 never double-assigned; the plan applier re-checks via
@@ -382,51 +383,79 @@ class CarveLedger:
 
 def carve_block(snapshot, ledger: CarveLedger, record: list,
                 req: RequestedDevice, picks: np.ndarray,
-                node_ids: List[str]):
+                node_ids: List[str], slots: Optional[np.ndarray] = None):
     """Instance ids for every row of one eval's picks, in one pass over
-    its nodes: a node's rows take its group's first free ids in the
-    group's own order, row after row, which is what a sequence of
-    `assign_devices` calls gives them.  For ONE request on nodes of ONE
-    device group (prepare_batch's admission rule).
+    its nodes: a row takes the first free ids, in the group's own order,
+    of the device group the kernel charged it to, row after row, which is
+    what a sequence of `assign_devices` calls gives them.  For ONE
+    request (prepare_batch's admission rule).
 
-    `picks[i]` is row i's node (an index into `node_ids`).  In use on a
-    node: what its live allocations hold in `snapshot`, and what the
-    ledger's open and not yet visible records carved.  Returns
-    (ids [rows, count] unicode array, {pick: (vendor, type, name)},
-    short): `short` is the picks whose node has too few free instances
-    (a foreign write took them after the kernel looked).  Such a node
-    gives NONE of its rows ids, nothing of it enters the record, and its
-    rows' entries in `ids` are empty strings: the caller drops them."""
+    `picks[i]` is row i's node (an index into `node_ids`), `slots[i]` the
+    slot of that node's groups the kernel charged (its groups in node
+    order, pack/packer.py DEVICE_GROUP_SLOTS).  Without `slots` (a
+    launch that carries no slot state: an engine with a mesh, where only
+    single-group fleets ride) a row takes its node's first group the
+    request accepts.  In use on a node: what its live allocations hold
+    in `snapshot`, and what the ledger's open and not yet visible
+    records carved.  Returns (ids [rows, count] unicode array, groups,
+    short): `groups` maps a row's key, (pick, slot) with `slots` and the
+    pick without, to its group's (vendor, type, name); `short` is the
+    picks whose node has too few free instances in a group charged (a
+    foreign write took them after the kernel looked).  Such a node gives
+    NONE of its rows ids, nothing of it enters the record, and its rows'
+    entries in `ids` are empty strings: the caller drops them."""
     need = max(req.count, 1)
-    uniq, inv = np.unique(picks, return_inverse=True)
-    counts = np.bincount(inv, minlength=len(uniq)).tolist()
-    order = np.argsort(inv, kind="stable")
-    rows_ids: List[Optional[List[str]]] = []
-    groups: Dict[int, tuple] = {}
+    slot_of = slots.tolist() if slots is not None else None
+    rows_of: Dict[int, List[int]] = {}          # node, its rows in order
+    for i, pick in enumerate(picks.tolist()):
+        rows_of.setdefault(pick, []).append(i)
+    row_ids: List[Optional[List[str]]] = [None] * len(picks)
+    groups: Dict[object, tuple] = {}
     short: List[int] = []
     memo: Dict[tuple, bool] = {}
-    for pick, k in zip(uniq.tolist(), counts):
+    for pick, rows in rows_of.items():
         nid = node_ids[pick]
         node = snapshot.node_by_id(nid)
-        dev = next((d for d in (node.resources.devices if node else ())
-                    if group_accepts(d, req, memo)), None)
-        free: List[str] = []
-        if dev is not None:
-            busy = held_instances(snapshot.allocs_by_node(nid))
-            busy |= ledger.held(nid)
-            free = [i for i in dev.instance_ids if i not in busy]
-        if len(free) < k * need:
-            short.append(pick)
-            rows_ids.append(None)
+        devs = node.resources.devices if node else []
+        if slot_of is None:
+            first = next((s for s, d in enumerate(devs)
+                          if group_accepts(d, req, memo)), None)
+            charged = [first] * len(rows)
+        else:
+            charged = [slot_of[i] for i in rows]
+        busy = None
+        takes: Dict[int, List[str]] = {}
+        for s in dict.fromkeys(charged):
+            if s is None or s >= len(devs):
+                break
+            if busy is None:
+                busy = held_instances(snapshot.allocs_by_node(nid))
+                busy |= ledger.held(nid)
+            free = [i for i in devs[s].instance_ids if i not in busy]
+            k = charged.count(s) * need
+            if len(free) < k:
+                break
+            takes[s] = free[:k]
+        else:
+            if len(takes) == 1:
+                # the common case: the node's rows of one group, in order
+                (take,) = takes.values()
+                ledger.note(record, nid, take)
+                for j, i in enumerate(rows):
+                    row_ids[i] = take[j * need:(j + 1) * need]
+            else:
+                ledger.note(record, nid,
+                            [i for t in takes.values() for i in t])
+                pos = [0] * len(devs)
+                for i, s in zip(rows, charged):
+                    row_ids[i] = takes[s][pos[s]:pos[s] + need]
+                    pos[s] += need
+            for s in takes:
+                d = devs[s]
+                groups[(pick, s) if slot_of is not None else pick] = (
+                    d.vendor, d.type, d.name)
             continue
-        take = free[:k * need]
-        ledger.note(record, nid, take)
-        groups[pick] = (dev.vendor, dev.type, dev.name)
-        rows_ids.append(take)
-    flat: List[str] = []
-    for take, k in zip(rows_ids, counts):
-        flat.extend(take if take is not None else [""] * (k * need))
-    by_node = np.asarray(flat, np.str_).reshape(len(picks), need)
-    out = np.empty_like(by_node)
-    out[order] = by_node            # node-major back to the rows' order
-    return out, groups, short
+        short.append(pick)
+    blank = [""] * need
+    return (np.asarray([r if r is not None else blank for r in row_ids],
+                       np.str_).reshape(len(picks), need), groups, short)

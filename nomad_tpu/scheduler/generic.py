@@ -19,7 +19,7 @@ from typing import Dict, List, Optional, Tuple
 
 from nomad_tpu.chaos.clock import SystemClock
 from nomad_tpu.ops import PlacementEngine, PlacementRequest
-from nomad_tpu.ops.engine import BulkDecisions
+from nomad_tpu.ops.engine import DEVICE_GROUP_SLOTS, BulkDecisions
 from nomad_tpu.pack.spread import job_spreads
 from nomad_tpu.structs import (
     Allocation,
@@ -66,10 +66,11 @@ PORT_WAVE_MAX_STATIC = 8
 
 # Batched device path (ISSUE 30): when True, a group whose ONE device
 # request has no affinities, that asks for no ports, on a fleet whose
-# nodes advertise at most one device group each, rides the wave: the
-# kernel accounts free instances as a capacity dimension and
-# `_materialize_bulk` carves the ids per node as columns of the
-# AllocBlock.  False sends every device-asking eval down the solo path
+# nodes advertise at most DEVICE_GROUP_SLOTS device groups each, rides
+# the wave: the kernel accounts free instances a group slot and charges
+# each placement to one group, and `_materialize_bulk` carves the ids
+# from that group as columns of the AllocBlock.  False sends every
+# device-asking eval down the solo path
 # (the exact scan, then `_assign_devices`): the PARITY REFERENCE
 # tests/test_device_batched.py compares against.  A module constant that
 # only tests set; no server option reaches it.
@@ -413,12 +414,16 @@ class GenericScheduler(Scheduler):
         """The admission rule that keeps a device-asking group off the
         wave, or "" when it rides: ONE request, without affinities (and no
         port ask beside it: one host-assigned column set a block), on a
-        fleet where no node advertises more than one device group.  Then
-        "instances in use on the node" is one number whatever the
-        request's name, which the node tensors carry, and the request's
-        name and constraints are a static mask (ops/engine.py
-        device_static_mask); the carve takes the node's first free ids
-        and has no group to choose."""
+        fleet where no node advertises more device groups than the node
+        tensors have slots (ops/engine.py DEVICE_GROUP_SLOTS), and, where
+        a node advertises several, on an engine that is not sharded (the
+        mesh's wave kernel carries no slot state).  Then the instances
+        free in each group a node has are numbers the tensors carry, the
+        request's name and constraints are a static accept table by slot
+        (ops/engine.py device_accept_table), the kernel charges each
+        placement to the first accepted group with room, as
+        `assign_devices` picks one, and the carve takes that group's
+        first free ids."""
         if not DEVICE_BATCHED:
             return "off"
         if len(dev_reqs) != 1:
@@ -428,8 +433,11 @@ class GenericScheduler(Scheduler):
         if asks_ports(tg):
             return "ports"
         engine = self.engine
-        if not engine.single_group_fleet(engine.packer.update(self.state)):
-            return "multi_group_node"
+        most = engine.fleet_device_groups(engine.packer.update(self.state))
+        if most > DEVICE_GROUP_SLOTS:
+            return "slots"
+        if most > 1 and engine.mesh is not None:
+            return "mesh"
         return ""
 
     def submit_batched(self, evaluation: Evaluation, prep, bds,
@@ -1274,7 +1282,9 @@ class GenericScheduler(Scheduler):
                     ports_arr = self._carve_ports_batch(
                         picks_ok, node_ids, net_labels, net_static,
                         net_idx, victim_ids)
-            dev_ids = dev_groups = None
+            dev_ids = dev_groups = dev_group_of = None
+            slots_ok = (None if bd.slots is None
+                        else bd.slots[ok_mask] if n_fail else bd.slots)
             n_short = 0
             if dev_req is not None and n_ok:
                 # (no ports to carve: prepare_batch keeps a group that
@@ -1288,7 +1298,7 @@ class GenericScheduler(Scheduler):
                       else contextlib.nullcontext()):
                     dev_ids, dev_groups, short = carve_block(
                         self.state, ledger, record, dev_req, picks_ok,
-                        node_ids)
+                        node_ids, slots=slots_ok)
                 from nomad_tpu.core.telemetry import REGISTRY
                 REGISTRY.inc("nomad.device.evals_batched")
                 if short:
@@ -1304,10 +1314,20 @@ class GenericScheduler(Scheduler):
                     keep_ok = ~(gone[ok_mask] if n_fail else gone)
                     dev_ids = dev_ids[keep_ok]
                     picks_ok = picks_ok[keep_ok]
+                    if slots_ok is not None:
+                        slots_ok = slots_ok[keep_ok]
                     ok_mask = ok_mask & ~gone
                     n_ok -= n_short
                 REGISTRY.inc("nomad.device.instances_carved",
                              int(dev_ids.size))
+                # the block's table of groups and each row's entry in it
+                keys = (picks_ok.tolist() if slots_ok is None
+                        else zip(picks_ok.tolist(), slots_ok.tolist()))
+                index: Dict[tuple, int] = {}
+                dev_group_of = np.fromiter(
+                    (index.setdefault(dev_groups[k], len(index))
+                     for k in keys), np.int32, len(picks_ok))
+                dev_groups = list(index)
             if not has_net or n_ok == 0 or ports_arr is not None:
                 if n_fail:
                     # aggregate failure accounting: one stored metric
@@ -1363,8 +1383,8 @@ class GenericScheduler(Scheduler):
                                  if ports_arr is not None else []),
                     ports=ports_arr,
                     device_task=dev_task if dev_ids is not None else "",
-                    device_groups=([dev_groups[int(r)] for r in uniq]
-                                   if dev_ids is not None else []),
+                    device_groups=dev_groups or [],
+                    device_group_of=dev_group_of,
                     device_ids=dev_ids,
                 ))
                 return

@@ -143,13 +143,17 @@ class AllocBlock:
     # COLUMNAR device assignment (ISSUE 30), None for a group that asks
     # for no device: device_ids[i] are the instance ids of row i, a
     # [count, k] unicode array with k the request's count, held by task
-    # `device_task`; device_groups[j] is the (vendor, type, name) of the
-    # device group on node_table[j] they were carved from.  The batched
-    # carve in scheduler/generic.py fills these, rows materialize with
-    # their own allocated_devices, and the applier audits them per node
-    # off the array (plan_apply._eval_blocks).
+    # `device_task`, carved from the device group
+    # device_groups[device_group_of[i]], a (vendor, type, name): the
+    # block's table of groups, one entry each, and a group index a row
+    # (two rows on one node may hold instances of two of its groups).
+    # The batched carve in scheduler/generic.py fills these, rows
+    # materialize with their own allocated_devices, and the applier
+    # audits them per node and group off the arrays
+    # (plan_apply._eval_blocks).
     device_task: str = ""
     device_groups: List[tuple] = field(default_factory=list)
+    device_group_of: Optional[np.ndarray] = None
     device_ids: Optional[np.ndarray] = None
     create_index: int = 0
     modify_index: int = 0
@@ -208,22 +212,33 @@ class AllocBlock:
                 pos += c
         return out
 
-    def devices_by_node(self) -> Dict[str, tuple]:
-        """{node_id: (group, [instance id, ...])} claimed by this block's
-        rows, the applier's per-node device audit input: one argsort
-        over the picks, no per-alloc objects."""
+    def devices_by_node(self) -> Dict[str, Dict[tuple, list]]:
+        """{node_id: {group: [instance id, ...]}} claimed by this block's
+        rows, the applier's per-node device audit input: one pass over
+        the rows' columns, no per-alloc objects."""
         if self.device_ids is None or not self.device_ids.size:
             return {}
-        order = np.argsort(self.picks, kind="stable")
-        grouped = self.device_ids[order]
-        out: Dict[str, tuple] = {}
-        pos = 0
-        for nid, group, c in zip(self.node_table, self.device_groups,
-                                 self.node_counts().tolist()):
-            if c:
-                out[nid] = (tuple(group),
-                            grouped[pos:pos + c].ravel().tolist())
-                pos += c
+        groups = [tuple(g) for g in self.device_groups]
+        out: Dict[str, Dict[tuple, list]] = {}
+        for p, gi, ids in zip(self.picks.tolist(),
+                              self.device_group_of.tolist(),
+                              self.device_ids.tolist()):
+            out.setdefault(self.node_table[p], {}).setdefault(
+                groups[gi], []).extend(ids)
+        return out
+
+    def device_use_by_node(self) -> Dict[str, Dict[str, int]]:
+        """{node_id: {group id: instances}} this block's rows hold: what
+        the packer's device group ledger counts."""
+        if self.device_ids is None or not self.device_ids.size:
+            return {}
+        gids = ["/".join(g) for g in self.device_groups]
+        k = self.device_ids.shape[1]
+        out: Dict[str, Dict[str, int]] = {}
+        for p, gi in zip(self.picks.tolist(),
+                         self.device_group_of.tolist()):
+            held = out.setdefault(self.node_table[p], {})
+            held[gids[gi]] = held.get(gids[gi], 0) + k
         return out
 
     def without_nodes(self, bad_node_ids) -> Optional["AllocBlock"]:
@@ -264,8 +279,9 @@ class AllocBlock:
             port_labels=list(self.port_labels),
             ports=self.ports[keep] if self.ports is not None else None,
             device_task=self.device_task,
-            device_groups=[self.device_groups[int(r)] for r in uniq]
-            if self.device_ids is not None else [],
+            device_groups=list(self.device_groups),
+            device_group_of=(self.device_group_of[keep]
+                             if self.device_group_of is not None else None),
             device_ids=(self.device_ids[keep]
                         if self.device_ids is not None else None),
         )
@@ -303,6 +319,8 @@ class AllocBlock:
             drows = (self.device_ids.tolist()
                      if self.device_ids is not None else None)
             dgroups = self.device_groups
+            dgroup_of = (self.device_group_of.tolist()
+                         if drows is not None else None)
             dtask = self.device_task
             rows = []
             alloc_new = Allocation.__new__
@@ -322,7 +340,7 @@ class AllocBlock:
                 if prows is not None:
                     d["allocated_ports"] = dict(zip(plabels, prows[i]))
                 if drows is not None:
-                    vendor, dtype, dname = dgroups[picks[i]]
+                    vendor, dtype, dname = dgroups[dgroup_of[i]]
                     d["allocated_devices"] = [AllocatedDeviceResource(
                         task=dtask, vendor=vendor, type=dtype, name=dname,
                         device_ids=drows[i])]

@@ -1,12 +1,15 @@
-"""ISSUE 30, 5 (d): once, outside any timed window, read GET
-/v1/job/<id>/allocations for ALL the jobs of a cycle of gpu50k-drain at
-the full size and hold the instance ids to the plain reference: every id
-belongs to the node's group, each allocation has exactly its count, no id
-twice on a node.  One JSON line at the end.  On the chip:
+"""Once, outside any timed window, read GET /v1/job/<id>/allocations for
+ALL the jobs of a cycle of a GPU configuration's drain cell (gpu50k or
+mig50k, drain256-purge) at the full size and hold the instance ids to the
+plain reference: each allocation holds exactly its count of distinct ids
+of ONE device group of its node, a group its request accepts by name
+(and, in mig50k, by constraint), every id one of that group's own, no id
+twice on a node.  One JSON line at the end.  On a TPU host, from the
+root of the repository:
 
-    chiprun -- env PYTHONPATH=. python3 scripts/gpu_ids_read.py <seed>
+    env PYTHONPATH=. python3 scripts/gpu_ids_read.py <config> <seed>
 
-(a second argument runs it at the rehearsal size, for the CPU here).  The
+(a third argument runs it at the rehearsal size, for the CPU here).  The
 benchmark's own check cannot do this: `run.py verify` hands a
 configuration's `check` node ids only (PERF.md section 7)."""
 import http.client
@@ -18,21 +21,36 @@ from urllib.parse import urlsplit
 
 from benchmark.loader import load_json, load_module
 
-seed = int(sys.argv[1]) if len(sys.argv) > 1 else 2147930777
-cfg = load_json("configs", "gpu50k")
+config = sys.argv[1] if len(sys.argv) > 1 else "gpu50k"
+seed = int(sys.argv[2]) if len(sys.argv) > 2 else 2147930777
+cfg = load_json("configs", config)
 traffic = load_json("traffic", "drain256-purge")
-mod = load_module("configs", "gpu50k")
-if len(sys.argv) > 2:                  # a CPU dry run at the rehearsal size
+mod = load_module("configs", config)
+rehearse = len(sys.argv) > 3
+if rehearse:                           # a CPU dry run at the rehearsal size
     cfg.update(cfg["rehearse"])
     traffic.update(traffic["rehearse"])
+
+
+def accepted(dev_req: dict, group) -> bool:
+    """The configuration's own plain match of a wire-form request and a
+    node's device group."""
+    if hasattr(mod, "group_accepts"):
+        return mod.group_accepts(
+            (dev_req["Name"], dev_req["Count"], tuple(
+                (c["LTarget"], c["Operand"], c["RTarget"])
+                for c in dev_req.get("Constraints") or ())),
+            (group.id(), len(group.instance_ids),
+             group.attributes.get("memory", "0")))
+    return mod.accepts(dev_req["Name"], group.id())
+
 
 import jax  # noqa: E402
 from nomad_tpu.agent import Agent  # noqa: E402
 
 print("platform", jax.devices()[0].platform, flush=True)
 nodes, fleet = mod.build_fleet(cfg, seed)
-inventory = {n.id: (n.resources.devices[0] if n.resources.devices else None)
-             for n in nodes}
+inventory = {n.id: {d.id(): d for d in n.resources.devices} for n in nodes}
 agent = Agent(num_clients=0, heartbeat_ttl=86400.0, num_workers=1,
               log_level="warn", mesh=False)
 agent.start()
@@ -80,12 +98,13 @@ conn = http.client.HTTPConnection(u.hostname, u.port, timeout=120)
 for job in jobs:
     conn.request("DELETE", f"/v1/job/{job['ID']}?purge=true")
     conn.getresponse().read()
-time.sleep(5 if len(sys.argv) <= 2 else 1)
+time.sleep(1 if rehearse else 5)
 jobs, drain_s, bad = cycle(per_cycle)
 failures = [f"{len(bad)} evals not complete or with a failed group"] \
     if bad else []
 held = {}
 allocs = instances = 0
+by_group = {}
 t0 = time.monotonic()
 for job in jobs:
     (dev_req,) = job["TaskGroups"][0]["Tasks"][0]["Resources"]["Devices"]
@@ -97,22 +116,27 @@ for job in jobs:
     for a in rows:
         allocs += 1
         devs = a.get("AllocatedDevices") or []
-        group = inventory.get(a["NodeID"])
-        if len(devs) != 1 or group is None:
+        groups = inventory.get(a["NodeID"]) or {}
+        if len(devs) != 1 or not groups:
+            # one entry: all of the request's ids name ONE group
             failures.append(f"{a['ID']}: {len(devs)} device entries on "
-                            f"{'a GPU' if group else 'a plain'} node")
+                            f"{'a GPU' if groups else 'a plain'} node")
             continue
         d = devs[0]
         ids = d["DeviceIds"]
         gid = f"{d['Vendor']}/{d['Type']}/{d['Name']}"
-        if gid != group.id() or not mod.accepts(dev_req["Name"], gid):
+        group = groups.get(gid)
+        if group is None or not accepted(dev_req, group):
             failures.append(f"{a['ID']}: group {gid} for request "
-                            f"{dev_req['Name']} on a {group.id()} node")
+                            f"{dev_req['Name']} on a node of {list(groups)}")
+            continue
         if len(ids) != dev_req["Count"] or len(set(ids)) != len(ids):
             failures.append(f"{a['ID']}: {len(ids)} ids for count "
                             f"{dev_req['Count']}")
         if not set(ids) <= set(group.instance_ids):
-            failures.append(f"{a['ID']}: an id the node's group lacks")
+            failures.append(f"{a['ID']}: an id its group lacks")
+        key = "multi_group" if len(groups) > 1 else "single_group"
+        by_group[key] = by_group.get(key, 0) + 1
         seen = held.setdefault(a["NodeID"], set())
         if seen & set(ids):
             failures.append(f"{a['NodeID']}: an instance held twice")
@@ -121,7 +145,8 @@ for job in jobs:
 print(json.dumps({
     "ok": not failures, "failures": failures[:5], "jobs": len(jobs),
     "allocations": allocs, "instances": instances,
-    "nodes_touched": len(held), "drain_s": round(drain_s, 4),
+    "nodes_touched": len(held), "allocations_by_node": by_group,
+    "config": config, "seed": seed, "drain_s": round(drain_s, 4),
     "read_s": round(time.monotonic() - t0, 2),
     "platform": jax.devices()[0].platform}), flush=True)
 agent.shutdown()

@@ -20,6 +20,7 @@ import pytest
 from nomad_tpu import mock
 from nomad_tpu.core.server import Server
 from nomad_tpu.core.telemetry import REGISTRY
+from nomad_tpu.ops.engine import DEVICE_GROUP_SLOTS
 from nomad_tpu.scheduler import generic
 from nomad_tpu.scheduler.device import (CarveLedger, carve_block,
                                         held_instances)
@@ -404,19 +405,20 @@ def with_affinity(tag):
             weight=50)])])
 
 
-@pytest.mark.parametrize("rule, make_job, two_group_node", [
-    ("requests", two_requests, False),
-    ("affinity", with_affinity, False),
-    ("multi_group_node", lambda tag: gpu_job(tag, 0, ANY, 1, 1), True),
-    ("off", lambda tag: gpu_job(tag, 0, ANY, 1, 1), False),
+@pytest.mark.parametrize("rule, make_job, more_groups", [
+    ("requests", two_requests, 0),
+    ("affinity", with_affinity, 0),
+    # one node of more device groups than the tensors have slots
+    ("slots", lambda tag: gpu_job(tag, 0, ANY, 1, 1), DEVICE_GROUP_SLOTS),
+    ("off", lambda tag: gpu_job(tag, 0, ANY, 1, 1), 0),
 ])
 def test_what_the_rule_refuses_takes_the_solo_path(rule, make_job,
-                                                   two_group_node,
+                                                   more_groups,
                                                    monkeypatch):
     monkeypatch.setattr(generic, "DEVICE_BATCHED", rule != "off")
     nodes = fleet(40, 23)
-    if two_group_node:
-        nodes[3].resources.devices.append(group(T4, 2, "extra"))
+    for k in range(more_groups):
+        nodes[3].resources.devices.append(group(T4, 2, f"extra{k}"))
     odd = make_job(f"fb-{rule}")
     mate = gpu_job(f"fb-{rule}", 1, ANY, 1, 1)
     before = REGISTRY.counter("nomad.device.evals_solo", rule=rule)
@@ -434,6 +436,24 @@ def test_what_the_rule_refuses_takes_the_solo_path(rule, make_job,
         ok, dim, _ = allocs_fit(node, snap.allocs_by_node(node.id),
                                 check_devices=True)
         assert ok, dim
+
+
+def test_a_two_group_fleet_rides_the_wave(monkeypatch):
+    """A node that advertises two device groups no longer sends the
+    fleet's device asks solo: every eval rides a wave, each allocation
+    holds instances of ONE group its request accepts."""
+    monkeypatch.setattr(generic, "DEVICE_BATCHED", True)
+    nodes = fleet(40, 23)
+    nodes[3].resources.devices.append(group(T4, 2, "extra"))
+    jobs = mixed_jobs("two", 8, 1)
+    solo0 = counter("nomad.device.evals_solo")
+    batched0 = counter("nomad.device.evals_batched")
+    s = cluster(nodes)
+    wave(s, jobs, "two")
+    assert counter("nomad.device.evals_solo") == solo0
+    assert counter("nomad.device.evals_batched") - batched0 == len(jobs)
+    assert reference_failures(nodes, jobs, placements(s, jobs)) == []
+    assert s.plan_applier.stats["plans_refuted"] == 0
 
 
 # ------------------------------------------------- the engine's static mask

@@ -677,13 +677,15 @@ def test_benchmark_lists_the_cell_and_its_metrics():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
-    assert list(cells)[-1] == CELL and cells[CELL]["chips"] == 1
+    # the cell and configuration after it came with a later PR
+    # (mig50k-drain)
+    assert list(cells)[-2] == CELL and cells[CELL]["chips"] == 1
     assert cells[CELL] == {k: load_json("workloads", CELL)[k] for k in (
         "name", "config", "traffic", "chips", "why")}
-    assert bench["configs"][-1]["name"] == "groups50k"
+    assert bench["configs"][-2]["name"] == "groups50k"
     solo = next(m for m in bench["end_to_end"]
                 if m["name"] == "solo_placed_per_s")
-    assert solo["workloads"][-1] == CELL and solo["bound"] == 0.2
+    assert solo["workloads"][-2] == CELL and solo["bound"] == 0.2
     mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
     assert len(mine) == 13 and [m["name"] for m in mine] == load_json(
         "workloads", CELL)["per_layer"]

@@ -214,3 +214,41 @@ def test_the_kernel_compiles_for_a_v5e_within_its_budget(one_chip, n, p,
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("slots", [1, 4])
+def test_the_flat_kernels_slot_rule_compiles_for_a_v5e(one_chip, slots):
+    """The flat multi-eval kernel with device group slots (the S = 1
+    form of a single-group fleet, and all of `DEVICE_GROUP_SLOTS`), as
+    the engine launches a 64-eval wave of one round each: the TPU's
+    compiler takes it, and its answer holds a round's charges by slot
+    after the meta block."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from nomad_tpu.ops.engine import DEVICE_GROUP_SLOTS
+    n, g, r, s = 5000, 64, 64, slots
+    assert s in (1, DEVICE_GROUP_SLOTS)
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    i32, b = jnp.int32, jnp.bool_
+    inp = select.MultiEvalInputs(
+        attrs=sd((n, 12), i32), cap=sd((n, 4), i32), used0=sd((n, 4), i32),
+        elig=sd((n,), b), luts=sd((2, 64), b), base_mask=sd((2, n), b),
+        con=sd((2, 2, 3), i32), u_mask=sd((2,), i32),
+        aff=sd((1, 1, 4), i32), req=sd((g, 4), i32), desired=sd((g,), i32),
+        dh_limit=sd((g,), i32), g_static=sd((g,), i32),
+        g_aff=sd((g,), i32), g_job=sd((g,), i32),
+        job_count0=sd((g, n), i32), spread_algo=sd((), b),
+        round_g=sd((r,), i32), round_want=sd((r,), i32),
+        seed=sd((g,), jnp.uint32), dev_cap=sd((s, n), i32),
+        dev_used0=sd((s, n), i32), dev_accept=sd((8, s, n), b),
+        g_dev=sd((g,), i32))
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = select.place_multi_packed_jit.lower(inp, 64).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    buf, used, _, dev_used = compiled.out_info
+    assert buf.shape == (r, 64 + 16 + 64 * s)
+    assert used.shape == (n, 4) and dev_used.shape == (s, n)
